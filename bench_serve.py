@@ -2394,7 +2394,7 @@ def main(argv=None) -> int:
     from glom_tpu.telemetry.sinks import bench_bootstrap, emit
 
     if not bench_bootstrap("serve_p95_latency", "ms"):
-        return 0
+        return 1
 
     import dataclasses
 
@@ -2414,8 +2414,8 @@ def main(argv=None) -> int:
         load_fracs = (0.25, 0.5, 0.8)
         ceiling_repeats = 5
     else:
-        # CPU fallback: the labelled small config — live numbers for the
-        # harness/CI, never a dead zero for the trajectory. The budget is
+        # The caller asked for the CPU: the labelled small config, a
+        # functional drive of the harness for CI. The budget is
         # raised past the config's 2L default so the two-tier A/B's easy
         # requests have room to converge inside it (~budget-6 at
         # threshold 1e-3; hard 100x requests land near the budget).
@@ -2429,8 +2429,9 @@ def main(argv=None) -> int:
         load_fracs = (0.5,)
         ceiling_repeats = 2
         emit(
-            {"note": "TPU backend unavailable; measuring the labelled "
-             "cpu-fallback serve config instead of recording a dead zero"},
+            {"note": "JAX_PLATFORMS=cpu functional drive at the labelled "
+             "cpu-fallback serve config: a harness check, not a device "
+             "measurement"},
             kind="note",
         )
     overrides = {}

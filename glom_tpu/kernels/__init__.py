@@ -1,7 +1,3 @@
-from glom_tpu.utils.compat import install_pallas_tpu_compat
-
-install_pallas_tpu_compat()  # pltpu.CompilerParams name on old jax
-
 from glom_tpu.kernels.banded_consensus import banded_ragged_consensus
 from glom_tpu.kernels.grouped_mlp import fused_grouped_ffw, fused_grouped_ffw_lm
 from glom_tpu.kernels.consensus_update import fused_consensus_update

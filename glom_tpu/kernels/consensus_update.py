@@ -1227,25 +1227,29 @@ def _xla_reference(levels_lm, bu_lm, td_lm, *, side, radius, attend_self):
     return new.astype(levels_lm.dtype)
 
 
-# Fallback dense sim-buffer cap when the runtime reports no memory stats
-# (CPU interpret tests): the conservative round-3 constant.
+# Dense sim-buffer cap where the backend has no allocator stats (the CPU:
+# memory_stats() is None there).
 _DENSE_SIM_LIMIT = 2 * 1024 * 1024 * 1024
 
 
 def _dense_bwd_budget() -> int:
     """HBM budget for the dense backward's [L*B, n, n] f32 intermediates,
     derived from the device's reported capacity rather than a constant
-    (round-3 weak item: the 2GB cap forced blockwise at shapes whose dense
-    buffers demonstrably fit a 16GB chip). A 0.3 fraction leaves the rest
-    for params/opt state, residual stacks, and XLA workspace — batch-aware
-    because the caller multiplies by the actual [L, B, n, n] bytes."""
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        lim = int(stats.get("bytes_limit", 0))
-        if lim > 0:
-            return int(0.3 * lim)
-    except Exception:  # noqa: BLE001 - platform without memory stats
-        pass
+    (the 2GB cap forced blockwise at shapes whose dense buffers
+    demonstrably fit a 16GB chip; a v5e reports bytes_limit
+    16,909,336,064). A 0.3 fraction leaves the rest for params/opt state,
+    residual stacks, and XLA workspace — batch-aware because the caller
+    multiplies by the actual [L, B, n, n] bytes. A TPU that reports no
+    limit is an error, not a reason to guess."""
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return int(0.3 * stats["bytes_limit"])
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev.device_kind} reports no bytes_limit in memory_stats(); "
+            "the dense-backward budget cannot be derived"
+        )
     return _DENSE_SIM_LIMIT
 
 

@@ -1,6 +1,6 @@
 """Hand-rolled VJP over the WHOLE T-iteration GLOM loop.
 
-Why this exists (measured, results/profiles/PROFILE.md round 3): with the
+Why this exists (measured on a v5e profile in round 3): with the
 per-op custom_vjps the train step was ~86% Pallas kernels, and the
 remaining ~6% device time was XLA glue BETWEEN them that op-local autodiff
 cannot remove:
@@ -267,10 +267,9 @@ def _grid_mode() -> str:
     configuration. 'combined' (GLOM_LOOP_GRID=combined): ONE call over all
     2L-1 groups (td groups 0..L-2, bu groups L-1..2L-2), killing a kernel
     boundary per phase per iteration and giving Mosaic a single larger
-    grid to overlap dw flushes across — VERDICT r4 item #5's 'fuse the
-    bu/td backward grids'. Values are bit-identical (same per-group math,
-    same accumulation order); promote to default only after the hardware
-    A/B (scratch/ffw_bwd_sched_probe.py) measures >= split. A mid-session
+    grid to overlap dw flushes across. Values are bit-identical (same
+    per-group math, same accumulation order); promote to default only
+    after an A/B on the chip measures >= split. A mid-session
     env flip between a forward and its cached backward cannot corrupt
     results: the residual tuple LENGTH encodes the layout (4 = combined,
     5 = split, 3 = remat, whose recompute is layout-agnostic)."""

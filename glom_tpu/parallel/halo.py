@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from glom_tpu.parallel.ring import _block_sim_masks
-from glom_tpu.utils.compat import axis_size, shard_map
 from glom_tpu.utils.helpers import halo_supported, l2norm
 
 
@@ -42,7 +41,7 @@ def halo_consensus_shard(
 ) -> jnp.ndarray:
     """Per-shard body (under shard_map; n sharded over `axis_name` in
     row-major row bands). x: [b, n_loc, L, d] -> [b, n_loc, L, d]."""
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, n_loc, L, d = x.shape
     n_total = n_loc * S
@@ -130,7 +129,7 @@ def make_halo_consensus(
         side=side,
         radius=radius,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(None, axis_name, None, None),

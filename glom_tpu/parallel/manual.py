@@ -1,6 +1,6 @@
 """Fully-manual SPMD training path: Pallas kernels composed with DP x SP.
 
-Round-1 limitation (VERDICT weak #6): the fused Pallas kernels were illegal
+Round-1 limitation (review item, weak #6): the fused Pallas kernels were illegal
 inside GSPMD-sharded regions (custom calls carry no partitioning rule), so
 `use_pallas` evaporated exactly where perf matters — the distributed
 configs. The TPU-native fix is NOT a partitioning rule per kernel but this
@@ -30,7 +30,8 @@ work. Collectives are explicit and minimal:
            reconstructs the full FFW result. b2 is added in-kernel scaled
            by 1/mp so the psum reconstructs it exactly (mp is a power of
            two, so the scale is exact in bf16). Gradient correctness under
-           check_vma=False was established empirically (scratch/tp_proto.py):
+           check_vma=False is locked by
+           tests/test_manual.py::test_manual_tp_grads_match_dense:
            a RAW lax.psum composes correctly with the shard_map transpose —
            partial dx cotangents get psum'd over 'model', sharded-weight
            cotangents stay local, replicated-param cotangents come out
@@ -62,7 +63,6 @@ from glom_tpu.telemetry import diagnostics as diag
 from glom_tpu.train.objectives import DenoiseParams, default_recon_index
 from glom_tpu.train.trainer import TrainState, pinned_grad_accum
 from glom_tpu.utils.config import GlomConfig, TrainConfig
-from glom_tpu.utils.compat import array_vma, pcast_varying, shard_map
 
 DATA_AXIS = "data"
 SEQ_AXIS = "seq"
@@ -175,8 +175,8 @@ def _forward_local(
         # kernel output is a partial sum over f, completed by one psum.
         # b2 is added in-kernel, so scale it 1/mp (exact: mp is a power of
         # two) and let the psum reconstruct it. Raw psum composes correctly
-        # with the shard_map transpose under check_vma=False — verified in
-        # scratch/tp_proto.py (variant D) against dense-reference grads.
+        # with the shard_map transpose under check_vma=False — verified
+        # against dense-reference grads (tests/test_manual.py).
         inner_ffw, inv_mp = ffw_lm, 1.0 / mp
 
         def ffw_lm(p, x):
@@ -244,9 +244,9 @@ def _forward_local(
         # ring.py). Under check_vma=False the vma set is empty and pcast
         # must not run. (A carried-in levels0 is already sharded input —
         # already varying — and must NOT be pcast.)
-        vma = array_vma(tokens_loc)
+        vma = tuple(jax.typeof(tokens_loc).vma)
         if vma:
-            levels_lm = pcast_varying(levels_lm, vma)
+            levels_lm = lax.pcast(levels_lm, vma, to="varying")
     divisor_lm = contribution_divisor(L, jnp.float32).reshape(L, 1, 1, 1)
 
     # seq=1 / mp=1 shards with an admissible local shape take the
@@ -443,7 +443,7 @@ def make_manual_loss(
 
     batch_spec = P(DATA_AXIS)  # [b, c, H, W]; replicated over seq (sliced in-body)
     param_spec = _manual_param_spec(mp)
-    return shard_map(
+    return jax.shard_map(
         loss_body,
         mesh=mesh,
         in_specs=(param_spec, batch_spec, batch_spec),
@@ -477,7 +477,7 @@ def make_manual_forward(
     (final [b, n, L, d], or all T+1 states with return_all) as one
     shard_map over (data, seq, model) — the path `Glom(mesh=...)` uses so
     the preserved API reaches the Pallas kernels under a mesh (round-2
-    VERDICT weak #5: training got the manual fused region, inference
+    Review item (weak #5): training got the manual fused region, inference
     didn't). with_levels=True compiles the temporal variant taking a
     [b, n, L, d] carried-in state sharded (data, seq)."""
     seq = mesh.shape[SEQ_AXIS]
@@ -532,14 +532,14 @@ def make_manual_forward(
     out_spec = P(None, DATA_AXIS, SEQ_AXIS) if return_all else lv_spec
 
     if with_levels:
-        return shard_map(
+        return jax.shard_map(
             fwd_body,
             mesh=mesh,
             in_specs=(param_spec, batch_spec, lv_spec),
             out_specs=out_spec,
             check_vma=False,
         )
-    return shard_map(
+    return jax.shard_map(
         lambda p, img: fwd_body(p, img, None),
         mesh=mesh,
         in_specs=(param_spec, batch_spec),
@@ -914,7 +914,7 @@ def make_manual_zero_train_step(
             metric_keys.append("skipped_nonfinite")
         if probe_quant:
             metric_keys.append("quant_rel_err")
-    update_sm = shard_map(
+    update_sm = jax.shard_map(
         update_body,
         mesh=mesh,
         in_specs=(param_spec, opt_pspecs, batch_spec, batch_spec),

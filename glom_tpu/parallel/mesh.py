@@ -32,7 +32,8 @@ def make_mesh(cfg: MeshConfig, devices: Optional[list] = None) -> Mesh:
 
     Uses mesh_utils.create_device_mesh on real TPU slices so mesh axes map
     contiguously onto the ICI torus (nearest-neighbor collectives stay on
-    ICI links); falls back to a simple reshape for CPU/virtual devices.
+    ICI links); CPU/virtual devices take a simple reshape (as does a TPU
+    device list create_device_mesh refuses, with a warning).
     """
     devices = devices if devices is not None else jax.devices()
     n = cfg.num_devices
@@ -46,7 +47,17 @@ def make_mesh(cfg: MeshConfig, devices: Optional[list] = None) -> Mesh:
     if devices[0].platform == "tpu":
         try:
             dev_array = mesh_utils.create_device_mesh(cfg.shape, devices=devices)
-        except (ValueError, AssertionError):
+        except (ValueError, AssertionError) as e:
+            # Enumeration order need not follow the torus: after a plain
+            # reshape a mesh axis may hop across ICI links. Never silent
+            # on real hardware.
+            warnings.warn(
+                f"create_device_mesh failed for mesh {cfg.shape} over "
+                f"{len(devices)} TPU devices ({e}); falling back to a "
+                "reshape of the device list — mesh axes may not be "
+                "ICI-contiguous",
+                stacklevel=2,
+            )
             dev_array = np.asarray(devices).reshape(cfg.shape)
     else:
         dev_array = np.asarray(devices).reshape(cfg.shape)

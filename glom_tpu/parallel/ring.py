@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from glom_tpu.utils.compat import array_vma, axis_size, pcast_varying, shard_map
 from glom_tpu.utils.helpers import TOKEN_ATTEND_SELF_VALUE, l2norm
 
 NEG_MAX = -jnp.finfo(jnp.float32).max
@@ -79,7 +78,7 @@ def ring_consensus_shard(
 
     x: [b, n_loc, L, d] local block -> [b, n_loc, L, d].
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, n_loc, L, d = x.shape
     n_total = n_loc * S
@@ -96,10 +95,11 @@ def ring_consensus_shard(
     # types line up (JAX vma tracking under shard_map). Match x's varying
     # axes, not just the ring axis — this body may run inside a larger
     # manual region (e.g. parallel.manual's (data, seq) shard_map).
-    vma = array_vma(x)
+    vma = tuple(jax.typeof(x).vma)
 
     def varying(t):
-        return pcast_varying(t, vma)
+        # check_vma=False regions carry an empty vma: nothing to align.
+        return lax.pcast(t, vma, to="varying") if vma else t
 
     m0 = varying(jnp.full((b, L, n_loc, 1), NEG_MAX, jnp.float32))
     s0 = varying(jnp.zeros((b, L, n_loc, 1), jnp.float32))
@@ -161,7 +161,7 @@ def make_ring_consensus(
         side=side,
         radius=radius,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(None, axis_name, None, None),

@@ -300,16 +300,6 @@ class DistributedTrainer:
         # (Megatron psum hand-written in the manual body). Only the
         # EP-style 'levels' TP stays GSPMD-only.
         self.use_manual = bool(tcfg.use_pallas)
-        from glom_tpu.utils.compat import HAS_PARTIAL_MANUAL
-
-        if not self.use_manual and mesh_cfg.seq > 1 and not HAS_PARTIAL_MANUAL:
-            # Old-jax fallback: the GSPMD step would nest a partial-manual
-            # consensus shard_map (manual 'seq', auto 'data'/'model'),
-            # which that jax line cannot partition (see compat.py). The
-            # fully-manual region runs the identical per-shard bodies with
-            # every collective explicit, so SP configs route there; with
-            # use_pallas=False it composes the plain-XLA ops.
-            self.use_manual = True
         if self.use_manual and not manual_supported(self.mesh, tp_axis):
             warnings.warn(
                 "use_pallas=True with tp_axis='levels': the manual fused path "

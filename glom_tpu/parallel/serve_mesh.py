@@ -49,7 +49,6 @@ from glom_tpu.ops.patch import image_to_tokens
 from glom_tpu.parallel.manual import shard_consensus_fn
 from glom_tpu.parallel.mesh import make_mesh
 from glom_tpu.telemetry import counters as tele_counters
-from glom_tpu.utils.compat import shard_map
 from glom_tpu.utils.config import GlomConfig, MeshConfig, ServeConfig
 
 # Module-level axis constants (the *_AXIS vocabulary glom-lint's
@@ -453,7 +452,7 @@ def make_serve_forward(
             )
             return body_fn(glom_params, img, mask, lv_loc)
 
-        return shard_map(
+        return jax.shard_map(
             paged_body,
             mesh=mesh,
             in_specs=(P(), batch_spec, batch_spec, P(DATA_AXIS), P()),
@@ -461,14 +460,14 @@ def make_serve_forward(
             check_vma=False,
         )
     if warm:
-        return shard_map(
+        return jax.shard_map(
             body_fn,
             mesh=mesh,
             in_specs=(P(), batch_spec, batch_spec, lv_spec),
             out_specs=out_specs,
             check_vma=False,
         )
-    return shard_map(
+    return jax.shard_map(
         lambda p, img, mask: body_fn(p, img, mask, None),
         mesh=mesh,
         in_specs=(P(), batch_spec, batch_spec),

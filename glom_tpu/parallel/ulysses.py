@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 from jax import lax
 
-from glom_tpu.utils.compat import axis_size, shard_map
 from glom_tpu.ops.consensus import consensus_attention
 
 
@@ -41,7 +40,7 @@ def ulysses_consensus_shard(
     (round-4 weak #5: the old local_mask= plumbing reintroduced the
     reference's O(n^2) init cost, reference :42-52, on this path).
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     L = x.shape[2]
     if L % S != 0:
         raise ValueError(f"Ulysses needs levels ({L}) divisible by mesh axis ({S})")
@@ -71,7 +70,7 @@ def make_ulysses_consensus(
         side=side,
         radius=radius,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(None, axis_name, None, None),

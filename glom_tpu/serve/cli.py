@@ -324,6 +324,17 @@ def parse_ramp(spec: str):
     return phases
 
 
+def engine_device(i: int):
+    """The device single-device engine replica `i` is pinned to: replicas
+    deal round-robin over this process's devices, so `--engines 4` on a
+    four-chip host is four one-chip replicas, each on its own chip (a
+    chip belongs to one process; one process drives them all)."""
+    import jax
+
+    devices = jax.local_devices()
+    return devices[i % len(devices)]
+
+
 def _req_source(args) -> Iterable[Tuple[object, int, object]]:
     """(request id, seed, session id) triples from --synthetic or
     --requests. Synthetic with --streams S deals requests round-robin
@@ -349,6 +360,9 @@ def _req_source(args) -> Iterable[Tuple[object, int, object]]:
 
 
 def main(argv=None) -> int:
+    from glom_tpu.utils.startup import enable_compile_cache
+
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
     n_sources = sum(
         x is not None
@@ -483,7 +497,8 @@ def main(argv=None) -> int:
         # One params init shared by every engine replica (fan-out serves
         # ONE model), one engine per replica. A serve mesh partitions the
         # device pool into one contiguous group per engine
-        # (parallel/runtime.make_engine_meshes).
+        # (parallel/runtime.make_engine_meshes); single-device replicas
+        # are pinned one per device (engine_device).
         import jax
 
         from glom_tpu.models.core import init_glom
@@ -529,7 +544,9 @@ def main(argv=None) -> int:
             engines.append(
                 InferenceEngine(
                     cfg, scfg, params=params, writer=writer,
-                    mesh=meshes[i], name=f"engine{i}", fault_hook=hook,
+                    mesh=meshes[i],
+                    device=engine_device(i) if meshes[i] is None else None,
+                    name=f"engine{i}", fault_hook=hook,
                 )
             )
         degraded_iters = None
@@ -640,7 +657,9 @@ def main(argv=None) -> int:
                         mesh = engine_mesh_for(scfg, i)
                     eng = InferenceEngine(
                         cfg, scfg, params=params, writer=writer,
-                        mesh=mesh, name=f"engine{i}",
+                        mesh=mesh,
+                        device=engine_device(i) if mesh is None else None,
+                        name=f"engine{i}",
                     )
                     spawn_seq[0] += 1
                     return eng

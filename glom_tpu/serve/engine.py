@@ -96,6 +96,14 @@ class RaggedServeResult(NamedTuple):
     phases: Optional[dict] = None
 
 
+def mosaic_calls(compiled) -> int:
+    """Mosaic (Pallas TPU) custom calls in a compiled program's text —
+    what the warmup event reports next to `use_pallas`, which only echoes
+    the config flag: off-TPU or at a shape a kernel does not take the
+    dispatch gives way to XLA and this reads 0."""
+    return compiled.as_text().count("tpu_custom_call")
+
+
 def _resolve_donate(donate: Optional[bool]) -> bool:
     if donate is not None:
         return donate
@@ -112,6 +120,10 @@ class InferenceEngine:
     `warmup`/first-miss compilation is serialized by the GIL + dict
     memoization. `name` labels this engine's records in multi-engine
     fan-out deployments (one engine per replica behind one batcher).
+    `device` pins a single-device engine — params, page pool, staged
+    inputs and compiled programs — to that device, so one process runs
+    one replica per chip; None leaves everything on JAX's default device.
+    A serve mesh places the engine instead and excludes `device`.
     """
 
     def __init__(
@@ -125,6 +137,7 @@ class InferenceEngine:
         retry=None,
         fault_hook=None,
         mesh=None,
+        device=None,
         name: str = "engine0",
     ):
         self.cfg = cfg
@@ -133,7 +146,6 @@ class InferenceEngine:
         if params is None:
             key = key if key is not None else jax.random.PRNGKey(0)
             params = init_glom(key, cfg)
-        self.params = params
         self.writer = writer
         self._donate = _resolve_donate(scfg.donate)
         self._compute_dtype = (
@@ -146,6 +158,25 @@ class InferenceEngine:
 
             mesh = make_serve_mesh(scfg)
         self.mesh = mesh
+        # Params live where the programs run: on the pinned device, or
+        # replicated over the serve mesh (the sharded signatures'
+        # in_sharding) — params left on the default device would be
+        # re-transferred by every dispatch.
+        self._device_sharding = None
+        if device is not None:
+            if mesh is not None:
+                raise ValueError(
+                    "device pins a single-device engine; a serve mesh "
+                    "places the engine itself"
+                )
+            self._device_sharding = jax.sharding.SingleDeviceSharding(device)
+            params = jax.device_put(params, self._device_sharding)
+        elif mesh is not None:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+
+            params = jax.device_put(params, NamedSharding(mesh, P()))
+        self.params = params
         if mesh is not None and cfg.num_patches % scfg.mesh_seq != 0:
             raise ValueError(
                 f"patches {cfg.num_patches} not divisible by "
@@ -213,7 +244,7 @@ class InferenceEngine:
         # (parallel/serve_mesh.py).
         from glom_tpu.serve.paged_columns import resolve_page_pool
 
-        pool_sharding = None
+        pool_sharding = self._device_sharding
         if mesh is not None and getattr(scfg, "page_pool_pages", 0) > 0:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
@@ -735,7 +766,7 @@ class InferenceEngine:
         fn = self._build_fn(
             bucket, iters_override, auto_budget=auto_budget, warm=warm
         )
-        jit_kw = {"donate_argnums": donate}
+        jit_kw = {"donate_argnums": donate, **self._pinned_jit_kw()}
         if self.mesh is not None:
             in_sh, out_sh = self._serve_shardings(warm)
             jit_kw.update(in_shardings=in_sh, out_shardings=out_sh)
@@ -781,6 +812,8 @@ class InferenceEngine:
                 "degraded": iters_override is not None,
                 "sharded": self.mesh is not None,
                 "use_pallas": self.scfg.use_pallas,
+                "mosaic_calls": mosaic_calls(compiled),
+                "devices": self._param_devices(),
                 "compile_time_s": round(dt, 4),
             }
         )
@@ -848,9 +881,9 @@ class InferenceEngine:
             iters_override, auto_budget=auto_budget, cont=cont
         )
         t0 = time.perf_counter()
-        compiled = jax.jit(fn, donate_argnums=donate).lower(
-            *abstract
-        ).compile()
+        compiled = jax.jit(
+            fn, donate_argnums=donate, **self._pinned_jit_kw()
+        ).lower(*abstract).compile()
         dt = time.perf_counter() - t0
         self._compiled[sig] = compiled
         self._stats.setdefault(sig, StepTimeStats()).observe(
@@ -865,6 +898,8 @@ class InferenceEngine:
                 "degraded": iters_override is not None,
                 "sharded": False,
                 "use_pallas": self.scfg.use_pallas,
+                "mosaic_calls": mosaic_calls(compiled),
+                "devices": self._param_devices(),
                 "compile_time_s": round(dt, 4),
             }
         )
@@ -928,12 +963,30 @@ class InferenceEngine:
             )
         return self._shardings[warm]
 
+    def _param_devices(self) -> list:
+        """Ids of the devices the params actually live on — the warmup
+        event's witness of where this engine runs."""
+        return sorted(d.id for d in self.params.pos_emb.devices())
+
+    def _pinned_jit_kw(self) -> dict:
+        """in/out shardings that compile a program FOR the pinned device
+        (AOT lowering from abstract shapes would otherwise target the
+        default one); empty when unpinned."""
+        if self._device_sharding is None:
+            return {}
+        return {
+            "in_shardings": self._device_sharding,
+            "out_shardings": self._device_sharding,
+        }
+
     def _device_input(self, src, sharding_spec=None):
         """One fresh device buffer per attempt (donation invalidates the
         previous one). On the sharded route the host array device_puts
-        straight into its NamedSharding; single-device keeps the plain
-        transfer."""
-        if self.mesh is not None and sharding_spec is not None:
+        straight into its NamedSharding, on a pinned engine onto its
+        device; otherwise the plain transfer."""
+        if sharding_spec is None:
+            sharding_spec = self._device_sharding
+        if sharding_spec is not None:
             return jax.device_put(np.asarray(src), sharding_spec)
         return jnp.asarray(src)
 
@@ -1077,22 +1130,15 @@ class InferenceEngine:
             else:
                 make_levels = None
         mask_host = np.arange(b) < n_valid
-        mask = (
-            jax.device_put(mask_host, mask_sh)
-            if mask_sh is not None
-            else jnp.asarray(mask_host)
-        )
+        mask = self._device_input(mask_host, mask_sh)
         if warm in ("paged", "paged-inc"):
             # The whole point: the warm state stays device-resident —
             # only the tiny int32 page map (plus, on the incremental
             # route, the bool support map) crosses the host boundary.
-            pidx_dev = (
-                jax.device_put(page_rows, pidx_sh)
-                if pidx_sh is not None
-                else jnp.asarray(page_rows)
-            )
+            pidx_dev = self._device_input(page_rows, pidx_sh)
             supp_dev = (
-                jnp.asarray(support_rows) if warm == "paged-inc" else None
+                self._device_input(support_rows)
+                if warm == "paged-inc" else None
             )
         sig = self.signature(
             b, iters_override, auto_budget=auto_budget, warm=warm
@@ -1315,7 +1361,7 @@ class InferenceEngine:
             P, iters_override, auto_budget=auto_budget, cont=cont
         )
         stats = self._stats.setdefault(sig, StepTimeStats())
-        n_dev = jnp.asarray(n_host)
+        n_dev = self._device_input(n_host)
         attempts = [0]
         split = self.phase_split
         ph = {"h2d_s": 0.0, "resolve_s": 0.0}
@@ -1332,12 +1378,12 @@ class InferenceEngine:
                     }
                 )
             t_h = time.perf_counter()
-            staged = jnp.asarray(patches)
+            staged = self._device_input(patches)
             args = (self.params, staged, n_dev)
             pinned = False
             try:
                 if cont:
-                    lv_staged = jnp.asarray(lv_host.astype(lv_dtype))
+                    lv_staged = self._device_input(lv_host.astype(lv_dtype))
                     levels0_h2d[0] += lv_staged.nbytes
                     args = args + (lv_staged,)
                 elif self.pool is not None:
@@ -1346,7 +1392,8 @@ class InferenceEngine:
                     # donation of the buffer this program reads (a CoW
                     # pool is unaffected — the pin is a free counter).
                     args = args + (
-                        self.pool.acquire_read(), jnp.asarray(pidx_host)
+                        self.pool.acquire_read(),
+                        self._device_input(pidx_host),
                     )
                     pinned = True
                 if split:

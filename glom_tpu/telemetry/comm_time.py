@@ -247,7 +247,6 @@ class CollectiveTimeSampler:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from glom_tpu.utils.compat import shard_map
 
         collective = site["collective"]
         axis = site["axis"]
@@ -274,7 +273,7 @@ class CollectiveTimeSampler:
             raise ValueError(f"unknown collective {collective!r}")
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=self.mesh, in_specs=(), out_specs=P(),
                 check_vma=False,
             )

@@ -18,13 +18,13 @@ Two host-side pieces that complete the telemetry loop:
     record, so driver-parsed bench lines, trainer JSONL, and hw-queue rows
     are one schema (`python -m glom_tpu.telemetry.schema` lints them all).
 
-  * bench_bootstrap() — the shared fail-fast gate every bench entrypoint
-    runs before touching a backend: probe through the watchdog (throwaway
-    subprocess — a wedged plugin HANGS in-process init), register it
-    globally so every subsequent record stamps backend_state, fall back to
-    CPU when the default platform is down, and when even CPU cannot
-    initialize emit ONE schema-v2 "error" record with `value: null` —
-    never the round-5 dead zero the trajectory tooling then ingested.
+  * bench_bootstrap() — the shared gate every bench entrypoint runs
+    first: place the compile cache, probe the backend through the
+    watchdog and register it globally so every subsequent record stamps
+    backend_state. The platform is the one the caller's environment gave
+    JAX; when that is not a measurable one the gate emits ONE "error"
+    record with `value: null` (never a zero the trajectory tooling would
+    ingest) and the caller exits non-zero.
 """
 
 from __future__ import annotations
@@ -117,43 +117,53 @@ def bench_bootstrap(
     *,
     probe_timeout: float = 120.0,
 ) -> bool:
-    """Fail-fast backend gate for bench entrypoints. Returns True when a
-    backend (the default platform, or the CPU fallback it downgrades to)
-    is measurable; on total failure emits the UNMEASURED record — kind
-    "error", `value: null` (NEVER 0.0: round 5's zero rows polluted the
-    bench trajectory, and `python -m glom_tpu.telemetry compare` treats
-    these as missing) with the full watchdog outage timeline — and returns
-    False. The watchdog stays registered either way, so every line the
-    bench then emits carries the backend state."""
-    import os
-
+    """Gate for bench entrypoints. Returns True when the backend answers
+    and is one a bench may run on: a TPU, or the CPU when the caller
+    asked for it by name (JAX_PLATFORMS=cpu — CI's functional drives;
+    their rows carry no MFU). Otherwise — backend down, or the CPU JAX
+    fell back to because it found no chip — emits the UNMEASURED record
+    (kind "error", `value: null`, which `python -m glom_tpu.telemetry
+    compare` treats as missing) with the watchdog timeline and returns
+    False; the caller exits non-zero. The watchdog stays registered
+    either way, so every line the bench then emits carries the backend
+    state."""
     from glom_tpu.telemetry.watchdog import BackendWatchdog, set_global_watchdog
-    from glom_tpu.utils.metrics import apply_env_platform
+    from glom_tpu.utils.startup import (
+        cpu_requested,
+        device_summary,
+        enable_compile_cache,
+    )
 
+    enable_compile_cache()
     wd = BackendWatchdog(probe_timeout=probe_timeout)
     set_global_watchdog(wd)
     if wd.probe_once() == "down":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        if wd.probe_once() == "down":
-            # The metric label stays the BARE one the measured rows carry:
-            # the compare gate matches rows by label, and a decorated
-            # label would make the outage read as a vanished metric
-            # instead of an UNMEASURED one. The error field carries the
-            # machine-readable cause.
-            emit(
-                {
-                    "metric": metric,
-                    "value": None,
-                    "unit": unit,
-                    "error": "backend-init-unavailable",
-                    "note": "UNMEASURED: jax backend init failed or hung",
-                    "watchdog_timeline": wd.timeline(),
-                },
-                kind="error",
-            )
-            return False
-    # A successful probe validated the platform JAX_PLATFORMS names (the
-    # probe honors it at config level); mirror it here so the bench cannot
-    # initialize a different — possibly wedged — backend past the gate.
-    apply_env_platform()
-    return True
+        error, note = (
+            "backend-init-unavailable",
+            "UNMEASURED: jax backend init failed or hung",
+        )
+    else:
+        dev = device_summary()
+        if dev["platform"] == "tpu" or cpu_requested():
+            return True
+        error, note = (
+            "no-accelerator",
+            f"UNMEASURED: no TPU (platform={dev['platform']}) and the "
+            "caller did not ask for the CPU",
+        )
+    # The metric label stays the BARE one the measured rows carry: the
+    # compare gate matches rows by label, and a decorated label would
+    # make the outage read as a vanished metric instead of an UNMEASURED
+    # one. The error field carries the machine-readable cause.
+    emit(
+        {
+            "metric": metric,
+            "value": None,
+            "unit": unit,
+            "error": error,
+            "note": note,
+            "watchdog_timeline": wd.timeline(),
+        },
+        kind="error",
+    )
+    return False

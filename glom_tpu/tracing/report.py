@@ -35,8 +35,8 @@ def perf_report(
 
 
 class StepTimer:
-    """Rolling wall-clock step timer that syncs on a supplied scalar, for
-    platforms where block_until_ready is unreliable (see bench.py)."""
+    """Rolling wall-clock step timer that syncs on a supplied scalar (a
+    host fetch cannot return before the work that produces it)."""
 
     def __init__(self):
         self._t0: Optional[float] = None

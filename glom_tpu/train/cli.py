@@ -69,9 +69,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--watchdog-interval", type=float, default=0.0, metavar="SECONDS",
-        help="backend-liveness heartbeat: probe backend init in a throwaway "
-        "subprocess every N seconds, stamping up/down/flapping transitions "
-        "into the metrics stream (0 = off)",
+        help="backend-liveness heartbeat: round-trip a scalar through this "
+        "process's devices every N seconds, stamping up/down/flapping "
+        "transitions into the metrics stream (0 = off)",
     )
     p.add_argument("--seed", type=int, default=None)
     p.add_argument("--data", choices=["shapes", "gaussian"], default="shapes")
@@ -174,6 +174,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from glom_tpu.utils.startup import enable_compile_cache
+
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
 
     if args.debug_nans:
@@ -222,10 +225,9 @@ def main(argv=None) -> int:
         args.metrics_file, echo=True, tensorboard_dir=args.tensorboard
     )
 
-    # Backend-liveness heartbeat: transitions (up/down/flapping — round
-    # 5's 60-second flap went unrecorded) land in the SAME stream as the
-    # training records, and every record stamps the current state via the
-    # global registration.
+    # Backend-liveness heartbeat: transitions (up/down/flapping) land in
+    # the SAME stream as the training records, and every record stamps the
+    # current state via the global registration.
     # Crash flight recorder FIRST: even a setup failure (bad --data-dir,
     # preset error) then leaves a postmortem trail of whatever telemetry
     # preceded it. The atexit/SIGTERM hooks stay installed for the process

@@ -146,7 +146,7 @@ class TestTracePurity:
     def test_numpy_on_parameter_in_shard_map_body(self, tmp_path):
         src = (
             "import numpy as np\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "def build(mesh):\n"
             "    def body(params, x):\n"
             "        return np.asarray(x).sum()\n"
@@ -1159,7 +1159,7 @@ class TestAxisEnvironment:
         src = (
             "from jax import lax\n"
             "from glom_tpu.utils.config import MeshConfig\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "DATA_AXIS = 'data'\n"
             "MODEL_AXIS = 'model'\n"
             "def build(make_mesh, P):\n"
@@ -1198,7 +1198,7 @@ class TestAxisEnvironment:
         src = (
             "from jax import lax\n"
             "from glom_tpu.utils.config import MeshConfig\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "DATA_AXIS = 'data'\n"
             "MODEL_AXIS = 'model'\n"
             "def train_mesh(make_mesh):\n"
@@ -1223,7 +1223,7 @@ class TestAxisEnvironment:
         src = (
             "from jax import lax\n"
             "from glom_tpu.utils.config import MeshConfig\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "DATA_AXIS = 'data'\n"
             "MODEL_AXIS = 'model'\n"
             "def train_mesh(make_mesh):\n"
@@ -1247,7 +1247,7 @@ class TestAxisEnvironment:
         the checker never guesses."""
         src = (
             "from jax import lax\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "DATA_AXIS = 'data'\n"
             "MODEL_AXIS = 'model'\n"
             "def build(mesh, P):\n"
@@ -1266,7 +1266,7 @@ class TestAxisEnvironment:
         src = (
             "from jax import lax\n"
             "from glom_tpu.utils.config import MeshConfig\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "DATA_AXIS = 'data'\n"
             "SEQ_AXIS = 'seq'\n"
             "MODEL_AXIS = 'model'\n"
@@ -1291,7 +1291,7 @@ class TestAxisEnvironment:
         src = (
             "from jax import lax\n"
             "from glom_tpu.utils.config import MeshConfig\n"
-            "from glom_tpu.utils.compat import shard_map\n"
+            "from jax import shard_map\n"
             "DATA_AXIS = 'data'\n"
             "SEQ_AXIS = 'seq'\n"
             "def build(make_mesh, P):\n"

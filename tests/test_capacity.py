@@ -176,7 +176,7 @@ class TestTimedCollective:
         from jax.sharding import PartitionSpec as P
 
         from glom_tpu.parallel.mesh import make_mesh
-        from glom_tpu.utils.compat import shard_map
+        from jax import shard_map
         from glom_tpu.utils.config import MeshConfig
 
         mesh = make_mesh(MeshConfig(data=2), jax.devices()[:2])

@@ -15,13 +15,13 @@ CFG = GlomConfig(dim=16, levels=3, image_size=8, patch_size=4)
 
 class TestPerfReport:
     def test_fields_and_values(self):
-        r = perf_report(CFG, column_iters_per_sec=100.0, chip="cpu")
-        assert r["chip"] == "cpu"
+        r = perf_report(CFG, column_iters_per_sec=100.0, chip="v5e")
+        assert r["chip"] == "v5e"
         assert r["num_chips"] == 1
         assert r["column_iters_per_sec_per_chip"] == 100.0
         assert r["flops_per_column_iter"] == flops_per_column_iter(CFG)
         assert r["mfu"] == pytest.approx(
-            100.0 * flops_per_column_iter(CFG) / PEAK_FLOPS["cpu"]
+            100.0 * flops_per_column_iter(CFG) / PEAK_FLOPS["v5e"]
         )
         assert r["mfu"] > 0
 

@@ -530,12 +530,12 @@ class TestProfilingShim:
 
         cfg = GlomConfig(dim=16, levels=3, image_size=8, patch_size=2)
         rep = perf_report(
-            cfg, column_iters_per_sec=1000.0, chip="cpu", num_chips=2,
+            cfg, column_iters_per_sec=1000.0, chip="v5e", num_chips=2,
             backward=True,
         )
         assert rep["column_iters_per_sec_per_chip"] == 500.0
         assert rep["flops_per_column_iter"] == flops_per_column_iter(cfg)
-        assert rep["mfu"] == mfu(cfg, 500.0, chip="cpu", backward=True)
+        assert rep["mfu"] == mfu(cfg, 500.0, chip="v5e", backward=True)
         assert rep["num_chips"] == 2
 
     def test_step_timer_best(self):
