@@ -40,20 +40,21 @@ def prefetch_to_device(
     the staged batches, so neither the thread nor the device buffers
     outlive the consumer.
 
-    The worker's two host phases are span-covered (tracing.spans.spanned:
+    The worker's two host phases are spans (tracing.spans.span:
     host_prefetch_next = pulling from the source iterator,
-    host_prefetch_stage = initiating the device transfer) into a private
-    aggregator; `metrics_writer` (when given) receives the per-phase
-    rollup "span" records when the stream ends — the last unattributed
-    host-time sink the ROADMAP named. Without a writer the rollups feed
-    the global flight recorder.
+    host_prefetch_stage = initiating the device transfer), each carrying
+    the worker's batch count as `step=`, rolled up in a private
+    aggregator. The returned iterator's `span_records()` drains that
+    aggregator: `fit_loop` calls it at every logging boundary, so a stream
+    that never ends still reports. What is left when the stream ends goes
+    to `metrics_writer` (or, without one, to the global flight recorder).
     """
     if size < 1:
         raise ValueError(f"prefetch size must be >= 1, got {size}")
     q: queue.Queue = queue.Queue(maxsize=size)
     stop = threading.Event()
 
-    from glom_tpu.tracing.spans import SpanAggregator, spanned
+    from glom_tpu.tracing.spans import SpanAggregator, span
 
     spans = SpanAggregator()
 
@@ -67,23 +68,19 @@ def prefetch_to_device(
                 continue
         return False
 
-    stage = spanned("host_prefetch_stage", aggregator=spans)(
-        lambda batch: jax.device_put(batch, sharding)
-        if sharding is not None
-        else jax.device_put(batch)
-    )
-    pull_next = spanned("host_prefetch_next", aggregator=spans)(
-        lambda it: next(it, _END)
-    )
-
     def worker():
         try:
+            n = 0
             while True:
-                batch = pull_next(iter_data)
+                with span("host_prefetch_next", aggregator=spans, step=n):
+                    batch = next(iter_data, _END)
                 if batch is _END:
                     break
-                if not put(stage(batch)):
+                with span("host_prefetch_stage", aggregator=spans, step=n):
+                    staged = jax.device_put(batch, sharding)
+                if not put(staged):
                     return
+                n += 1
         except BaseException as e:  # noqa: BLE001 - relay to the consumer
             put((_END, e))
             return
@@ -91,10 +88,13 @@ def prefetch_to_device(
 
     iter_data = iter(data)
 
+    def span_records(extra: Optional[dict] = None) -> list:
+        return spans.records(extra={**(extra or {}), "source": "prefetch_to_device"})
+
     def _drain_spans():
         from glom_tpu.tracing.flight import write_or_observe
 
-        for rec in spans.records(extra={"source": "prefetch_to_device"}):
+        for rec in span_records():
             write_or_observe(metrics_writer, rec)
 
     thread = threading.Thread(target=worker, daemon=True)
@@ -135,4 +135,22 @@ def prefetch_to_device(
             drain()
             _drain_spans()
 
-    return gen()
+    return _Prefetched(gen(), span_records)
+
+
+class _Prefetched:
+    """The staged stream: the generator's iteration and clean-up (dropping
+    it stops the worker), plus the worker's span rollups."""
+
+    def __init__(self, gen, span_records):
+        self._gen = gen
+        self.span_records = span_records
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return next(self._gen)
+
+    def close(self):
+        self._gen.close()

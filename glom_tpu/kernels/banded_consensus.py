@@ -180,5 +180,6 @@ def banded_ragged_consensus(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="consensus_banded_fwd",
     )(band_page0, len_page, pages, pages)
     return out.reshape(T, L, d)

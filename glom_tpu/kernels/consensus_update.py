@@ -452,6 +452,7 @@ def _forward(
                 vmem_limit_bytes=32 * 1024 * 1024
             ),
             interpret=interpret,
+            name="consensus_update_streamed_fwd",
         )(levels_lm, levels_lm, bu_lm, td_lm)
 
     grid = (L, B // tile_b, n // tile_i)
@@ -494,6 +495,7 @@ def _forward(
             else None
         ),
         interpret=interpret,
+        name="consensus_update_fwd",
     )(levels_lm, levels_lm, bu_lm, td_lm)
 
 
@@ -1031,6 +1033,7 @@ def _consensus_bwd_onesweep(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="consensus_update_bwd_onesweep",
     )(levels_lm, graw, levels_lm, graw, cons, m, l)
     # dq rows complete only at the end of each (g, b) subgrid — joined here
     # (one fused add sweep, O(n*d), vs the O(n^2) kernel work).
@@ -1107,6 +1110,7 @@ def _consensus_update_bwd(
                 vmem_limit_bytes=32 * 1024 * 1024
             ),
             interpret=interpret,
+            name="consensus_update_bwd_small",
         )(levels_lm, graw, m, l)
         return dlv, dmean
 
@@ -1161,6 +1165,7 @@ def _consensus_update_bwd(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024),
         interpret=interpret,
+        name="consensus_update_bwd_dq",
     )(levels_lm, levels_lm, graw, m, l)
 
     def _j_spec(shape_last):
@@ -1197,6 +1202,7 @@ def _consensus_update_bwd(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024),
         interpret=interpret,
+        name="consensus_update_bwd_dkv",
     )(levels_lm, graw, dq, levels_lm, graw, m, l, dd)
 
     return dlv, None

@@ -138,6 +138,7 @@ def _ffw_fwd_ext(
             out_specs=out_spec,
             compiler_params=_VMEM_64M,
             interpret=interpret,
+            name="loop_ffw_add_fwd",
         )(ext2, add, params.w1, params.b1[:, None, :], params.w2,
           params.b2[:, None, :])
     else:
@@ -149,6 +150,7 @@ def _ffw_fwd_ext(
             out_specs=out_spec,
             compiler_params=_VMEM_64M,
             interpret=interpret,
+            name="loop_ffw_fwd",
         )(ext2, params.w1, params.b1[:, None, :], params.w2,
           params.b2[:, None, :])
     return out if save_pre else (out[0], None)
@@ -206,6 +208,7 @@ def _pre_fwd_ext(
             out_specs=out_spec,
             compiler_params=_VMEM_64M,
             interpret=interpret,
+            name="loop_ffw_add_pre_fwd",
         )(ext2, add, params.w1, params.b1[:, None, :])
     return pl.pallas_call(
         _pre_kernel,
@@ -215,6 +218,7 @@ def _pre_fwd_ext(
         out_specs=out_spec,
         compiler_params=_VMEM_64M,
         interpret=interpret,
+        name="loop_ffw_pre_fwd",
     )(ext2, params.w1, params.b1[:, None, :])
 
 
@@ -365,6 +369,7 @@ def _ffw_fwd_cat(
         out_specs=out_spec,
         compiler_params=_VMEM_64M,
         interpret=interpret,
+        name="loop_ffw_cat_fwd",
     )(ext2, a2, wcat.w1, wcat.b1[:, None, :], wcat.w2, wcat.b2[:, None, :])
     return out if save_pre else (out[0], None)
 
@@ -396,6 +401,7 @@ def _pre_fwd_cat(
         out_specs=pl.BlockSpec((1, tile_m, f), lambda g, m: (g, m, 0)),
         compiler_params=_VMEM_64M,
         interpret=interpret,
+        name="loop_ffw_cat_pre_fwd",
     )(ext2, a2, wcat.w1, wcat.b1[:, None, :])
 
 
@@ -529,6 +535,7 @@ def _ffw_bwd_cat(
             out_specs=out_specs,
             compiler_params=compiler_params,
             interpret=interpret,
+            name="loop_ffw_cat_acc_bwd",
         )(ext2, a2, wcat.w1, pre_cat, wcat.w2, gcot2,
           acc.w1, acc.b1, acc.w2, acc.b2, da_in)
         return GroupedFFWParams(dw1, db1, dw2, db2), dx, da
@@ -540,6 +547,7 @@ def _ffw_bwd_cat(
         out_specs=out_specs,
         compiler_params=compiler_params,
         interpret=interpret,
+        name="loop_ffw_cat_bwd",
     )(ext2, a2, wcat.w1, pre_cat, wcat.w2, gcot2)
     fresh = GroupedFFWParams(dw1, db1, dw2, db2)
     return jax.tree_util.tree_map(jnp.add, acc, fresh), dx, da_in + da
@@ -625,6 +633,7 @@ def _ffw_bwd_ext(
                 out_specs=out_specs + (da_spec,),
                 compiler_params=compiler_params,
                 interpret=interpret,
+                name="loop_ffw_add_acc_bwd",
             )(ext2, add, params.w1, pre, params.w2, gcot2,
               acc.w1, acc.b1, acc.w2, acc.b2, da_in)
             return GroupedFFWParams(dw1, db1, dw2, db2), dx, da
@@ -636,6 +645,7 @@ def _ffw_bwd_ext(
             out_specs=out_specs + (da_spec,),
             compiler_params=compiler_params,
             interpret=interpret,
+            name="loop_ffw_add_bwd",
         )(ext2, add, params.w1, pre, params.w2, gcot2)
         fresh = GroupedFFWParams(dw1, db1, dw2, db2)
         return (
@@ -652,6 +662,7 @@ def _ffw_bwd_ext(
             out_specs=out_specs,
             compiler_params=compiler_params,
             interpret=interpret,
+            name="loop_ffw_acc_bwd",
         )(ext2, params.w1, pre, params.w2, gcot2,
           acc.w1, acc.b1, acc.w2, acc.b2)
         return GroupedFFWParams(dw1, db1, dw2, db2), dx, None
@@ -663,6 +674,7 @@ def _ffw_bwd_ext(
         out_specs=out_specs,
         compiler_params=compiler_params,
         interpret=interpret,
+        name="loop_ffw_bwd",
     )(ext2, params.w1, pre, params.w2, gcot2)
     fresh = GroupedFFWParams(dw1, db1, dw2, db2)
     return jax.tree_util.tree_map(jnp.add, acc, fresh), dx, None
@@ -737,6 +749,7 @@ def _cons_fwd_ext(
         in_specs=in_specs,
         out_specs=out_spec,
         interpret=interpret,
+        name="loop_consensus_fwd",
     )(ext, ext, bu, td)
 
 
@@ -837,6 +850,7 @@ def _cons_bwd_ext(
         out_specs=(spec(d, ident), spec(d, ident)),
         compiler_params=_VMEM_32M,
         interpret=interpret,
+        name="loop_consensus_bwd",
     )(*ins)
     return dlv, dmean
 

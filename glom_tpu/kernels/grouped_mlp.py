@@ -192,6 +192,7 @@ def _fused_forward(
             else None
         ),
         interpret=interpret,
+        name="ffw_fwd",
     )(x, params.w1, params.b1[:, None, :], params.w2, params.b2[:, None, :])
 
 
@@ -230,6 +231,7 @@ def _fused_forward_add(
         out_specs=out_spec,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="ffw_add_fwd",
     )(x, a, params.w1, params.b1[:, None, :], params.w2, params.b2[:, None, :])
 
 
@@ -520,6 +522,7 @@ def _fused_backward(params, x, g, *, tile_m: int, interpret: bool, pre=None):
         ),
         compiler_params=_bwd_compiler_params(tile_m, d, f, x.dtype.itemsize),
         interpret=interpret,
+        name="ffw_bwd",
     )(x, params.w1, second_in, params.w2, g)
 
     w1, b1, w2, b2 = params
@@ -569,6 +572,7 @@ def _fused_backward_add(params, x, a, pre, g, *, tile_m: int, interpret: bool):
         ),
         compiler_params=_bwd_compiler_params(tile_m, d, f, x.dtype.itemsize),
         interpret=interpret,
+        name="ffw_add_bwd",
     )(x, a, params.w1, pre, params.w2, g)
 
     w1, b1, w2, b2 = params

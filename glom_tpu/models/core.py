@@ -204,7 +204,10 @@ def glom_forward(
     if remat:
         body = jax.checkpoint(body)
 
-    final, stacked = jax.lax.scan(body, levels, None, length=T, unroll=unroll)
+    # "loop" holds the scan and what autodiff adds around its body (saved
+    # residuals, counters); the body's own phases nest inside it.
+    with jax.named_scope("loop"):
+        final, stacked = jax.lax.scan(body, levels, None, length=T, unroll=unroll)
 
     if return_all:
         return jnp.concatenate([levels[None], stacked], axis=0)  # [T+1, b, n, L, d]
@@ -345,13 +348,17 @@ def _glom_forward_fused(
     if _use_fused_loop(params, cfg, b, n, d, iters, levels_in, return_all, remat):
         from glom_tpu.kernels.fused_loop import fused_glom_loop
 
-        final = fused_glom_loop(
-            params.bottom_up, params.top_down, params.pos_emb, tokens,
-            levels_lm, iters, cfg.num_patches_side,
-            float(cfg.local_consensus_radius), cfg.consensus_self, False,
-            remat,
-        )
-        return jnp.transpose(final, (1, 2, 0, 3))  # [b, n, L, d]
+        # One scope for the whole-loop VJP: its kernels (`loop_*`) run
+        # bottom-up, top-down and consensus of every iteration, and the
+        # glue XLA leaves around them belongs to no single one of those.
+        with jax.named_scope("loop"):
+            final = fused_glom_loop(
+                params.bottom_up, params.top_down, params.pos_emb, tokens,
+                levels_lm, iters, cfg.num_patches_side,
+                float(cfg.local_consensus_radius), cfg.consensus_self, False,
+                remat,
+            )
+            return jnp.transpose(final, (1, 2, 0, 3))  # [b, n, L, d]
 
     def body(carry, _):
         lv = carry
@@ -385,7 +392,10 @@ def _glom_forward_fused(
     if remat:
         body = jax.checkpoint(body)
 
-    final, stacked = jax.lax.scan(body, levels_lm, None, length=iters, unroll=unroll)
+    with jax.named_scope("loop"):
+        final, stacked = jax.lax.scan(
+            body, levels_lm, None, length=iters, unroll=unroll
+        )
 
     if return_all:
         all_lm = jnp.concatenate([levels_lm[None], stacked], axis=0)

@@ -220,11 +220,14 @@ def _forward_local(
     # the layout ring/halo assume. (Patchify+embed on the full image is
     # O(n * p^2 * c * d), noise vs one scan iteration; slicing after keeps
     # the code free of pixel-band geometry.)
-    tokens = image_to_tokens(
-        glom_params.token_embed, noised, cfg.patch_size
-    )  # [b_loc, n, d]
-    seq_idx = lax.axis_index(SEQ_AXIS)
-    tokens_loc = lax.dynamic_slice_in_dim(tokens, seq_idx * n_loc, n_loc, axis=1)
+    with jax.named_scope("image_to_tokens"):
+        tokens = image_to_tokens(
+            glom_params.token_embed, noised, cfg.patch_size
+        )  # [b_loc, n, d]
+        seq_idx = lax.axis_index(SEQ_AXIS)
+        tokens_loc = lax.dynamic_slice_in_dim(
+            tokens, seq_idx * n_loc, n_loc, axis=1
+        )
     pos_loc = lax.dynamic_slice_in_dim(
         glom_params.pos_emb, seq_idx * n_loc, n_loc, axis=0
     )
@@ -265,43 +268,50 @@ def _forward_local(
     ):
         from glom_tpu.kernels.fused_loop import fused_glom_loop
 
-        final = fused_glom_loop(
-            glom_params.bottom_up, glom_params.top_down, pos_loc,
-            tokens_loc, levels_lm, iters, cfg.num_patches_side,
-            float(cfg.local_consensus_radius), cfg.consensus_self,
-            interpret, remat,
-        )
-        return final if return_mode == "final" else final[-1]
+        with jax.named_scope("loop"):
+            final = fused_glom_loop(
+                glom_params.bottom_up, glom_params.top_down, pos_loc,
+                tokens_loc, levels_lm, iters, cfg.num_patches_side,
+                float(cfg.local_consensus_radius), cfg.consensus_self,
+                interpret, remat,
+            )
+            return final if return_mode == "final" else final[-1]
 
     def body(carry, _):
         lv = carry
-        bu_in = jnp.concatenate([tokens_lm, lv[:-1]], axis=0)
-        bu = ffw_lm(
-            glom_params.bottom_up, bu_in.reshape(L, b_loc * n_loc, d)
-        ).reshape(L, b_loc, n_loc, d)
-        td = ffw_lm(
-            glom_params.top_down, (lv[1:] + pos_lm).reshape(L - 1, b_loc * n_loc, d)
-        ).reshape(L - 1, b_loc, n_loc, d)
+        with jax.named_scope("bottom_up"):
+            bu_in = jnp.concatenate([tokens_lm, lv[:-1]], axis=0)
+            bu = ffw_lm(
+                glom_params.bottom_up, bu_in.reshape(L, b_loc * n_loc, d)
+            ).reshape(L, b_loc, n_loc, d)
+        with jax.named_scope("top_down"):
+            td = ffw_lm(
+                glom_params.top_down,
+                (lv[1:] + pos_lm).reshape(L - 1, b_loc * n_loc, d),
+            ).reshape(L - 1, b_loc, n_loc, d)
         if consensus_shard is None:
-            new = fused_consensus_update(
-                lv, bu, td,
-                side=cfg.num_patches_side,
-                radius=float(cfg.local_consensus_radius),
-                attend_self=cfg.consensus_self,
-            )
-        else:
-            cons = consensus_shard(jnp.transpose(lv, (1, 2, 0, 3)))
-            cons_lm = jnp.transpose(cons, (2, 0, 1, 3))
-            td_full = jnp.concatenate([td, jnp.zeros_like(td[:1])], axis=0)
-            new = (
-                (
-                    lv.astype(jnp.float32)
-                    + bu.astype(jnp.float32)
-                    + td_full.astype(jnp.float32)
-                    + cons_lm.astype(jnp.float32)
+            with jax.named_scope("consensus_update"):
+                new = fused_consensus_update(
+                    lv, bu, td,
+                    side=cfg.num_patches_side,
+                    radius=float(cfg.local_consensus_radius),
+                    attend_self=cfg.consensus_self,
                 )
-                / divisor_lm
-            ).astype(lv.dtype)
+        else:
+            with jax.named_scope("consensus"):
+                cons = consensus_shard(jnp.transpose(lv, (1, 2, 0, 3)))
+                cons_lm = jnp.transpose(cons, (2, 0, 1, 3))
+            with jax.named_scope("mean_update"):
+                td_full = jnp.concatenate([td, jnp.zeros_like(td[:1])], axis=0)
+                new = (
+                    (
+                        lv.astype(jnp.float32)
+                        + bu.astype(jnp.float32)
+                        + td_full.astype(jnp.float32)
+                        + cons_lm.astype(jnp.float32)
+                    )
+                    / divisor_lm
+                ).astype(lv.dtype)
         return new, None
 
     if return_mode == "all":
@@ -313,14 +323,14 @@ def _forward_local(
         # scaled(iters): the body traces ONCE here but executes per scan
         # iteration — collective sites inside it (the TP psum) must price
         # every execution (same convention as the stage-2 microbatch hook).
-        with tele_counters.scaled(iters):
+        with tele_counters.scaled(iters), jax.named_scope("loop"):
             final, ys = lax.scan(
                 body_ys, levels_lm, None, length=iters, unroll=unroll
             )
         return jnp.concatenate([levels_lm[None], ys], axis=0)  # [T+1, L, ...]
     if remat:
         body = jax.checkpoint(body)
-    with tele_counters.scaled(iters):
+    with tele_counters.scaled(iters), jax.named_scope("loop"):
         final, _ = lax.scan(body, levels_lm, None, length=iters, unroll=unroll)
     if return_mode == "final":
         return final  # [L, b_loc, n_loc, d]
@@ -377,9 +387,10 @@ def _build_local_loss(
             glom_params = jax.tree_util.tree_map(
                 lambda t: t.astype(compute_dtype), glom_params
             )
-        noised = (img + noise).astype(
-            compute_dtype if compute_dtype is not None else img.dtype
-        )
+        with jax.named_scope("noise"):
+            noised = (img + noise).astype(
+                compute_dtype if compute_dtype is not None else img.dtype
+            )
         top = _forward_local(
             glom_params,
             noised,
@@ -397,15 +408,16 @@ def _build_local_loss(
         # Reconstruction + MSE in PATCH space: identical pixel set to the
         # reference's image-space MSE (patchify is a permutation), no
         # all-gather needed for the local band.
-        recon = top.astype(img.dtype) @ params.to_pixels.w + params.to_pixels.b
-        target = patchify(img, cfg.patch_size)  # [b_loc, n, p*p*c]
-        n_loc = cfg.num_patches // seq
-        seq_idx = lax.axis_index(SEQ_AXIS)
-        target_loc = lax.dynamic_slice_in_dim(
-            target, seq_idx * n_loc, n_loc, axis=1
-        )
-        local_mse = jnp.mean((target_loc - recon) ** 2)
-        return lax.pmean(local_mse, SEQ_AXIS)
+        with jax.named_scope("reconstruction"):
+            recon = top.astype(img.dtype) @ params.to_pixels.w + params.to_pixels.b
+            target = patchify(img, cfg.patch_size)  # [b_loc, n, p*p*c]
+            n_loc = cfg.num_patches // seq
+            seq_idx = lax.axis_index(SEQ_AXIS)
+            target_loc = lax.dynamic_slice_in_dim(
+                target, seq_idx * n_loc, n_loc, axis=1
+            )
+            local_mse = jnp.mean((target_loc - recon) ** 2)
+            return lax.pmean(local_mse, SEQ_AXIS)
 
     return loss_body, seq, mp
 
@@ -439,7 +451,9 @@ def make_manual_loss(
     )
 
     def loss_body(params: DenoiseParams, img: jnp.ndarray, noise: jnp.ndarray):
-        return lax.pmean(local_loss(params, img, noise), DATA_AXIS)
+        loss = local_loss(params, img, noise)
+        with jax.named_scope("reconstruction"):
+            return lax.pmean(loss, DATA_AXIS)
 
     batch_spec = P(DATA_AXIS)  # [b, c, H, W]; replicated over seq (sliced in-body)
     param_spec = _manual_param_spec(mp)
@@ -583,8 +597,11 @@ def make_manual_train_step(
     )
 
     def train_step(state: TrainState, img: jnp.ndarray, rng: jax.Array):
-        noise_rng = jax.random.fold_in(rng, state.step)
-        noise = tcfg.noise_std * jax.random.normal(noise_rng, img.shape, img.dtype)
+        with jax.named_scope("noise"):
+            noise_rng = jax.random.fold_in(rng, state.step)
+            noise = tcfg.noise_std * jax.random.normal(
+                noise_rng, img.shape, img.dtype
+            )
         if accum > 1:
             from glom_tpu.train.trainer import accumulate_grads
 
@@ -593,30 +610,35 @@ def make_manual_train_step(
             )
         else:
             loss, grads = jax.value_and_grad(loss_fn)(state.params, img, noise)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        metrics = {"loss": loss, "step": state.step}
-        if with_grad_norm or level != "off":
-            grad_norm = optax.global_norm(grads)
-        if with_grad_norm:
-            metrics["grad_norm"] = grad_norm
-        if level != "off":
-            # The grads/updates here are full replicated trees (the
-            # shard_map transpose already reduced them), so the scalar
-            # taps and the guard run OUTSIDE the manual region — same
-            # fused-reduction cost as the GSPMD step's.
-            taps = diag.scalar_taps(
-                loss=loss, grad_norm=grad_norm, updates=updates, params=params
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
             )
-            nonfinite = taps.pop("nonfinite")
-            if tcfg.nonfinite_policy == "skip":
-                params = diag.guard_update(nonfinite, params, state.params)
-                opt_state = diag.guard_update(
-                    nonfinite, opt_state, state.opt_state
+            params = optax.apply_updates(state.params, updates)
+        metrics = {"loss": loss, "step": state.step}
+        with jax.named_scope("step_metrics"):
+            if with_grad_norm or level != "off":
+                grad_norm = optax.global_norm(grads)
+            if with_grad_norm:
+                metrics["grad_norm"] = grad_norm
+            if level != "off":
+                # The grads/updates here are full replicated trees (the
+                # shard_map transpose already reduced them), so the scalar
+                # taps and the guard run OUTSIDE the manual region — same
+                # fused-reduction cost as the GSPMD step's.
+                taps = diag.scalar_taps(
+                    loss=loss, grad_norm=grad_norm, updates=updates,
+                    params=params,
                 )
-                metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
-            metrics.update(taps)
-            metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
+                nonfinite = taps.pop("nonfinite")
+                if tcfg.nonfinite_policy == "skip":
+                    params = diag.guard_update(nonfinite, params, state.params)
+                    opt_state = diag.guard_update(
+                        nonfinite, opt_state, state.opt_state
+                    )
+                    metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
+                metrics.update(taps)
+                metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
         return TrainState(params, opt_state, state.step + 1), metrics
 
     return train_step
@@ -844,7 +866,7 @@ def make_manual_zero_train_step(
             def scatter_microbatch(g):
                 # One trace, `accum` executions: scale the measured
                 # counters so they price the whole step's wire traffic.
-                with tele_counters.scaled(accum):
+                with tele_counters.scaled(accum), jax.named_scope("grad_reduce"):
                     return reduce_scatter_tree(g)
 
             gkw = (
@@ -863,44 +885,52 @@ def make_manual_zero_train_step(
             if zero_stage >= 2:
                 g_shards = grads
             else:
-                g_shards, qerr = reduce_full(grads)
+                with jax.named_scope("grad_reduce"):
+                    g_shards, qerr = reduce_full(grads)
         else:
             loss_loc, grads = jax.value_and_grad(local_loss)(params, img, noise)
-            g_shards, qerr = reduce_full(grads)
+            with jax.named_scope("grad_reduce"):
+                g_shards, qerr = reduce_full(grads)
 
-        p_shards = jax.tree_util.tree_map(slice_shard, params, shard_axes)
-        updates, new_opt = optimizer.update(g_shards, opt_state, p_shards)
-        new_p_shards = optax.apply_updates(p_shards, updates)
-        new_params = jax.tree_util.tree_map(
-            gather_shard, new_p_shards, shard_axes
-        )
-        loss = lax.pmean(loss_loc, DATA_AXIS)
+        with jax.named_scope("optimizer"):
+            p_shards = jax.tree_util.tree_map(slice_shard, params, shard_axes)
+            updates, new_opt = optimizer.update(g_shards, opt_state, p_shards)
+            new_p_shards = optax.apply_updates(p_shards, updates)
+        with jax.named_scope("grad_reduce"):
+            # the all-gather half of the sharded update's wire pattern
+            new_params = jax.tree_util.tree_map(
+                gather_shard, new_p_shards, shard_axes
+            )
+        with jax.named_scope("reconstruction"):
+            loss = lax.pmean(loss_loc, DATA_AXIS)
         metrics = {"loss": loss}
-        if with_grad_norm or level != "off":
-            # grad_norm is part of the scalars bundle on every path (it is
-            # computed for the guard anyway): the fast-variant record must
-            # carry the same keys here as on the GSPMD/manual steps.
-            gnorm = sharded_grad_norm(g_shards)
-            metrics["grad_norm"] = gnorm
-        if level != "off":
-            # In-region telemetry on the sharded triple: update norm via
-            # the same ownership-partition decomposition as the grad norm;
-            # param norm on the gathered (replicated) tree is collective-
-            # free. The guard's where() runs on the gathered params and
-            # the sharded opt state alike — the non-finite flag is built
-            # from psum'd scalars, so it is replica-invariant.
-            from glom_tpu.telemetry.diagnostics import nonfinite_flag
+        with jax.named_scope("step_metrics"):
+            if with_grad_norm or level != "off":
+                # grad_norm is part of the scalars bundle on every path (it
+                # is computed for the guard anyway): the fast-variant record
+                # must carry the same keys here as on the GSPMD/manual steps.
+                gnorm = sharded_grad_norm(g_shards)
+                metrics["grad_norm"] = gnorm
+            if level != "off":
+                # In-region telemetry on the sharded triple: update norm
+                # via the same ownership-partition decomposition as the
+                # grad norm; param norm on the gathered (replicated) tree
+                # is collective-free. The guard's where() runs on the
+                # gathered params and the sharded opt state alike — the
+                # non-finite flag is built from psum'd scalars, so it is
+                # replica-invariant.
+                from glom_tpu.telemetry.diagnostics import nonfinite_flag
 
-            metrics["update_norm"] = sharded_grad_norm(updates)
-            metrics["param_norm"] = optax.global_norm(new_params)
-            nonfinite = nonfinite_flag(loss, gnorm)
-            if tcfg.nonfinite_policy == "skip":
-                new_params = diag.guard_update(nonfinite, new_params, params)
-                new_opt = diag.guard_update(nonfinite, new_opt, opt_state)
-                metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
-            metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
-            if probe_quant:
-                metrics["quant_rel_err"] = qerr
+                metrics["update_norm"] = sharded_grad_norm(updates)
+                metrics["param_norm"] = optax.global_norm(new_params)
+                nonfinite = nonfinite_flag(loss, gnorm)
+                if tcfg.nonfinite_policy == "skip":
+                    new_params = diag.guard_update(nonfinite, new_params, params)
+                    new_opt = diag.guard_update(nonfinite, new_opt, opt_state)
+                    metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
+                metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
+                if probe_quant:
+                    metrics["quant_rel_err"] = qerr
         return new_params, new_opt, metrics
 
     batch_spec = P(DATA_AXIS)
@@ -923,8 +953,11 @@ def make_manual_zero_train_step(
     )
 
     def train_step(state: TrainState, img: jnp.ndarray, rng: jax.Array):
-        noise_rng = jax.random.fold_in(rng, state.step)
-        noise = tcfg.noise_std * jax.random.normal(noise_rng, img.shape, img.dtype)
+        with jax.named_scope("noise"):
+            noise_rng = jax.random.fold_in(rng, state.step)
+            noise = tcfg.noise_std * jax.random.normal(
+                noise_rng, img.shape, img.dtype
+            )
         new_params, new_opt, metrics = update_sm(
             state.params, state.opt_state, img, noise
         )

@@ -16,8 +16,6 @@ HBM went or what the last N steps looked like before the crash. Layers:
     flight   — bounded ring buffer of the last N telemetry events, dumped
                to flight_<ts>.jsonl on backend-down, anomaly storm,
                SIGTERM/atexit, or an unhandled fit_loop exception
-    report   — MFU perf report + the rolling StepTimer (moved from the
-               utils/profiling.py stub, which re-exports for compat)
 
 Re-exports are LAZY (PEP 562, same pattern as glom_tpu/telemetry): spans
 and flight are pure stdlib and must stay importable in a jax-broken
@@ -27,6 +25,8 @@ capture/memory import jax only inside the functions that need it.
 
 _EXPORTS = {
     "PHASES": "spans",
+    "HOST_PHASES": "spans",
+    "DEVICE_PHASES": "spans",
     "SpanAggregator": "spans",
     "span": "spans",
     "spanned": "spans",
@@ -41,10 +41,8 @@ _EXPORTS = {
     "get_global_flight_recorder": "flight",
     "observe_event": "flight",
     "set_global_flight_recorder": "flight",
-    "StepTimer": "report",
-    "perf_report": "report",
 }
-_SUBMODULES = ("spans", "capture", "memory", "flight", "report")
+_SUBMODULES = ("spans", "capture", "memory", "flight")
 
 __all__ = sorted([*_EXPORTS, *_SUBMODULES])
 

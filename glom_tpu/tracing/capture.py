@@ -24,6 +24,19 @@ import contextlib
 from typing import Tuple
 
 
+def start_trace(trace_dir: str) -> None:
+    """Open the profiler with the Python tracer off. With it on, opening a
+    trace stalls every Python thread for 1-2.4 s (PERF.md section 6) and
+    the window measures the profiler; host threads still carry the
+    runtime's own events and every TraceAnnotation, the program's spans
+    among them."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+
 def parse_trace_steps(spec: str) -> Tuple[int, int]:
     """'A:B' -> (first, last) inclusive; a bare 'A' captures one step."""
     parts = spec.split(":")
@@ -94,9 +107,7 @@ class TraceCapture:
     # -- the window --------------------------------------------------------
 
     def _start(self) -> None:
-        import jax
-
-        jax.profiler.start_trace(self.trace_dir)
+        start_trace(self.trace_dir)
         self._active = True
         self._emit(
             {
@@ -175,7 +186,7 @@ def trace(log_dir: str = "/tmp/glom_tpu_trace"):
     """
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    start_trace(log_dir)
     try:
         yield log_dir
     finally:

@@ -8,33 +8,61 @@ versioned "span" JSONL events into the same stream every other telemetry
 record rides, so a run with no profiler backend (the CPU functional
 drives, a postmortem) still attributes time per phase.
 
-Naming: in-graph phases already carry `jax.named_scope` names (bottom_up /
-top_down / consensus / mean_update in models/core.py — mirrored here as
-PHASES so span streams and XProf traces group under one vocabulary); host
-phases the fit loop times are prefixed `host_` (host_data_next,
-host_step_dispatch, host_log_fetch). `span(..., annotate=True)` also enters
-a `jax.profiler.TraceAnnotation`, so when an XLA capture window is open the
-same block shows up in XProf under the same name.
+Naming: one vocabulary, PHASES, for host spans and in-graph scopes alike.
+In-graph phases are the `jax.named_scope` names of the step builders and
+models/core.py, and the prefixes of the Pallas kernels' `name=`; host
+phases the fit loop and the prefetch worker time are prefixed `host_`.
+Every span also enters a `jax.profiler.TraceAnnotation` of its own name
+(with the span's extra fields, e.g. the loop's `step=`), so an open
+profiler window shows the block on the device trace's clock; with no
+window open the annotation is a flag test.
 
-Cost: a bare span (aggregator only, no writer) is two perf_counter calls
-plus dict arithmetic — single-digit microseconds. The fit loop therefore
-aggregates per-name between logging steps (SpanAggregator) and emits one
-rollup span event per phase per logging record instead of two JSONL lines
-per step; `python bench_train.py --span-ab` keeps the measured overhead
-under the 1% bar. Pure stdlib: importable with jax broken or absent.
+Cost: a span with an aggregator and no writer is two perf_counter calls,
+the annotation's enter and exit, and dict arithmetic — single-digit
+microseconds. The fit loop therefore aggregates per-name between logging
+steps (SpanAggregator) and emits one rollup span event per phase per
+logging record instead of two JSONL lines per step. Pure stdlib but for
+the annotation, which is resolved on the first span and left out where
+jax is broken or absent.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
-# The scan body's jax.named_scope vocabulary (models/core.py) — span names
-# for in-graph phases must come from here so host events and XProf traces
-# group identically.
-PHASES = ("bottom_up", "top_down", "consensus", "mean_update")
+# The phases of a training step, host and device: the names of the fit
+# loop's and the prefetch worker's spans, of every `jax.named_scope` in the
+# step builders (train/trainer.py, parallel/manual.py) and the forward
+# (models/core.py, train/objectives.py), and the first word of every Pallas
+# kernel's `name=` (`loop_*`: kernels/fused_loop.py, `ffw_*`:
+# kernels/grouped_mlp.py, `consensus_*`: the consensus kernels). A device op
+# of the step belongs to the innermost of these in its `op_name`.
+HOST_PHASES = (
+    "host_data_next",
+    "host_step_dispatch",
+    "host_log_fetch",
+    "host_prefetch_next",
+    "host_prefetch_stage",
+)
+DEVICE_PHASES = (
+    "noise",
+    "image_to_tokens",
+    "loop",
+    "bottom_up",
+    "top_down",
+    "ffw",
+    "consensus",
+    "mean_update",
+    "consensus_update",
+    "reconstruction",
+    "grad_reduce",
+    "optimizer",
+    "step_metrics",
+)
+PHASES = HOST_PHASES + DEVICE_PHASES
 
 # The serving stack's host phases (glom_tpu/serve): one request's path is
 # enqueue -> (gathered into a) batch -> dispatch (the compiled forward) ->
@@ -48,6 +76,26 @@ SERVE_PHASES = (
 )
 
 _local = threading.local()
+
+
+def _no_annotation(name, **fields):
+    """Stands in for jax.profiler.TraceAnnotation where jax is broken or
+    absent: the span itself must work in exactly that environment."""
+    return nullcontext()
+
+
+_annotation_cls = None  # resolved by the first span
+
+
+def _trace_annotation():
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # jax absent, or broken at import: spans still work
+            TraceAnnotation = _no_annotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
 
 
 def _stack() -> list:
@@ -117,7 +165,6 @@ def span(
     *,
     writer=None,
     aggregator: Optional[SpanAggregator] = None,
-    annotate: bool = False,
     **fields,
 ):
     """Time the enclosed block as a named span.
@@ -126,34 +173,20 @@ def span(
     stamped "span" event per close — start wall time, duration, nesting
     depth, and the enclosing span's name. `aggregator` rolls the duration
     into a SpanAggregator instead (the cheap fit-loop form; both may be
-    given). `annotate=True` additionally enters jax.profiler.TraceAnnotation
-    so an open XLA capture window shows the block under the same name —
-    skipped silently when jax is broken or absent (the span itself must
-    work in exactly that environment). Extra keyword `fields` ride the
-    emitted event."""
+    given). The block also runs inside a jax.profiler.TraceAnnotation of
+    the same name, so an open profiler window shows it on the device
+    trace's clock. Extra keyword `fields` (numbers or strings, e.g. the
+    loop's `step=`) ride both the emitted event and the annotation."""
     stack = _stack()
     parent = stack[-1] if stack else None
     stack.append(name)
-    ann = None
-    if annotate:
-        try:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        except Exception:
-            ann = None
-    t_wall = time.time()
+    t_wall = time.time() if writer is not None else 0.0
     t0 = time.perf_counter()
     try:
-        yield
+        with _trace_annotation()(name, **fields):
+            yield
     finally:
         dur = time.perf_counter() - t0
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
         stack.pop()
         if aggregator is not None:
             aggregator.observe(name, dur)

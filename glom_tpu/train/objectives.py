@@ -82,7 +82,8 @@ def denoise_loss(
     if not 1 <= k <= T:
         raise ValueError(f"recon_index {k} outside 1..{T}")
 
-    noised = img + noise
+    with jax.named_scope("noise"):
+        noised = img + noise
     final = glom_forward(
         params.glom,
         noised,
@@ -94,12 +95,12 @@ def denoise_loss(
         use_pallas=use_pallas,
         unroll=unroll,
     )
-    top = final[:, :, -1]  # [b, n, d] — the top level
     with jax.named_scope("reconstruction"):
+        top = final[:, :, -1]  # [b, n, d] — the top level
         recon = tokens_to_image(
             params.to_pixels, top.astype(img.dtype), cfg.patch_size, cfg.image_size
         )
-    loss = jnp.mean((img - recon) ** 2)
+        loss = jnp.mean((img - recon) ** 2)
     if with_diagnostics:
         from glom_tpu.telemetry.diagnostics import level_agreement
 

@@ -12,6 +12,7 @@ in reference; README recipe only). TPU-native design:
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from typing import Any, Callable, Iterator, NamedTuple, Optional, Tuple
 
 import jax
@@ -369,8 +370,11 @@ def make_train_step(
         )
 
     def train_step(state: TrainState, img: jnp.ndarray, rng: jax.Array):
-        noise_rng = jax.random.fold_in(rng, state.step)
-        noise = tcfg.noise_std * jax.random.normal(noise_rng, img.shape, img.dtype)
+        with jax.named_scope("noise"):
+            noise_rng = jax.random.fold_in(rng, state.step)
+            noise = tcfg.noise_std * jax.random.normal(
+                noise_rng, img.shape, img.dtype
+            )
 
         if grad_accum > 1:
             if zero_stage >= 2 and zero_shardings is not None:
@@ -400,48 +404,59 @@ def make_train_step(
         if quantized:
             from glom_tpu.parallel.quantized import quantize_dequantize
 
-            dq = jax.tree_util.tree_map(quantize_dequantize, grads)
+            with jax.named_scope("grad_reduce"):
+                dq = jax.tree_util.tree_map(quantize_dequantize, grads)
             if level != "off":
                 # EQuARX wire-hop accuracy probe: what one quantized ride
                 # cost THIS step's gradient, on the record next to the
                 # loss it perturbs.
-                metrics["quant_rel_err"] = diag.quantization_error(grads, dq)
+                with jax.named_scope("step_metrics"):
+                    metrics["quant_rel_err"] = diag.quantization_error(grads, dq)
             grads = dq
-        if zero_stage >= 1 and zero_shardings is not None:
-            # Reduce-scatter: the cross-replica grad reduction lands each
-            # leaf already split on its zero_shard_axis.
-            grads = jax.lax.with_sharding_constraint(grads, zero_shardings.grads)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        if zero_stage >= 1 and zero_shardings is not None:
-            # All-gather the updated shards back to the replicated layout
-            # the next forward reads.
-            params = jax.lax.with_sharding_constraint(
-                params, zero_shardings.params
-            )
-        metrics.update({"loss": loss, "step": state.step})
-        if with_grad_norm or level != "off":
-            grad_norm = optax.global_norm(grads)
-        if with_grad_norm:
-            metrics["grad_norm"] = grad_norm
-        if level != "off":
-            taps = diag.scalar_taps(
-                loss=loss, grad_norm=grad_norm, updates=updates, params=params
-            )
-            nonfinite = taps.pop("nonfinite")
-            if tcfg.nonfinite_policy == "skip":
-                # Drop the poisoned update in-graph: params AND optimizer
-                # state keep their previous values; the step counter still
-                # advances so schedules/logs stay aligned.
-                params = diag.guard_update(nonfinite, params, state.params)
-                opt_state = diag.guard_update(
-                    nonfinite, opt_state, state.opt_state
+        with jax.named_scope("optimizer"):
+            if zero_stage >= 1 and zero_shardings is not None:
+                # Reduce-scatter: the cross-replica grad reduction lands
+                # each leaf already split on its zero_shard_axis.
+                grads = jax.lax.with_sharding_constraint(
+                    grads, zero_shardings.grads
                 )
-                metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
-            metrics.update(taps)
-            metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
-            if full and aux is not None:
-                metrics["level_agreement"] = aux["level_agreement"]
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
+            if zero_stage >= 1 and zero_shardings is not None:
+                # All-gather the updated shards back to the replicated
+                # layout the next forward reads.
+                params = jax.lax.with_sharding_constraint(
+                    params, zero_shardings.params
+                )
+        metrics.update({"loss": loss, "step": state.step})
+        # What only observability needs: the grad-norm sweep of the logging
+        # variant, and the telemetry taps and guard of every variant.
+        with jax.named_scope("step_metrics"):
+            if with_grad_norm or level != "off":
+                grad_norm = optax.global_norm(grads)
+            if with_grad_norm:
+                metrics["grad_norm"] = grad_norm
+            if level != "off":
+                taps = diag.scalar_taps(
+                    loss=loss, grad_norm=grad_norm, updates=updates,
+                    params=params,
+                )
+                nonfinite = taps.pop("nonfinite")
+                if tcfg.nonfinite_policy == "skip":
+                    # Drop the poisoned update in-graph: params AND
+                    # optimizer state keep their previous values; the step
+                    # counter still advances so schedules/logs stay aligned.
+                    params = diag.guard_update(nonfinite, params, state.params)
+                    opt_state = diag.guard_update(
+                        nonfinite, opt_state, state.opt_state
+                    )
+                    metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
+                metrics.update(taps)
+                metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
+                if full and aux is not None:
+                    metrics["level_agreement"] = aux["level_agreement"]
         return TrainState(params, opt_state, state.step + 1), metrics
 
     # Static routing facts for the trainers' metric records (strings can't
@@ -497,10 +512,13 @@ def fit_loop(
 
     Tracing hooks (glom_tpu/tracing/, docs/OBSERVABILITY.md):
       * host spans — host_data_next / host_step_dispatch / host_log_fetch
-        are aggregated per phase between logging steps (SpanAggregator:
-        dict arithmetic, <1% of the CPU bench step by bench_train.py
-        --span-ab) and drained as one "span" record per phase into the
-        metrics stream at each log boundary;
+        are aggregated per phase between logging steps (SpanAggregator)
+        and drained as one "span" record per phase into the metrics
+        stream at each log boundary, together with the prefetch worker's
+        host_prefetch_next / host_prefetch_stage when `data` is a
+        prefetched stream; each span also enters a profiler
+        TraceAnnotation of its name carrying this loop's step index, so a
+        device trace shows which phase the host was in;
       * trace_capture — a tracing.capture.TraceCapture whose [A, B] step
         window this loop advances (the capture's counter persists across
         fit() calls; the CALLER owns close());
@@ -529,6 +547,9 @@ def fit_loop(
     # a second fit() call even with a shared tracker).
     compiled = compile_tracker if compile_tracker is not None else set()
     pending_flags = []  # (step index, device-scalar nonfinite flag)
+    # A prefetched stream (data.prefetch_to_device) times its worker
+    # thread's phases itself; its rollups join the loop's at each boundary.
+    data_span_records = getattr(data, "span_records", None)
     t0 = time.perf_counter()
     i = -1
     try:
@@ -543,25 +564,26 @@ def fit_loop(
             # a data-pipeline signal, not step time — folding it in would
             # make a loader stall read as a step/compile regression on
             # every record.
-            with span("host_data_next", aggregator=spans):
+            with span("host_data_next", aggregator=spans, step=i):
                 batch = next(data)
-            t_step = time.perf_counter()
-            with span("host_step_dispatch", aggregator=spans):
-                if trace_capture is not None:
-                    with trace_capture.unit():
-                        metrics = fn(batch)
-                else:
+            # The capture's unit goes around the span, not inside it: a unit
+            # that opens or closes the profiler does so outside the
+            # dispatch's time, and the window's first dispatch is a span the
+            # trace holds.
+            with trace_capture.unit() if trace_capture is not None else nullcontext():
+                t_step = time.perf_counter()
+                with span("host_step_dispatch", aggregator=spans, step=i):
                     metrics = fn(batch)
-            # Each jit variant's first call is trace+compile — both the
-            # fast step's (iteration 0) and the logging step's (first log
-            # boundary) — and must not pollute the steady-state
-            # percentiles.
-            stats.observe(time.perf_counter() - t_step, is_compile=first_call)
+                # Each jit variant's first call is trace+compile — both the
+                # fast step's (iteration 0) and the logging step's (first
+                # log boundary) — and must not pollute the steady-state
+                # percentiles.
+                stats.observe(time.perf_counter() - t_step, is_compile=first_call)
             if "nonfinite_step" in metrics and not logging_step:
                 pending_flags.append((i, metrics["nonfinite_step"]))
             if not logging_step:
                 continue
-            with span("host_log_fetch", aggregator=spans):
+            with span("host_log_fetch", aggregator=spans, step=i):
                 metrics = diag.split_level_agreement(metrics)
                 metrics = {k: _jsonable(v) for k, v in metrics.items()}
             metrics["steps_per_sec"] = (i + 1) / (time.perf_counter() - t0)
@@ -576,7 +598,11 @@ def fit_loop(
                 # No writer: feed the flight recorder directly so a crash
                 # in a writerless run still has a postmortem trail.
                 flight.observe_event(rec)
-            for srec in spans.records(extra={"step": rec.get("step", float(i))}):
+            at_step = {"step": rec.get("step", float(i))}
+            span_recs = spans.records(extra=at_step)
+            if data_span_records is not None:
+                span_recs += data_span_records(extra=at_step)
+            for srec in span_recs:
                 if metrics_writer is not None:
                     metrics_writer.write(srec)
                 else:

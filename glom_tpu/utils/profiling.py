@@ -6,10 +6,8 @@ the new surface (docs/OBSERVABILITY.md). The original names keep working
 from here:
 
     trace / start_server / annotate  -> glom_tpu.tracing.capture
-    perf_report / StepTimer          -> glom_tpu.tracing.report
 """
 
 from glom_tpu.tracing.capture import annotate, start_server, trace
-from glom_tpu.tracing.report import StepTimer, perf_report
 
-__all__ = ["StepTimer", "annotate", "perf_report", "start_server", "trace"]
+__all__ = ["annotate", "start_server", "trace"]

@@ -1,0 +1,159 @@
+"""One vocabulary of phases (tracing.spans.PHASES) on every device op of a
+training step: each Pallas kernel goes by a `name=` that starts with a
+phase, and the compiled step of every step builder carries the named
+scopes in its instructions' `op_name`, with next to nothing left outside.
+
+CPU only: what is checked is metadata (names and counts), never a time.
+"""
+
+import ast
+import collections
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from glom_tpu.tracing.spans import DEVICE_PHASES, HOST_PHASES, PHASES
+from glom_tpu.utils.config import GlomConfig, TrainConfig
+
+KERNELS = pathlib.Path(__file__).resolve().parent.parent / "glom_tpu" / "kernels"
+N_PALLAS_CALLS = 25
+
+
+def _pallas_call_names():
+    """(file, line, name= literal or None) of every pallas_call site."""
+    sites = []
+    for path in sorted(KERNELS.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                name = next((kw.value for kw in node.keywords if kw.arg == "name"), None)
+                literal = (name.value if isinstance(name, ast.Constant)
+                           and isinstance(name.value, str) else None)
+                sites.append((path.name, node.lineno, literal))
+    return sites
+
+
+class TestKernelNames:
+    def test_every_pallas_call_site_has_a_literal_name(self):
+        sites = _pallas_call_names()
+        assert len(sites) == N_PALLAS_CALLS
+        assert [s for s in sites if s[2] is None] == []
+
+    def test_names_are_unique_lower_snake(self):
+        names = [s[2] for s in _pallas_call_names()]
+        assert len(set(names)) == len(names)
+        assert all(re.fullmatch(r"[a-z][a-z0-9]*(_[a-z0-9]+)*", n) for n in names)
+
+    def test_names_start_with_a_phase_and_say_their_direction(self):
+        for fname, line, name in _pallas_call_names():
+            assert any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), (
+                fname, line, name)
+            assert {"fwd", "bwd"} & set(name.split("_")), (fname, line, name)
+
+    def test_vocabulary_is_host_then_device_without_repeats(self):
+        assert PHASES == HOST_PHASES + DEVICE_PHASES
+        assert len(set(PHASES)) == len(PHASES)
+        assert all(p.startswith("host_") for p in HOST_PHASES)
+
+
+# --------------------------------------------------- scopes in compiled steps
+
+CFG = GlomConfig(dim=32, levels=3, image_size=16, patch_size=4)
+_SKIP_OPCODES = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast")
+
+
+def phase_counts(hlo_text: str) -> collections.Counter:
+    """Instructions of a compiled program by the innermost phase in their
+    `op_name` (the reduction of benchmark/reduce_phases.py, on text).
+    Instructions the compiler made itself carry no op_name and are not the
+    program's to name."""
+    counts = collections.Counter()
+    for line in hlo_text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        op = re.search(r" = [^=]*? ([a-z][a-z0-9\-]*)\(", line)
+        if not m or not m.group(1) or not op or op.group(1) in _SKIP_OPCODES:
+            continue
+        phases = [t for t in re.findall(r"[A-Za-z0-9_]+", m.group(1))
+                  if t in DEVICE_PHASES]
+        counts[phases[-1] if phases else "(none)"] += 1
+    return counts
+
+
+def _single_device_step(use_pallas: bool, with_grad_norm: bool):
+    from glom_tpu.train.trainer import create_train_state, make_train_step
+
+    tcfg = TrainConfig(batch_size=8, use_pallas=use_pallas)
+    state, opt = create_train_state(jax.random.PRNGKey(0), CFG, tcfg, None)
+    step = make_train_step(CFG, tcfg, opt, with_grad_norm=with_grad_norm)
+    return jax.jit(step), state
+
+
+def _manual_step(zero_stage: int):
+    from glom_tpu.parallel import DistributedTrainer
+    from glom_tpu.utils.config import MeshConfig
+
+    tcfg = TrainConfig(batch_size=8, use_pallas=True, zero_stage=zero_stage)
+    trainer = DistributedTrainer(CFG, tcfg, MeshConfig(data=4))
+    assert trainer.use_manual and trainer.zero_stage == zero_stage
+    return trainer._step, trainer.state
+
+
+def _gspmd_step():
+    from glom_tpu.parallel import DistributedTrainer
+    from glom_tpu.utils.config import MeshConfig
+
+    tcfg = TrainConfig(batch_size=8, use_pallas=False)
+    trainer = DistributedTrainer(CFG, tcfg, MeshConfig(data=4))
+    assert not trainer.use_manual
+    return trainer._step, trainer.state
+
+
+BUILDERS = {
+    # builder -> (make, the device phases its step must carry)
+    "make_train_step.scan": (
+        lambda: _single_device_step(False, True),
+        {"noise", "image_to_tokens", "loop", "bottom_up", "top_down", "consensus",
+         "mean_update", "reconstruction", "optimizer", "step_metrics"}),
+    "make_train_step.scan_fast_variant": (
+        lambda: _single_device_step(False, False),
+        {"noise", "image_to_tokens", "loop", "bottom_up", "top_down", "consensus",
+         "mean_update", "reconstruction", "optimizer"}),
+    "make_train_step.level_major": (
+        lambda: _single_device_step(True, True),
+        {"noise", "image_to_tokens", "loop", "bottom_up", "top_down",
+         "consensus_update", "reconstruction", "optimizer", "step_metrics"}),
+    "make_manual_train_step.dp4": (
+        lambda: _manual_step(0),
+        {"noise", "image_to_tokens", "loop", "bottom_up", "top_down",
+         "consensus_update", "reconstruction", "optimizer", "step_metrics"}),
+    "make_manual_zero_train_step.dp4": (
+        lambda: _manual_step(1),
+        {"noise", "image_to_tokens", "loop", "bottom_up", "top_down",
+         "consensus_update", "reconstruction", "grad_reduce", "optimizer",
+         "step_metrics"}),
+    "gspmd.dp4": (
+        _gspmd_step,
+        {"noise", "image_to_tokens", "loop", "bottom_up", "top_down", "consensus",
+         "mean_update", "reconstruction", "optimizer", "step_metrics"}),
+}
+
+
+@pytest.mark.parametrize("builder", sorted(BUILDERS))
+def test_compiled_step_carries_every_phase(builder):
+    make, expected = BUILDERS[builder]
+    if "dp4" in builder and len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    step, state = make()
+    img = jnp.zeros((8, CFG.channels, CFG.image_size, CFG.image_size), jnp.float32)
+    text = step.lower(state, img, jax.random.PRNGKey(1)).compile().as_text()
+    counts = phase_counts(text)
+    named = sum(counts.values())
+    assert named > 100, counts
+    missing = expected - set(counts)
+    assert not missing, (missing, counts)
+    assert not ({"step_metrics"} & set(counts)) or "step_metrics" in expected, counts
+    assert counts["(none)"] < 0.05 * named, counts

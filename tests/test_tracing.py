@@ -10,6 +10,7 @@ spans' and flight recorder's own contract.
 """
 
 import json
+import threading
 
 import jax.numpy as jnp
 import numpy as np
@@ -107,9 +108,14 @@ class FakeProfiler:
 
     def __init__(self):
         self.calls = []
+        self.options = []  # the profiler_options of each start_trace
 
-    def start_trace(self, log_dir):
+    class ProfileOptions:
+        python_tracer_level = 1  # the real default: Python tracer on
+
+    def start_trace(self, log_dir, profiler_options=None):
         self.calls.append(("start", log_dir))
+        self.options.append(profiler_options)
 
     def stop_trace(self):
         self.calls.append(("stop", None))
@@ -190,6 +196,23 @@ class TestTraceCapture:
         with cap.unit():
             pass
         assert len(fake_profiler.calls) == 2
+
+    def test_window_opens_with_python_tracer_off(self, fake_profiler):
+        # With the Python tracer on, opening a trace stalls every Python
+        # thread for seconds and the window measures the profiler.
+        cap = TraceCapture.parse("0:0", "/tmp/tr", writer=ListWriter())
+        with cap.unit():
+            pass
+        (opts,) = fake_profiler.options
+        assert opts.python_tracer_level == 0
+
+    def test_whole_block_trace_opens_with_python_tracer_off(self, fake_profiler):
+        from glom_tpu.tracing.capture import trace
+
+        with trace("/tmp/tr"):
+            pass
+        (opts,) = fake_profiler.options
+        assert opts.python_tracer_level == 0
 
 
 class FakeDevice:
@@ -499,6 +522,168 @@ class TestFitLoopTracingHooks:
         assert cap._count == 4
 
 
+class RecordingAnnotation:
+    """Stands where spans.py puts jax.profiler.TraceAnnotation: records
+    (thread, event, name, fields) of every enter and exit."""
+
+    log = None  # a list, set by the fixture
+
+    def __init__(self, name, **fields):
+        self.name, self.fields = name, fields
+
+    def __enter__(self):
+        self.log.append((threading.get_ident(), "enter", self.name, self.fields))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append((threading.get_ident(), "exit", self.name, self.fields))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    from glom_tpu.tracing import spans
+
+    log = []
+    monkeypatch.setattr(RecordingAnnotation, "log", log)
+    monkeypatch.setattr(spans, "_annotation_cls", RecordingAnnotation)
+    return log
+
+
+def _endless(shape=(2, 3)):
+    while True:
+        yield np.ones(shape, np.float32)
+
+
+class TestSpansInTheProfilerTrace:
+    """The program's host spans are TraceAnnotations of the same name, on
+    the thread that runs them, carrying the step they belong to."""
+
+    def test_fit_loop_spans_annotate_with_the_step_index(self, annotations):
+        from glom_tpu.train.trainer import fit_loop
+
+        fit_loop(lambda b: {"loss": 1.0, "step": 0.0}, _endless(), 3,
+                 log_every=2, metrics_writer=ListWriter())
+        me = threading.get_ident()
+        assert {t for t, *_ in annotations} == {me}
+        entered = [(n, f) for _, ev, n, f in annotations if ev == "enter"]
+        assert entered == [
+            ("host_data_next", {"step": 0}), ("host_step_dispatch", {"step": 0}),
+            ("host_data_next", {"step": 1}), ("host_step_dispatch", {"step": 1}),
+            ("host_log_fetch", {"step": 1}),
+            ("host_data_next", {"step": 2}), ("host_step_dispatch", {"step": 2}),
+            ("host_log_fetch", {"step": 2}),
+        ]
+        # every span is left before the next is entered: no nesting
+        events = [ev for _, ev, _, _ in annotations]
+        assert events == ["enter", "exit"] * len(entered)
+
+    def test_prefetch_worker_annotates_on_its_own_thread(self, annotations):
+        from glom_tpu.data.prefetch import prefetch_to_device
+        from glom_tpu.train.trainer import fit_loop
+
+        data = prefetch_to_device(_endless(), size=2)
+        fit_loop(lambda b: {"loss": 1.0, "step": 0.0}, data, 3, log_every=3)
+        data.close()
+        me = threading.get_ident()
+        worker = [(n, f["step"]) for t, ev, n, f in annotations
+                  if t != me and ev == "enter"]
+        assert len({t for t, *_ in annotations}) == 2
+        assert {n for n, _ in worker} == {"host_prefetch_next", "host_prefetch_stage"}
+        staged = [k for n, k in worker if n == "host_prefetch_stage"]
+        assert staged == list(range(len(staged))) and len(staged) >= 3
+        loop = {n for t, ev, n, f in annotations if t == me}
+        assert loop == {"host_data_next", "host_step_dispatch", "host_log_fetch"}
+
+    def test_span_fields_ride_event_and_annotation(self, annotations):
+        w = ListWriter()
+        with span("phase_a", writer=w, step=7):
+            pass
+        assert annotations[0][2:] == ("phase_a", {"step": 7})
+        assert w.records[0]["step"] == 7
+
+    def test_span_works_with_jax_unimportable(self, monkeypatch):
+        import sys
+
+        from glom_tpu.tracing import spans
+
+        monkeypatch.setattr(spans, "_annotation_cls", None)  # not yet resolved
+        monkeypatch.setitem(sys.modules, "jax", None)         # import jax -> ImportError
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        agg = SpanAggregator()
+        with span("phase_a", aggregator=agg, step=1):
+            with span("phase_b", aggregator=agg):
+                assert current_span() == "phase_b"
+        assert spans._annotation_cls is spans._no_annotation
+        assert [r["name"] for r in agg.records()] == ["phase_a", "phase_b"]
+
+    def test_spans_module_imports_without_jax(self):
+        import subprocess
+        import sys
+
+        code = (
+            "import sys; sys.modules['jax'] = None\n"
+            "from glom_tpu.tracing.spans import PHASES, span, SpanAggregator\n"
+            "agg = SpanAggregator()\n"
+            "with span(PHASES[0], aggregator=agg, step=0): pass\n"
+            "assert agg._stats[PHASES[0]][0] == 1\n"
+            "assert not [m for m in sys.modules if m.startswith('jax.')]\n"
+        )
+        subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+class TestPrefetchRollupsAtLogBoundaries:
+    """A training stream does not end inside a run: the worker's rollups
+    reach the metrics stream with the loop's, at every log boundary."""
+
+    def test_endless_stream_reports_at_each_boundary_once(self):
+        from glom_tpu.data.prefetch import prefetch_to_device
+        from glom_tpu.train.trainer import fit_loop
+
+        pulled = []
+
+        def source():
+            while True:
+                pulled.append(1)
+                yield np.ones((2, 3), np.float32)
+
+        end_sink, w = ListWriter(), ListWriter()
+        data = prefetch_to_device(source(), size=2, metrics_writer=end_sink)
+        fit_loop(lambda b: {"loss": 1.0, "step": 0.0}, data, 6, log_every=2,
+                 metrics_writer=w)
+        kinds = [(r["kind"], r.get("name")) for r in w.records]
+        boundaries = [i for i, k in enumerate(kinds) if k[0] == "train_step"]
+        assert len(boundaries) == 3
+        for lo, hi in zip(boundaries, boundaries[1:] + [len(kinds)]):
+            names = [n for k, n in kinds[lo:hi] if k == "span"]
+            assert sorted(names) == sorted(
+                ["host_data_next", "host_step_dispatch", "host_log_fetch",
+                 "host_prefetch_next", "host_prefetch_stage"])
+        for r in w.records:
+            assert schema.validate_record(r) == [], r
+            if r.get("name", "").startswith("host_prefetch_"):
+                assert r["source"] == "prefetch_to_device" and "step" in r
+        assert end_sink.records == []  # the stream has not ended
+        data.close()
+        # The end of the stream reports what no boundary has: each batch the
+        # worker pulled and staged is counted exactly once over both sinks.
+        both = w.records + end_sink.records
+        n_next = sum(r["count"] for r in both if r.get("name") == "host_prefetch_next")
+        n_stage = sum(r["count"] for r in both if r.get("name") == "host_prefetch_stage")
+        assert n_next == len(pulled)
+        assert n_next - 1 <= n_stage <= n_next
+        assert n_stage >= 6
+
+    def test_plain_iterator_has_no_rollups_to_drain(self):
+        from glom_tpu.train.trainer import fit_loop
+
+        w = ListWriter()
+        fit_loop(lambda b: {"loss": 1.0, "step": 0.0}, _endless(), 2, log_every=2,
+                 metrics_writer=w)
+        names = {r["name"] for r in w.records if r["kind"] == "span"}
+        assert names == {"host_data_next", "host_step_dispatch", "host_log_fetch"}
+
+
 class TestProfilingShim:
     def test_reexports_are_the_tracing_objects(self):
         from glom_tpu import tracing
@@ -507,8 +692,6 @@ class TestProfilingShim:
         assert profiling.trace is tracing.capture.trace
         assert profiling.start_server is tracing.capture.start_server
         assert profiling.annotate is tracing.capture.annotate
-        assert profiling.perf_report is tracing.report.perf_report
-        assert profiling.StepTimer is tracing.report.StepTimer
 
     def test_trace_context_manager_drives_profiler(self, fake_profiler):
         from glom_tpu.utils.profiling import trace
@@ -522,32 +705,6 @@ class TestProfilingShim:
             with trace("/tmp/shimtrace2"):
                 raise RuntimeError("boom")
         assert fake_profiler.calls[-1] == ("stop", None)
-
-    def test_perf_report_math(self):
-        from glom_tpu.utils.config import GlomConfig
-        from glom_tpu.utils.metrics import flops_per_column_iter, mfu
-        from glom_tpu.utils.profiling import perf_report
-
-        cfg = GlomConfig(dim=16, levels=3, image_size=8, patch_size=2)
-        rep = perf_report(
-            cfg, column_iters_per_sec=1000.0, chip="v5e", num_chips=2,
-            backward=True,
-        )
-        assert rep["column_iters_per_sec_per_chip"] == 500.0
-        assert rep["flops_per_column_iter"] == flops_per_column_iter(cfg)
-        assert rep["mfu"] == mfu(cfg, 500.0, chip="v5e", backward=True)
-        assert rep["num_chips"] == 2
-
-    def test_step_timer_best(self):
-        from glom_tpu.utils.profiling import StepTimer
-
-        t = StepTimer()
-        for _ in range(3):
-            t.start()
-            t.stop(sync_scalar=jnp.float32(1.0))
-        assert len(t.history) == 3
-        assert t.best == min(t.history)
-        assert t.best >= 0
 
 
 class TestHostSpanCoverage:
