@@ -55,10 +55,9 @@ def train_readings(cell, seeds, precision):
         low = drv.reference_numbers(cell, seed, pool, tcfg, precision=precision)
         low["loss_steps"] = program["loss_steps"]
         low["losses"] = [low["losses"][s] for s in low["loss_steps"]]
-        loose = dict.fromkeys(cell["limits"], float("inf"))
         rows.append({"seed": seed,
-                     "sound": cmp.compare_train(program, ref, loose)["numbers"],
-                     "control": cmp.compare_train(low, ref, loose)["numbers"],
+                     "sound": cmp.train_numbers(program, ref),
+                     "control": cmp.train_numbers(low, ref),
                      "seconds": time.perf_counter() - t0})
         print("READING", json.dumps(rows[-1]), flush=True)
     return rows
@@ -74,7 +73,6 @@ def serve_readings(cell, seeds, precision, seconds):
 
     harness.start_jax(cell["chips"])
     traf = cell["traffic_file"]
-    loose = dict.fromkeys(cell["limits"], float("inf"))
     rows = []
     for seed in seeds:
         t0 = time.perf_counter()
@@ -95,8 +93,7 @@ def serve_readings(cell, seeds, precision, seconds):
         low = drv.reference_columns(cell, seed, images, kept, iters, precision=precision)
         sound = [(kept[i], ref[i]) for i in sorted(kept)]
         control = [(low[i], ref[i]) for i in sorted(kept)]
-        errs = lambda pairs: [cmp.compare_serve([p], loose)["numbers"]["columns_rel_rms_worst"]
-                              for p in pairs]
+        errs = lambda pairs: [cmp.serve_numbers([p])["columns_rel_rms_worst"] for p in pairs]
         s_err, c_err = errs(sound), errs(control)
         rows.append({"seed": seed,
                      "sound": {"columns_rel_rms_worst": max(s_err)},

@@ -211,20 +211,22 @@ def run(cell: dict, args, clock) -> int:
     jax.clear_caches()
     t_ref = time.perf_counter()
     ref = reference_columns(cell, seed, images, kept, iters)
-    ok = cmp.compare_serve([(kept[i], ref[i]) for i in sorted(kept)],
-                           cell["limits"])["ok"]
+    verdict = cmp.Verdict()
+    verdict.numbers(cmp.serve_numbers([(kept[i], ref[i]) for i in sorted(kept)]),
+                    cell["limits"])
     log(f"reference took {time.perf_counter() - t_ref:.2f}s")
     if cfgf["bench"].get("expect_mosaic_calls", True):
-        ok &= cmp.require("every warmed program holds a Mosaic call", mosaic_ok)
-    ok &= cmp.require(f"a sample of {n_check} finished requests was compared",
-                      len(kept) >= max(1, n_check - failed))
+        verdict.fact("warmed_programs", "all hold a Mosaic call" if mosaic_ok
+                     else "one holds none", "a Mosaic call in each", mosaic_ok)
+    verdict.fact("requests_compared", len(kept), f"{max(1, n_check - failed)} or more",
+                 len(kept) >= max(1, n_check - failed))
 
     # Whichever of these the cell's entry lists as end-to-end is reported as
     # such; the latencies are in `ctx` for their per-layer readers as well.
     user_facing = {"serve_images_per_s": images_per_s, "serve_p50_ms": p50,
                    "serve_p95_ms": p95, "setup_s": setup_s}
     return harness.report(
-        cell, args, correct=ok, attempted=attempted, failed=failed,
+        cell, args, verdict=verdict, attempted=attempted, failed=failed,
         end_to_end={m["name"]: {"value": user_facing[m["name"]], "unit": m["unit"]}
                     for m in cell["end_to_end"]},
         device=dict(dev, memory_peak_bytes=peak),

@@ -287,13 +287,20 @@ def quantile(sorted_values, q: float) -> float:
     return sorted_values[k]
 
 
-def print_result(*, correct: bool, attempted: int, failed: int, metrics: dict,
+def print_result(*, verdict, attempted: int, failed: int, metrics: dict,
                  device: dict, breakdown=None) -> None:
-    line = {"correct": bool(correct), "attempted": int(attempted),
+    """The run's last lines: on standard error everything `correct` was
+    decided from, each number beside its limit (`correct.Verdict`); on
+    standard output the result object, with the same under `compared`, its
+    last key."""
+    line = {"correct": bool(verdict.ok), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = verdict.compared
     sys.stdout.flush()
+    print("\n".join(verdict.lines + [f"correct: {bool(verdict.ok)}"]),
+          file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
 
 
@@ -311,13 +318,13 @@ class Collector:
             self.records.append(rec)
 
 
-def report(cell: dict, args, *, correct: bool, attempted: int, failed: int,
+def report(cell: dict, args, *, verdict, attempted: int, failed: int,
            end_to_end: dict, device: dict, ctx: dict, trace_dir) -> int:
     """The run's last line. Untraced: the end-to-end metrics. Traced: the
     trace is reduced, every per-layer reader of the cell reads `ctx`, and the
     device record gains busy_s/window_s and the breakdown."""
     if not args.trace:
-        print_result(correct=correct, attempted=attempted, failed=failed,
+        print_result(verdict=verdict, attempted=attempted, failed=failed,
                      metrics=end_to_end, device=device)
         return 0
     from benchmark.reduce_trace import reduce_xplane
@@ -331,7 +338,7 @@ def report(cell: dict, args, *, correct: bool, attempted: int, failed: int,
         breakdown = {"device_ops": trace["top_ops"][:10],
                      "idle_gaps": trace["idle_gaps"][:10]}
         log(f"trace: {trace['summary']}")
-    print_result(correct=correct, attempted=attempted, failed=failed,
+    print_result(verdict=verdict, attempted=attempted, failed=failed,
                  metrics=metrics, device=device, breakdown=breakdown)
     return 0
 

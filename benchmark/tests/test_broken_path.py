@@ -11,6 +11,26 @@ def test_sound_training_run_is_correct(capsys):
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["metrics"]) == {"train_col_iters_per_s_per_chip", "setup_s"}
     assert "compiles in window 0" in out
+    assert line["compared"]["route"] == {"value": "scan_dense", "limit": "scan_dense",
+                                         "ok": True}
+
+
+@pytest.mark.parametrize("expect, correct", [(None, True), ("fused_loop", False)])
+def test_the_configuration_may_leave_the_route_open_or_pin_it(capsys, expect, correct):
+    """Without `bench.expect_vjp_path` a sound run is correct on whatever
+    route the trainer resolved (on the CPU: scan_dense, with no kernel); with
+    it, a program on another route is not."""
+    cell = tiny_cell("flagship.train")
+    if expect is None:
+        del cell["config_file"]["bench"]["expect_vjp_path"]
+    else:
+        cell["config_file"]["bench"]["expect_vjp_path"] = expect
+    line, out = drive(cell, capsys)
+    assert line["correct"] is correct
+    assert line["compared"]["route"] == {"value": "scan_dense", "limit": expect or "any",
+                                         "ok": correct}
+    assert line["compared"]["records_vjp_path"]["ok"]
+    assert all(c["ok"] for name, c in line["compared"].items() if name != "route")
 
 
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(capsys, monkeypatch):

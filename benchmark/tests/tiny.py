@@ -40,6 +40,14 @@ def drive(cell: dict, capsys, *, seed: int = 2**31 + 12345, seconds: float = 1.5
     driver = importlib.import_module("benchmark.drivers." + cell["traffic_file"]["kind"])
     with on_cpu():
         rc = driver.run(cell, args, harness.Clock(time.perf_counter()))
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     assert rc == 0
-    return json.loads(out.strip().splitlines()[-1]), out
+    line = json.loads(out.strip().splitlines()[-1])
+    # everything `correct` was decided from: the line's last key, and the
+    # last lines of standard error
+    assert list(line)[-1] == "compared" and line["compared"]
+    said = err.strip().splitlines()[-len(line["compared"]) - 1:]
+    assert said[-1] == f"correct: {line['correct']}"
+    for text, (name, c) in zip(said, line["compared"].items()):
+        assert text.startswith(f"correct: {name} = ") and text.endswith("  ok") == c["ok"]
+    return line, out
