@@ -1262,39 +1262,31 @@ def _dense_bwd_budget() -> int:
 def _use_blockwise_bwd(
     levels_shape, side, radius, bwd_impl: str, itemsize: int = 2
 ) -> bool:
-    """Measured (n, radius) crossover between the dense-recompute VJP and
-    the blockwise backward kernels (results/longctx_bench.jsonl):
+    """Which side serves the fused op at this static shape: the blockwise
+    Pallas kernels (True) or the dense XLA composition (False). Decided
+    from (L, B, n, d), side, radius and itemsize alone — no environment
+    variable, config field or preset name reaches it.
 
-      * the dense VJP — one XLA fusion over the materialized [n, n]
-        similarity — wins for global consensus at every n that fits HBM
-        (it runs the same matmul count at full MXU rate, no tile logic);
-      * the blockwise kernels win when the local-radius band prunes most
-        of the row (its grid never visits dead tiles), and are the ONLY
-        option when the dense sim buffer would blow HBM (any n, since the
-        streaming rewrite removed the row-residency cap).
+    Two bodies of evidence stand behind the branches, and each branch says
+    which it rests on:
+
+      * v5e, the full train step through benchmark/run.py, default against
+        forced side on shared seeds (PERF.md section 6, PR 26): the
+        batched long-row branch;
+      * results/longctx_bench.jsonl, unstamped B=1 timings of the isolated
+        op from a remote chip (ROADMAP D9): the band-sparsity branch, the
+        n >= 4096 one-sweep branch and the dense default they leave. They
+        keep their text until a cell measures them.
+
+    The HBM gate at the end is arithmetic, not a timing: the dense side is
+    not an option where its [L, B, n, n] f32 scores do not fit.
 
     bwd_impl forces a side ('blockwise' / 'dense') for tests and benches.
     `itemsize` is the compute dtype's — callers on the training path pass
-    the real one so the n>=4096 one-sweep branch and _fused_fwd's
-    save_cons gate share one predicate (an f32 long row must not be
-    routed blockwise without its cons residual).
+    the real one so the one-sweep branches and _fused_fwd's save_cons gate
+    share one predicate (an f32 long row must not be routed blockwise
+    without its cons residual).
     """
-    import os
-    import warnings
-
-    if bwd_impl == "auto":
-        # bench/debug override (read at trace time): lets bench_train
-        # compare dispatch sides at the full train step without a config
-        # field for what is a measurement knob.
-        env = os.environ.get("GLOM_CONSENSUS_BWD", "auto")
-        if env in ("auto", "blockwise", "dense"):
-            bwd_impl = env
-        else:
-            warnings.warn(
-                f"GLOM_CONSENSUS_BWD={env!r} ignored (valid: auto / "
-                "blockwise / dense)",
-                stacklevel=3,
-            )
     if bwd_impl not in ("auto", "blockwise", "dense"):
         raise ValueError(
             f"bwd_impl={bwd_impl!r}: one of 'auto', 'blockwise', 'dense'"
@@ -1304,6 +1296,8 @@ def _use_blockwise_bwd(
         return True
     if bwd_impl == "dense":
         return False
+    # (B=1 file) A local band that prunes at least half the row: the
+    # kernels' grids never visit dead tiles.
     if radius > 0:
         reach = int(radius + 1) * side
         live = min(n, 2 * reach + _pick_tile(n))
@@ -1313,20 +1307,44 @@ def _use_blockwise_bwd(
     # backward keeps the scores in VMEM while the dense VJP sweeps the
     # [B, L, n, n] scores through HBM several times — measured at the
     # flagship train step (B=64, n=256): ~3950 vs 3522 col-iters/s
-    # full-step. Confined to the measured region (batched AND n within
-    # the single-tile kernel); the batched long-row region (B>=8,
-    # n>=1024 global) is unmeasured and stays on the dense side that won
-    # at B=1 (0.28 vs 0.47 ms at n=1024, 7.2 vs 7.6 ms at n=4096) until
-    # its sim buffer trips the memory cap below.
+    # full-step (round 4's remote chip; the flagship cells resolve
+    # fused_loop before they reach this line, so no cell re-reads it).
     if B >= 8 and n <= _SMALL_BWD_N:
         return True
-    # Long global rows: the one-sweep kernel (scores once, no inter-pass
-    # HBM round trips) wins where its whole-row dq accumulator fits VMEM —
-    # measured 5.61 vs 7.23 ms at n=4096 r=0 B=1 and 27.6 vs 30.5 ms at
-    # n=9216 r=0 (results/longctx_bench.jsonl, round 4; the round-3
-    # two-pass form LOST 38.8 vs 30.5 there). Below the crossover the
-    # dense path keeps the mid-n global regime (0.281 vs 0.388 at n=1024
-    # B=1). The HBM budget remains the hard gate for dense regardless.
+    # Batched LONG rows, 512 < n < 4096, at the 256-wide tile (PR 26, v5e,
+    # full train step at L=6 d=512 bf16, dense -> blockwise):
+    #   n=1024 r=7  B=32  373.34 -> 317.72 ms a step (596.1 -> 699.7
+    #               col-iters/s/chip, peak HBM 15.53 -> 8.67 GB)
+    #   n=1024 r=7  B=16  169.67 -> 147.26 ms;  B=8  85.54 -> 72.27 ms
+    #               (642.0 -> 756.0): the B >= 8 read at n=256 holds here
+    #   n=1024 r=0  B=32  373.26 -> 322.25 ms (596.6 -> 689.9): the band is
+    #               not what wins — the kernels' time rises 6% from 10 to
+    #               16 of 16 tile pairs, XLA's dense f32 scores cost
+    #               151.6-151.8 ms at either radius — so radius is not a
+    #               condition of this branch
+    #   n=1024 r=0  B=8   85.34 -> 73.44 ms
+    #   n=2304 r=0  B=8   286.39 -> 226.30 ms (peak HBM 16.39 -> 10.74 GB;
+    #               the dense side fills the chip there)
+    # and the edge it stops at: n=576 r=7 B=32, where _pick_tile gives 64
+    # and the kernels LOSE, 171.63 -> 188.07 ms (1291.9 -> 1178.2). A
+    # square grid's n tiles at 256 or at 64 and below, nothing between, so
+    # the branch asks for the tile it was measured at. B < 8 is unmeasured
+    # at the train step and stays on the dense side of the B=1 file.
+    if (
+        B >= 8
+        and _SMALL_BWD_N < n < 4096
+        and _pick_tile(n) == 256
+        and _onesweep_ok(B, n, d, itemsize)
+    ):
+        return True
+    # (B=1 file) Long global rows: the one-sweep kernel (scores once, no
+    # inter-pass HBM round trips) wins where its whole-row dq accumulator
+    # fits VMEM — measured 5.61 vs 7.23 ms at n=4096 r=0 B=1 and 27.6 vs
+    # 30.5 ms at n=9216 r=0 (results/longctx_bench.jsonl, round 4; the
+    # round-3 two-pass form LOST 38.8 vs 30.5 there). Below the crossover
+    # the dense path keeps the small-batch mid-n regime (0.281 vs 0.388 at
+    # n=1024 B=1). The HBM budget remains the hard gate for dense
+    # regardless.
     if n >= 4096 and _onesweep_ok(B, n, d, itemsize):
         return True
     return 2 * L * B * n * n * 4 > _dense_bwd_budget()
@@ -1444,12 +1462,10 @@ def fused_consensus_update(
     levels_lm: [L, B, n, d] level-major; bu_lm: [L, B, n, d];
     td_lm: [L-1, B, n, d] (top level's zero contribution is implicit).
     Returns [L, B, n, d]. Falls back to the XLA composition off-TPU.
-    bwd_impl: 'auto' dispatches the backward between the dense-recompute
-    VJP and the streamed blockwise kernels by the measured (n, radius)
-    crossover; 'blockwise'/'dense' force a side (tests, benches).
+    bwd_impl: 'auto' dispatches between the dense XLA composition and the
+    blockwise kernels by _use_blockwise_bwd's measured regions;
+    'blockwise'/'dense' force a side (tests, benches).
     """
-    import os
-
     L, B, n, d = levels_lm.shape
     on_tpu = jax.devices()[0].platform == "tpu"
     supported = d % 128 == 0 and n % 8 == 0 and L >= 2
@@ -1462,15 +1478,13 @@ def fused_consensus_update(
     # directions there (fwd 0.118 vs 0.139 ms, autodiff bwd 0.281 vs 0.354
     # at n=1024 B=1 — longctx bench), so hand the WHOLE op to XLA autodiff:
     # zero custom_vjp overhead by construction (round-3 weak #3's 17%).
-    # Forced sides (bwd_impl or the env override) keep the custom_vjp so
-    # tests and A/B benches still reach the kernel paths; n >= 4096 keeps
-    # the hybrid (the Pallas forward wins there: 1.66 vs 3.13 ms).
-    forced = (
-        bwd_impl != "auto"
-        or os.environ.get("GLOM_CONSENSUS_BWD", "auto") != "auto"
-    )
+    # A forced side (bwd_impl) keeps the custom_vjp so tests and benches
+    # still reach the kernel paths; n >= 4096 keeps the hybrid (the Pallas
+    # forward wins there: 1.66 vs 3.13 ms). The forward rides the same
+    # decision: where the predicate says blockwise, a forward-only call
+    # (evaluation, serving) runs consensus_update_fwd too.
     if (
-        not forced
+        bwd_impl == "auto"
         and n < 4096
         and not _use_blockwise_bwd(
             (L, B, n, d), side, radius, bwd_impl, levels_lm.dtype.itemsize

@@ -243,23 +243,19 @@ def resolve_vjp_path(
     directly and never dispatch to the whole-loop VJP.
 
     assume_on_tpu=True bypasses only the platform check (the CPU
-    interpret-mode shard tests drive the real dispatch policy — including
-    the GLOM_CONSENSUS_BWD gate — without hardware).
+    interpret-mode shard tests drive the real dispatch policy without
+    hardware).
     """
-    import os
-
     from glom_tpu.kernels.consensus_update import _use_blockwise_bwd
     from glom_tpu.kernels.fused_loop import loop_supported
 
     n, d, L = cfg.num_patches, cfg.dim, cfg.levels
     if not use_pallas or custom_consensus or not (assume_on_tpu or _on_tpu()):
         return "scan_dense"
-    env_auto = os.environ.get("GLOM_CONSENSUS_BWD", "auto") == "auto"
     if (
         not scan_only
         and not return_all
         and b >= 8
-        and env_auto
         and loop_supported(
             L, b, n, d, d * cfg.mult, itemsize, iters, n, remat
         )
@@ -283,13 +279,12 @@ def _use_fused_loop(
     consensus_update._use_blockwise_bwd's crossover table). remat=True
     rides the loop too (round 5): the VJP's recompute-per-iteration mode
     keeps the glue-free structure at BASELINE config 5's
-    checkpoint-over-iters regime. The GLOM_CONSENSUS_BWD=dense override
-    disables it so bench A/B comparisons still reach the dense VJP.
+    checkpoint-over-iters regime.
 
     Thin shape-consistency gate over resolve_vjp_path (the single
-    resolution source — the non-auto-env / b<8 / return_all policy lives
-    THERE): this checks only what requires the actual params and tokens
-    (dtype agreement, pos-emb/config coherence)."""
+    resolution source — the b<8 / return_all policy lives THERE): this
+    checks only what requires the actual params and tokens (dtype
+    agreement, pos-emb/config coherence)."""
     if exists(levels_in) and levels_in.dtype != params.init_levels.dtype:
         return False
     if (n, d) != (cfg.num_patches, cfg.dim) or params.pos_emb.shape[0] != n:

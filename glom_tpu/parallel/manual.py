@@ -123,9 +123,9 @@ def _use_loop_vjp(
 ) -> bool:
     """Should this seq=1/mp=1 shard body dispatch to the whole-loop VJP
     (kernels/fused_loop.py) instead of scanning the per-op kernels? This
-    is resolve_vjp_path — THE resolution source, including the
-    GLOM_CONSENSUS_BWD A/B gate — at the SHARD-LOCAL batch: a DP run must
-    get the same glue-free backward the single-chip flagship gets.
+    is resolve_vjp_path — THE resolution source — at the SHARD-LOCAL
+    batch: a DP run must get the same glue-free backward the single-chip
+    flagship gets.
     interpret=True (CPU shard_map tests) bypasses only the platform
     check; the policy itself is never duplicated here."""
     from glom_tpu.models.core import resolve_vjp_path

@@ -465,33 +465,123 @@ class TestFusedConsensusUpdate:
                 np.asarray(c), np.asarray(b), rtol=2e-3, atol=1e-5
             )
 
-    def test_bwd_dispatch_predicate(self):
-        """The measured-crossover dispatch (results/longctx_bench.jsonl,
-        round 4): long global rows go to the ONE-SWEEP blockwise kernel
-        (wins from n=4096 up: 5.6 vs 7.2 ms at n=4096 B=1, 27.6 vs 30.5 at
-        n=9216); mid rows stay dense; a truly-sparse local band goes
-        blockwise; forced sides are honored."""
+    # (shape, side, radius, bwd_impl) -> blockwise? Each case names the
+    # evidence its branch rests on: "PR 26" is the v5e full train step
+    # (PERF.md section 6), "B=1 file" is results/longctx_bench.jsonl.
+    DISPATCH_CASES = [
+        # flagship train (B=64, single-tile row): batched regime -> blockwise
+        pytest.param((6, 64, 256, 512), 16, 0.0, "auto", True, id="flagship-b64-n256"),
+        # small-batch inference-style at n=256 -> dense
+        pytest.param((6, 2, 256, 512), 16, 0.0, "auto", False, id="b2-n256"),
+        # PR 26, the cell: 373.34 -> 317.72 ms a step
+        pytest.param((6, 32, 1024, 512), 32, 7.0, "auto", True, id="local1024-b32-r7"),
+        # PR 26, point C: 169.67 -> 147.26 ms at B=16, 85.54 -> 72.27 at B=8
+        pytest.param((6, 16, 1024, 512), 32, 7.0, "auto", True, id="n1024-b16-r7"),
+        pytest.param((6, 8, 1024, 512), 32, 7.0, "auto", True, id="n1024-b8-r7"),
+        # below the batch threshold: unmeasured at the train step, stays
+        # on the dense side of the B=1 file
+        pytest.param((6, 4, 1024, 512), 32, 7.0, "auto", False, id="n1024-b4-r7"),
+        # PR 26, point B: global rows at n=1024, 373.26 -> 322.25 ms at B=32
+        pytest.param((6, 32, 1024, 512), 32, 0.0, "auto", True, id="n1024-b32-global"),
+        pytest.param((6, 8, 1024, 512), 32, 0.0, "auto", True, id="n1024-b8-global"),
+        # B=1 file: dense autodiff wins (0.281 vs 0.388 at n=1024)
+        pytest.param((6, 1, 1024, 512), 32, 0.0, "auto", False, id="n1024-b1-global"),
+        # PR 26, point D: n=576 tiles at 64 and the kernels lose there,
+        # 171.63 -> 188.07 ms
+        pytest.param((6, 32, 576, 512), 24, 7.0, "auto", False, id="n576-tile64"),
+        # the other square n in (512, 4096) that tiles at 256
+        pytest.param((6, 8, 2304, 512), 48, 0.0, "auto", True, id="n2304-b8-global"),
+        # B=1 file: long global rows (any batch), the one-sweep kernel wins
+        pytest.param((6, 1, 4096, 512), 64, 0.0, "auto", True, id="n4096-b1-global"),
+        pytest.param((6, 8, 4096, 512), 64, 0.0, "auto", True, id="n4096-b8-global"),
+        pytest.param((6, 1, 9216, 512), 96, 0.0, "auto", True, id="n9216-b1-global"),
+        # n=4096, radius 7 on side 64: band covers <1/2 the row -> blockwise
+        pytest.param((6, 1, 4096, 512), 64, 7.0, "auto", True, id="n4096-b1-r7"),
+        # n=16384 global (side 128): one-sweep dq block still fits
+        pytest.param((6, 1, 16384, 512), 128, 0.0, "auto", True, id="n16384-b1-global"),
+        # forced sides are honored
+        pytest.param((6, 64, 256, 512), 16, 0.0, "blockwise", True, id="forced-block"),
+        pytest.param((6, 1, 4096, 512), 64, 7.0, "dense", False, id="forced-dense"),
+        pytest.param((6, 32, 1024, 512), 32, 7.0, "dense", False, id="forced-dense-1024"),
+    ]
+
+    @pytest.mark.parametrize("shape,side,radius,impl,want", DISPATCH_CASES)
+    def test_bwd_dispatch_predicate(self, shape, side, radius, impl, want):
+        """The dispatch as the chip decided it: batched long rows at the
+        256-wide tile go to the blockwise kernels whatever the radius (PR
+        26's runs on the v5e); long global rows go to the one-sweep kernel
+        from n=4096 up, small-batch mid rows stay dense and a truly-sparse
+        local band goes blockwise (the B=1 file); forced sides are
+        honored. No environment variable takes part."""
         from glom_tpu.kernels.consensus_update import _use_blockwise_bwd
 
-        # flagship train (B=64, single-tile row): batched regime ->
-        # blockwise (measured faster at the full train step)
-        assert _use_blockwise_bwd((6, 64, 256, 512), 16, 0.0, "auto")
-        # small-batch inference-style at n=256 -> dense
-        assert not _use_blockwise_bwd((6, 2, 256, 512), 16, 0.0, "auto")
-        # mid global rows: dense autodiff wins (0.281 vs 0.388 at n=1024)
-        assert not _use_blockwise_bwd((6, 8, 1024, 512), 32, 0.0, "auto")
-        assert not _use_blockwise_bwd((6, 1, 1024, 512), 32, 0.0, "auto")
-        # long global rows (any batch): the one-sweep kernel wins
-        assert _use_blockwise_bwd((6, 1, 4096, 512), 64, 0.0, "auto")
-        assert _use_blockwise_bwd((6, 8, 4096, 512), 64, 0.0, "auto")
-        assert _use_blockwise_bwd((6, 1, 9216, 512), 96, 0.0, "auto")
-        # n=4096, radius 7 on side 64: band covers <1/2 the row -> blockwise
-        assert _use_blockwise_bwd((6, 1, 4096, 512), 64, 7.0, "auto")
-        # n=16384 global (side 128): one-sweep dq block still fits -> blockwise
-        assert _use_blockwise_bwd((6, 1, 16384, 512), 128, 0.0, "auto")
-        # forced
-        assert _use_blockwise_bwd((6, 64, 256, 512), 16, 0.0, "blockwise")
-        assert not _use_blockwise_bwd((6, 1, 4096, 512), 64, 7.0, "dense")
+        assert _use_blockwise_bwd(shape, side, radius, impl) is want
+
+    @pytest.mark.parametrize(
+        "preset,b,want",
+        [
+            # the cell: batch 32 of n=1024 under a radius-7 window
+            ("imagenet256-local", 32, "scan_blockwise"),
+            # a pure-DP run of it at the preset's data=2: 16 a shard
+            ("imagenet256-local", 16, "scan_blockwise"),
+            # the flagship and every preset at n <= 512 keep their route
+            ("imagenet224-dp8", 64, "fused_loop"),
+            ("imagenet224-dp8", 8, "fused_loop"),
+            ("imagenet64-local", 64, "fused_loop"),
+            ("mnist", 32, "fused_loop"),
+            ("cifar10", 64, "fused_loop"),
+        ],
+    )
+    def test_route_by_preset(self, preset, b, want):
+        """resolve_vjp_path is the dispatch's one consumer outside the
+        module: the trainer's label, the records' vjp_path and the
+        benchmark's `route` follow it. assume_on_tpu bypasses only the
+        platform check; on the CPU the dense budget falls back to 2 GB and
+        the cell's scores are 1.61 GB, so the memory gate does not mask
+        the rule."""
+        from glom_tpu.models.core import resolve_vjp_path
+        from glom_tpu.train.trainer import resolve_route_keys
+        from glom_tpu.utils.presets import get_preset
+
+        p = get_preset(preset)
+        k, itemsize = resolve_route_keys(p.model, p.train)
+        got = resolve_vjp_path(
+            p.model, b, k, remat=p.train.remat, use_pallas=True,
+            itemsize=itemsize, assume_on_tpu=True,
+        )
+        assert got == want
+
+    def test_grad_auto_in_batched_long_row_region_matches_dense(self):
+        """The smallest shape the batched long-row branch admits (B=8,
+        n=1024, tile 256): `auto` must reach the one-sweep kernels through
+        the public entry point and agree with the forced dense side."""
+        from glom_tpu.kernels import fused_consensus_update
+        from glom_tpu.kernels.consensus_update import _use_blockwise_bwd
+
+        L, B, side, d = 2, 8, 32, 128
+        n = side * side
+        assert _use_blockwise_bwd((L, B, n, d), side, 7.0, "auto", 4)
+        levels, bu, td = self._rand(jax.random.PRNGKey(11), L, B, n, d)
+
+        def loss(impl):
+            def f(lv, b_, t_):
+                out = fused_consensus_update(
+                    lv, b_, t_, side=side, radius=7.0, interpret=True,
+                    bwd_impl=impl,
+                )
+                return jnp.mean(out ** 2)
+            return f
+
+        grad_auto = jax.grad(loss("auto"), argnums=(0, 1, 2))
+        traced = str(jax.make_jaxpr(grad_auto)(levels, bu, td))
+        assert "consensus_update_fwd" in traced
+        assert "consensus_update_bwd_onesweep" in traced
+        g1 = grad_auto(levels, bu, td)
+        g2 = jax.grad(loss("dense"), argnums=(0, 1, 2))(levels, bu, td)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-5
+            )
 
     def test_top_level_divisor_and_zero_topdown(self):
         """Top level must ignore td entirely and divide by 3 (reference

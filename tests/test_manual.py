@@ -281,27 +281,15 @@ class TestShardFusedLoop:
         noise = jnp.asarray(rng.normal(size=(16, 3, 16, 16)), jnp.float32)
         return img, noise
 
-    def test_gate_engages_at_shard_shape(self, monkeypatch):
+    def test_gate_engages_at_shard_shape(self):
         from glom_tpu.parallel.manual import _use_loop_vjp
 
-        monkeypatch.delenv("GLOM_CONSENSUS_BWD", raising=False)
         assert _use_loop_vjp(
             self.LCFG, 8, 2, False, jnp.dtype(jnp.float32), True
         )
         # sub-batched shards stay on the scan path
         assert not _use_loop_vjp(
             self.LCFG, 2, 2, False, jnp.dtype(jnp.float32), True
-        )
-
-    def test_env_override_pins_scan_path(self, monkeypatch):
-        """GLOM_CONSENSUS_BWD=dense (the A/B measurement knob) must pin
-        the scan path through the shard dispatch too — the gate lives in
-        resolve_vjp_path, not re-implemented here."""
-        from glom_tpu.parallel.manual import _use_loop_vjp
-
-        monkeypatch.setenv("GLOM_CONSENSUS_BWD", "dense")
-        assert not _use_loop_vjp(
-            self.LCFG, 8, 2, False, jnp.dtype(jnp.float32), True
         )
 
     # The heaviest single test in the suite (interpret-mode whole-loop VJP
@@ -341,7 +329,6 @@ class TestShardFusedLoop:
         from glom_tpu.parallel import DistributedTrainer
 
         monkeypatch.setattr(core, "_on_tpu", lambda: True)
-        monkeypatch.delenv("GLOM_CONSENSUS_BWD", raising=False)
         tr = DistributedTrainer(
             self.LCFG, self.LTCFG, MeshConfig(data=2), sp_strategy="none"
         )
