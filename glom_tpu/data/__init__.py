@@ -7,6 +7,7 @@ from glom_tpu.data.prefetch import prefetch_to_device
 from glom_tpu.data.synthetic import (
     gaussian_dataset,
     shapes_dataset,
+    token_dataset,
     write_shapes_dataset,
 )
 
@@ -17,5 +18,6 @@ __all__ = [
     "npy_dataset",
     "prefetch_to_device",
     "shapes_dataset",
+    "token_dataset",
     "write_shapes_dataset",
 ]

@@ -64,6 +64,18 @@ def gaussian_dataset(
         )
 
 
+def token_dataset(
+    batch_size: int, seq_len: int, vocab_size: int, *, seed: int = 0
+) -> Iterator[np.ndarray]:
+    """Infinite iterator of [b, seq_len] int32 token ids, uniform over the
+    vocabulary rows held: packed sequences for the language-model objective
+    (no document boundaries; content is irrelevant to speed and to the
+    comparison with the reference)."""
+    rng = np.random.default_rng(seed)
+    while True:
+        yield rng.integers(0, vocab_size, (batch_size, seq_len), dtype=np.int32)
+
+
 def write_shapes_dataset(
     out_dir: str,
     num_images: int,

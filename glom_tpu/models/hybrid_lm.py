@@ -1,0 +1,426 @@
+"""A hybrid language model: a stack of pre-norm residual layers,
+`x <- x + Mixer(RMSNorm(x))`, each with one of three mixers by the config's
+pattern — `M` Mamba-2 (the chunked SSD form), `*` grouped-query causal
+attention, `E` a latent mixture of experts with one shared expert — then a
+final norm, an untied head and next-token cross-entropy. The layer equations
+are the published `nemotron_h` ones; `utils/config.HybridLMConfig` holds the
+shapes.
+
+The model is told what it holds of each layer (a chip's share of a
+deployment that divides a layer over several chips): which experts, how many
+heads, how many rows of the vocabulary. An `E` layer routes over all the
+experts the router scores, and computes the terms of the experts held here
+for the tokens that chose them: a sort of the token-expert pairs by expert,
+group sizes as uneven as the routing makes them, one grouped product over
+the experts held (`lax.ragged_dot`), a weighted combine. The rows are sized
+for the worst imbalance (every token choosing every held expert), so no pair
+is ever dropped; what the absent experts would have added is left out.
+
+XLA only: there is no Pallas kernel on this path (`vjp_path: lm_xla`).
+Parameters are float32; with a compute dtype the residual stream and the
+matrix products run in it, the router, the recurrences' decays, the norms'
+statistics, the softmaxes and the loss in float32. Every device op sits
+under one of `tracing.spans.LM_DEVICE_PHASES`.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from glom_tpu.utils.config import HybridLMConfig
+
+Params = Any  # {"embed", "layers": (one dict a layer), "final_norm", "head"}
+ATTN_QUERY_BLOCK = 1024
+LOSS_ROW_BLOCK = 2048
+ROW_TILE = 1024  # the experts' rows come in multiples of this
+COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_max_expert_load")
+
+
+# ----------------------------------------------------------------- parameters
+
+
+def mamba_in_width(cfg: HybridLMConfig) -> int:
+    """Columns of the in-projection: z, x, B, C, dt."""
+    return 2 * cfg.mamba_inner + 2 * cfg.n_groups * cfg.ssm_state_size + cfg.mamba_num_heads
+
+
+def layer_shapes(kind: str, cfg: HybridLMConfig) -> dict:
+    """{leaf: shape} of one layer of mixer `kind`."""
+    d = cfg.hidden_size
+    if kind == "M":
+        return {
+            "norm": (d,), "in_proj": (d, mamba_in_width(cfg)),
+            "conv_w": (cfg.mamba_conv_dim, cfg.conv_kernel), "conv_b": (cfg.mamba_conv_dim,),
+            "dt_bias": (cfg.mamba_num_heads,), "A_log": (cfg.mamba_num_heads,),
+            "D": (cfg.mamba_num_heads,), "gnorm": (cfg.mamba_inner,),
+            "out_proj": (cfg.mamba_inner, d),
+        }
+    if kind == "*":
+        q = cfg.num_attention_heads * cfg.head_dim
+        kv = cfg.num_key_value_heads * cfg.head_dim
+        return {"norm": (d,), "q": (d, q), "k": (d, kv), "v": (d, kv), "o": (q, d)}
+    e, lat, f = cfg.n_routed_experts, cfg.moe_latent_size, cfg.moe_intermediate_size
+    fs = cfg.moe_shared_expert_intermediate_size
+    return {
+        "norm": (d,), "router": (d, cfg.n_routed_experts_total),
+        "down": (d, lat), "up": (lat, d), "w1": (e, lat, f), "w2": (e, f, lat),
+        "s1": (d, fs), "s2": (fs, d),
+    }
+
+
+def param_shapes(cfg: HybridLMConfig) -> dict:
+    d, v = cfg.hidden_size, cfg.vocab_size
+    return {
+        "embed": (v, d),
+        "layers": tuple(layer_shapes(kind, cfg) for kind in cfg.pattern),
+        "final_norm": (d,),
+        "head": (d, v),
+    }
+
+
+def _is_shape(s) -> bool:
+    return isinstance(s, tuple) and all(isinstance(i, int) for i in s)
+
+
+def init_leaf(key, name: str, shape, cfg: HybridLMConfig):
+    """One leaf's initial value, float32. Matrices are normal with std 0.02,
+    the out-projections of the Mamba-2 and attention mixers scaled by
+    1/sqrt(2 x layers of the published stack) (`rescale_prenorm_residual`);
+    norms are ones; the conv and the recurrence's `dt_bias`, `A_log`, `D`
+    as the Mamba-2 reference initialises them."""
+    if name in ("norm", "gnorm", "final_norm", "D"):
+        return jnp.ones(shape, jnp.float32)
+    if name in ("conv_w", "conv_b"):
+        bound = cfg.conv_kernel ** -0.5
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+    if name == "dt_bias":
+        lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) * (hi - lo) + lo)
+        dt = jnp.maximum(dt, cfg.time_step_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+    if name == "A_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    std = 0.02
+    if name in ("out_proj", "o"):
+        std /= math.sqrt(2.0 * cfg.num_hidden_layers_total)
+    return std * jax.random.normal(key, shape, jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def init_hybrid_lm(key: jax.Array, cfg: HybridLMConfig) -> Params:
+    shapes = param_shapes(cfg)
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
+    return jax.tree_util.tree_unflatten(treedef, [
+        init_leaf(jax.random.fold_in(key, i), path[-1].key, shape, cfg)
+        for i, (path, shape) in enumerate(leaves)])
+
+
+# --------------------------------------------------------------------- pieces
+
+
+def _cast(w, dtype):
+    return w if dtype is None else w.astype(dtype)
+
+
+def rms_norm(x, weight, eps: float):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (y * weight).astype(x.dtype)
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def _mm(a, b):
+    """a [..., k] @ b [k, n], float32 accumulation, in the operands' type."""
+    return jnp.einsum("...k,kn->...n", a, b, preferred_element_type=jnp.float32)
+
+
+# -------------------------------------------------------------------- Mamba-2
+
+
+def causal_conv(x, w, b):
+    """Depthwise causal conv over time: x [B, T, C], w [C, K], b [C];
+    out[t] = b + sum_j w[:, j] x[t - (K - 1) + j]."""
+    k = w.shape[1]
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return b + sum(xp[:, j:j + t] * w[:, j] for j in range(k))
+
+
+def ssd_chunked(x, dt, a, b, c, chunk: int):
+    """The selective state-space recurrence
+        h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,   y_t = h_t C_t
+    by chunks (the SSD form of Mamba-2): within a chunk the quadratic,
+    attention-like form; between chunks the states, carried by their decays.
+    x [B, T, G, R, P] (G groups of R heads, head size P), dt [B, T, G, R]
+    float32, a [G, R] float32 (negative), b and c [B, T, G, N]. A length that
+    is no multiple of the chunk is padded with steps of dt = 0, which neither
+    decay the state nor add to it. Returns y [B, T, G, R, P] in x's type.
+    Inside, heads come before positions ([B, chunks, G, R, Q, ...]) so that
+    the arrays' last two dimensions are the large ones."""
+    bsz, t = x.shape[:2]
+    pad = -t % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (t + pad) // chunk
+    dtype = x.dtype
+    x = x.reshape(bsz, nc, chunk, *x.shape[2:]).transpose(0, 1, 3, 4, 2, 5)   # b z g r q p
+    dt = dt.reshape(bsz, nc, chunk, *dt.shape[2:]).transpose(0, 1, 3, 4, 2)   # b z g r q
+    b = b.reshape(bsz, nc, chunk, *b.shape[2:]).transpose(0, 1, 3, 2, 4)      # b z g q n
+    c = c.reshape(bsz, nc, chunk, *c.shape[2:]).transpose(0, 1, 3, 2, 4)
+    cs = jnp.cumsum(dt * a[:, :, None], axis=-1)   # inclusive, within the chunk, <= 0
+    # within the chunk: y_i += sum_{j<=i} (C_i . B_j) exp(cs_i - cs_j) dt_j x_j
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(lower, cs[..., :, None] - cs[..., None, :], -jnp.inf))
+    cb = jnp.einsum("bzgin,bzgjn->bzgij", c, b, preferred_element_type=jnp.float32)
+    w = (cb[:, :, :, None] * decay * dt[..., None, :]).astype(dtype)
+    y = jnp.einsum("bzgrij,bzgrjp->bzgrip", w, x, preferred_element_type=jnp.float32)
+    # the state each chunk adds: S_z = sum_j exp(cs_last - cs_j) dt_j x_j B_j^T
+    to_end = (jnp.exp(cs[..., -1:] - cs) * dt).astype(dtype)
+    s = jnp.einsum("bzgrjp,bzgjn->bzgrpn", x * to_end[..., None], b,
+                   preferred_element_type=jnp.float32)
+    # the state entering chunk z: h_z = sum_{k<z} exp(the decays of the chunks between) S_k
+    total = jnp.moveaxis(cs[..., -1], 1, -1)       # [B, G, R, nc]
+    run = jnp.cumsum(total, axis=-1)
+    before = jnp.tril(jnp.ones((nc, nc), bool), -1)
+    carry = jnp.exp(jnp.where(before, (run - total)[..., :, None] - run[..., None, :], -jnp.inf))
+    h = jnp.einsum("bgrzk,bkgrpn->bzgrpn", carry, s)
+    # what the entering state gives position i: exp(cs_i) C_i . h_z
+    off = jnp.einsum("bzgin,bzgrpn->bzgrip", c, h.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    y = y + off * jnp.exp(cs)[..., None]
+    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(bsz, nc * chunk, *y.shape[2:4], y.shape[-1])
+    return y[:, :t].astype(dtype)
+
+
+def mamba_mixer(p, x_in, cfg: HybridLMConfig, dtype):
+    """The layer's input [B, T, d] -> the mixer's output [B, T, d]; the heads
+    held here are whole groups."""
+    g, n, pdim = cfg.n_groups, cfg.ssm_state_size, cfg.mamba_head_dim
+    r = cfg.mamba_num_heads // g
+    di = cfg.mamba_inner
+    bsz, t = x_in.shape[:2]
+    with jax.named_scope("mamba_in"):
+        u = rms_norm(x_in, p["norm"], cfg.layer_norm_epsilon)
+        zxbcdt = _mm(u, _cast(p["in_proj"], dtype)).astype(u.dtype)
+        z, xbc, dt = jnp.split(zxbcdt, [di, di + cfg.mamba_conv_dim], axis=-1)
+        xbc = jax.nn.silu(causal_conv(xbc, _cast(p["conv_w"], dtype), _cast(p["conv_b"], dtype)))
+        x, b, c = jnp.split(xbc, [di, di + g * n], axis=-1)
+        x = x.reshape(bsz, t, g, r, pdim)
+        b, c = b.reshape(bsz, t, g, n), c.reshape(bsz, t, g, n)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"]).reshape(bsz, t, g, r)
+        a = -jnp.exp(p["A_log"]).reshape(g, r)
+    with jax.named_scope("ssd_scan"):
+        y = ssd_chunked(x, dt, a, b, c, cfg.chunk_size)
+        y = y + _cast(p["D"], dtype).reshape(g, r, 1) * x
+    with jax.named_scope("mamba_out"):
+        # gated RMSNorm by group: y * silu(z), normalised within each group
+        y = (y.reshape(bsz, t, di) * jax.nn.silu(z)).reshape(bsz, t, g, di // g)
+        y = rms_norm(y, p["gnorm"].reshape(g, di // g), cfg.layer_norm_epsilon)
+        return _mm(y.reshape(bsz, t, di), _cast(p["out_proj"], dtype)).astype(u.dtype)
+
+
+# ------------------------------------------------------------------ attention
+
+
+def _attend(q, k, v, first: int):
+    """One block of queries against the keys at or before them. q [B, tq,
+    G, R, D] at positions first..first+tq, k and v [B, tk, G, D] at 0..tk."""
+    scale = q.shape[-1] ** -0.5
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32) * scale
+    qpos = first + jnp.arange(q.shape[1])[:, None]
+    s = jnp.where(jnp.arange(k.shape[1])[None, :] <= qpos, s, -jnp.inf)
+    pr = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", pr, v, preferred_element_type=jnp.float32
+                      ).astype(q.dtype)
+
+
+def attention_mixer(p, x_in, cfg: HybridLMConfig, dtype):
+    """Grouped-query causal attention, no positions, no bias. Queries go a
+    block at a time against the keys up to the block's end, each block
+    recomputed in the backward pass, so that no [T, T] array is kept."""
+    g, dh = cfg.num_key_value_heads, cfg.head_dim
+    r = cfg.num_attention_heads // g
+    bsz, t = x_in.shape[:2]
+    with jax.named_scope("attention"):
+        u = rms_norm(x_in, p["norm"], cfg.layer_norm_epsilon)
+        q = _mm(u, _cast(p["q"], dtype)).astype(u.dtype).reshape(bsz, t, g, r, dh)
+        k = _mm(u, _cast(p["k"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
+        v = _mm(u, _cast(p["v"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
+        out = []
+        for first in range(0, t, ATTN_QUERY_BLOCK):
+            last = min(t, first + ATTN_QUERY_BLOCK)
+            block = jax.checkpoint(functools.partial(_attend, first=first))
+            out.append(block(q[:, first:last], k[:, :last], v[:, :last]))
+        o = jnp.concatenate(out, axis=1).reshape(bsz, t, g * r * dh)
+        return _mm(o, _cast(p["o"], dtype)).astype(u.dtype)
+
+
+# ---------------------------------------------------- latent mixture of experts
+
+
+def route(p, u2, cfg: HybridLMConfig):
+    """The router: u2 [N, d] -> the chosen experts [N, k] (of all the router
+    scores) and their weights [N, k]. Scores are sigmoids of a float32
+    product; the k largest are chosen (one group, so plain top-k; the
+    selection bias is zero), and weigh s_k / sum_k s_k * the scaling factor."""
+    logits = jnp.dot(u2.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), cfg.num_experts_per_tok)
+    return top_i, top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+
+
+def dispatch(top_i, cfg: HybridLMConfig):
+    """Sort the token-expert pairs by expert and keep those of the experts
+    held here. Returns, for R = N * min(k, experts held) + experts held rows,
+    rounded up to ROW_TILE (room for every pair whatever the imbalance, and
+    one row of room inside every expert's group, so that the grouped product
+    never meets an empty group): the
+    pair each row is (`pair`, an index into the flattened [N * k]), whether
+    the row is a pair at all (`valid`), and the sizes of the experts' groups
+    of rows [E], the rows of room counted in."""
+    n, k = top_i.shape
+    e = cfg.n_routed_experts
+    local = top_i - cfg.expert_offset
+    key = jnp.where((local >= 0) & (local < e), local, e).reshape(-1)
+    key = jnp.concatenate([key, jnp.arange(e, dtype=key.dtype)])  # the rows of room
+    # rounded up: the TPU's grouped product tiles its rows, and a count that
+    # is no multiple of the tile is tiled by 8 (65,544 rows took 70 times
+    # 65,536's time on a v5e)
+    rows = min(-(-(n * min(k, e) + e) // ROW_TILE) * ROW_TILE, key.shape[0])
+    row = jnp.argsort(key, stable=True)[:rows]
+    valid = (key[row] < e) & (row < n * k)
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(e)[None, :], axis=0, dtype=jnp.int32)
+    return jnp.minimum(row, n * k - 1), valid, group_sizes
+
+
+def moe_routed(p, u2, cfg: HybridLMConfig, dtype, choices=None):
+    """The routed experts' part, for the experts held here: u2 [N, d] ->
+    ([N, d], counters). `choices` (top_i, weights) replaces the router's."""
+    n = u2.shape[0]
+    k = cfg.num_experts_per_tok
+    with jax.named_scope("moe_router"):
+        top_i, top_w = route(p, u2, cfg) if choices is None else choices
+    with jax.named_scope("moe_dispatch"):
+        v = _mm(u2, _cast(p["down"], dtype)).astype(u2.dtype)
+        pair, valid, group_sizes = dispatch(top_i, cfg)
+        token = pair // k
+        x = jnp.where(valid[:, None], v[token], 0)
+    with jax.named_scope("moe_experts"):
+        # The rows past the last group are room, and the TPU's grouped
+        # product leaves them unwritten, forward and backward (NaN among
+        # them): they are zeroed by a select wherever they come out, before
+        # anything multiplies them.
+        h = jax.lax.ragged_dot(x, _cast(p["w1"], dtype), group_sizes)
+        h = relu2(jnp.where(valid[:, None], h, 0))
+        y = jax.lax.ragged_dot(h, _cast(p["w2"], dtype), group_sizes)
+    with jax.named_scope("moe_combine"):
+        # masked before it is weighted: a product with an unwritten row would
+        # carry its NaN into the weights' gradient, whatever the cotangent
+        y = jnp.where(valid[:, None], y, 0) * top_w.reshape(-1)[pair][:, None]
+        routed = jax.ops.segment_sum(y, token, num_segments=n).astype(u2.dtype)
+        out = _mm(routed, _cast(p["up"], dtype)).astype(u2.dtype)
+    with jax.named_scope("step_metrics"):
+        counters = {
+            "moe_pairs_here": jnp.sum(valid).astype(jnp.float32),
+            "moe_rows_computed": jnp.float32(pair.shape[0]),
+            "moe_max_expert_load": (jnp.max(group_sizes) - 1).astype(jnp.float32),
+        }
+    return out, counters, top_i
+
+
+def moe_shared(p, u2, dtype):
+    with jax.named_scope("moe_shared"):
+        h = relu2(_mm(u2, _cast(p["s1"], dtype))).astype(u2.dtype)
+        return _mm(h, _cast(p["s2"], dtype)).astype(u2.dtype)
+
+
+def moe_mixer(p, x_in, cfg: HybridLMConfig, dtype):
+    with jax.named_scope("moe_router"):
+        u2 = rms_norm(x_in, p["norm"], cfg.layer_norm_epsilon).reshape(-1, x_in.shape[-1])
+    routed, counters, top_i = moe_routed(p, u2, cfg, dtype)
+    return (routed + moe_shared(p, u2, dtype)).reshape(x_in.shape), counters, top_i
+
+
+# ------------------------------------------------------------------ the stack
+
+
+def layer(kind: str, p, x, cfg: HybridLMConfig, dtype):
+    """x <- x + Mixer(RMSNorm(x)). Returns (x, the layer's counters or {},
+    the router's choices or None)."""
+    if kind == "M":
+        return x + mamba_mixer(p, x, cfg, dtype), {}, None
+    if kind == "*":
+        return x + attention_mixer(p, x, cfg, dtype), {}, None
+    out, counters, top_i = moe_mixer(p, x, cfg, dtype)
+    return x + out, counters, top_i
+
+
+def hidden_states(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
+                  remat: bool = True):
+    """ids [B, T] -> (the last layer's output [B, T, d], one counters dict
+    an `E` layer, the routers' choices [E layers, B * T, k])."""
+    with jax.named_scope("embed"):
+        x = _cast(params["embed"][ids], compute_dtype)
+    counters, choices = [], []
+    for kind, p in zip(cfg.pattern, params["layers"]):
+        f = functools.partial(layer, kind, cfg=cfg, dtype=compute_dtype)
+        x, c, top_i = (jax.checkpoint(f) if remat else f)(p, x)
+        if kind == "E":
+            counters.append(c)
+            choices.append(top_i)
+    return x, counters, choices
+
+
+def routing_choices(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None):
+    """The experts every token chose in every `E` layer: [layers, B * T, k]."""
+    return jnp.stack(hidden_states(params, ids, cfg, compute_dtype=compute_dtype,
+                                   remat=False)[2])
+
+
+def _block_nll(h, head, targets):
+    logits = _mm(h, head)                          # float32 [rows, V]
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    return lse - jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
+
+
+def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
+            remat: bool = True) -> Tuple[jnp.ndarray, dict]:
+    """Next-token cross-entropy over the vocabulary rows held here, float32
+    logits, the mean over the B * (T - 1) positions that have a next token.
+    Returns (loss, counters): pairs routed to the experts held and rows the
+    grouped product ran, each the mean over the `E` layers, and the fullest
+    expert's load over all of them."""
+    x, counters, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
+    bsz, t = ids.shape
+    with jax.named_scope("lm_head_loss"):
+        h = rms_norm(x, params["final_norm"], cfg.layer_norm_epsilon).reshape(bsz * t, -1)
+        head = _cast(params["head"], compute_dtype)
+        targets = jnp.roll(ids, -1, axis=1).reshape(-1)
+        has_next = jnp.tile(jnp.arange(t) < t - 1, bsz)
+        total = jnp.zeros((), jnp.float32)
+        for first in range(0, bsz * t, LOSS_ROW_BLOCK):
+            rows = slice(first, min(bsz * t, first + LOSS_ROW_BLOCK))
+            nll = jax.checkpoint(_block_nll)(h[rows], head, targets[rows])
+            total = total + jnp.sum(jnp.where(has_next[rows], nll, 0.0))
+        loss = total / (bsz * (t - 1))
+    with jax.named_scope("step_metrics"):
+        merged = {}
+        if counters:
+            for name in COUNTERS:
+                vals = jnp.stack([c[name] for c in counters])
+                merged[name] = jnp.max(vals) if name == "moe_max_expert_load" else jnp.mean(vals)
+    return loss, merged
+
+
+def param_count(cfg: HybridLMConfig) -> int:
+    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
+        param_shapes(cfg), is_leaf=_is_shape))

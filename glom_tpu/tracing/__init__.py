@@ -27,6 +27,7 @@ _EXPORTS = {
     "PHASES": "spans",
     "HOST_PHASES": "spans",
     "DEVICE_PHASES": "spans",
+    "LM_DEVICE_PHASES": "spans",
     "SpanAggregator": "spans",
     "span": "spans",
     "spanned": "spans",

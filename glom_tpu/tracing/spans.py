@@ -64,6 +64,30 @@ DEVICE_PHASES = (
 )
 PHASES = HOST_PHASES + DEVICE_PHASES
 
+# The device scopes of the hybrid language model's step (models/hybrid_lm.py),
+# in a tuple of their own: the trainer's `optimizer` and `step_metrics` above
+# are shared, the rest of DEVICE_PHASES is GLOM's. `embed`; a Mamba-2 layer
+# is `mamba_in` (in-projection, conv, gates), `ssd_scan` (the chunked
+# recurrence), `mamba_out` (gated norm, out-projection); `attention`; an
+# expert layer is `moe_router` (scores, top-k, weights), `moe_dispatch`
+# (latent down-projection, sort by expert, gather), `moe_experts` (the
+# grouped products), `moe_combine` (weights, scatter back, latent
+# up-projection), `moe_shared`; `lm_head_loss` (final norm, head,
+# cross-entropy). Each layer's input norm belongs to no scope.
+LM_DEVICE_PHASES = (
+    "embed",
+    "mamba_in",
+    "ssd_scan",
+    "mamba_out",
+    "attention",
+    "moe_router",
+    "moe_dispatch",
+    "moe_experts",
+    "moe_combine",
+    "moe_shared",
+    "lm_head_loss",
+)
+
 # The serving stack's host phases (glom_tpu/serve): one request's path is
 # enqueue -> (gathered into a) batch -> dispatch (the compiled forward) ->
 # fetch (device->host of the valid rows). The batcher aggregates these the

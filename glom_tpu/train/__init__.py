@@ -1,5 +1,6 @@
 from glom_tpu.train.objectives import (
     DenoiseParams,
+    Objective,
     default_recon_index,
     denoise_loss,
     init_denoise,
@@ -13,11 +14,13 @@ from glom_tpu.train.trainer import (
     create_train_state,
     default_optimizer,
     make_train_step,
+    objective_for,
     resolve_training_route,
 )
 
 __all__ = [
     "DenoiseParams",
+    "Objective",
     "default_recon_index",
     "denoise_loss",
     "init_denoise",
@@ -30,5 +33,6 @@ __all__ = [
     "create_train_state",
     "default_optimizer",
     "make_train_step",
+    "objective_for",
     "resolve_training_route",
 ]

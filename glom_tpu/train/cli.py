@@ -23,7 +23,9 @@ def _nonneg_int(s: str) -> int:
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
-        prog="glom-tpu-train", description="Train GLOM (self-supervised denoising)"
+        prog="glom-tpu-train",
+        description="Train GLOM (self-supervised denoising) or, by an LM preset, "
+        "the hybrid language model (next-token loss)",
     )
     p.add_argument("--preset", default="cifar10", help="see glom_tpu.utils.presets")
     p.add_argument("--steps", type=int, default=100)
@@ -317,9 +319,25 @@ def _pod_setup(args, writer):
 
 def _train_body(args, preset, cfg, tcfg, writer) -> int:
     from glom_tpu.data import gaussian_dataset, shapes_dataset
-    from glom_tpu.train import Trainer
+    from glom_tpu.train import Trainer, objective_for
+    from glom_tpu.utils.config import GlomConfig
 
-    if args.data_dir is not None:
+    # What the feed yields a row of: [c, H, W] images or [T] token ids.
+    example_size = objective_for(cfg, tcfg).batch_shape[-1]
+    if not isinstance(cfg, GlomConfig):
+        # The language-model family: token ids, one chip (what it holds of a
+        # layer is the config's; the sharded trainers are GLOM's).
+        if args.distributed or args.check_parity or args.data_dir is not None:
+            raise SystemExit(
+                f"--preset {preset.name} trains token ids on one chip: "
+                "--distributed, --check-parity and --data-dir are GLOM's"
+            )
+        from glom_tpu.data import token_dataset
+
+        def make_data(batch_size, seq_len, seed=0):
+            return token_dataset(batch_size, seq_len, cfg.vocab_size, seed=seed)
+
+    elif args.data_dir is not None:
         from glom_tpu.data import file_dataset
 
         def make_data(batch_size, image_size, seed=0):
@@ -364,7 +382,7 @@ def _train_body(args, preset, cfg, tcfg, writer) -> int:
 
         fit_supervised(
             make_trainer,
-            lambda: make_data(tcfg.batch_size, cfg.image_size, seed=tcfg.seed),
+            lambda: make_data(tcfg.batch_size, example_size, seed=tcfg.seed),
             args.steps,
             checkpoint_dir=args.checkpoint_dir,
             checkpoint_every=args.checkpoint_every,
@@ -378,7 +396,7 @@ def _train_body(args, preset, cfg, tcfg, writer) -> int:
         )
         return 0
 
-    data = make_data(tcfg.batch_size, cfg.image_size, seed=tcfg.seed)
+    data = make_data(tcfg.batch_size, example_size, seed=tcfg.seed)
 
     if args.check_parity:
         from glom_tpu.parallel import DistributedTrainer
@@ -388,8 +406,8 @@ def _train_body(args, preset, cfg, tcfg, writer) -> int:
         dist = DistributedTrainer(
             cfg, tcfg, scaled.mesh, sp_strategy=scaled.sp_strategy
         )
-        d1 = make_data(tcfg.batch_size, cfg.image_size, seed=tcfg.seed)
-        d2 = make_data(tcfg.batch_size, cfg.image_size, seed=tcfg.seed)
+        d1 = make_data(tcfg.batch_size, example_size, seed=tcfg.seed)
+        d2 = make_data(tcfg.batch_size, example_size, seed=tcfg.seed)
         h1 = single.fit(d1, num_steps=args.steps, log_every=args.log_every)
         h2 = dist.fit(d2, num_steps=args.steps, log_every=args.log_every)
         worst = max(

@@ -20,7 +20,7 @@ memory before remat even enters the picture.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,66 @@ def init_denoise(key: jax.Array, cfg: GlomConfig, dtype=jnp.float32) -> DenoiseP
     return DenoiseParams(
         glom=init_glom(k_glom, cfg, dtype),
         to_pixels=init_linear(k_pix, cfg.dim, cfg.patch_dim, dtype),
+    )
+
+
+class Objective(NamedTuple):
+    """What a model family gives the trainer (train/trainer.py): the one
+    step builder, fit loop, prefetch, optimizer and records drive whatever
+    fills this in. GLOM's denoising is `trainer.denoise_objective`, the
+    hybrid language model's next-token loss `lm_objective` below; the
+    initial parameters of either come from `init_params(key, cfg)`."""
+
+    # (rng, step, batch) -> what the loss takes beside the batch that is
+    # random: drawn on the device, outside the gradient (GLOM's noise)
+    draw: Callable[[jax.Array, jnp.ndarray, jnp.ndarray], Any]
+    # (params, batch, drawn) -> loss, or (loss, aux) with has_aux; aux is a
+    # dict of device scalars that join every record of the step
+    loss: Callable[[Any, jnp.ndarray, Any], Any]
+    has_aux: bool
+    # one example of the batch: the feed yields [batch_size, *batch_shape]
+    batch_shape: Tuple[int, ...]
+    batch_dtype: Any
+    # static routing facts the records carry
+    grad_accum: int
+    vjp_path: str
+
+
+def init_params(key: jax.Array, cfg):
+    """The initial parameters of whichever family `cfg` configures."""
+    if isinstance(cfg, GlomConfig):
+        return init_denoise(key, cfg)
+    from glom_tpu.models.hybrid_lm import init_hybrid_lm
+
+    return init_hybrid_lm(key, cfg)
+
+
+def lm_objective(cfg, tcfg) -> Objective:
+    """Next-token cross-entropy of the hybrid language model
+    (models/hybrid_lm.py) on [batch, seq_len] token ids. Nothing is drawn;
+    the routing counters are the aux. One route: XLA only, per-layer
+    recomputation by `tcfg.remat`."""
+    from glom_tpu.models.hybrid_lm import lm_loss
+
+    if tcfg.compute_dtype not in ("float32", "bfloat16"):
+        raise ValueError(
+            f"compute_dtype={tcfg.compute_dtype!r}: must be 'float32' or 'bfloat16'"
+        )
+    if tcfg.grad_accum not in (None, 1):
+        raise ValueError("the language-model objective has no gradient accumulation")
+    compute_dtype = jnp.bfloat16 if tcfg.compute_dtype == "bfloat16" else None
+
+    def loss_of(params, ids, drawn):
+        return lm_loss(params, ids, cfg, compute_dtype=compute_dtype, remat=tcfg.remat)
+
+    return Objective(
+        draw=lambda rng, step, ids: (),
+        loss=loss_of,
+        has_aux=True,
+        batch_shape=(cfg.seq_len,),
+        batch_dtype=jnp.int32,
+        grad_accum=1,
+        vjp_path="lm_xla",
     )
 
 

@@ -22,28 +22,29 @@ import optax
 from glom_tpu.models.core import ConsensusFn, resolve_vjp_path
 from glom_tpu.telemetry import diagnostics as diag
 from glom_tpu.train.objectives import (
-    DenoiseParams,
+    Objective,
     default_recon_index,
     denoise_loss,
-    init_denoise,
+    init_params,
+    lm_objective,
 )
 from glom_tpu.utils.config import GlomConfig, TrainConfig
 
 
 class TrainState(NamedTuple):
-    params: DenoiseParams
+    params: Any  # the objective's: DenoiseParams, or the language model's tree
     opt_state: Any
     step: jnp.ndarray  # scalar int32
 
 
 def create_train_state(
     key: jax.Array,
-    cfg: GlomConfig,
+    cfg,
     tcfg: TrainConfig,
     optimizer: Optional[optax.GradientTransformation] = None,
 ) -> Tuple[TrainState, optax.GradientTransformation]:
     optimizer = optimizer if optimizer is not None else default_optimizer(tcfg)
-    params = init_denoise(key, cfg)
+    params = init_params(key, cfg)
     return (
         TrainState(
             params=params,
@@ -275,8 +276,91 @@ def default_optimizer(tcfg: TrainConfig) -> optax.GradientTransformation:
     return optax.adam(lr)
 
 
-def make_train_step(
+def denoise_objective(
     cfg: GlomConfig,
+    tcfg: TrainConfig,
+    *,
+    consensus_fn: Optional[ConsensusFn] = None,
+    scan_only: bool = False,
+) -> Objective:
+    """GLOM's self-supervised denoising as the trainer's objective: the
+    noise is what is drawn, on the device, outside the gradient; the route
+    through the kernels (`resolve_training_route`) is resolved here, once."""
+    if tcfg.compute_dtype not in ("float32", "bfloat16"):
+        raise ValueError(
+            f"compute_dtype={tcfg.compute_dtype!r}: must be 'float32' or 'bfloat16'"
+        )
+    pinned = pinned_grad_accum(tcfg)
+    if tcfg.batch_size % pinned != 0:
+        raise ValueError(
+            f"grad_accum={tcfg.grad_accum} must divide batch_size="
+            f"{tcfg.batch_size}"
+        )
+    compute_dtype = jnp.bfloat16 if tcfg.compute_dtype == "bfloat16" else None
+    # Auto-route oversized batches through exact microbatch accumulation
+    # when that recovers the fused-loop VJP (see resolve_training_route);
+    # the decision is static, exposed on the step fn (.grad_accum /
+    # .vjp_path), and logged by the trainers next to sp_strategy.
+    grad_accum, vjp_path = resolve_training_route(
+        cfg, tcfg, custom_consensus=consensus_fn is not None,
+        scan_only=scan_only,
+    )
+    full = diag.resolve_telemetry_level(tcfg) == "full"
+
+    def draw(rng, step, img):
+        with jax.named_scope("noise"):
+            noise_rng = jax.random.fold_in(rng, step)
+            return tcfg.noise_std * jax.random.normal(
+                noise_rng, img.shape, img.dtype
+            )
+
+    def loss_of(params, img, noise):
+        return denoise_loss(
+            params,
+            img,
+            noise,
+            cfg,
+            recon_index=tcfg.recon_iter_index,
+            iters=tcfg.iters,
+            remat=tcfg.remat,
+            compute_dtype=compute_dtype,
+            consensus_fn=consensus_fn,
+            use_pallas=tcfg.use_pallas,
+            unroll=tcfg.scan_unroll,
+            with_diagnostics=full,
+        )
+
+    return Objective(
+        draw=draw,
+        loss=loss_of,
+        has_aux=full,
+        batch_shape=(cfg.channels, cfg.image_size, cfg.image_size),
+        batch_dtype=jnp.float32,
+        grad_accum=grad_accum,
+        vjp_path=vjp_path,
+    )
+
+
+def objective_for(
+    cfg,
+    tcfg: TrainConfig,
+    *,
+    consensus_fn: Optional[ConsensusFn] = None,
+    scan_only: bool = False,
+) -> Objective:
+    """The objective a model configuration trains by: the seam between a
+    model family and the one step builder, fit loop and trainer."""
+    if isinstance(cfg, GlomConfig):
+        return denoise_objective(
+            cfg, tcfg, consensus_fn=consensus_fn, scan_only=scan_only
+        )
+    if consensus_fn is not None:
+        raise ValueError("consensus_fn belongs to GLOM's objective")
+    return lm_objective(cfg, tcfg)
+
+
+def make_train_step(
+    cfg,
     tcfg: TrainConfig,
     optimizer: optax.GradientTransformation,
     *,
@@ -326,55 +410,19 @@ def make_train_step(
     guard on EVERY variant including the fast one (a guard that only runs
     on logging steps misses 9 of every 10 anomalies), plus per-level
     consensus agreement and the quantization-error probe at "full"."""
-    if tcfg.compute_dtype not in ("float32", "bfloat16"):
-        raise ValueError(
-            f"compute_dtype={tcfg.compute_dtype!r}: must be 'float32' or 'bfloat16'"
-        )
-    pinned = pinned_grad_accum(tcfg)
-    if tcfg.batch_size % pinned != 0:
-        raise ValueError(
-            f"grad_accum={tcfg.grad_accum} must divide batch_size="
-            f"{tcfg.batch_size}"
-        )
-    compute_dtype = jnp.bfloat16 if tcfg.compute_dtype == "bfloat16" else None
-    # Auto-route oversized batches through exact microbatch accumulation
-    # when that recovers the fused-loop VJP (see resolve_training_route);
-    # the decision is static, exposed on the returned fn (.grad_accum /
-    # .vjp_path), and logged by the trainers next to sp_strategy.
-    grad_accum, vjp_path = resolve_training_route(
-        cfg, tcfg, custom_consensus=consensus_fn is not None,
-        scan_only=scan_only,
+    objective = objective_for(
+        cfg, tcfg, consensus_fn=consensus_fn, scan_only=scan_only
     )
+    grad_accum, vjp_path = objective.grad_accum, objective.vjp_path
     quantized = (
         bool(tcfg.quantized_reduce)
         if quantized_reduce is None
         else quantized_reduce
     )
     level = diag.resolve_telemetry_level(tcfg)
-    full = level == "full"
 
-    def loss_of(params, img, noise):
-        return denoise_loss(
-            params,
-            img,
-            noise,
-            cfg,
-            recon_index=tcfg.recon_iter_index,
-            iters=tcfg.iters,
-            remat=tcfg.remat,
-            compute_dtype=compute_dtype,
-            consensus_fn=consensus_fn,
-            use_pallas=tcfg.use_pallas,
-            unroll=tcfg.scan_unroll,
-            with_diagnostics=full,
-        )
-
-    def train_step(state: TrainState, img: jnp.ndarray, rng: jax.Array):
-        with jax.named_scope("noise"):
-            noise_rng = jax.random.fold_in(rng, state.step)
-            noise = tcfg.noise_std * jax.random.normal(
-                noise_rng, img.shape, img.dtype
-            )
+    def train_step(state: TrainState, batch: jnp.ndarray, rng: jax.Array):
+        drawn = objective.draw(rng, state.step, batch)
 
         if grad_accum > 1:
             if zero_stage >= 2 and zero_shardings is not None:
@@ -390,15 +438,15 @@ def make_train_step(
             else:
                 gkw = {}
             loss, grads = accumulate_grads(
-                loss_of, state.params, img, noise, grad_accum,
-                has_aux=full, **gkw
+                objective.loss, state.params, batch, drawn, grad_accum,
+                has_aux=objective.has_aux, **gkw
             )
         else:
-            loss, grads = jax.value_and_grad(loss_of, has_aux=full)(
-                state.params, img, noise
-            )
+            loss, grads = jax.value_and_grad(
+                objective.loss, has_aux=objective.has_aux
+            )(state.params, batch, drawn)
         aux = None
-        if full:
+        if objective.has_aux:
             loss, aux = loss
         metrics = {}
         if quantized:
@@ -455,8 +503,11 @@ def make_train_step(
                     metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
                 metrics.update(taps)
                 metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
-                if full and aux is not None:
-                    metrics["level_agreement"] = aux["level_agreement"]
+            if aux is not None:
+                # What the objective reports beside its loss: GLOM's
+                # per-level agreement (telemetry "full"), the language
+                # model's routing counters.
+                metrics.update(aux)
         return TrainState(params, opt_state, state.step + 1), metrics
 
     # Static routing facts for the trainers' metric records (strings can't
@@ -665,7 +716,7 @@ class Trainer:
 
     def __init__(
         self,
-        cfg: GlomConfig,
+        cfg,  # a GlomConfig or a HybridLMConfig: whatever objective_for knows
         tcfg: TrainConfig,
         *,
         optimizer: Optional[optax.GradientTransformation] = None,
@@ -768,7 +819,9 @@ class Trainer:
         prefetch: int = 0,
         trace_capture=None,
     ) -> list[dict]:
-        """Run `num_steps` updates pulling [b, c, H, W] batches from `data`.
+        """Run `num_steps` updates pulling batches of the objective's shape
+        from `data` ([b, c, H, W] images for GLOM, [b, T] token ids for the
+        language model).
         prefetch > 0 stages that many upcoming batches on device from a
         background thread (hides the host->device transfer).
 

@@ -54,6 +54,91 @@ class GlomConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class HybridLMConfig:
+    """A hybrid language model (models/hybrid_lm.py): a stack of pre-norm
+    residual layers, each one mixer by `hybrid_override_pattern` — `M` a
+    Mamba-2 mixer, `*` grouped-query causal attention, `E` a latent mixture
+    of experts with one shared expert. Field names are those of the
+    published `nemotron_h` config.json; the defaults are
+    NVIDIA-Nemotron-3-Super-120B-A12B-BF16's.
+
+    The counts are of what THIS chip holds of a layer (its share of a
+    deployment that divides each layer over several chips): experts
+    `expert_offset .. expert_offset + n_routed_experts` of the
+    `n_routed_experts_total` the router scores, `mamba_num_heads` heads in
+    `n_groups` groups, `num_attention_heads` query heads over
+    `num_key_value_heads` KV heads, `vocab_size` rows of the embedding and
+    the head, layers `layer_offset .. layer_offset + num_hidden_layers` of
+    the pattern. Widths are never a share."""
+
+    hidden_size: int = 4096
+    hybrid_override_pattern: str = (
+        "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+        "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+    )
+    layer_offset: int = 0
+    num_hidden_layers: int = 88
+    # How many layers the published stack has: the out-projections' initial
+    # values are scaled by 1/sqrt(2 * this) (rescale_prenorm_residual).
+    num_hidden_layers_total: int = 88
+    layer_norm_epsilon: float = 1e-5
+    vocab_size: int = 131072
+    # E: latent mixture of experts
+    n_routed_experts: int = 512
+    n_routed_experts_total: int = 512
+    expert_offset: int = 0
+    num_experts_per_tok: int = 22
+    moe_latent_size: int = 1024
+    moe_intermediate_size: int = 2688
+    moe_shared_expert_intermediate_size: int = 5376
+    routed_scaling_factor: float = 5.0
+    # M: Mamba-2
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # *: grouped-query attention
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    # the training sequence: tokens of one packed row of the batch
+    seq_len: int = 8192
+
+    def __post_init__(self):
+        if set(self.pattern) - set("ME*") or len(self.pattern) != self.num_hidden_layers:
+            raise ValueError(
+                f"layers {self.layer_offset}..{self.layer_offset + self.num_hidden_layers}"
+                f" of a pattern of {len(self.hybrid_override_pattern)} over 'M', 'E', '*'"
+            )
+        if self.mamba_num_heads % self.n_groups or (
+            self.num_attention_heads % self.num_key_value_heads
+        ):
+            raise ValueError("heads must divide evenly over their groups")
+        if self.expert_offset + self.n_routed_experts > self.n_routed_experts_total:
+            raise ValueError("the experts held lie outside the router's width")
+
+    @property
+    def pattern(self) -> str:
+        """The mixers of the layers held here, in order."""
+        return self.hybrid_override_pattern[
+            self.layer_offset:self.layer_offset + self.num_hidden_layers
+        ]
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+
+@dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Parallelism layout. Axis sizes of 1 disable an axis.
 

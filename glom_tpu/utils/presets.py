@@ -9,9 +9,15 @@ is actually available (e.g. the 8-device CPU test harness or one chip).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Union
 
-from glom_tpu.utils.config import GlomConfig, MeshConfig, ServeConfig, TrainConfig
+from glom_tpu.utils.config import (
+    GlomConfig,
+    HybridLMConfig,
+    MeshConfig,
+    ServeConfig,
+    TrainConfig,
+)
 from glom_tpu.utils.helpers import halo_supported
 
 
@@ -19,7 +25,9 @@ from glom_tpu.utils.helpers import halo_supported
 class Preset:
     name: str
     description: str
-    model: GlomConfig
+    # The family the preset trains: its type picks the objective
+    # (train/trainer.objective_for).
+    model: Union[GlomConfig, HybridLMConfig]
     train: TrainConfig
     mesh: MeshConfig
     sp_strategy: str = "none"  # none | ring | ulysses | halo | auto
@@ -59,10 +67,14 @@ class Preset:
 
 
 PRESETS: Dict[str, Preset] = {}
+# The presets of the second family (a HybridLMConfig model), in a table of
+# their own: PRESETS stays GLOM's driver configurations, which is what the
+# sharded trainers and the serving stack iterate; `get_preset` finds both.
+LM_PRESETS: Dict[str, Preset] = {}
 
 
 def _register(p: Preset) -> Preset:
-    PRESETS[p.name] = p
+    (PRESETS if isinstance(p.model, GlomConfig) else LM_PRESETS)[p.name] = p
     return p
 
 
@@ -265,7 +277,61 @@ _register(
 )
 
 
+# 6. The second family: NVIDIA-Nemotron-3-Super-120B-A12B-BF16 (nemotron_h:
+# Mamba-2, grouped-query attention, a latent mixture of 512 experts), as ONE
+# chip of a deployment in which 64 chips share each layer sees it: routed
+# experts divided 64 ways (8 of 512 here), the mixers' heads and the
+# vocabulary 8 ways (16 of 128 Mamba-2 heads = 1 of 8 groups, 4 of 32 query
+# heads over 1 of 2 KV heads, 16,384 of 131,072 rows); depth cut to one
+# period of the published pattern, layers 26-36 (EMEMEMEMEM*). Every width
+# is the published one. 701M parameters held; one packed sequence of 8,192
+# tokens a step with per-layer recomputation fills a 16 GB chip.
+_register(
+    Preset(
+        name="nemotron3-super-ep64tp8",
+        description="Nemotron-3-Super-120B-A12B: one chip of 64 a layer "
+        "(8/512 experts, 1/8 heads and vocabulary), layers 26-36, 8k tokens",
+        model=HybridLMConfig(
+            layer_offset=26, num_hidden_layers=11,
+            n_routed_experts=8, expert_offset=40,
+            mamba_num_heads=16, n_groups=1,
+            num_attention_heads=4, num_key_value_heads=1,
+            vocab_size=16384, seq_len=8192,
+        ),
+        train=TrainConfig(
+            batch_size=1, learning_rate=3e-4, compute_dtype="bfloat16", remat=True,
+        ),
+        mesh=MeshConfig(),
+    )
+)
+
+# 6b. The same family at a size the CPU holds: every mixer, a share of the
+# experts (4 of 16, offset 4) — tests and functional drives, never a
+# measurement.
+_register(
+    Preset(
+        name="hybrid-lm-tiny",
+        description="hybrid LM, hidden 64, ME*EM, 4 of 16 experts — CPU drives",
+        model=HybridLMConfig(
+            hidden_size=64, hybrid_override_pattern="ME*EM", num_hidden_layers=5,
+            num_hidden_layers_total=5, vocab_size=128,
+            n_routed_experts=4, n_routed_experts_total=16, expert_offset=4,
+            num_experts_per_tok=4, moe_latent_size=32, moe_intermediate_size=48,
+            moe_shared_expert_intermediate_size=96,
+            mamba_num_heads=8, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+            chunk_size=8, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            seq_len=64,
+        ),
+        train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
+        mesh=MeshConfig(),
+    )
+)
+
+
 def get_preset(name: str) -> Preset:
-    if name not in PRESETS:
-        raise KeyError(f"unknown preset {name!r}; available: {sorted(PRESETS)}")
-    return PRESETS[name]
+    for table in (PRESETS, LM_PRESETS):
+        if name in table:
+            return table[name]
+    raise KeyError(
+        f"unknown preset {name!r}; available: {sorted(PRESETS) + sorted(LM_PRESETS)}"
+    )
