@@ -12,9 +12,11 @@ heads, how many rows of the vocabulary. An `E` layer routes over all the
 experts the router scores, and computes the terms of the experts held here
 for the tokens that chose them: a sort of the token-expert pairs by expert,
 group sizes as uneven as the routing makes them, one grouped product over
-the experts held (`lax.ragged_dot`), a weighted combine. The rows are sized
-for the worst imbalance (every token choosing every held expert), so no pair
-is ever dropped; what the absent experts would have added is left out.
+the experts held (`lax.ragged_dot`), a weighted combine. The rows are the
+smallest rung that holds this step's pairs, of a short static ladder chosen
+on the device (`row_rungs`); the last rung is the worst imbalance (every
+token choosing every held expert), so no pair is ever dropped; what the
+absent experts would have added is left out.
 
 XLA only: there is no Pallas kernel on this path (`vjp_path: lm_xla`).
 Parameters are float32; with a compute dtype the residual stream and the
@@ -38,7 +40,9 @@ Params = Any  # {"embed", "layers": (one dict a layer), "final_norm", "head"}
 ATTN_QUERY_BLOCK = 1024
 LOSS_ROW_BLOCK = 2048
 ROW_TILE = 1024  # the experts' rows come in multiples of this
-COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_max_expert_load")
+RUNG_LOADS = (2,)  # the small row counts, in balanced loads (`row_rungs`)
+COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_rows_full_share",
+            "moe_max_expert_load")
 
 
 # ----------------------------------------------------------------- parameters
@@ -278,60 +282,129 @@ def route(p, u2, cfg: HybridLMConfig):
     return top_i, top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling_factor
 
 
+def row_rungs(n: int, cfg: HybridLMConfig) -> Tuple[int, ...]:
+    """The row counts the routed experts' part may run at for n tokens,
+    ascending. The last is the full count, n * min(k, experts held) +
+    experts held rounded up to ROW_TILE: room for every pair whatever the
+    imbalance, and a row of room inside every expert's group, so that the
+    grouped product never meets an empty group. Before it, RUNG_LOADS times
+    the pairs a balanced router sends here (n * k * experts held / experts
+    scored), rounded up likewise, where that is fewer rows than the full
+    count: a share that holds most of the experts has one rung."""
+    k, e = cfg.num_experts_per_tok, cfg.n_routed_experts
+    # rounded up: the TPU's grouped product tiles its rows, and a count that
+    # is no multiple of the tile is tiled by 8 (65,544 rows took 70 times
+    # 65,536's time on a v5e)
+    tile = lambda rows: -(-rows // ROW_TILE) * ROW_TILE
+    full = min(tile(n * min(k, e) + e), n * k + e)
+    balanced = n * k * e / cfg.n_routed_experts_total
+    small = sorted({tile(math.ceil(load * balanced)) for load in RUNG_LOADS})
+    return tuple(rows for rows in small if rows < full) + (full,)
+
+
 def dispatch(top_i, cfg: HybridLMConfig):
-    """Sort the token-expert pairs by expert and keep those of the experts
-    held here. Returns, for R = N * min(k, experts held) + experts held rows,
-    rounded up to ROW_TILE (room for every pair whatever the imbalance, and
-    one row of room inside every expert's group, so that the grouped product
-    never meets an empty group): the
-    pair each row is (`pair`, an index into the flattened [N * k]), whether
-    the row is a pair at all (`valid`), and the sizes of the experts' groups
-    of rows [E], the rows of room counted in."""
+    """Sort the token-expert pairs by expert, those of the experts held here
+    first, each expert's group closed by its row of room. Returns, for all
+    N * k + experts held rows of the sorted order (the first
+    sum(group_sizes) are what the experts held compute; the caller keeps a
+    rung's worth): the pair each row is (`pair`, an index into the flattened
+    [N * k]), whether the row is a pair of an expert held (`valid`), and the
+    sizes of the experts' groups of rows [E], the rows of room counted in."""
     n, k = top_i.shape
     e = cfg.n_routed_experts
     local = top_i - cfg.expert_offset
     key = jnp.where((local >= 0) & (local < e), local, e).reshape(-1)
     key = jnp.concatenate([key, jnp.arange(e, dtype=key.dtype)])  # the rows of room
-    # rounded up: the TPU's grouped product tiles its rows, and a count that
-    # is no multiple of the tile is tiled by 8 (65,544 rows took 70 times
-    # 65,536's time on a v5e)
-    rows = min(-(-(n * min(k, e) + e) // ROW_TILE) * ROW_TILE, key.shape[0])
-    row = jnp.argsort(key, stable=True)[:rows]
-    valid = (key[row] < e) & (row < n * k)
+    # jnp.argsort's own sort, keeping the sorted keys it drops
+    key, row = jax.lax.sort_key_val(key, jnp.arange(key.shape[0], dtype=jnp.int32),
+                                    is_stable=True)
+    valid = (key < e) & (row < n * k)
     group_sizes = jnp.sum(key[:, None] == jnp.arange(e)[None, :], axis=0, dtype=jnp.int32)
     return jnp.minimum(row, n * k - 1), valid, group_sizes
 
 
-def moe_routed(p, u2, cfg: HybridLMConfig, dtype, choices=None):
-    """The routed experts' part, for the experts held here: u2 [N, d] ->
-    ([N, d], counters). `choices` (top_i, weights) replaces the router's."""
-    n = u2.shape[0]
-    k = cfg.num_experts_per_tok
-    with jax.named_scope("moe_router"):
-        top_i, top_w = route(p, u2, cfg) if choices is None else choices
+def expert_rows(rows: int, k: int, diff, order):
+    """The part of the routed experts whose arrays have a row a pair, at a
+    static count of `rows` that holds sum(group_sizes): gather, the two
+    grouped products, weights, scatter back, latent up-projection. `diff` =
+    (v [N, latent], top_w [N, k], and the parameters w1, w2, up, which
+    multiply in v's type); `order` is `dispatch`'s."""
+    v, top_w, w1, w2, up = diff
+    pair, valid, group_sizes = order
     with jax.named_scope("moe_dispatch"):
-        v = _mm(u2, _cast(p["down"], dtype)).astype(u2.dtype)
-        pair, valid, group_sizes = dispatch(top_i, cfg)
+        pair, valid = pair[:rows], valid[:rows, None]
         token = pair // k
-        x = jnp.where(valid[:, None], v[token], 0)
+        x = jnp.where(valid, v[token], 0)
     with jax.named_scope("moe_experts"):
         # The rows past the last group are room, and the TPU's grouped
         # product leaves them unwritten, forward and backward (NaN among
         # them): they are zeroed by a select wherever they come out, before
         # anything multiplies them.
-        h = jax.lax.ragged_dot(x, _cast(p["w1"], dtype), group_sizes)
-        h = relu2(jnp.where(valid[:, None], h, 0))
-        y = jax.lax.ragged_dot(h, _cast(p["w2"], dtype), group_sizes)
+        h = jax.lax.ragged_dot(x, w1.astype(v.dtype), group_sizes)
+        h = relu2(jnp.where(valid, h, 0))
+        y = jax.lax.ragged_dot(h, w2.astype(v.dtype), group_sizes)
     with jax.named_scope("moe_combine"):
         # masked before it is weighted: a product with an unwritten row would
         # carry its NaN into the weights' gradient, whatever the cotangent
-        y = jnp.where(valid[:, None], y, 0) * top_w.reshape(-1)[pair][:, None]
-        routed = jax.ops.segment_sum(y, token, num_segments=n).astype(u2.dtype)
-        out = _mm(routed, _cast(p["up"], dtype)).astype(u2.dtype)
+        y = jnp.where(valid, y, 0) * top_w.reshape(-1)[pair][:, None]
+        routed = jax.ops.segment_sum(y, token, num_segments=v.shape[0]).astype(v.dtype)
+        return _mm(routed, up.astype(v.dtype)).astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def on_the_ladder(rungs, k, rung, diff, order):
+    """`expert_rows` at rungs[rung], chosen on the device. Differentiable in
+    `diff`; what the backward pass keeps is the operands, which no rung
+    sizes: it chooses the rung again and differentiates `expert_rows` inside
+    the branch. (Differentiating the `switch` itself makes every branch
+    return every branch's intermediates, the full rung's among them, as
+    zeros where it did not run.)"""
+    return jax.lax.switch(rung, [functools.partial(expert_rows, rows, k) for rows in rungs],
+                          diff, order)
+
+
+def _ladder_fwd(rungs, k, rung, diff, order):
+    return on_the_ladder(rungs, k, rung, diff, order), (rung, diff, order)
+
+
+def _ladder_bwd(rungs, k, kept, g):
+    rung, diff, order = kept
+
+    def pull(rows):
+        return lambda diff, order, g: jax.vjp(
+            lambda diff: expert_rows(rows, k, diff, order), diff)[1](g)[0]
+
+    return None, jax.lax.switch(rung, [pull(rows) for rows in rungs], diff, order, g), None
+
+
+on_the_ladder.defvjp(_ladder_fwd, _ladder_bwd)
+
+
+def moe_routed(p, u2, cfg: HybridLMConfig, dtype, choices=None):
+    """The routed experts' part, for the experts held here: u2 [N, d] ->
+    ([N, d], counters). `choices` (top_i, weights) replaces the router's.
+    The row-sized part runs at the smallest of `row_rungs` that holds this
+    step's pairs and the rows of room."""
+    k = cfg.num_experts_per_tok
+    rungs = row_rungs(u2.shape[0], cfg)
+    with jax.named_scope("moe_router"):
+        top_i, top_w = route(p, u2, cfg) if choices is None else choices
+    with jax.named_scope("moe_dispatch"):
+        v = _mm(u2, _cast(p["down"], dtype)).astype(u2.dtype)
+        order = _, valid, group_sizes = dispatch(top_i, cfg)
+        # the first rung that holds the pairs and the rows of room
+        rung = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(rungs[:-1], jnp.int32),
+                       dtype=jnp.int32)
+    diff = (v, top_w, p["w1"], p["w2"], p["up"])
+    if len(rungs) == 1:
+        out = expert_rows(rungs[0], k, diff, order)
+    else:
+        out = on_the_ladder(rungs, k, rung, diff, order)
     with jax.named_scope("step_metrics"):
         counters = {
             "moe_pairs_here": jnp.sum(valid).astype(jnp.float32),
-            "moe_rows_computed": jnp.float32(pair.shape[0]),
+            "moe_rows_computed": jnp.asarray(rungs, jnp.float32)[rung],
+            "moe_rows_full_share": (rung == len(rungs) - 1).astype(jnp.float32),
             "moe_max_expert_load": (jnp.max(group_sizes) - 1).astype(jnp.float32),
         }
     return out, counters, top_i
@@ -396,9 +469,10 @@ def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
             remat: bool = True) -> Tuple[jnp.ndarray, dict]:
     """Next-token cross-entropy over the vocabulary rows held here, float32
     logits, the mean over the B * (T - 1) positions that have a next token.
-    Returns (loss, counters): pairs routed to the experts held and rows the
-    grouped product ran, each the mean over the `E` layers, and the fullest
-    expert's load over all of them."""
+    Returns (loss, counters): pairs routed to the experts held, rows of the
+    rung the grouped product ran at and whether that was the full one, each
+    the mean over the `E` layers, and the fullest expert's load over all of
+    them."""
     x, counters, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     bsz, t = ids.shape
     with jax.named_scope("lm_head_loss"):

@@ -4,7 +4,9 @@ hidden 64, latent 32, 16 experts of which 4 are held, 4 a token, 8 Mamba-2
 heads in 2 groups, chunk 8, 64 tokens. Each mixer and the whole stack,
 forward, loss and gradients; the chunked scan against the recurrence; the
 share tests (what all the chips of a layer compute adds up to the uncut
-layer); no pair dropped under any imbalance.
+layer); no pair dropped under any imbalance, at any rung of the ladder of
+row counts (a share of 2 of 32 experts and 1,536 tokens make it 1,024 /
+4,096 rows; the tiny preset's has the one full rung).
 
 Tolerances: float32 against the float32 reference differs by summation
 order only (3e-5 of the output's scale). In bfloat16 the program rounds
@@ -15,6 +17,7 @@ allowed.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -275,55 +278,101 @@ def test_the_attention_head_shares_add_up_to_the_uncut_layer():
 
 # --------------------------------------------------------- no pair is dropped
 
-
-def forced_choices(case, n):
-    """[n, k] experts every token chooses, and equal weights."""
-    k, lo, hi = CFG.num_experts_per_tok, CFG.expert_offset, CFG.expert_offset + CFG.n_routed_experts
-    outside = [e for e in range(CFG.n_routed_experts_total) if not lo <= e < hi]
-    if case == "every_token_the_same_held_expert":
-        row = [lo + 1] + outside[:k - 1]
-    elif case == "every_token_every_held_expert":
-        row = list(range(lo, hi))
-    else:  # no token any held expert
-        row = outside[:k]
-    top_i = jnp.tile(jnp.asarray(row, jnp.int32), (n, 1))
-    return top_i, jnp.full((n, k), CFG.routed_scaling_factor / k, jnp.float32)
+# A share small enough for a ladder: k = 4 of 32 experts scored, 2 held, so
+# 1,536 tokens send 384 pairs here when the router is balanced and at most
+# 3,072; more experts a token than experts held, so that the full count
+# (4,096) is no other array's size.
+LADDER = dataclasses.replace(CFG, n_routed_experts_total=32, n_routed_experts=2, expert_offset=4)
+LADDER_TOKENS = 1536
+CASES = ["every_token_the_same_held_expert", "every_token_every_held_expert",
+         "no_token_any_held_expert"]
 
 
-@pytest.mark.parametrize("case", ["every_token_the_same_held_expert",
-                                  "every_token_every_held_expert",
-                                  "no_token_any_held_expert"])
-def test_no_pair_is_dropped_under_any_imbalance(weights, case):
-    w = ref.layer_weights(weights, first_layer("E"))
-    u2 = stream(8).reshape(-1, CFG.hidden_size)
-    top_i, top_w = forced_choices(case, u2.shape[0])
-    f = lambda w: lm.moe_routed(w, u2, CFG, None, choices=(top_i, top_w))
-    out, counters, _ = f(w)
+def ladder_cases():
+    """The pair counts at each small rung's edge: the last that fits it (the
+    rung less the rows of room), and one more."""
+    rungs = lm.row_rungs(LADDER_TOKENS, LADDER)
+    assert len(rungs) > 1 and rungs[-1] == 4096 and all(r % lm.ROW_TILE == 0 for r in rungs)
+    held = LADDER.n_routed_experts
+    return [(LADDER, pairs) for r in rungs[:-1] for pairs in (r - held, r - held + 1)]
+
+
+def forced_choices(cfg, case, n):
+    """[n, k] experts every token chooses, and weights. `case` is a name, or
+    a count of pairs on the experts held: as many tokens as it takes choose
+    them all, one more token the rest, the others none."""
+    k, held, lo = cfg.num_experts_per_tok, cfg.n_routed_experts, cfg.expert_offset
+    outside = [e for e in range(cfg.n_routed_experts_total) if not lo <= e < lo + held]
+    rows = {"every_token_the_same_held_expert": [lo + 1] + outside[:k - 1],
+            "every_token_every_held_expert": (list(range(lo, lo + held)) + outside)[:k],
+            "no_token_any_held_expert": outside[:k]}
+    if case in rows:
+        top_i = np.tile(np.asarray(rows[case], np.int32), (n, 1))
+    else:
+        top_i = np.tile(np.asarray(outside[:k], np.int32), (n, 1))
+        whole, rest = divmod(case, min(k, held))
+        top_i[:whole, :min(k, held)] = np.arange(lo, lo + min(k, held))
+        top_i[whole, :rest] = np.arange(lo, lo + rest)
+    weights = 0.5 + jax.random.uniform(jax.random.PRNGKey(11), (n, k))
+    weights = weights / jnp.sum(weights, -1, keepdims=True) * cfg.routed_scaling_factor
+    return jnp.asarray(top_i), weights
+
+
+def expert_layer(cfg, seed=3):
+    model = dataclasses.asdict(cfg)
+    return ref.layer_weights(weights_lm.make_weights(seed, model), cfg.pattern.index("E"))
+
+
+@pytest.mark.parametrize("cfg, case", [(CFG, c) for c in CASES] + [(LADDER, c) for c in CASES]
+                         + ladder_cases(),
+                         ids=lambda v: "ladder" if v is LADDER else "tiny" if v is CFG else str(v))
+def test_no_pair_is_dropped_under_any_imbalance(cfg, case, monkeypatch):
+    n = 128 if cfg is CFG else LADDER_TOKENS
+    w = expert_layer(cfg)
+    u2 = jax.random.normal(jax.random.PRNGKey(8), (n, cfg.hidden_size), jnp.float32)
+    top_i, top_w = forced_choices(cfg, case, n)
+    lo, held, k = cfg.expert_offset, cfg.n_routed_experts, cfg.num_experts_per_tok
+    here = (top_i >= lo) & (top_i < lo + held)
+    pairs = int(jnp.sum(here))
+
+    def run():
+        f = lambda w, u2, tw: lm.moe_routed(w, u2, cfg, None, choices=(top_i, tw))
+        out, counters, _ = jax.jit(f)(w, u2, top_w)
+        grads = jax.jit(jax.grad(lambda *a: jnp.sum(f(*a)[0] ** 2), argnums=(0, 1, 2)))(
+            w, u2, top_w)
+        return out, counters, grads
+
+    out, counters, grads = run()
     # the same sum written out: every token through every held expert it
     # chose, with the weight it gave it
     v = u2 @ w["down"]
-    latent = sum(top_w[0, j] * ref.relu2(v @ w["w1"][e - CFG.expert_offset])
-                 @ w["w2"][e - CFG.expert_offset]
-                 for j, e in enumerate(np.asarray(top_i[0]))
-                 if CFG.expert_offset <= e < CFG.expert_offset + CFG.n_routed_experts)
-    want = latent @ w["up"] if not isinstance(latent, int) else jnp.zeros_like(u2)
-    held = int(jnp.sum((top_i[0] >= CFG.expert_offset)
-                       & (top_i[0] < CFG.expert_offset + CFG.n_routed_experts)))
-    assert float(counters["moe_pairs_here"]) == held * u2.shape[0]
-    assert float(counters["moe_max_expert_load"]) == (u2.shape[0] if held else 0)
-    assert float(counters["moe_rows_computed"]) >= float(counters["moe_pairs_here"])
-    if held:
-        assert rel(out, want) < F32_TOL
-    else:
-        assert float(jnp.max(jnp.abs(out))) == 0.0 and float(jnp.max(jnp.abs(want))) == 0.0
-    grads = jax.grad(lambda w: jnp.sum(f(w)[0] ** 2))(w)
-    assert all(np.isfinite(np.asarray(g)).all() for g in grads.values())
+    latent = sum(jnp.where(here[:, j, None] & (top_i[:, j, None] == lo + e), top_w[:, j, None], 0)
+                 * (ref.relu2(v @ w["w1"][e]) @ w["w2"][e])
+                 for j in range(k) for e in range(held))
+    assert rel(out, latent @ w["up"]) < F32_TOL if pairs else float(jnp.max(jnp.abs(out))) == 0.0
+    # the counters say what was run: the smallest rung that holds the pairs
+    # and the experts' rows of room
+    rungs = lm.row_rungs(n, cfg)
+    rows = next(r for r in rungs if r >= pairs + held)
+    assert float(counters["moe_pairs_here"]) == pairs
+    assert float(counters["moe_rows_computed"]) == rows
+    assert float(counters["moe_rows_full_share"]) == (rows == rungs[-1])
+    assert float(counters["moe_max_expert_load"]) == int(jnp.max(jnp.sum(
+        here[:, :, None] & (top_i[:, :, None] == lo + jnp.arange(held)), axis=(0, 1))))
+    assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(grads))
+    if len(rungs) > 1:
+        # the single program at the full count gives the same, whichever rung ran
+        monkeypatch.setattr(lm, "row_rungs", lambda n, cfg, real=lm.row_rungs: real(n, cfg)[-1:])
+        want_out, want_counters, want_grads = run()
+        assert float(want_counters["moe_rows_computed"]) == rungs[-1]
+        for a, b in zip(jax.tree_util.tree_leaves((out, grads)),
+                        jax.tree_util.tree_leaves((want_out, want_grads))):
+            assert rel(a, b) < 1e-6
 
 
-def test_rows_past_the_groups_may_hold_anything(weights, ids, monkeypatch):
-    """The TPU's grouped product leaves the rows past the last group
-    unwritten, in its output and in its input's gradient. Here they are
-    filled with NaN: loss and gradients stay what they were."""
+def poison_the_rows_past_the_groups(monkeypatch):
+    """`lax.ragged_dot` with NaN in the rows past the last group, in its
+    output and in its input's gradient: what the TPU's leaves unwritten."""
     real = jax.lax.ragged_dot
 
     def tail(rows, group_sizes):
@@ -342,14 +391,111 @@ def test_rows_past_the_groups_may_hold_anything(weights, ids, monkeypatch):
         return jnp.where(tail(x.shape[0], group_sizes), jnp.nan, dx), dw, None
 
     poisoned.defvjp(fwd, bwd)
-    params = weights_lm.to_program_params(weights)
-    f = lambda: jax.value_and_grad(lambda p: lm.lm_loss(p, ids, CFG)[0])(params)
-    want_loss, want = f()
     monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
-    loss, grads = f()
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want)):
+
+
+@pytest.mark.parametrize("where", ["the_whole_stack", "a_small_rung"])
+def test_rows_past_the_groups_may_hold_anything(weights, ids, monkeypatch, where):
+    """The TPU's grouped product leaves the rows past the last group
+    unwritten, in its output and in its input's gradient. Here they are
+    filled with NaN: loss and gradients stay what they were, in the tiny
+    stack (one rung) and in an expert layer whose pairs take the ladder's
+    first rung."""
+    if where == "the_whole_stack":
+        params = weights_lm.to_program_params(weights)
+        f = lambda: jax.value_and_grad(lambda p: lm.lm_loss(p, ids, CFG)[0])(params)
+    else:
+        w = expert_layer(LADDER)
+        x = jax.random.normal(jax.random.PRNGKey(8), (1, LADDER_TOKENS, LADDER.hidden_size))
+
+        def loss(w, x):
+            out, counters, _ = lm.layer("E", w, x, LADDER, None)
+            return jnp.sum(out ** 2), counters
+
+        f = lambda: jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(w, x)
+        assert float(f()[0][1]["moe_rows_computed"]) == lm.row_rungs(LADDER_TOKENS, LADDER)[0]
+    want = f()
+    poison_the_rows_past_the_groups(monkeypatch)
+    got = f()
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(a)).all() and rel(a, b) < 1e-5
+
+
+def inner_jaxprs(eqn, but_the_last_branch=False):
+    """The jaxprs an equation holds (a `cond`'s branches, a `remat`'s or a
+    `custom_vjp_call`'s body, ...)."""
+    found = []
+    for name, val in eqn.params.items():
+        vals = val if isinstance(val, (tuple, list)) else (val,)
+        if but_the_last_branch and eqn.primitive.name == "cond" and name == "branches":
+            vals = vals[:-1]
+        found += [getattr(v, "jaxpr", v) for v in vals
+                  if hasattr(getattr(v, "jaxpr", v), "eqns")]
+    return found
+
+
+def shapes_outside_the_last_branch(jaxpr):
+    """Every array shape in a jaxpr and the jaxprs inside it, but for the
+    last branch of each `cond` (a ladder's full rung)."""
+    shapes = set()
+    for eqn in jaxpr.eqns:
+        shapes |= {tuple(v.aval.shape) for v in list(eqn.invars) + list(eqn.outvars)
+                   if hasattr(v.aval, "shape")}
+        for sub in inner_jaxprs(eqn, but_the_last_branch=True):
+            shapes |= shapes_outside_the_last_branch(sub)
+    return shapes
+
+
+def conds_in(jaxpr):
+    return sum((eqn.primitive.name == "cond") + sum(conds_in(j) for j in inner_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
+def test_only_the_full_rung_holds_arrays_of_the_full_row_count():
+    """In the step's gradient, forward, recomputation and backward: an array
+    with the full count of rows lives in the full rung's own branch and
+    nowhere else. (Differentiating the `switch` itself fails this: every
+    branch then returns the full rung's intermediates.)"""
+    cfg = dataclasses.replace(LADDER, seq_len=LADDER_TOKENS)
+    k, rungs = cfg.num_experts_per_tok, lm.row_rungs(LADDER_TOKENS, cfg)
+    full = rungs[-1]
+    params = jax.eval_shape(lambda: lm.init_hybrid_lm(jax.random.PRNGKey(0), cfg))
+    ids = jax.ShapeDtypeStruct((1, LADDER_TOKENS), jnp.int32)
+    grad = jax.make_jaxpr(jax.grad(lambda p, ids: lm.lm_loss(p, ids, cfg)[0]))(params, ids)
+    # a forward and a backward choice an expert layer; the recomputation's
+    # forward choice may be there or pruned
+    layers = cfg.pattern.count("E")
+    assert 2 * layers <= conds_in(grad.jaxpr) <= 3 * layers
+    outside = shapes_outside_the_last_branch(grad.jaxpr)
+    assert any(s[:1] == rungs[:1] for s in outside)
+    assert not [s for s in outside if full in s]
+    # the check sees what it is for: the plain derivative of the choice
+    # returns the full rung's intermediates from every branch
+    w = jax.eval_shape(lambda: expert_layer(cfg))
+
+    def plain(w, u2):
+        top_i, top_w = lm.route(w, u2, cfg)
+        diff = (u2 @ w["down"], top_w, w["w1"], w["w2"], w["up"])
+        branches = [functools.partial(lm.expert_rows, rows, k) for rows in rungs]
+        return jnp.sum(jax.lax.switch(0, branches, diff, lm.dispatch(top_i, cfg)))
+
+    u2 = jax.ShapeDtypeStruct((LADDER_TOKENS, cfg.hidden_size), jnp.float32)
+    unioned = jax.make_jaxpr(jax.grad(plain))(w, u2).jaxpr
+    assert [s for s in shapes_outside_the_last_branch(unioned) if full in s]
+
+
+def test_a_share_that_holds_most_of_the_experts_traces_no_choice():
+    """Where the first rung is no smaller than the full count there is one
+    rung and the single program: the tiny preset, and any uncut layer."""
+    whole = dataclasses.replace(LADDER, n_routed_experts=32, expert_offset=0)
+    for cfg, n in ((CFG, 128), (uncut(), 128), (whole, LADDER_TOKENS)):
+        assert len(lm.row_rungs(n, cfg)) == 1
+        w = jax.eval_shape(lambda: expert_layer(cfg))
+        u2 = jax.ShapeDtypeStruct((n, cfg.hidden_size), jnp.float32)
+        out = lambda w, u2, cfg=cfg: jnp.sum(lm.moe_routed(w, u2, cfg, None)[0])
+        assert conds_in(jax.make_jaxpr(jax.grad(out))(w, u2).jaxpr) == 0
+    full = get_preset("nemotron3-super-ep64tp8").model
+    assert lm.row_rungs(8192, full) == (6144, 66560)
 
 
 def test_dispatch_sorts_by_expert_and_gives_every_group_a_row_of_room():

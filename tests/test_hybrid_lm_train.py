@@ -7,6 +7,7 @@ CPU only: what is checked is behaviour and metadata, never a time.
 """
 
 import dataclasses
+import itertools
 import re
 
 import jax
@@ -172,11 +173,22 @@ def test_fit_emits_the_three_host_spans_and_the_prefetch_workers(fitted):
 def test_the_logging_records_carry_the_routing_counters(fitted, tiny):
     cfg = tiny[0]
     _, history, _ = fitted
+    from glom_tpu.models.hybrid_lm import row_rungs
+
     n, k, e = 2 * cfg.seq_len, cfg.num_experts_per_tok, cfg.n_routed_experts
+    layers = cfg.pattern.count("E")
+    rungs = row_rungs(n, cfg)
+    assert rungs[-1] == n * min(k, e) + e
     for r in history:
-        assert r["moe_rows_computed"] == n * min(k, e) + e
+        # the mean over the expert layers of the rung each ran, which holds
+        # its pairs and the experts' rows of room
+        assert any(sum(ran) == r["moe_rows_computed"] * layers
+                   for ran in itertools.combinations_with_replacement(rungs, layers))
+        assert r["moe_pairs_here"] + e <= r["moe_rows_computed"] <= rungs[-1]
+        assert 0.0 <= r["moe_rows_full_share"] <= 1.0
+        assert (r["moe_rows_full_share"] == 1.0) == (r["moe_rows_computed"] == rungs[-1])
         assert 0 < r["moe_pairs_here"] <= n * min(k, e)
-        assert r["moe_pairs_here"] / cfg.pattern.count("E") <= r["moe_max_expert_load"] * e
+        assert r["moe_pairs_here"] / layers <= r["moe_max_expert_load"] * e
         assert r["moe_max_expert_load"] <= n
 
 
