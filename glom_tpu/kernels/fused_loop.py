@@ -265,294 +265,6 @@ def _ffw_bwd_acc_add_kernel(
         da_ref[...] += da_step
 
 
-def _grid_mode() -> str:
-    """Trace-time knob for the loop's FFW grid layout. 'split' (default):
-    two pallas_calls per direction per iteration — the round-4 measured
-    configuration. 'combined' (GLOM_LOOP_GRID=combined): ONE call over all
-    2L-1 groups (td groups 0..L-2, bu groups L-1..2L-2), killing a kernel
-    boundary per phase per iteration and giving Mosaic a single larger
-    grid to overlap dw flushes across. Values are bit-identical (same
-    per-group math, same accumulation order); promote to default only
-    after an A/B on the chip measures >= split. A mid-session
-    env flip between a forward and its cached backward cannot corrupt
-    results: the residual tuple LENGTH encodes the layout (4 = combined,
-    5 = split, 3 = remat, whose recompute is layout-agnostic)."""
-    import os
-    import warnings
-
-    mode = os.environ.get("GLOM_LOOP_GRID", "split")
-    if mode not in ("split", "combined"):
-        # a typo in an A/B run must not silently measure split twice
-        warnings.warn(
-            f"GLOM_LOOP_GRID={mode!r} ignored (valid: split / combined); "
-            "using split",
-            stacklevel=3,
-        )
-        return "split"
-    return mode
-
-
-def _cat_params(td_params: GroupedFFWParams, bu_params: GroupedFFWParams):
-    """td||bu group-axis concat, built ONCE per step (weights are loop
-    invariants): [2L-1, ...] per leaf, ~0.1 ms of HBM traffic at the
-    flagship vs 2·T kernel-boundary bubbles saved."""
-    return GroupedFFWParams(
-        jnp.concatenate([td_params.w1, bu_params.w1]),
-        jnp.concatenate([td_params.b1, bu_params.b1]),
-        jnp.concatenate([td_params.w2, bu_params.w2]),
-        jnp.concatenate([td_params.b2, bu_params.b2]),
-    )
-
-
-def _cat_x_spec(tile_m: int, d: int, split: int):
-    """x read for cat grids: td group g reads carry slot g+2, bu group
-    g' = g-split reads slot g' (tokens pinned in slot 0)."""
-    return pl.BlockSpec(
-        (1, tile_m, d),
-        lambda g, m, _s=split: (jnp.where(g < _s, g + 2, g - _s), m, 0),
-    )
-
-
-def _cat_addend(pos_emb: jnp.ndarray) -> jnp.ndarray:
-    """[2n, d]: row-block 0 = the positional table (td groups), row-block
-    1 = zeros (bu groups add nothing) — selected per group by the addend
-    index map, so _mlp_kernel_add / _pre_add_kernel run UNCHANGED on the
-    cat grid."""
-    return jnp.concatenate([pos_emb, jnp.zeros_like(pos_emb)], axis=0)
-
-
-def _cat_a_spec(n: int, d: int, split: int):
-    return pl.BlockSpec(
-        (n, d), lambda g, m, _s=split: (jnp.where(g < _s, 0, 1), 0)
-    )
-
-
-def _ffw_fwd_cat(
-    wcat: GroupedFFWParams,
-    ext2: jnp.ndarray,   # [L+1, M, d]
-    a2: jnp.ndarray,     # [2n, d] padded addend (_cat_addend, hoisted)
-    L: int,
-    *,
-    tile_m: int,
-    interpret: bool,
-    save_pre: bool = True,
-):
-    """Combined bu+td forward: one grid over 2L-1 groups. Returns
-    (out_cat [G, M, d], pre_cat [G, M, f] | None)."""
-    M, d = ext2.shape[1], ext2.shape[2]
-    f = wcat.w1.shape[-1]
-    G, split = 2 * L - 1, L - 1
-    n = a2.shape[0] // 2
-    grid = (G, M // tile_m)
-    out_shape = (
-        jax.ShapeDtypeStruct((G, M, d), ext2.dtype),
-        jax.ShapeDtypeStruct((G, M, f), ext2.dtype),
-    )
-    out_spec = (
-        pl.BlockSpec((1, tile_m, d), lambda g, m: (g, m, 0)),
-        pl.BlockSpec((1, tile_m, f), lambda g, m: (g, m, 0)),
-    )
-    if not save_pre:
-        out_shape, out_spec = out_shape[:1], out_spec[:1]
-    out = pl.pallas_call(
-        _mlp_kernel_add,
-        out_shape=out_shape,
-        grid=grid,
-        in_specs=[
-            _cat_x_spec(tile_m, d, split),
-            _cat_a_spec(n, d, split),
-            pl.BlockSpec((1, d, f), lambda g, m: (g, 0, 0)),
-            pl.BlockSpec((1, 1, f), lambda g, m: (g, 0, 0)),
-            pl.BlockSpec((1, f, d), lambda g, m: (g, 0, 0)),
-            pl.BlockSpec((1, 1, d), lambda g, m: (g, 0, 0)),
-        ],
-        out_specs=out_spec,
-        compiler_params=_VMEM_64M,
-        interpret=interpret,
-        name="loop_ffw_cat_fwd",
-    )(ext2, a2, wcat.w1, wcat.b1[:, None, :], wcat.w2, wcat.b2[:, None, :])
-    return out if save_pre else (out[0], None)
-
-
-def _pre_fwd_cat(
-    wcat: GroupedFFWParams,
-    ext2: jnp.ndarray,
-    a2: jnp.ndarray,     # [2n, d] padded addend (_cat_addend, hoisted)
-    L: int,
-    *,
-    tile_m: int,
-    interpret: bool,
-):
-    """Remat-mode pre recompute on the cat grid (first matmul only)."""
-    M, d = ext2.shape[1], ext2.shape[2]
-    f = wcat.w1.shape[-1]
-    G, split = 2 * L - 1, L - 1
-    n = a2.shape[0] // 2
-    return pl.pallas_call(
-        _pre_add_kernel,
-        out_shape=jax.ShapeDtypeStruct((G, M, f), ext2.dtype),
-        grid=(G, M // tile_m),
-        in_specs=[
-            _cat_x_spec(tile_m, d, split),
-            _cat_a_spec(n, d, split),
-            pl.BlockSpec((1, d, f), lambda g, m: (g, 0, 0)),
-            pl.BlockSpec((1, 1, f), lambda g, m: (g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_m, f), lambda g, m: (g, m, 0)),
-        compiler_params=_VMEM_64M,
-        interpret=interpret,
-        name="loop_ffw_cat_pre_fwd",
-    )(ext2, a2, wcat.w1, wcat.b1[:, None, :])
-
-
-def _ffw_bwd_cat_acc_kernel(
-    x_ref, a_ref, w1_ref, pre_ref, w2_ref, g_ref,
-    dw1i_ref, db1i_ref, dw2i_ref, db2i_ref, dai_ref,
-    dx_ref, dw1_ref, db1_ref, dw2_ref, db2_ref, da_ref,
-    *, split,
-):
-    """Chained cat-grid backward: like _ffw_bwd_acc_add_kernel, but the da
-    reduction is MASKED to the td groups (the zero-addend trick keeps the
-    matmul math identical for bu groups, but their dx must not leak into
-    d(pos))."""
-    gid = pl.program_id(0)
-    xa = _tiled_add(x_ref[0], a_ref[...]).astype(x_ref.dtype)
-    dx32 = _mlp_bwd_tail(
-        pre_ref[0].astype(jnp.float32), xa, g_ref[0], w1_ref[0], w2_ref[0],
-        dx_ref, dw1_ref, db1_ref, dw2_ref, db2_ref,
-        inc=(dw1i_ref, db1i_ref, dw2i_ref, db2i_ref),
-    )
-    tm, d = dx32.shape
-    n = a_ref.shape[0]
-    da_step = jnp.where(
-        gid < split,
-        jnp.sum(dx32.reshape(tm // n, n, d), axis=0),
-        0.0,
-    )
-    first = (gid == 0) & (pl.program_id(1) == 0)
-
-    @pl.when(first)
-    def _init_da():
-        da_ref[...] = dai_ref[...] + da_step
-
-    @pl.when(jnp.logical_not(first))
-    def _accum_da():
-        da_ref[...] += da_step
-
-
-def _ffw_bwd_cat_kernel(
-    x_ref, a_ref, w1_ref, pre_ref, w2_ref, g_ref,
-    dx_ref, dw1_ref, db1_ref, dw2_ref, db2_ref, da_ref,
-    *, split,
-):
-    """Unchained cat-grid backward (fresh dw per iteration, XLA adds)."""
-    gid = pl.program_id(0)
-    xa = _tiled_add(x_ref[0], a_ref[...]).astype(x_ref.dtype)
-    dx32 = _mlp_bwd_tail(
-        pre_ref[0].astype(jnp.float32), xa, g_ref[0], w1_ref[0], w2_ref[0],
-        dx_ref, dw1_ref, db1_ref, dw2_ref, db2_ref,
-    )
-    tm, d = dx32.shape
-    n = a_ref.shape[0]
-    da_step = jnp.where(
-        gid < split,
-        jnp.sum(dx32.reshape(tm // n, n, d), axis=0),
-        0.0,
-    )
-    first = (gid == 0) & (pl.program_id(1) == 0)
-
-    @pl.when(first)
-    def _init_da():
-        da_ref[...] = da_step
-
-    @pl.when(jnp.logical_not(first))
-    def _accum_da():
-        da_ref[...] += da_step
-
-
-def _ffw_bwd_cat(
-    wcat: GroupedFFWParams,
-    ext2: jnp.ndarray,       # [L+1, M, d] saved carry
-    pre_cat: jnp.ndarray,    # [G, M, f]
-    gcot2: jnp.ndarray,      # [L, M, d] dmean
-    acc: GroupedFFWParams,   # [G, ...] incoming f32 accumulators
-    a2: jnp.ndarray,         # [2n, d] padded addend (_cat_addend, hoisted)
-    da_in: jnp.ndarray,
-    L: int,
-    *,
-    tile_m: int,
-    interpret: bool,
-    chain: bool,
-):
-    """Combined bu+td backward: one grid over 2L-1 groups. td group g
-    reads cotangent dmean slot g; bu group g' reads slot g'. Returns
-    (accumulated grads [G, ...], dx_cat [G, M, d], da)."""
-    M, d = ext2.shape[1], ext2.shape[2]
-    f = wcat.w1.shape[-1]
-    G, split = 2 * L - 1, L - 1
-    n = a2.shape[0] // 2
-    f32 = jnp.float32
-    grid = (G, M // tile_m)
-    row_spec = pl.BlockSpec((1, tile_m, d), lambda g, m: (g, m, 0))
-    cot_spec = pl.BlockSpec(
-        (1, tile_m, d),
-        lambda g, m, _s=split: (jnp.where(g < _s, g, g - _s), m, 0),
-    )
-    acc_specs = [
-        pl.BlockSpec((1, d, f), lambda g, m: (g, 0, 0)),
-        pl.BlockSpec((1, 1, f), lambda g, m: (g, 0, 0)),
-        pl.BlockSpec((1, f, d), lambda g, m: (g, 0, 0)),
-        pl.BlockSpec((1, 1, d), lambda g, m: (g, 0, 0)),
-    ]
-    da_spec = pl.BlockSpec((n, d), lambda g, m: (0, 0))
-    out_shapes = (
-        jax.ShapeDtypeStruct((G, M, d), ext2.dtype),
-        jax.ShapeDtypeStruct((G, d, f), f32),
-        jax.ShapeDtypeStruct((G, 1, f), f32),
-        jax.ShapeDtypeStruct((G, f, d), f32),
-        jax.ShapeDtypeStruct((G, 1, d), f32),
-        jax.ShapeDtypeStruct((n, d), f32),
-    )
-    out_specs = (row_spec,) + tuple(acc_specs) + (da_spec,)
-    common = [
-        _cat_x_spec(tile_m, d, split),
-        _cat_a_spec(n, d, split),  # pos row for td groups, zeros for bu
-        pl.BlockSpec((1, d, f), lambda g, m: (g, 0, 0)),  # w1
-        pl.BlockSpec((1, tile_m, f), lambda g, m: (g, m, 0)),  # pre
-        pl.BlockSpec((1, f, d), lambda g, m: (g, 0, 0)),  # w2
-        cot_spec,
-    ]
-    compiler_params = (
-        _VMEM_64M if chain
-        else _bwd_compiler_params(tile_m, d, f, ext2.dtype.itemsize)
-    )
-    if chain:
-        dx, dw1, db1, dw2, db2, da = pl.pallas_call(
-            partial(_ffw_bwd_cat_acc_kernel, split=split),
-            out_shape=out_shapes,
-            grid=grid,
-            in_specs=common + acc_specs + [da_spec],
-            out_specs=out_specs,
-            compiler_params=compiler_params,
-            interpret=interpret,
-            name="loop_ffw_cat_acc_bwd",
-        )(ext2, a2, wcat.w1, pre_cat, wcat.w2, gcot2,
-          acc.w1, acc.b1, acc.w2, acc.b2, da_in)
-        return GroupedFFWParams(dw1, db1, dw2, db2), dx, da
-    dx, dw1, db1, dw2, db2, da = pl.pallas_call(
-        partial(_ffw_bwd_cat_kernel, split=split),
-        out_shape=out_shapes,
-        grid=grid,
-        in_specs=common,
-        out_specs=out_specs,
-        compiler_params=compiler_params,
-        interpret=interpret,
-        name="loop_ffw_cat_bwd",
-    )(ext2, a2, wcat.w1, pre_cat, wcat.w2, gcot2)
-    fresh = GroupedFFWParams(dw1, db1, dw2, db2)
-    return jax.tree_util.tree_map(jnp.add, acc, fresh), dx, da_in + da
-
-
 def _chain_ws_ok(bt: int, d: int, f: int, itemsize: int, n: int) -> bool:
     """Can the accumulator-CHAINED backward kernels fit the working-set
     budget? Chaining adds the incoming dw1/dw2 f32 blocks (2*d*f*4) and
@@ -682,24 +394,19 @@ def _ffw_bwd_ext(
 
 def _cons_fwd_ext(
     ext: jnp.ndarray,   # [L+1, B, n, d] slot carry
-    bu: jnp.ndarray,    # [L, B, n, d], or the [2L-1, ...] cat buffer
-    td: jnp.ndarray,    # [L-1, B, n, d], or the same cat buffer
+    bu: jnp.ndarray,    # [L, B, n, d]
+    td: jnp.ndarray,    # [L-1, B, n, d]
     *,
     side: int,
     radius: float,
     attend_self: bool,
     interpret: bool,
-    cat: bool = False,
 ):
     """Fused consensus+mean update on the slot carry: level g's q/k/v read
     slot g+1, and the output writes slots 1..L of a fresh [L+1] buffer
     (slot 0 is re-pinned to the tokens by the caller's in-place
     dynamic_update_slice — the buffer's only other use). Always emits the
-    (m, l) stats — the only caller is the training forward.
-
-    cat=True: bu and td are the SAME [2L-1, B, n, d] combined-grid buffer
-    (td groups in slots 0..L-2, bu in L-1..2L-2); only the index maps
-    change — no slicing/copying of the cat buffer ever materializes."""
+    (m, l) stats — the only caller is the training forward."""
     Lp1, B, n, d = ext.shape
     L = Lp1 - 1
     tile_i = _pick_cons_tile(n)
@@ -727,20 +434,21 @@ def _cons_fwd_ext(
         jax.ShapeDtypeStruct((Lp1, B, n, d), ext.dtype), stat_shape, stat_shape
     )
     out_spec = (lv_spec(d), g_spec(1), g_spec(1))
-    bu_off = L - 1 if cat else 0  # bu groups live at cat slots L-1..2L-2
     in_specs = [
         lv_spec(d),  # x (self tile): slot g+1
         pl.BlockSpec(
             (1, tile_b, n, d), lambda g, b, i: (g + 1, b, 0, 0)
         ),  # kv rows: slot g+1
+        # `0 + g`, not `g`: the add is part of the index map the ledger's
+        # runs were lowered from (the deleted one-grid layout kept its bu
+        # groups at an offset here); dropping it changes the kernel's bytes.
         pl.BlockSpec(
-            (1, tile_b, tile_i, d),
-            lambda g, b, i, _o=bu_off: (_o + g, b, i, 0),
+            (1, tile_b, tile_i, d), lambda g, b, i: (0 + g, b, i, 0)
         ),  # bu
         pl.BlockSpec(
             (1, tile_b, tile_i, d),
             lambda g, b, i, _L=L: (jnp.minimum(g, _L - 2), b, i, 0),
-        ),  # td (clamped top, masked in-kernel; cat slots 0..L-2 ARE td)
+        ),  # td (clamped top, masked in-kernel)
     ]
     return pl.pallas_call(
         partial(_consensus_update_kernel, **kw),
@@ -801,12 +509,7 @@ def _cons_bwd_ext(
     radius: float,
     attend_self: bool,
     interpret: bool,
-    cat: bool = False,
 ):
-    """cat=True: dx_bu and dx_td are the SAME [2L-1, B, n, d] combined-grid
-    dx buffer (td cotangents in slots 0..L-2, bu in L-1..2L-2); the bu
-    stream's index map shifts by L-1, the td stream's already lands in the
-    right slots."""
     Lp1, B, n, d = ext.shape
     L = Lp1 - 1
     itemsize = ext.dtype.itemsize
@@ -822,13 +525,10 @@ def _cons_bwd_ext(
     in_specs = [spec(d, lambda g, b: (g + 1, b, 0, 0)), spec(d, ident)]
     ins = [ext, dg]
     if dx_bu is not None:
-        bu_off = L - 1 if cat else 0
         in_specs += [
-            spec(
+            spec(  # `0 +`: as in _cons_fwd_ext, the lowered bytes' own add
                 d,
-                lambda g, b, _L=L, _o=bu_off: (
-                    _o + jnp.minimum(g + 1, _L - 1), b, 0, 0
-                ),
+                lambda g, b, _L=L: (0 + jnp.minimum(g + 1, _L - 1), b, 0, 0),
             ),
             spec(d, lambda g, b: (jnp.maximum(g - 1, 0), b, 0, 0)),
         ]
@@ -957,46 +657,26 @@ def _loop_fwd(
     ext = jnp.concatenate([tokens[None], levels0], axis=0)
     ext2_shape = (L + 1, B * n, d)
     tile_m = _pick_tile(B * n, d, bu_params.w1.shape[-1], tokens.dtype.itemsize)
-    combined = _grid_mode() == "combined"
-    wcat = _cat_params(td_params, bu_params) if combined else None
-    a2 = _cat_addend(pos_emb) if combined else None  # loop-invariant
     saved = []
     for _ in range(iters):
         ext2 = ext.reshape(ext2_shape)
-        if combined:
-            out_cat, pre_cat = _ffw_fwd_cat(
-                wcat, ext2, a2, L, tile_m=tile_m, interpret=interpret,
-                save_pre=not remat,
-            )
-            cat4 = out_cat.reshape(2 * L - 1, B, n, d)
-            new_ext, m, l = _cons_fwd_ext(
-                ext, cat4, cat4,
-                side=side, radius=radius, attend_self=attend_self,
-                interpret=interpret, cat=True,
-            )
-            # Residual tuple LENGTH encodes the grid layout for _loop_bwd:
-            # 4 = combined, 5 = split, 3 = remat (layout-agnostic).
-            saved.append((ext, m, l) if remat else (ext, pre_cat, m, l))
-        else:
-            bu, pre_bu = _ffw_fwd_ext(
-                bu_params, ext2, 0, L, tile_m=tile_m, interpret=interpret,
-                save_pre=not remat,
-            )
-            td, pre_td = _ffw_fwd_ext(
-                td_params, ext2, 2, L - 1, tile_m=tile_m, interpret=interpret,
-                add=pos_emb, save_pre=not remat,
-            )
-            new_ext, m, l = _cons_fwd_ext(
-                ext, bu.reshape(L, B, n, d), td.reshape(L - 1, B, n, d),
-                side=side, radius=radius, attend_self=attend_self,
-                interpret=interpret,
-            )
-            # Remat mode saves only the carry + the tiny [L, B, n, 1]
-            # stats; the pre-activations (the dominant residual) are
-            # recomputed per iteration in _loop_bwd.
-            saved.append(
-                (ext, m, l) if remat else (ext, pre_bu, pre_td, m, l)
-            )
+        bu, pre_bu = _ffw_fwd_ext(
+            bu_params, ext2, 0, L, tile_m=tile_m, interpret=interpret,
+            save_pre=not remat,
+        )
+        td, pre_td = _ffw_fwd_ext(
+            td_params, ext2, 2, L - 1, tile_m=tile_m, interpret=interpret,
+            add=pos_emb, save_pre=not remat,
+        )
+        new_ext, m, l = _cons_fwd_ext(
+            ext, bu.reshape(L, B, n, d), td.reshape(L - 1, B, n, d),
+            side=side, radius=radius, attend_self=attend_self,
+            interpret=interpret,
+        )
+        # Remat mode saves only the carry + the tiny [L, B, n, 1] stats;
+        # the pre-activations (the dominant residual) are recomputed per
+        # iteration in _loop_bwd.
+        saved.append((ext, m, l) if remat else (ext, pre_bu, pre_td, m, l))
         ext = jax.lax.dynamic_update_slice(new_ext, tokens[None], (0, 0, 0, 0))
     return ext[1:], (bu_params, td_params, pos_emb, tuple(saved))
 
@@ -1026,83 +706,39 @@ def _loop_bwd(iters, side, radius, attend_self, interpret, remat, res, g):
     tile_fwd = _pick_tile(M, d, f_bu, g.dtype.itemsize)
     chain = _chain_ws_ok(bt, d, f_bu, g.dtype.itemsize, n)
 
-    # Grid layout from the residual STRUCTURE (4-tuple = combined,
-    # 5-tuple = split); remat residuals (3-tuple) are layout-agnostic —
-    # the recompute form follows the env knob, values identical.
-    combined = len(saved[0]) == 4 or (
-        len(saved[0]) == 3 and _grid_mode() == "combined"
-    )
-    if combined:
-        G, split = 2 * L - 1, L - 1
-        wcat = _cat_params(td_params, bu_params)
-        a2 = _cat_addend(pos_emb)  # loop-invariant, built once
-        acc_cat = GroupedFFWParams(
-            jnp.zeros((G, d, f_bu), f32),
-            jnp.zeros((G, 1, f_bu), f32),
-            jnp.zeros((G, f_bu, d), f32),
-            jnp.zeros((G, 1, d), f32),
-        )
-        dx_cat4 = None
-        for t in reversed(range(iters)):
-            if len(saved[t]) == 3:
-                ext, m, l = saved[t]
-                pre_cat = _pre_fwd_cat(
-                    wcat, ext.reshape(L + 1, M, d), a2, L,
-                    tile_m=tile_fwd, interpret=interpret,
-                )
-            else:
-                ext, pre_cat, m, l = saved[t]
-            dlv, dmean = _cons_bwd_ext(
-                ext, m, l, dlv, dx_cat4, dx_cat4,
-                side=side, radius=radius, attend_self=attend_self,
-                interpret=interpret, cat=True,
-            )
-            ext2 = ext.reshape(L + 1, M, d)
-            dmean2 = dmean.reshape(L, M, d)
-            acc_cat, dx_cat, da = _ffw_bwd_cat(
-                wcat, ext2, pre_cat, dmean2, acc_cat, a2, da, L,
-                tile_m=bt, interpret=interpret, chain=chain,
-            )
-            dx_cat4 = dx_cat.reshape(G, B, n, d)
-            dtok = dtok + dx_cat4[split].astype(f32)
-        dx_bu = dx_cat4[split:]
-        dx_td = dx_cat4[:split]
-        acc_td = jax.tree_util.tree_map(lambda t: t[:split], acc_cat)
-        acc_bu = jax.tree_util.tree_map(lambda t: t[split:], acc_cat)
-    else:
-        for t in reversed(range(iters)):
-            if remat:
-                ext, m, l = saved[t]
-                ext2_r = ext.reshape(L + 1, M, d)
-                pre_bu = _pre_fwd_ext(
-                    bu_params, ext2_r, 0, L, tile_m=tile_fwd,
-                    interpret=interpret,
-                )
-                pre_td = _pre_fwd_ext(
-                    td_params, ext2_r, 2, L - 1, tile_m=tile_fwd,
-                    interpret=interpret, add=pos_emb,
-                )
-            else:
-                ext, pre_bu, pre_td, m, l = saved[t]
-            dlv, dmean = _cons_bwd_ext(
-                ext, m, l, dlv, dx_bu, dx_td,
-                side=side, radius=radius, attend_self=attend_self,
+    for t in reversed(range(iters)):
+        if remat:
+            ext, m, l = saved[t]
+            ext2_r = ext.reshape(L + 1, M, d)
+            pre_bu = _pre_fwd_ext(
+                bu_params, ext2_r, 0, L, tile_m=tile_fwd,
                 interpret=interpret,
             )
-            ext2 = ext.reshape(L + 1, M, d)
-            dmean2 = dmean.reshape(L, M, d)
-            acc_td, dx_td2, da = _ffw_bwd_ext(
-                td_params, ext2, 2, L - 1, pre_td, dmean2, acc_td,
-                tile_m=bt, interpret=interpret, add=pos_emb, da_in=da,
-                chain=chain,
+            pre_td = _pre_fwd_ext(
+                td_params, ext2_r, 2, L - 1, tile_m=tile_fwd,
+                interpret=interpret, add=pos_emb,
             )
-            acc_bu, dx_bu2, _ = _ffw_bwd_ext(
-                bu_params, ext2, 0, L, pre_bu, dmean2, acc_bu,
-                tile_m=bt, interpret=interpret, chain=chain,
-            )
-            dx_bu = dx_bu2.reshape(L, B, n, d)
-            dx_td = dx_td2.reshape(L - 1, B, n, d)
-            dtok = dtok + dx_bu[0].astype(f32)
+        else:
+            ext, pre_bu, pre_td, m, l = saved[t]
+        dlv, dmean = _cons_bwd_ext(
+            ext, m, l, dlv, dx_bu, dx_td,
+            side=side, radius=radius, attend_self=attend_self,
+            interpret=interpret,
+        )
+        ext2 = ext.reshape(L + 1, M, d)
+        dmean2 = dmean.reshape(L, M, d)
+        acc_td, dx_td2, da = _ffw_bwd_ext(
+            td_params, ext2, 2, L - 1, pre_td, dmean2, acc_td,
+            tile_m=bt, interpret=interpret, add=pos_emb, da_in=da,
+            chain=chain,
+        )
+        acc_bu, dx_bu2, _ = _ffw_bwd_ext(
+            bu_params, ext2, 0, L, pre_bu, dmean2, acc_bu,
+            tile_m=bt, interpret=interpret, chain=chain,
+        )
+        dx_bu = dx_bu2.reshape(L, B, n, d)
+        dx_td = dx_td2.reshape(L - 1, B, n, d)
+        dtok = dtok + dx_bu[0].astype(f32)
 
     # Final combine at the loop entry: d(levels0) gathers all three streams.
     # Written as slice-adds + one concatenate (NOT .at[].add, which lowers

@@ -269,8 +269,9 @@ def resolve_vjp_path(
 
 
 def _use_fused_loop(
-    params: GlomParams, cfg: GlomConfig, b: int, n: int, d: int,
-    iters: int, levels_in, return_all: bool, remat: bool,
+    bottom_up: GroupedFFWParams, pos: jnp.ndarray, levels_lm: jnp.ndarray,
+    cfg: GlomConfig, iters: int, stack: bool, remat: bool,
+    interpret: bool = False,
 ) -> bool:
     """Dispatch to the hand-rolled whole-loop VJP (kernels/fused_loop.py)
     on the flagship training regime: TPU, final-state-only, the
@@ -283,22 +284,152 @@ def _use_fused_loop(
 
     Thin shape-consistency gate over resolve_vjp_path (the single
     resolution source — the b<8 / return_all policy lives THERE): this
-    checks only what requires the actual params and tokens (dtype
-    agreement, pos-emb/config coherence)."""
-    if exists(levels_in) and levels_in.dtype != params.init_levels.dtype:
+    checks only what requires the actual arrays (dtype agreement,
+    pos-emb/config coherence, whole rows and whole FFW weights — a
+    sequence or tensor-parallel shard holds neither). interpret=True (the
+    CPU shard_map tests) bypasses only the platform check."""
+    _, b, n, d = levels_lm.shape
+    dtype = bottom_up.w1.dtype
+    if levels_lm.dtype != dtype:
         return False
-    if (n, d) != (cfg.num_patches, cfg.dim) or params.pos_emb.shape[0] != n:
+    if (n, d) != (cfg.num_patches, cfg.dim) or pos.shape[0] != n:
         return False
-    if params.bottom_up.w1.shape[-1] != d * cfg.mult:
+    if bottom_up.w1.shape[-1] != d * cfg.mult:
         return False
     return (
         resolve_vjp_path(
             cfg, b, iters, remat=remat, use_pallas=True,
-            itemsize=params.init_levels.dtype.itemsize,
-            return_all=return_all,
+            itemsize=dtype.itemsize, return_all=stack,
+            assume_on_tpu=interpret,
         )
         == "fused_loop"
     )
+
+
+def level_major_loop(
+    bottom_up: GroupedFFWParams,
+    top_down: GroupedFFWParams,
+    pos: jnp.ndarray,
+    tokens: jnp.ndarray,
+    levels_lm: jnp.ndarray,
+    cfg: GlomConfig,
+    *,
+    iters: int,
+    remat: bool,
+    unroll: bool = False,
+    stack: bool = False,
+    ffw_lm=None,
+    consensus_shard: Optional[ConsensusFn] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """THE level-major GLOM loop, on arrays local to the caller: `iters`
+    column updates of levels_lm [L, b, n, d] from tokens [b, n, d] and
+    pos [n, d]. Returns the final carry, or with stack=True all T+1 states
+    [T+1, L, b, n, d] including the initial one. Two callers: the
+    single-device forward below (whole rows, whole weights) and
+    parallel/manual.py's shard body (its band of rows, its share of the
+    FFW hidden axis), which differ in two arguments only they can know:
+
+      ffw_lm           the level-major grouped FFW, (params, x [G, M, d],
+                       add=[n, d] | None) -> [G, M, d]. None: the Pallas
+                       kernel, which manual passes too unless it wraps it
+                       for tensor parallelism (a psum over 'model') or
+                       runs the XLA form.
+      consensus_shard  [b, n, L, d] -> same, for rows that span devices
+                       (ring / halo / Ulysses) or the dense XLA op. None:
+                       consensus and the 4-way mean run fused in the
+                       Pallas consensus kernel.
+
+    With the plain Pallas FFW and no consensus_shard the loop is whole on
+    this device, and where _use_fused_loop admits the shape it runs as the
+    hand-rolled whole-loop VJP: the ONE dispatch site of fused_glom_loop."""
+    from glom_tpu.kernels import fused_consensus_update
+    from glom_tpu.kernels.grouped_mlp import fused_grouped_ffw_lm
+
+    L, b, n, d = levels_lm.shape
+    side, radius = cfg.num_patches_side, float(cfg.local_consensus_radius)
+    ffw_lm = default(ffw_lm, fused_grouped_ffw_lm)
+
+    if (
+        ffw_lm is fused_grouped_ffw_lm
+        and consensus_shard is None
+        and _use_fused_loop(
+            bottom_up, pos, levels_lm, cfg, iters, stack, remat, interpret
+        )
+    ):
+        from glom_tpu.kernels.fused_loop import fused_glom_loop
+
+        # One scope for the whole-loop VJP: its kernels (`loop_*`) run
+        # bottom-up, top-down and consensus of every iteration, and the
+        # glue XLA leaves around them belongs to no single one of those.
+        with jax.named_scope("loop"):
+            return fused_glom_loop(
+                bottom_up, top_down, pos, tokens, levels_lm, iters, side,
+                radius, cfg.consensus_self, interpret, remat,
+            )
+
+    tokens_lm = tokens[None]  # [1, b, n, d]
+    if exists(consensus_shard):
+        divisor_lm = contribution_divisor(L, jnp.float32).reshape(L, 1, 1, 1)
+
+    def body(carry, _):
+        lv = carry
+        # Bottom-up input: (image tokens, levels 1..L-1) — level 1 re-reads
+        # the RAW tokens every iteration (reference :127).
+        with jax.named_scope("bottom_up"):
+            bu_in = jnp.concatenate([tokens_lm, lv[:-1]], axis=0)
+            bu_out = ffw_lm(
+                bottom_up, bu_in.reshape(L, b * n, d)
+            ).reshape(L, b, n, d)
+        # Top-down input: levels 2..L with pos-emb injected HERE only
+        # (reference :129); the top level's zero pad + the 4-vs-3 divisor
+        # live in the consensus kernel's epilogue. The pos addend folds
+        # into the kernel's tile loads (add=) — the [L-1, b, n, d] sum
+        # never materializes on the fused path.
+        with jax.named_scope("top_down"):
+            td_out = ffw_lm(
+                top_down,
+                lv[1:].reshape(L - 1, b * n, d),
+                add=pos,
+            ).reshape(L - 1, b, n, d)
+        if not exists(consensus_shard):
+            with jax.named_scope("consensus_update"):
+                new = fused_consensus_update(
+                    lv, bu_out, td_out,
+                    side=side,
+                    radius=radius,
+                    attend_self=cfg.consensus_self,
+                )
+        else:
+            with jax.named_scope("consensus"):
+                cons = consensus_shard(jnp.transpose(lv, (1, 2, 0, 3)))
+                cons_lm = jnp.transpose(cons, (2, 0, 1, 3))
+            with jax.named_scope("mean_update"):
+                td_full = jnp.concatenate(
+                    [td_out, jnp.zeros_like(td_out[:1])], axis=0
+                )
+                new = (
+                    (
+                        lv.astype(jnp.float32)
+                        + bu_out.astype(jnp.float32)
+                        + td_full.astype(jnp.float32)
+                        + cons_lm.astype(jnp.float32)
+                    )
+                    / divisor_lm
+                ).astype(lv.dtype)
+        return new, (new if stack else None)
+
+    if remat:
+        body = jax.checkpoint(body)
+
+    with jax.named_scope("loop"):
+        final, stacked = jax.lax.scan(
+            body, levels_lm, None, length=iters, unroll=unroll
+        )
+
+    if stack:
+        return jnp.concatenate([levels_lm[None], stacked], axis=0)
+    return final
 
 
 def _glom_forward_fused(
@@ -321,14 +452,9 @@ def _glom_forward_fused(
     and the whole 4-way mean update runs inside the consensus kernel's
     epilogue instead of as separate XLA HBM sweeps.
     """
-    from glom_tpu.kernels import fused_consensus_update
-    from glom_tpu.kernels.grouped_mlp import fused_grouped_ffw_lm
-
     with jax.named_scope("image_to_tokens"):
         tokens = image_to_tokens(params.token_embed, img, cfg.patch_size)
     b, n, d = tokens.shape
-    L = cfg.levels
-    tokens_lm = tokens[None]  # [1, b, n, d]
 
     if exists(levels_in):
         # Keep the caller's carry dtype (the reference path's scan carry is
@@ -337,62 +463,13 @@ def _glom_forward_fused(
         levels_lm = jnp.transpose(levels_in, (2, 0, 1, 3))
     else:
         levels_lm = jnp.broadcast_to(
-            params.init_levels[:, None, None], (L, b, n, d)
+            params.init_levels[:, None, None], (cfg.levels, b, n, d)
         ).astype(tokens.dtype)
 
-    if _use_fused_loop(params, cfg, b, n, d, iters, levels_in, return_all, remat):
-        from glom_tpu.kernels.fused_loop import fused_glom_loop
-
-        # One scope for the whole-loop VJP: its kernels (`loop_*`) run
-        # bottom-up, top-down and consensus of every iteration, and the
-        # glue XLA leaves around them belongs to no single one of those.
-        with jax.named_scope("loop"):
-            final = fused_glom_loop(
-                params.bottom_up, params.top_down, params.pos_emb, tokens,
-                levels_lm, iters, cfg.num_patches_side,
-                float(cfg.local_consensus_radius), cfg.consensus_self, False,
-                remat,
-            )
-            return jnp.transpose(final, (1, 2, 0, 3))  # [b, n, L, d]
-
-    def body(carry, _):
-        lv = carry
-        # Bottom-up input: (image tokens, levels 1..L-1) — level 1 re-reads
-        # the RAW tokens every iteration (reference :127).
-        with jax.named_scope("bottom_up"):
-            bu_in = jnp.concatenate([tokens_lm, lv[:-1]], axis=0)
-            bu_out = fused_grouped_ffw_lm(
-                params.bottom_up, bu_in.reshape(L, b * n, d)
-            ).reshape(L, b, n, d)
-        # Top-down input: levels 2..L with pos-emb injected HERE only
-        # (reference :129); the top level's zero pad + the 4-vs-3 divisor
-        # live in the consensus kernel's epilogue. The pos addend folds
-        # into the kernel's tile loads (add=) — the [L-1, b, n, d] sum
-        # never materializes on the fused path.
-        with jax.named_scope("top_down"):
-            td_out = fused_grouped_ffw_lm(
-                params.top_down,
-                lv[1:].reshape(L - 1, b * n, d),
-                add=params.pos_emb,
-            ).reshape(L - 1, b, n, d)
-        with jax.named_scope("consensus_update"):
-            new = fused_consensus_update(
-                lv, bu_out, td_out,
-                side=cfg.num_patches_side,
-                radius=float(cfg.local_consensus_radius),
-                attend_self=cfg.consensus_self,
-            )
-        return new, (new if return_all else None)
-
-    if remat:
-        body = jax.checkpoint(body)
-
-    with jax.named_scope("loop"):
-        final, stacked = jax.lax.scan(
-            body, levels_lm, None, length=iters, unroll=unroll
-        )
-
+    out = level_major_loop(
+        params.bottom_up, params.top_down, params.pos_emb, tokens, levels_lm,
+        cfg, iters=iters, remat=remat, unroll=unroll, stack=return_all,
+    )
     if return_all:
-        all_lm = jnp.concatenate([levels_lm[None], stacked], axis=0)
-        return jnp.transpose(all_lm, (0, 2, 3, 1, 4))  # [T+1, b, n, L, d]
-    return jnp.transpose(final, (1, 2, 0, 3))  # [b, n, L, d]
+        return jnp.transpose(out, (0, 2, 3, 1, 4))  # [T+1, b, n, L, d]
+    return jnp.transpose(out, (1, 2, 0, 3))  # [b, n, L, d]

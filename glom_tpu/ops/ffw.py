@@ -83,11 +83,17 @@ def grouped_ffw(
     return out.astype(x.dtype)
 
 
-def grouped_ffw_lm(params: GroupedFFWParams, x: jnp.ndarray) -> jnp.ndarray:
+def grouped_ffw_lm(
+    params: GroupedFFWParams, x: jnp.ndarray, *, add: jnp.ndarray | None = None
+) -> jnp.ndarray:
     """Level-major form: x [G, M, d] -> [G, M, d]. Same math as grouped_ffw
     (group axis leading instead of next-to-last) — the layout the fused
-    kernel and the level-major scan carry use natively."""
+    kernel and the level-major scan carry use natively. add: optional
+    [n, d] positional addend with M = b*n (n inner), summed into x first
+    (the signature kernels.fused_grouped_ffw_lm folds into its loads)."""
     w1, b1, w2, b2 = params
+    if add is not None:
+        x = x + jnp.tile(add, (x.shape[1] // add.shape[0], 1))[None]
     acc = jnp.float32
     h = jnp.einsum("gmd,gdf->gmf", x, w1, preferred_element_type=acc)
     h = jax.nn.gelu(h + b1[:, None, :], approximate=False).astype(x.dtype)

@@ -37,10 +37,11 @@ work. Collectives are explicit and minimal:
            cotangents stay local, replicated-param cotangents come out
            unscaled. No custom_vjp link functions needed.
 
-Reference parity: the per-shard scan body is the same §3.2 contract as
-models/core.py (same kernels, same 4-vs-3 divisor, same pos-emb placement);
-parity is locked by tests/test_manual.py against the single-device dense
-forward.
+Reference parity: the per-shard loop IS models/core.level_major_loop (the
+body single-device jobs run, and its one dispatch to the whole-loop VJP);
+this module slices the shard, builds the TP FFW wrapper and picks the
+per-shard consensus. Parity is locked by tests/test_manual.py against the
+single-device dense and fused forwards.
 """
 
 from __future__ import annotations
@@ -54,7 +55,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from glom_tpu.models.core import contribution_divisor
+from glom_tpu.models.core import level_major_loop
 from glom_tpu.ops.patch import image_to_tokens, patchify
 from glom_tpu.parallel.halo import halo_consensus_shard
 from glom_tpu.parallel.ring import ring_consensus_shard
@@ -118,28 +119,6 @@ def shard_consensus_fn(cfg: GlomConfig, seq: int, sp_strategy: str):
     )
 
 
-def _use_loop_vjp(
-    cfg: GlomConfig, b_loc: int, iters: int, remat: bool, dtype, interpret: bool
-) -> bool:
-    """Should this seq=1/mp=1 shard body dispatch to the whole-loop VJP
-    (kernels/fused_loop.py) instead of scanning the per-op kernels? This
-    is resolve_vjp_path — THE resolution source — at the SHARD-LOCAL
-    batch: a DP run must get the same glue-free backward the single-chip
-    flagship gets.
-    interpret=True (CPU shard_map tests) bypasses only the platform
-    check; the policy itself is never duplicated here."""
-    from glom_tpu.models.core import resolve_vjp_path
-
-    return (
-        resolve_vjp_path(
-            cfg, b_loc, iters,
-            remat=remat, use_pallas=True, itemsize=dtype.itemsize,
-            assume_on_tpu=interpret,
-        )
-        == "fused_loop"
-    )
-
-
 def _forward_local(
     glom_params,
     noised: jnp.ndarray,
@@ -156,19 +135,24 @@ def _forward_local(
     return_mode: str = "top",
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Per-shard forward: local batch, local patch band, local FFW hidden
-    shard (level-major carry, Pallas FFWs; fused consensus+update kernel
-    when seq == 1, and the WHOLE-LOOP VJP when the shard-local shape
-    admits it — see _use_loop_vjp). levels0_lm optionally carries in a
-    [L, b_loc, n_loc, d] initial state (the temporal API). return_mode:
+    """Per-shard forward: slice this shard's patch band and hand the local
+    batch, band and FFW hidden shard to models/core.level_major_loop — the
+    one loop body, and the one dispatch to the WHOLE-LOOP VJP (taken when
+    seq == 1, mp == 1 and the shard-local shape admits it). levels0_lm
+    optionally carries in a [L, b_loc, n_loc, d] initial state (the
+    temporal API). return_mode:
       'top'   — final top level [b_loc, n_loc, d] (the training loss path);
       'final' — full final carry [L, b_loc, n_loc, d];
       'all'   — all T+1 states [T+1, L, b_loc, n_loc, d] incl. the initial
                 (reference return_all contract, T+1 states)."""
-    from glom_tpu.kernels import fused_consensus_update
     from glom_tpu.kernels.grouped_mlp import fused_grouped_ffw_lm
     from glom_tpu.ops.ffw import grouped_ffw_lm
 
+    if consensus_shard is None and not use_pallas:
+        raise ValueError(
+            "seq=1 without use_pallas has no per-shard consensus body; pass "
+            "one (make_manual_loss builds the dense composition for this case)"
+        )
     ffw_lm = fused_grouped_ffw_lm if use_pallas else grouped_ffw_lm
     if mp > 1:
         # Megatron TP: this rank's weights cover f/mp hidden units; the
@@ -179,11 +163,11 @@ def _forward_local(
         # against dense-reference grads (tests/test_manual.py).
         inner_ffw, inv_mp = ffw_lm, 1.0 / mp
 
-        def ffw_lm(p, x):
+        def ffw_lm(p, x, add=None):
             p = p._replace(b2=p.b2 * jnp.asarray(inv_mp, p.b2.dtype))
-            out = inner_ffw(p, x)
+            out = inner_ffw(p, x, add=add)
             # This is a WIRE-MOVING collective (full FFW activations over
-            # 'model', every scan iteration — the scans below run under
+            # 'model', every scan iteration — the loop below runs under
             # scaled(iters) so the trace-time record prices every
             # execution), found unregistered by glom-lint's
             # collective-coverage pass: the drift reconciliation could
@@ -206,14 +190,9 @@ def _forward_local(
                 tele_counters.ring_allreduce_bytes(out, mp),
                 lambda o: lax.psum(o, MODEL_AXIS), out, collective="psum",
             )
-    if consensus_shard is None and not use_pallas:
-        raise ValueError(
-            "seq=1 without use_pallas has no per-shard consensus body; pass "
-            "one (make_manual_loss builds the dense composition for this case)"
-        )
 
     L, d = cfg.levels, cfg.dim
-    n, n_loc = cfg.num_patches, cfg.num_patches // seq
+    n_loc = cfg.num_patches // seq
 
     # Patchify the full image, then slice this shard's patch band. The patch
     # grid is row-major, so a contiguous n-band is a contiguous row band —
@@ -233,8 +212,6 @@ def _forward_local(
     )
 
     b_loc = tokens_loc.shape[0]
-    tokens_lm = tokens_loc[None]  # [1, b_loc, n_loc, d]
-    pos_lm = pos_loc[None, None]  # [1, 1, n_loc, d]
     if levels0_lm is not None:
         levels_lm = levels0_lm.astype(tokens_loc.dtype)
     else:
@@ -242,7 +219,7 @@ def _forward_local(
             glom_params.init_levels[:, None, None], (L, b_loc, n_loc, d)
         ).astype(tokens_loc.dtype)
         # The initial carry is device-invariant (broadcast replicated
-        # params) but the scan body's output varies over both mesh axes (it
+        # params) but the loop body's output varies over both mesh axes (it
         # consumes the local tokens); align the vma types up front (see
         # ring.py). Under check_vma=False the vma set is empty and pcast
         # must not run. (A carried-in levels0 is already sharded input —
@@ -250,91 +227,19 @@ def _forward_local(
         vma = tuple(jax.typeof(tokens_loc).vma)
         if vma:
             levels_lm = lax.pcast(levels_lm, vma, to="varying")
-    divisor_lm = contribution_divisor(L, jnp.float32).reshape(L, 1, 1, 1)
 
-    # seq=1 / mp=1 shards with an admissible local shape take the
-    # hand-rolled whole-loop VJP — the same backward the single-chip
-    # flagship trains on (slot carry, chained/unchained accumulators,
-    # in-register cotangent combine) instead of the scan-autodiff path.
-    # Composes with the data-axis shard_map transpose exactly like the
-    # per-op custom_vjps: the loop emits per-shard cotangents; the params
-    # psum comes from the shard_map transpose of the replicated in_spec.
-    if (
-        consensus_shard is None
-        and mp == 1
-        and use_pallas
-        and return_mode in ("top", "final")
-        and _use_loop_vjp(cfg, b_loc, iters, remat, tokens_loc.dtype, interpret)
-    ):
-        from glom_tpu.kernels.fused_loop import fused_glom_loop
-
-        with jax.named_scope("loop"):
-            final = fused_glom_loop(
-                glom_params.bottom_up, glom_params.top_down, pos_loc,
-                tokens_loc, levels_lm, iters, cfg.num_patches_side,
-                float(cfg.local_consensus_radius), cfg.consensus_self,
-                interpret, remat,
-            )
-            return final if return_mode == "final" else final[-1]
-
-    def body(carry, _):
-        lv = carry
-        with jax.named_scope("bottom_up"):
-            bu_in = jnp.concatenate([tokens_lm, lv[:-1]], axis=0)
-            bu = ffw_lm(
-                glom_params.bottom_up, bu_in.reshape(L, b_loc * n_loc, d)
-            ).reshape(L, b_loc, n_loc, d)
-        with jax.named_scope("top_down"):
-            td = ffw_lm(
-                glom_params.top_down,
-                (lv[1:] + pos_lm).reshape(L - 1, b_loc * n_loc, d),
-            ).reshape(L - 1, b_loc, n_loc, d)
-        if consensus_shard is None:
-            with jax.named_scope("consensus_update"):
-                new = fused_consensus_update(
-                    lv, bu, td,
-                    side=cfg.num_patches_side,
-                    radius=float(cfg.local_consensus_radius),
-                    attend_self=cfg.consensus_self,
-                )
-        else:
-            with jax.named_scope("consensus"):
-                cons = consensus_shard(jnp.transpose(lv, (1, 2, 0, 3)))
-                cons_lm = jnp.transpose(cons, (2, 0, 1, 3))
-            with jax.named_scope("mean_update"):
-                td_full = jnp.concatenate([td, jnp.zeros_like(td[:1])], axis=0)
-                new = (
-                    (
-                        lv.astype(jnp.float32)
-                        + bu.astype(jnp.float32)
-                        + td_full.astype(jnp.float32)
-                        + cons_lm.astype(jnp.float32)
-                    )
-                    / divisor_lm
-                ).astype(lv.dtype)
-        return new, None
-
-    if return_mode == "all":
-        def body_ys(carry, _):
-            new, _ = body(carry, _)
-            return new, new
-        if remat:
-            body_ys = jax.checkpoint(body_ys)
-        # scaled(iters): the body traces ONCE here but executes per scan
-        # iteration — collective sites inside it (the TP psum) must price
-        # every execution (same convention as the stage-2 microbatch hook).
-        with tele_counters.scaled(iters), jax.named_scope("loop"):
-            final, ys = lax.scan(
-                body_ys, levels_lm, None, length=iters, unroll=unroll
-            )
-        return jnp.concatenate([levels_lm[None], ys], axis=0)  # [T+1, L, ...]
-    if remat:
-        body = jax.checkpoint(body)
-    with tele_counters.scaled(iters), jax.named_scope("loop"):
-        final, _ = lax.scan(body, levels_lm, None, length=iters, unroll=unroll)
-    if return_mode == "final":
-        return final  # [L, b_loc, n_loc, d]
-    return final[-1]  # top level, [b_loc, n_loc, d]
+    # scaled(iters): the loop body traces ONCE but executes per iteration —
+    # collective sites inside it (the TP psum) must price every execution
+    # (same convention as the stage-2 microbatch hook).
+    with tele_counters.scaled(iters):
+        out = level_major_loop(
+            glom_params.bottom_up, glom_params.top_down, pos_loc, tokens_loc,
+            levels_lm, cfg, iters=iters, remat=remat, unroll=unroll,
+            stack=return_mode == "all", ffw_lm=ffw_lm,
+            consensus_shard=consensus_shard, interpret=interpret,
+        )
+    # 'all': [T+1, L, ...]; 'final': [L, b_loc, n_loc, d]; 'top': its last
+    return out[-1] if return_mode == "top" else out
 
 
 def _build_local_loss(

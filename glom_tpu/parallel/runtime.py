@@ -349,9 +349,10 @@ class DistributedTrainer:
 
             k, itemsize = resolve_route_keys(cfg, tcfg)
             # seq=1/mp=1 manual shards dispatch to the whole-loop VJP at
-            # the shard-local batch when admissible (manual._use_loop_vjp
-            # makes the same resolve_vjp_path call) — the label must
-            # follow the dispatch; TP shards (mp>1) stay scan-only.
+            # the shard-local batch when admissible (the one dispatch,
+            # models/core.level_major_loop, makes the same
+            # resolve_vjp_path call) — the label must follow the
+            # dispatch; TP shards (mp>1) stay scan-only.
             self.vjp_path = resolve_vjp_path(
                 cfg,
                 tcfg.batch_size // accum_base // mesh_cfg.data,

@@ -36,8 +36,8 @@ step probe 120 python -c "import jax; print(jax.devices())" || true
 grep -q "TpuDevice\|tpu" results/hw_queue/probe.log || {
     log "backend still down; aborting queue"; exit 1; }
 
-# 1. Hardware parity first (17 checks incl. the fused-loop
-#    primal-vs-VJP, remat-grad, combined-grid and banded checks) — the
+# 1. Hardware parity first (16 checks incl. the fused-loop
+#    primal-vs-VJP, remat-grad and banded checks) — the
 #    measurement steps below are meaningless if these fail, so a parity
 #    failure STOPS the queue here.
 step tpu_validate 2400 python -u tpu_validate.py || {

@@ -893,53 +893,6 @@ class TestFusedLoop:
         bt_f = _pick_bwd_tile(64 * 256, 512, 2048, 2)
         assert _chain_ws_ok(bt_f, 512, 2048, 2, 256)
 
-    # Same grid-relayout check on the local mask — slow-marked for the
-    # tier-1 budget; CI runs it.
-    @pytest.mark.parametrize(
-        "radius", [0.0, pytest.param(1.5, marks=pytest.mark.slow)]
-    )
-    def test_combined_grid_matches_split(self, monkeypatch, radius):
-        """GLOM_LOOP_GRID=combined (one 2L-1-group pallas_call per phase
-        per iteration instead of separate bu/td calls) is a pure grid
-        relayout: same per-group math, same accumulation order — loss and
-        every cotangent must match the split default to float-exactness,
-        in both the saved-pre and remat modes."""
-        from glom_tpu.kernels import fused_loop
-
-        args = self._inputs()
-
-        def loss(remat):
-            def f(*a):
-                return jnp.mean(
-                    fused_loop.fused_glom_loop(
-                        *a, 3, self.side, radius, False, True, remat
-                    )
-                    ** 2
-                )
-
-            return f
-
-        vg = lambda remat: jax.value_and_grad(
-            loss(remat), argnums=tuple(range(5))
-        )(*args)
-        # pin the baseline: an exported GLOM_LOOP_GRID=combined in the
-        # developer's shell must not turn this into a self-comparison
-        monkeypatch.setenv("GLOM_LOOP_GRID", "split")
-        l_split, g_split = vg(False)
-        monkeypatch.setenv("GLOM_LOOP_GRID", "combined")
-        l_comb, g_comb = vg(False)
-        l_comb_r, g_comb_r = vg(True)
-        np.testing.assert_allclose(float(l_split), float(l_comb), rtol=1e-6)
-        np.testing.assert_allclose(float(l_split), float(l_comb_r), rtol=1e-6)
-        for want in (g_comb, g_comb_r):
-            for a, b in zip(
-                jax.tree_util.tree_leaves(g_split),
-                jax.tree_util.tree_leaves(want),
-            ):
-                np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
-                )
-
     def test_remat_admits_bigger_residuals(self):
         """The remat residual stack (carry + stats only) fits shapes the
         full stack cannot: flagship batch 128 x 12 iters is 20.6GB of
@@ -949,3 +902,25 @@ class TestFusedLoop:
 
         assert not loop_supported(6, 128, 256, 512, 2048, 2, 12, 256)
         assert loop_supported(6, 128, 256, 512, 2048, 2, 12, 256, remat=True)
+
+
+@pytest.mark.parametrize("package", ["kernels", "models", "train"])
+def test_no_program_module_reads_the_environment(package):
+    """What a step computes is decided by its arguments and shapes alone:
+    the last GLOM_* switch (it chose a second grid layout of the
+    whole-loop VJP) went in PR 29 as the consensus one did in PR 26.
+    Deployment settings (mesh.py, startup.py) live in other packages."""
+    import ast
+    import pathlib
+
+    root = pathlib.Path(__file__).resolve().parent.parent / "glom_tpu" / package
+    readers = [
+        f"{path.relative_to(root.parent)}:{node.lineno}"
+        for path in sorted(root.rglob("*.py"))
+        for node in ast.walk(ast.parse(path.read_text()))
+        if (isinstance(node, ast.Attribute) and node.attr in ("environ", "getenv")
+            and isinstance(node.value, ast.Name) and node.value.id == "os")
+        or (isinstance(node, ast.ImportFrom) and node.module == "os"
+            and any(a.name in ("environ", "getenv") for a in node.names))
+    ]
+    assert readers == []

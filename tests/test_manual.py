@@ -6,7 +6,9 @@ its gradients equal the single-device dense composition (denoise_loss) to
 float tolerance — DP x SP is a physical layout change, not a math change.
 """
 
+import ast
 import dataclasses
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -282,14 +284,30 @@ class TestShardFusedLoop:
         return img, noise
 
     def test_gate_engages_at_shard_shape(self):
-        from glom_tpu.parallel.manual import _use_loop_vjp
+        """models/core._use_fused_loop is the one gate, at the SHARD-LOCAL
+        shapes manual hands it (interpret=True stands in for the chip)."""
+        from glom_tpu.models.core import _use_fused_loop
 
-        assert _use_loop_vjp(
-            self.LCFG, 8, 2, False, jnp.dtype(jnp.float32), True
-        )
+        glom = init_denoise(jax.random.PRNGKey(3), self.LCFG).glom
+
+        def gate(b, n=16, ffw=glom.bottom_up):
+            levels = jax.ShapeDtypeStruct((4, b, n, 128), jnp.float32)
+            return _use_fused_loop(
+                ffw, glom.pos_emb[:n], levels, self.LCFG, 2, False, False, True
+            )
+
+        assert gate(8)
         # sub-batched shards stay on the scan path
-        assert not _use_loop_vjp(
-            self.LCFG, 2, 2, False, jnp.dtype(jnp.float32), True
+        assert not gate(2)
+        # a sequence-parallel shard holds a band of the rows, a
+        # tensor-parallel one a share of the hidden axis: neither is whole
+        assert not gate(8, n=8)
+        half = jax.tree_util.tree_map(lambda t: t[..., :256], glom.bottom_up)
+        assert not gate(8, ffw=half._replace(w2=glom.bottom_up.w2[:, :256]))
+        # and off the chip nothing dispatches without interpret
+        levels = jax.ShapeDtypeStruct((4, 8, 16, 128), jnp.float32)
+        assert not _use_fused_loop(
+            glom.bottom_up, glom.pos_emb, levels, self.LCFG, 2, False, False
         )
 
     # The heaviest single test in the suite (interpret-mode whole-loop VJP
@@ -342,3 +360,73 @@ class TestShardFusedLoop:
             sp_strategy="none",
         )
         assert tr_tp.vjp_path.startswith("scan_")
+
+
+# ------------------------------------------------- one loop, written once
+
+_MANUAL_SRC = (
+    pathlib.Path(__file__).resolve().parent.parent
+    / "glom_tpu" / "parallel" / "manual.py"
+)
+
+
+@pytest.mark.parametrize(
+    "name", ["scan", "checkpoint", "fused_glom_loop", "resolve_vjp_path"]
+)
+def test_manual_holds_no_loop_of_its_own(name):
+    """The level-major loop body, its jax.checkpoint, its lax.scan and its
+    dispatch to the whole-loop VJP live in models/core.level_major_loop;
+    parallel/manual.py slices the shard and calls it. A second copy here is
+    what let an optimisation land on one body and miss the other."""
+    used = set()
+    for node in ast.walk(ast.parse(_MANUAL_SRC.read_text())):
+        if isinstance(node, ast.Attribute):
+            used.add(node.attr)
+        elif isinstance(node, ast.Name):
+            used.add(node.id)
+        elif isinstance(node, ast.ImportFrom):
+            used.update(a.name for a in node.names)
+    assert name not in used
+
+
+@pytest.mark.parametrize("return_mode", ["top", "final", "all"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_shard_forward_is_the_single_chip_forward(dtype, return_mode):
+    """On a data=2 mesh at seq=1, mp=1 a shard's forward IS
+    glom_forward(use_pallas=True) on that shard's rows: one function runs
+    in both, so the results are equal to the last bit (this backend's
+    matrix product gives a row the same sum whatever the row count, so the
+    reference runs the whole batch at once)."""
+    from jax.sharding import PartitionSpec as P
+
+    from glom_tpu.models.core import glom_forward
+    from glom_tpu.parallel.manual import _forward_local
+
+    T = 3
+    mesh = make_mesh(MeshConfig(data=2), jax.devices()[:2])
+    glom = jax.tree_util.tree_map(
+        lambda t: t.astype(dtype), init_denoise(jax.random.PRNGKey(4), CFG).glom
+    )
+    img = _data(4)[0].astype(dtype)
+    lead = {"top": (), "final": (None,), "all": (None, None)}[return_mode]
+    manual = jax.jit(jax.shard_map(
+        lambda p, x: _forward_local(
+            p, x, CFG, iters=T, seq=1, mp=1, consensus_shard=None,
+            remat=False, use_pallas=True, return_mode=return_mode,
+        ),
+        mesh=mesh, in_specs=(P(), P("data")), out_specs=P(*lead, "data"),
+        check_vma=False,
+    ))
+    got = np.asarray(manual(glom, img).astype(jnp.float32))
+
+    single = jax.jit(lambda p, x: glom_forward(
+        p, x, CFG, iters=T, use_pallas=True, return_all=return_mode == "all"
+    ))
+    ref = np.asarray(single(glom, img).astype(jnp.float32))  # [.., b, n, L, d]
+    want = {
+        "top": lambda r: r[:, :, -1],                      # [b, n, d]
+        "final": lambda r: r.transpose(2, 0, 1, 3),        # [L, b, n, d]
+        "all": lambda r: r.transpose(0, 3, 1, 2, 4),       # [T+1, L, b, n, d]
+    }[return_mode](ref)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(got, want)

@@ -19,7 +19,7 @@ from glom_tpu.tracing.spans import DEVICE_PHASES, HOST_PHASES, PHASES
 from glom_tpu.utils.config import GlomConfig, TrainConfig
 
 KERNELS = pathlib.Path(__file__).resolve().parent.parent / "glom_tpu" / "kernels"
-N_PALLAS_CALLS = 25
+N_PALLAS_CALLS = 21
 
 
 def _pallas_call_names():

@@ -331,57 +331,6 @@ def check_fused_loop_remat_grads():
         )
 
 
-@check("fused_loop_combined_grid_parity")
-def check_fused_loop_combined_grid():
-    """GLOM_LOOP_GRID=combined on real Mosaic: the 2L-1-group cat grids
-    (jnp.where in BlockSpec index maps — first use on hardware) must
-    reproduce the split default's loss and cotangents: the correctness
-    gate before any promotion."""
-    import os
-
-    from glom_tpu.kernels.fused_loop import fused_glom_loop
-
-    args = _fused_loop_args(2)
-
-    def loss(*a):
-        return jnp.mean(
-            fused_glom_loop(*a, 3, 16, 0.0, False, False).astype(jnp.float32)
-            ** 2
-        )
-
-    prior = os.environ.get("GLOM_LOOP_GRID")
-    try:
-        # BOTH arms pinned explicitly (fresh jits: the knob is read at
-        # trace time). Inheriting the env for the baseline would make the
-        # check a vacuous combined-vs-combined self-comparison whenever an
-        # operator exports GLOM_LOOP_GRID=combined for the whole run.
-        os.environ["GLOM_LOOP_GRID"] = "split"
-        l_split, g_split = jax.jit(
-            jax.value_and_grad(loss, argnums=tuple(range(5)))
-        )(*args)
-        os.environ["GLOM_LOOP_GRID"] = "combined"
-        l_comb, g_comb = jax.jit(
-            jax.value_and_grad(loss, argnums=tuple(range(5)))
-        )(*args)
-    finally:
-        # restore, don't pop: an operator-set GLOM_LOOP_GRID must still
-        # govern the remaining checks in this run
-        if prior is None:
-            os.environ.pop("GLOM_LOOP_GRID", None)
-        else:
-            os.environ["GLOM_LOOP_GRID"] = prior
-    np.testing.assert_allclose(
-        float(l_split), float(l_comb), rtol=1e-5
-    )
-    for a, b in zip(
-        jax.tree_util.tree_leaves(g_split), jax.tree_util.tree_leaves(g_comb)
-    ):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            rtol=1e-3, atol=1e-5,
-        )
-
-
 @check("banded_ragged_consensus_parity")
 def check_banded_consensus():
     """The streaming banded kernel (kernels/banded_consensus.py, reached
@@ -518,7 +467,6 @@ def main():
         check_fused_loop_grads,
         check_fused_loop_primal_vs_vjp_forward,
         check_fused_loop_remat_grads,
-        check_fused_loop_combined_grid,
         check_banded_consensus,
         check_tp_composition,
         check_train, check_train_cross_path,
