@@ -67,6 +67,7 @@ on (n, radius); see _use_blockwise_bwd for the table.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -79,23 +80,44 @@ from glom_tpu.utils.helpers import TOKEN_ATTEND_SELF_VALUE
 _NEG_MAX = float(jnp.finfo(jnp.float32).min)
 
 def _row_col(idx, side):
-    """Patch-grid (row, col) coordinates of flat patch indices."""
-    return idx // side, idx % side
+    """Patch-grid (row, col) coordinates of flat patch indices (int32, never
+    negative). Divided as uint32: Python's floor division carries its sign
+    corrections into the kernel (three selects a vreg), the unsigned one
+    does not, and by a power of two it compiles to a shift and a mask."""
+    u, sd = idx.astype(jnp.uint32), jnp.uint32(side)
+    return (
+        jax.lax.div(u, sd).astype(jnp.int32),
+        jax.lax.rem(u, sd).astype(jnp.int32),
+    )
 
 
-def _apply_masks(s, row_ids, col_ids, *, side, radius, attend_self):
+def _tile_ids(origin, extent, axis):
+    """Flat patch indices origin .. origin + extent - 1 laid along `axis` of
+    a score tile's trailing two dims: an int32 [extent, 1] (axis 0) or
+    [1, extent] (axis 1) vector, never a whole tile."""
+    shape = (extent, 1) if axis == 0 else (1, extent)
+    return origin + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _apply_masks(s, ids_a, ids_b, *, side, radius, attend_self):
     """The dual mask semantics shared by EVERY kernel (reference :9/:61-67):
     diagonal REPLACED by the soft -5e-4 when attend_self=False; pairs past
-    the Euclidean grid radius hard-masked to -3e38. row_ids/col_ids are
-    QUERY-/KEY-index iotas shaped like s's trailing two dims."""
+    the Euclidean grid radius hard-masked to -3e38. ids_a [A, 1] / ids_b
+    [1, B] are the flat patch indices (_tile_ids) along s's trailing two
+    dims; both masks are symmetric in the pair, so a transposed score tile
+    passes its key indices as ids_a. The grid coordinates are formed on
+    those two vectors (A + B divisions, not A * B); only the differences,
+    squares, sum, comparison and select run on the score tile."""
     if not attend_self:
-        s = jnp.where((row_ids == col_ids)[None], TOKEN_ATTEND_SELF_VALUE, s)
+        s = jnp.where((ids_a == ids_b)[None], TOKEN_ATTEND_SELF_VALUE, s)
     if radius > 0:
-        ri, ci = _row_col(row_ids, side)
-        rj, cj = _row_col(col_ids, side)
-        dist2 = (ri - rj) ** 2 + (ci - cj) ** 2
+        ra, ca = _row_col(ids_a, side)
+        rb, cb = _row_col(ids_b, side)
+        dist2 = (ra - rb) ** 2 + (ca - cb) ** 2
+        # dist2 is an integer, so `dist2 > floor(r^2)` is the reference's
+        # `dist2 > r^2` without a tile-wide convert
         s = jnp.where(
-            (dist2.astype(jnp.float32) > radius * radius)[None], _NEG_MAX, s
+            (dist2 > math.floor(radius * radius))[None], _NEG_MAX, s
         )
     return s
 
@@ -142,7 +164,7 @@ def _consensus_update_kernel(
     x = x_ref[0]  # [TB, TI, d]
     q32 = x.astype(jnp.float32)
 
-    row_ids = i * tile_i + jax.lax.broadcasted_iota(jnp.int32, (tile_i, tile_j), 0)
+    row_ids = _tile_ids(i * tile_i, tile_i, 0)
 
     # Block sparsity for the local mask: the live j-window for this i-tile
     # (i is traced, so the window is int32 arithmetic; fori_loop takes
@@ -166,11 +188,8 @@ def _consensus_update_kernel(
             * scale
         )  # [TB, TI, TJ]
 
-        col_ids = j * tile_j + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_i, tile_j), 1
-        )
         s = _apply_masks(
-            s, row_ids, col_ids,
+            s, row_ids, _tile_ids(j * tile_j, tile_j, 1),
             side=side, radius=radius, attend_self=attend_self,
         )
 
@@ -264,14 +283,8 @@ def _consensus_update_kernel_streamed(
             )
             * scale
         )
-        row_ids = i * tile_i + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_i, tile_j), 0
-        )
-        col_ids = j * tile_j + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_i, tile_j), 1
-        )
         s = _apply_masks(
-            s, row_ids, col_ids,
+            s, _tile_ids(i * tile_i, tile_i, 0), _tile_ids(j * tile_j, tile_j, 1),
             side=side, radius=radius, attend_self=attend_self,
         )
         m = m_acc[...]
@@ -614,12 +627,8 @@ def _consensus_bwd_dq_kernel(
             )
             * scale
         )
-        row_ids = i * tile_i + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_i, tile_j), 0
-        )
-        col_ids = j * tile_j + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_i, tile_j), 1
-        )
+        row_ids = _tile_ids(i * tile_i, tile_i, 0)
+        col_ids = _tile_ids(j * tile_j, tile_j, 1)
         s = _apply_masks(
             s, row_ids, col_ids,
             side=side, radius=radius, attend_self=attend_self,
@@ -700,8 +709,8 @@ def _small_bwd_math(x, dcons, m, l, *, side, radius, attend_self, n):
         )
         * scale
     )  # [TB, n, n]
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    row_ids = _tile_ids(0, n, 0)
+    col_ids = _tile_ids(0, n, 1)
     diag = (row_ids == col_ids)[None]
     s = _apply_masks(
         s, row_ids, col_ids, side=side, radius=radius, attend_self=attend_self
@@ -787,9 +796,7 @@ def _consensus_bwd_dkv_kernel(
     @pl.when(i < hi)
     def _step():
         k = _normalized_k(xj)
-        col_ids = j * tile_j + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_j, tile_i), 0
-        )
+        col_ids = _tile_ids(j * tile_j, tile_j, 0)
         q = q_ref[0]              # [TB, TI, d]
         dcons = dm_ref[0]         # [TB, TI, d] raw
         m = m_ref[0][..., 0]      # [TB, TI]
@@ -804,10 +811,9 @@ def _consensus_bwd_dkv_kernel(
             )
             * scale
         )  # [TB, TJ, TI]
-        row_ids = i * tile_i + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_j, tile_i), 1
-        )  # query index along the LAST axis here (both masks are symmetric
-        #    in the pair, so the transposed orientation reuses the helper)
+        row_ids = _tile_ids(i * tile_i, tile_i, 1)
+        # query index along the LAST axis here (both masks are symmetric in
+        # the pair, so the transposed orientation reuses the helper)
         s2 = _apply_masks(
             s2, col_ids, row_ids,
             side=side, radius=radius, attend_self=attend_self,
@@ -908,12 +914,8 @@ def _consensus_bwd_onesweep_kernel(
         m = m_ref[0][..., 0]
         l = l_ref[0][..., 0]
 
-        col_ids = j * tile_j + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_j, tile_i), 0
-        )
-        row_ids = i * tile_i + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_j, tile_i), 1
-        )
+        col_ids = _tile_ids(j * tile_j, tile_j, 0)
+        row_ids = _tile_ids(i * tile_i, tile_i, 1)
         s2 = (
             jax.lax.dot_general(
                 k, q, (((2,), (2,)), ((0,), (0,))),

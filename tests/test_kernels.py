@@ -605,6 +605,140 @@ class TestFusedConsensusUpdate:
         assert not np.allclose(np.asarray(out1[-2]), np.asarray(out2[-2]))
 
 
+def _mask_cases():
+    """(side, radius, tile_a, tile_b, origin_a, origin_b): a-axis = a score
+    tile's second-to-last dim, b-axis its last. side 32 / tile 256 at tile
+    offsets -1, 0, +1 and both edges of the row; side 24 / tile 64 (a tile
+    that is no multiple of `side`, origins that are no multiple either);
+    side 16 with the forward's 128 x 256 tiles."""
+    geometries = (
+        [(32, 256, 256, 256 * i, 256 * j)
+         for i, j in ((1, 0), (1, 1), (1, 2), (0, 0), (0, 1), (3, 3), (3, 2))]
+        + [(24, 64, 64, 64 * i, 64 * j)
+           for i, j in ((0, 0), (1, 0), (4, 3), (4, 4), (4, 5), (8, 8), (8, 6))]
+        + [(16, 128, 256, 0, 0), (16, 128, 256, 128, 0)]
+    )
+    return [
+        pytest.param(side, radius, ta, tb, oa, ob,
+                     id=f"side{side}-r{radius}-{ta}x{tb}-at{oa},{ob}")
+        for side, ta, tb, oa, ob in geometries
+        for radius in (0.0, 1.0, 7.0, 7.5)
+    ]
+
+
+class TestConsensusMaskPredicate:
+    """_apply_masks takes the local-window mask from per-row and per-column
+    grid coordinates (PR 30). It is still the reference's predicate, tile by
+    tile: ops/consensus.build_local_mask's block, and the diagonal."""
+
+    @pytest.mark.parametrize("transposed", [False, True], ids=["s", "s2"])
+    @pytest.mark.parametrize("attend_self", [False, True], ids=["noself", "self"])
+    @pytest.mark.parametrize(
+        "side,radius,tile_a,tile_b,origin_a,origin_b", _mask_cases()
+    )
+    def test_tile_equals_reference_block(
+        self, side, radius, tile_a, tile_b, origin_a, origin_b, attend_self,
+        transposed,
+    ):
+        from glom_tpu.kernels.consensus_update import (
+            _NEG_MAX, _apply_masks, _tile_ids,
+        )
+        from glom_tpu.ops.consensus import build_local_mask
+        from glom_tpu.utils.helpers import TOKEN_ATTEND_SELF_VALUE
+
+        if transposed:  # the dkv / one-sweep kernels' s2: keys on the a-axis
+            tile_a, tile_b = tile_b, tile_a
+            origin_a, origin_b = origin_b, origin_a
+        a = np.arange(origin_a, origin_a + tile_a)
+        b = np.arange(origin_b, origin_b + tile_b)
+        want = np.ones((tile_a, tile_b), np.float32)
+        if not attend_self:
+            want[a[:, None] == b[None, :]] = TOKEN_ATTEND_SELF_VALUE
+        far = build_local_mask(side, radius)
+        if far is not None:
+            want[far[np.ix_(a, b)]] = _NEG_MAX
+
+        got = _apply_masks(
+            jnp.ones((1, tile_a, tile_b), jnp.float32),
+            _tile_ids(origin_a, tile_a, 0), _tile_ids(origin_b, tile_b, 1),
+            side=side, radius=radius, attend_self=attend_self,
+        )
+        np.testing.assert_array_equal(np.asarray(got[0]), want)
+
+
+def _eqns(jaxpr):
+    """Every equation of a traced program, nested programs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+class TestConsensusMaskCost:
+    """The CPU's guard on what only the chip can time (PR 30): in the traced
+    programs of the two n=1024 kernels no integer division runs on a score
+    tile, and the radius mask adds at most 12 tile-shaped equations to the
+    radius-0 build (it added 40)."""
+
+    # d = 128, not the cell's 512: the forward's radius-0 build takes a
+    # j-tile of 512, and no [tile, d] block may pass for a score tile
+    L, B, n, d, side = 2, 1, 1024, 128, 32
+
+    def _tile_eqns(self, kernel, radius):
+        from glom_tpu.kernels import consensus_update as cu
+
+        L, B, n, d = self.L, self.B, self.n, self.d
+        bf16, f32 = jnp.bfloat16, jnp.float32
+        lv = jax.ShapeDtypeStruct((L, B, n, d), bf16)
+        td = jax.ShapeDtypeStruct((L - 1, B, n, d), bf16)
+        st = jax.ShapeDtypeStruct((L, B, n, 1), f32)
+        kw = dict(
+            side=self.side, radius=radius, attend_self=False, interpret=True
+        )
+        tile = cu._pick_tile(n)
+        if kernel == "consensus_update_bwd_onesweep":
+            traced = jax.make_jaxpr(
+                lambda x, g, m, l, c: cu._consensus_bwd_onesweep(
+                    x, g, m, l, c, **kw
+                )
+            )(lv, lv, st, st, lv)
+            shape = (tile, tile)
+        else:
+            traced = jax.make_jaxpr(
+                lambda x, bu, t: cu._forward(
+                    x, bu, t, save_stats=True, save_cons=True, **kw
+                )
+            )(lv, lv, td)
+            shape = (tile, cu._pick_tile(n, cap=512 if radius <= 0 else 256))
+        return [
+            eqn
+            for eqn in _eqns(traced.jaxpr)
+            if eqn.primitive.name not in ("jit", "pjit")  # wrappers, not work
+            and any(
+                getattr(o.aval, "shape", ())[-2:] == shape for o in eqn.outvars
+            )
+        ]
+
+    @pytest.mark.parametrize(
+        "kernel", ["consensus_update_bwd_onesweep", "consensus_update_fwd"]
+    )
+    def test_radius_mask_runs_on_vectors(self, kernel):
+        banded = self._tile_eqns(kernel, 7.0)
+        assert len(banded) >= 10  # the walk did find the kernel's body
+        on_tiles = [e.primitive.name for e in banded]
+        assert not {"div", "rem"} & {
+            e.primitive.name
+            for e in banded
+            if any(
+                jnp.issubdtype(o.aval.dtype, jnp.integer) for o in e.outvars
+            )
+        }, on_tiles
+        assert len(banded) - len(self._tile_eqns(kernel, 0.0)) <= 12, on_tiles
+
+
 class TestFusedForwardParity:
     """The use_pallas=True fused level-major forward must match the
     reference-layout path on every contract point (CPU: kernels fall back to
