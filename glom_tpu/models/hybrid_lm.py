@@ -38,6 +38,7 @@ from glom_tpu.utils.config import HybridLMConfig
 
 Params = Any  # {"embed", "layers": (one dict a layer), "final_norm", "head"}
 ATTN_QUERY_BLOCK = 1024
+ATTN_KEY_BLOCK = 128  # the unit `blocked_attention` counts the keys it multiplies in
 LOSS_ROW_BLOCK = 2048
 ROW_TILE = 1024  # the experts' rows come in multiples of this
 RUNG_LOADS = (2,)  # the small row counts, in balanced loads (`row_rungs`)
@@ -115,13 +116,18 @@ def init_leaf(key, name: str, shape, cfg: HybridLMConfig):
     return std * jax.random.normal(key, shape, jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def init_hybrid_lm(key: jax.Array, cfg: HybridLMConfig) -> Params:
-    shapes = param_shapes(cfg)
+def init_tree(key: jax.Array, shapes, leaf, cfg):
+    """A tree of shapes -> the tree of `leaf(key_i, name, shape, cfg)`, the
+    leaf's name being the last key on its path."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
     return jax.tree_util.tree_unflatten(treedef, [
-        init_leaf(jax.random.fold_in(key, i), path[-1].key, shape, cfg)
+        leaf(jax.random.fold_in(key, i), path[-1].key, shape, cfg)
         for i, (path, shape) in enumerate(leaves)])
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def init_hybrid_lm(key: jax.Array, cfg: HybridLMConfig) -> Params:
+    return init_tree(key, param_shapes(cfg), init_leaf, cfg)
 
 
 # --------------------------------------------------------------------- pieces
@@ -235,22 +241,47 @@ def mamba_mixer(p, x_in, cfg: HybridLMConfig, dtype):
 # ------------------------------------------------------------------ attention
 
 
-def _attend(q, k, v, first: int):
-    """One block of queries against the keys at or before them. q [B, tq,
-    G, R, D] at positions first..first+tq, k and v [B, tk, G, D] at 0..tk."""
+def _attend(q, k, v, first: int, key_first: int = 0, window=None):
+    """One block of queries against the keys at or before them, and with a
+    `window` no more than window - 1 before. q [B, tq, G, R, D] at positions
+    first..first+tq, k [B, tk, G, D] and v [B, tk, G, Dv] at
+    key_first..key_first+tk."""
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k, preferred_element_type=jnp.float32) * scale
     qpos = first + jnp.arange(q.shape[1])[:, None]
-    s = jnp.where(jnp.arange(k.shape[1])[None, :] <= qpos, s, -jnp.inf)
+    kpos = jnp.arange(key_first, key_first + k.shape[1])[None, :]
+    seen = kpos <= qpos
+    if window is not None:
+        seen &= kpos > qpos - window
+    s = jnp.where(seen, s, -jnp.inf)
     pr = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrqk,bkgd->bqgrd", pr, v, preferred_element_type=jnp.float32
                       ).astype(q.dtype)
 
 
+def blocked_attention(q, k, v, window=None):
+    """Causal attention, queries a block at a time against the keys the
+    block can see: those up to its end and, with a `window`, from window - 1
+    before its first on. The keys a block cannot see are not sliced in, so
+    they are multiplied neither here nor in the backward pass, which
+    recomputes each block; no [T, T] array is kept. q [B, T, G, R, D], k
+    [B, T, G, D], v [B, T, G, Dv] -> ([B, T, G, R, Dv], the key blocks of
+    ATTN_KEY_BLOCK keys that were multiplied, summed over the query blocks)."""
+    t = q.shape[1]
+    out, key_blocks = [], 0
+    for first in range(0, t, ATTN_QUERY_BLOCK):
+        last = min(t, first + ATTN_QUERY_BLOCK)
+        low = 0 if window is None else max(0, first - window + 1)
+        block = jax.checkpoint(functools.partial(
+            _attend, first=first, key_first=low, window=window))
+        out.append(block(q[:, first:last], k[:, low:last], v[:, low:last]))
+        key_blocks += -(-(last - low) // ATTN_KEY_BLOCK)
+    return jnp.concatenate(out, axis=1), key_blocks
+
+
 def attention_mixer(p, x_in, cfg: HybridLMConfig, dtype):
-    """Grouped-query causal attention, no positions, no bias. Queries go a
-    block at a time against the keys up to the block's end, each block
-    recomputed in the backward pass, so that no [T, T] array is kept."""
+    """Grouped-query causal attention, no positions, no bias, by
+    `blocked_attention`."""
     g, dh = cfg.num_key_value_heads, cfg.head_dim
     r = cfg.num_attention_heads // g
     bsz, t = x_in.shape[:2]
@@ -259,12 +290,7 @@ def attention_mixer(p, x_in, cfg: HybridLMConfig, dtype):
         q = _mm(u, _cast(p["q"], dtype)).astype(u.dtype).reshape(bsz, t, g, r, dh)
         k = _mm(u, _cast(p["k"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
         v = _mm(u, _cast(p["v"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
-        out = []
-        for first in range(0, t, ATTN_QUERY_BLOCK):
-            last = min(t, first + ATTN_QUERY_BLOCK)
-            block = jax.checkpoint(functools.partial(_attend, first=first))
-            out.append(block(q[:, first:last], k[:, :last], v[:, :last]))
-        o = jnp.concatenate(out, axis=1).reshape(bsz, t, g * r * dh)
+        o = blocked_attention(q, k, v)[0].reshape(bsz, t, g * r * dh)
         return _mm(o, _cast(p["o"], dtype)).astype(u.dtype)
 
 
@@ -437,20 +463,39 @@ def layer(kind: str, p, x, cfg: HybridLMConfig, dtype):
     return x + out, counters, top_i
 
 
+def run_stack(params: Params, ids, layers, *, compute_dtype=None, remat: bool = True,
+              side=None):
+    """The stack every language-model family here shares: the embedding's
+    rows of `ids` [B, T], then the layers in order, each recomputed whole in
+    the backward pass under `remat`. `layers` holds one function a layer,
+    `f(p, x, side) -> (x, side, aux)`: `side` is what a layer hands the
+    layers after it beside the residual stream. A recomputed layer takes it
+    as an input, so its gradient flows back to the layer that made it.
+    Returns (the last layer's output [B, T, d], every layer's aux)."""
+    with jax.named_scope("embed"):
+        x = _cast(params["embed"][ids], compute_dtype)
+    aux = []
+    for f, p in zip(layers, params["layers"]):
+        x, side, a = (jax.checkpoint(f) if remat else f)(p, x, side)
+        aux.append(a)
+    return x, aux
+
+
 def hidden_states(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
                   remat: bool = True):
     """ids [B, T] -> (the last layer's output [B, T, d], one counters dict
     an `E` layer, the routers' choices [E layers, B * T, k])."""
-    with jax.named_scope("embed"):
-        x = _cast(params["embed"][ids], compute_dtype)
-    counters, choices = [], []
-    for kind, p in zip(cfg.pattern, params["layers"]):
-        f = functools.partial(layer, kind, cfg=cfg, dtype=compute_dtype)
-        x, c, top_i = (jax.checkpoint(f) if remat else f)(p, x)
-        if kind == "E":
-            counters.append(c)
-            choices.append(top_i)
-    return x, counters, choices
+
+    def held(kind):
+        def f(p, x, side):
+            x, c, top_i = layer(kind, p, x, cfg, compute_dtype)
+            return x, side, (c, top_i)
+        return f
+
+    x, aux = run_stack(params, ids, [held(kind) for kind in cfg.pattern],
+                       compute_dtype=compute_dtype, remat=remat)
+    experts = [a for kind, a in zip(cfg.pattern, aux) if kind == "E"]
+    return x, [c for c, _ in experts], [top_i for _, top_i in experts]
 
 
 def routing_choices(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None):
@@ -465,27 +510,35 @@ def _block_nll(h, head, targets):
     return lse - jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
 
 
+def next_token_loss(h, head, ids):
+    """Next-token cross-entropy of the normed hidden states h [B * T, d]
+    under the head [d, V] (the vocabulary rows held here): float32 logits, a
+    block of LOSS_ROW_BLOCK rows at a time, each block recomputed in the
+    backward pass; the mean over the B * (T - 1) positions that have a next
+    token."""
+    bsz, t = ids.shape
+    targets = jnp.roll(ids, -1, axis=1).reshape(-1)
+    has_next = jnp.tile(jnp.arange(t) < t - 1, bsz)
+    total = jnp.zeros((), jnp.float32)
+    for first in range(0, bsz * t, LOSS_ROW_BLOCK):
+        rows = slice(first, min(bsz * t, first + LOSS_ROW_BLOCK))
+        nll = jax.checkpoint(_block_nll)(h[rows], head, targets[rows])
+        total = total + jnp.sum(jnp.where(has_next[rows], nll, 0.0))
+    return total / (bsz * (t - 1))
+
+
 def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
             remat: bool = True) -> Tuple[jnp.ndarray, dict]:
-    """Next-token cross-entropy over the vocabulary rows held here, float32
-    logits, the mean over the B * (T - 1) positions that have a next token.
-    Returns (loss, counters): pairs routed to the experts held, rows of the
-    rung the grouped product ran at and whether that was the full one, each
-    the mean over the `E` layers, and the fullest expert's load over all of
-    them."""
+    """Next-token cross-entropy over the vocabulary rows held here
+    (`next_token_loss`). Returns (loss, counters): pairs routed to the
+    experts held, rows of the rung the grouped product ran at and whether
+    that was the full one, each the mean over the `E` layers, and the
+    fullest expert's load over all of them."""
     x, counters, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
-    bsz, t = ids.shape
     with jax.named_scope("lm_head_loss"):
-        h = rms_norm(x, params["final_norm"], cfg.layer_norm_epsilon).reshape(bsz * t, -1)
-        head = _cast(params["head"], compute_dtype)
-        targets = jnp.roll(ids, -1, axis=1).reshape(-1)
-        has_next = jnp.tile(jnp.arange(t) < t - 1, bsz)
-        total = jnp.zeros((), jnp.float32)
-        for first in range(0, bsz * t, LOSS_ROW_BLOCK):
-            rows = slice(first, min(bsz * t, first + LOSS_ROW_BLOCK))
-            nll = jax.checkpoint(_block_nll)(h[rows], head, targets[rows])
-            total = total + jnp.sum(jnp.where(has_next[rows], nll, 0.0))
-        loss = total / (bsz * (t - 1))
+        h = rms_norm(x, params["final_norm"], cfg.layer_norm_epsilon).reshape(
+            -1, x.shape[-1])
+        loss = next_token_loss(h, _cast(params["head"], compute_dtype), ids)
     with jax.named_scope("step_metrics"):
         merged = {}
         if counters:
@@ -495,6 +548,9 @@ def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
     return loss, merged
 
 
+def count_shapes(shapes) -> int:
+    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(shapes, is_leaf=_is_shape))
+
+
 def param_count(cfg: HybridLMConfig) -> int:
-    return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
-        param_shapes(cfg), is_leaf=_is_shape))
+    return count_shapes(param_shapes(cfg))

@@ -28,6 +28,7 @@ _EXPORTS = {
     "HOST_PHASES": "spans",
     "DEVICE_PHASES": "spans",
     "LM_DEVICE_PHASES": "spans",
+    "SAMBAY_DEVICE_PHASES": "spans",
     "SpanAggregator": "spans",
     "span": "spans",
     "spanned": "spans",

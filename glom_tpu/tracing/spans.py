@@ -88,6 +88,27 @@ LM_DEVICE_PHASES = (
     "lm_head_loss",
 )
 
+# The device scopes of the SambaY language model's step (models/sambay.py).
+# `embed`, `mamba_in`, `mamba_out` and `lm_head_loss` mean what they mean
+# above (in-projection, conv and step; skip, gate and out-projection);
+# `selective_scan` is the Mamba-1 recurrence alone; the three differential
+# attentions, each with its input norm, projections, softmaxes and
+# out-projection: `window_attention`, `full_attention` (which makes the
+# shared keys and values), `cross_attention` (which reads them); `gmu`;
+# `mlp` (every layer's second half: norm, SwiGLU).
+SAMBAY_DEVICE_PHASES = (
+    "embed",
+    "mamba_in",
+    "selective_scan",
+    "mamba_out",
+    "window_attention",
+    "full_attention",
+    "cross_attention",
+    "gmu",
+    "mlp",
+    "lm_head_loss",
+)
+
 # The serving stack's host phases (glom_tpu/serve): one request's path is
 # enqueue -> (gathered into a) batch -> dispatch (the compiled forward) ->
 # fetch (device->host of the valid rows). The batcher aggregates these the

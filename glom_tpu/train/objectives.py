@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from glom_tpu.models.core import ConsensusFn, GlomParams, glom_forward, init_glom
 from glom_tpu.ops.patch import LinearParams, init_linear, tokens_to_image
-from glom_tpu.utils.config import GlomConfig
+from glom_tpu.utils.config import GlomConfig, SambaYConfig
 
 
 class DenoiseParams(NamedTuple):
@@ -67,21 +67,32 @@ class Objective(NamedTuple):
     vjp_path: str
 
 
+def _lm_family(cfg):
+    """(init, loss) of the language-model family `cfg` configures: both
+    families share a stack and a loss, `init(key, cfg)` and `loss(params,
+    ids, cfg, compute_dtype=, remat=) -> (loss, counters)`."""
+    if isinstance(cfg, SambaYConfig):
+        from glom_tpu.models.sambay import init_sambay, lm_loss
+
+        return init_sambay, lm_loss
+    from glom_tpu.models.hybrid_lm import init_hybrid_lm, lm_loss
+
+    return init_hybrid_lm, lm_loss
+
+
 def init_params(key: jax.Array, cfg):
     """The initial parameters of whichever family `cfg` configures."""
     if isinstance(cfg, GlomConfig):
         return init_denoise(key, cfg)
-    from glom_tpu.models.hybrid_lm import init_hybrid_lm
-
-    return init_hybrid_lm(key, cfg)
+    return _lm_family(cfg)[0](key, cfg)
 
 
 def lm_objective(cfg, tcfg) -> Objective:
-    """Next-token cross-entropy of the hybrid language model
-    (models/hybrid_lm.py) on [batch, seq_len] token ids. Nothing is drawn;
-    the routing counters are the aux. One route: XLA only, per-layer
-    recomputation by `tcfg.remat`."""
-    from glom_tpu.models.hybrid_lm import lm_loss
+    """Next-token cross-entropy of a language model (models/hybrid_lm.py or
+    models/sambay.py, by the configuration's type) on [batch, seq_len] token
+    ids. Nothing is drawn; the model's step counters are the aux. One route:
+    XLA only, per-layer recomputation by `tcfg.remat`."""
+    lm_loss = _lm_family(cfg)[1]
 
     if tcfg.compute_dtype not in ("float32", "bfloat16"):
         raise ValueError(

@@ -716,7 +716,7 @@ class Trainer:
 
     def __init__(
         self,
-        cfg,  # a GlomConfig or a HybridLMConfig: whatever objective_for knows
+        cfg,  # a GlomConfig or a language model's config: whatever objective_for knows
         tcfg: TrainConfig,
         *,
         optimizer: Optional[optax.GradientTransformation] = None,

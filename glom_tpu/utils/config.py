@@ -139,6 +139,95 @@ class HybridLMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SambaYConfig:
+    """A SambaY decoder-hybrid-decoder language model (models/sambay.py):
+    pre-norm residual layers `h += mixer(LN(h)); h += MLP(LN(h))` whose
+    mixer is a function of the published layer index `i` of
+    `num_hidden_layers_total` = N and of nothing else (`layer_kind`): Mamba-1
+    on even `i <= N/2`, differential attention under a window of
+    `sliding_window` keys on odd `i < N/2`, the same attention causal and
+    full at `i = N/2 + 1`, and above it Gated Memory Units (even) that read
+    layer N/2's scan output and cross-attention (odd) that reads layer
+    N/2 + 1's keys and values. Field names are those of the published
+    `phi4flash` config.json where it has them; the defaults are
+    Phi-4-mini-flash-reasoning's. The Mamba sizes, `head_dim` and the
+    `time_step_*` of the initial values are not in that file: they are the
+    published modeling code's constants.
+
+    `num_hidden_layers` layers from `layer_offset` on and `vocab_size` rows
+    of the tied embedding are what THIS chip holds (a pipeline stage, and a
+    row slice of the embedding). Widths and head counts are never a share."""
+
+    hidden_size: int = 2560
+    intermediate_size: int = 10240
+    layer_norm_eps: float = 1e-5
+    mb_per_layer: int = 2
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    sliding_window: int = 512
+    vocab_size: int = 200064
+    layer_offset: int = 0
+    num_hidden_layers: int = 32
+    num_hidden_layers_total: int = 32
+    # Mamba-1
+    mamba_expand: int = 2
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # the training sequence: tokens of one packed row of the batch
+    seq_len: int = 8192
+
+    def __post_init__(self):
+        n, first = self.num_hidden_layers_total, self.layer_offset
+        held = range(first, first + self.num_hidden_layers)
+        if not held or first < 0 or held[-1] >= n or n % 2 or self.mb_per_layer != 2:
+            raise ValueError(f"layers {first}..{first + self.num_hidden_layers} of {n}, "
+                             "an even count with a Mamba layer every second one")
+        kinds = self.kinds
+        if ("G" in kinds and n // 2 not in held) or ("X" in kinds and n // 2 + 1 not in held):
+            raise ValueError(
+                f"layers {first}..{held[-1]} read layer {n // 2}'s memory or layer "
+                f"{n // 2 + 1}'s keys and values, which are not among them")
+        if self.num_attention_heads % self.num_key_value_heads or (
+                self.num_key_value_heads % 2) or self.hidden_size % self.num_attention_heads:
+            raise ValueError("query heads divide over KV heads, and KV heads pair up")
+
+    @property
+    def kinds(self) -> str:
+        """The mixers of the layers held here, in order: `M` Mamba-1, `W`
+        window attention, `F` full attention, `G` Gated Memory Unit, `X`
+        cross-attention."""
+        return "".join(layer_kind(i, self.num_hidden_layers_total)
+                       for i in range(self.layer_offset,
+                                      self.layer_offset + self.num_hidden_layers))
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def mamba_dt_rank(self) -> int:
+        return -(-self.hidden_size // 16)
+
+
+def layer_kind(i: int, n: int) -> str:
+    """The mixer of published layer `i` of `n` (SambaYConfig's letters), with
+    a Mamba layer every second one (the source's `mb_per_layer` = 2, which
+    SambaYConfig holds a configuration to)."""
+    if i % 2 == 0:
+        return "M" if i <= n // 2 else "G"
+    if i < n // 2:
+        return "W"
+    return "F" if i == n // 2 + 1 else "X"
+
+
+@dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Parallelism layout. Axis sizes of 1 disable an axis.
 

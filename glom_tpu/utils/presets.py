@@ -15,6 +15,7 @@ from glom_tpu.utils.config import (
     GlomConfig,
     HybridLMConfig,
     MeshConfig,
+    SambaYConfig,
     ServeConfig,
     TrainConfig,
 )
@@ -27,7 +28,7 @@ class Preset:
     description: str
     # The family the preset trains: its type picks the objective
     # (train/trainer.objective_for).
-    model: Union[GlomConfig, HybridLMConfig]
+    model: Union[GlomConfig, HybridLMConfig, SambaYConfig]
     train: TrainConfig
     mesh: MeshConfig
     sp_strategy: str = "none"  # none | ring | ulysses | halo | auto
@@ -67,7 +68,8 @@ class Preset:
 
 
 PRESETS: Dict[str, Preset] = {}
-# The presets of the second family (a HybridLMConfig model), in a table of
+# The presets of the language-model families (a HybridLMConfig or a
+# SambaYConfig model), in a table of
 # their own: PRESETS stays GLOM's driver configurations, which is what the
 # sharded trainers and the serving stack iterate; `get_preset` finds both.
 LM_PRESETS: Dict[str, Preset] = {}
@@ -321,6 +323,48 @@ _register(
             mamba_num_heads=8, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
             chunk_size=8, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
             seq_len=64,
+        ),
+        train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
+        mesh=MeshConfig(),
+    )
+)
+
+
+# 7. A third family: Phi-4-mini-flash-reasoning (phi4flash, SambaY: Mamba-1,
+# differential attention under a 512-key window and in full, one shared KV
+# read by cross-attention, Gated Memory Units), as ONE chip of a pipeline
+# whose stages hold six layers each sees it: published layers 14-19 (Mamba,
+# window attention, the Mamba layer that makes the memory, the full attention
+# that makes the shared keys and values, a GMU, a cross-attention), and rows
+# 0-25,007 of the tied embedding, which is divided by rows over the stage's 8
+# data-parallel replicas. Every width and every head is the published one.
+# 697M parameters held; one packed sequence of 8,192 tokens a step.
+_register(
+    Preset(
+        name="phi4-mini-flash-stage6vp8",
+        description="Phi-4-mini-flash-reasoning: the pipeline stage of layers "
+        "14-19, an eighth of the vocabulary, 8k tokens",
+        model=SambaYConfig(
+            layer_offset=14, num_hidden_layers=6, vocab_size=25008, seq_len=8192,
+        ),
+        train=TrainConfig(
+            batch_size=1, learning_rate=3e-4, compute_dtype="bfloat16", remat=True,
+        ),
+        mesh=MeshConfig(),
+    )
+)
+
+# 7b. The same family at a size the CPU holds: the whole rule at N = 8 (every
+# kind of layer, the memory and the shared keys and values made and read), a
+# window shorter than the sequence.
+_register(
+    Preset(
+        name="sambay-tiny",
+        description="SambaY LM, hidden 64, 8 layers MWMWMFGX, window 16 — CPU drives",
+        model=SambaYConfig(
+            hidden_size=64, intermediate_size=128, num_attention_heads=8,
+            num_key_value_heads=4, sliding_window=16, vocab_size=128,
+            num_hidden_layers=8, num_hidden_layers_total=8, seq_len=80,
         ),
         train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
         mesh=MeshConfig(),
