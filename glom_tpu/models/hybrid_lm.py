@@ -18,7 +18,9 @@ on the device (`row_rungs`); the last rung is the worst imbalance (every
 token choosing every held expert), so no pair is ever dropped; what the
 absent experts would have added is left out.
 
-XLA only: there is no Pallas kernel on this path (`vjp_path: lm_xla`).
+XLA, but for the attention's scores: where the shapes tile and the device is
+a TPU, `blocked_attention` runs the Pallas kernels of
+`kernels/flash_attention.py`. The route's name stayed `vjp_path: lm_xla`.
 Parameters are float32; with a compute dtype the residual stream and the
 matrix products run in it, the router, the recurrences' decays, the norms'
 statistics, the softmaxes and the loss in float32. Every device op sits
@@ -34,6 +36,7 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
+from glom_tpu.kernels import flash_attention
 from glom_tpu.utils.config import HybridLMConfig
 
 Params = Any  # {"embed", "layers": (one dict a layer), "final_norm", "head"}
@@ -260,14 +263,26 @@ def _attend(q, k, v, first: int, key_first: int = 0, window=None):
 
 
 def blocked_attention(q, k, v, window=None):
-    """Causal attention, queries a block at a time against the keys the
+    """Causal attention, a block of queries at a time against the keys the
     block can see: those up to its end and, with a `window`, from window - 1
-    before its first on. The keys a block cannot see are not sliced in, so
-    they are multiplied neither here nor in the backward pass, which
-    recomputes each block; no [T, T] array is kept. q [B, T, G, R, D], k
-    [B, T, G, D], v [B, T, G, Dv] -> ([B, T, G, R, Dv], the key blocks of
-    ATTN_KEY_BLOCK keys that were multiplied, summed over the query blocks)."""
-    t = q.shape[1]
+    before its first on. The keys a block cannot see are multiplied neither
+    here nor in the backward pass; no [T, T] array is kept. q [B, T, G, R,
+    D], k [B, T, G, D], v [B, T, Gv, Dv] (KV head g reads value head g // (G /
+    Gv)) -> ([B, T, G, R, Dv], the key blocks of ATTN_KEY_BLOCK keys that
+    were multiplied, summed over the query blocks).
+
+    Where the shapes tile (`flash_attention.tiles`) and the device is a TPU,
+    the blocks are the Pallas kernels' tiles and the scores never leave VMEM;
+    otherwise the blocks are ATTN_QUERY_BLOCK queries through XLA, each
+    recomputed in the backward pass."""
+    t, g = q.shape[1], k.shape[2]
+    tiled = flash_attention.tiles(t, q.shape[3], q.shape[4], v.shape[-1], window)
+    if tiled and flash_attention.on_tpu():
+        tq, tk = tiled
+        out = flash_attention.flash_attention(q, k, v, window, tq=tq, tk=tk)
+        return out, flash_attention.key_blocks(t, tq, tk, window, ATTN_KEY_BLOCK)
+    if v.shape[2] != g:
+        v = jnp.repeat(v, g // v.shape[2], axis=2)
     out, key_blocks = [], 0
     for first in range(0, t, ATTN_QUERY_BLOCK):
         last = min(t, first + ATTN_QUERY_BLOCK)

@@ -21,11 +21,13 @@ encoding. The mixer of published layer `i` of N is `config.layer_kind`'s:
 
 The stack (embedding, per-layer recomputation, blocked loss), the blocked
 attention core and the causal conv are `hybrid_lm`'s; what a layer hands the
-layers after it travels as `run_stack`'s `side`. XLA only (`vjp_path:
-lm_xla`). Parameters are float32; with a compute dtype the residual stream
-and the matrix products run in it, the norms' statistics, the step `dt`, the
-recurrence's state, the softmaxes and the loss in float32. Every device op
-sits under one of `tracing.spans.SAMBAY_DEVICE_PHASES`.
+layers after it travels as `run_stack`'s `side`. XLA but for the attentions'
+scores where the shapes tile (`kernels/flash_attention.py`; the route still
+goes by `vjp_path: lm_xla`). Parameters are float32; with a compute dtype
+the residual stream and the matrix products run in it, the norms'
+statistics, the step `dt`, the recurrence's state, the softmaxes and the
+loss in float32. Every device op sits under one of
+`tracing.spans.SAMBAY_DEVICE_PHASES`.
 """
 
 from __future__ import annotations
@@ -254,14 +256,13 @@ def differential_attention(p, q, k, v, cfg: SambaYConfig, index: int, dtype, win
     values lie side by side; query pair j reads KV pair j // (pairs a KV
     pair). Both softmaxes go through one `blocked_attention`: KV head (g, s)
     is read by the members s of its pair's query pairs, against the pair's
-    values."""
+    values, which it is handed once a pair."""
     hq, hkv, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     bsz, t = q.shape[:2]
     pairs, per = hkv // 2, hq // hkv
     q = q.reshape(bsz, t, pairs, per, 2, dh).swapaxes(3, 4).reshape(bsz, t, hkv, per, dh)
     k = k.reshape(bsz, t, hkv, dh)
-    v = jnp.broadcast_to(v.reshape(bsz, t, pairs, 1, 2 * dh), (bsz, t, pairs, 2, 2 * dh))
-    a, key_blocks = blocked_attention(q, k, v.reshape(bsz, t, hkv, 2 * dh), window)
+    a, key_blocks = blocked_attention(q, k, v.reshape(bsz, t, pairs, 2 * dh), window)
     a = a.reshape(bsz, t, pairs, 2, per, 2 * dh)
     lam0 = lambda_init(index)
     lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))

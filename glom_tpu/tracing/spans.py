@@ -37,7 +37,7 @@ from typing import Optional
 # loop's and the prefetch worker's spans, of every `jax.named_scope` in the
 # step builders (train/trainer.py, parallel/manual.py) and the forward
 # (models/core.py, train/objectives.py), and the first word of every Pallas
-# kernel's `name=` (`loop_*`: kernels/fused_loop.py, `ffw_*`:
+# GLOM kernel's `name=` (`loop_*`: kernels/fused_loop.py, `ffw_*`:
 # kernels/grouped_mlp.py, `consensus_*`: the consensus kernels). A device op
 # of the step belongs to the innermost of these in its `op_name`.
 HOST_PHASES = (
@@ -107,6 +107,20 @@ SAMBAY_DEVICE_PHASES = (
     "gmu",
     "mlp",
     "lm_head_loss",
+)
+
+# The language models' Pallas kernels, by their `name=`
+# (kernels/flash_attention.py). They open no scope of their own: a call runs
+# inside the model's attention scope (`attention`; `window_attention`,
+# `full_attention`, `cross_attention`), and its device time belongs to that
+# scope. All begin with `attn_`; none matches `loop_*`, `ffw_*`,
+# `consensus_*` or `ragged-dot*`, the names by which the benchmark tells the
+# routes apart.
+LM_KERNELS = (
+    # causal (windowed) grouped-query attention forward: online softmax over the scheduled key tiles
+    "attn_flash_fwd",
+    # its backward: one sweep over key tiles, scores rebuilt from the log-sum-exp, dq resident
+    "attn_flash_bwd_onesweep",
 )
 
 # The serving stack's host phases (glom_tpu/serve): one request's path is

@@ -91,7 +91,7 @@ def lm_objective(cfg, tcfg) -> Objective:
     """Next-token cross-entropy of a language model (models/hybrid_lm.py or
     models/sambay.py, by the configuration's type) on [batch, seq_len] token
     ids. Nothing is drawn; the model's step counters are the aux. One route:
-    XLA only, per-layer recomputation by `tcfg.remat`."""
+    XLA but for attention's scores, per-layer recomputation by `tcfg.remat`."""
     lm_loss = _lm_family(cfg)[1]
 
     if tcfg.compute_dtype not in ("float32", "bfloat16"):

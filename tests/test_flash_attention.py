@@ -1,0 +1,298 @@
+"""The blockwise attention kernels (kernels/flash_attention.py) in interpret
+mode on the CPU, against the XLA loop they replace where the shapes tile
+(`hybrid_lm.blocked_attention`'s other branch, which tests/test_sambay.py
+holds to whole [T, T] masked scores): the output and the three gradients at
+both cells' head shapes; the schedule, which is the grids, the masks' flags
+and the model's counters at once, against the masks themselves; the shapes
+that fall back; a SambaY stage whose attentions run the kernels; and the
+kernels compiled for a described v5e at the cells' real sizes.
+
+`blocked_attention` has no option that says where it runs: it asks
+`flash_attention.on_tpu()`. The tests that want its kernel branch on the CPU
+answer for the chip and hand it the kernels in interpret mode (`kernels_here`).
+
+Tolerances: in float32 the kernels differ from the loop by summation order
+(2e-6 of an array's norm); in bfloat16 the loop rounds normalised
+probabilities and the kernels unnormalised ones before the second product,
+and the backward pass rounds `ds` once where XLA's rounds it in the product
+(under 0.4% measured, 1% allowed).
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import weights_sambay as ws
+from glom_tpu.kernels import flash_attention as fa
+from glom_tpu.models import hybrid_lm, sambay
+from glom_tpu.utils.presets import get_preset
+
+# (KV heads, value heads, query heads a KV head, D, Dv): SambaY's pairs share
+# their values; the other model's one group of four
+HEADS = {"sambay_d64_dv128_r2": (2, 1, 2, 64, 128), "nemotron_d128_dv128_r4": (1, 1, 4, 128, 128)}
+TILE = 128
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def inputs(t, heads, dtype, seed=0, bsz=1):
+    g, gv, r, d, dv = heads
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (bsz, t, g, r, d), dtype),
+            jax.random.normal(ks[1], (bsz, t, g, d), dtype),
+            jax.random.normal(ks[2], (bsz, t, gv, dv), dtype),
+            jax.random.normal(ks[3], (bsz, t, g, r, dv), dtype))
+
+
+def out_and_grads(f, q, k, v, cot):
+    out, pull = jax.vjp(f, q, k, v)
+    return (out,) + pull(cot)
+
+
+@pytest.fixture
+def kernels_here(monkeypatch):
+    """`blocked_attention` takes its kernel branch on the CPU: the platform
+    answers as the chip's does, and the kernels run in interpret mode."""
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(fa.flash_attention, interpret=True))
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("query_tiles", [2, 5])
+@pytest.mark.parametrize("window", [None, 40, 200],
+                         ids=["full", "window_under_a_tile", "window_no_multiple_of_a_tile"])
+@pytest.mark.parametrize("heads", list(HEADS.values()), ids=list(HEADS))
+def test_the_kernels_match_the_xla_loop_forward_and_in_every_gradient(
+        heads, window, query_tiles, dtype):
+    q, k, v, cot = inputs(query_tiles * TILE, heads, dtype)
+    got = jax.jit(lambda *a: out_and_grads(
+        lambda q, k, v: fa.flash_attention(q, k, v, window, tq=TILE, tk=TILE, interpret=True),
+        *a))(q, k, v, cot)
+    want = jax.jit(lambda *a: out_and_grads(
+        lambda q, k, v: hybrid_lm.blocked_attention(q, k, v, window)[0], *a))(q, k, v, cot)
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert rel(a, b) < tol, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("tq, tk", [(128, 256), (256, 128)])
+def test_query_and_key_tiles_of_different_sizes(tq, tk):
+    q, k, v, cot = inputs(768, HEADS["sambay_d64_dv128_r2"], jnp.float32, seed=3, bsz=2)
+    for window in (None, 300):
+        got = out_and_grads(
+            lambda q, k, v: fa.flash_attention(q, k, v, window, tq=tq, tk=tk, interpret=True),
+            q, k, v, cot)
+        want = out_and_grads(lambda q, k, v: hybrid_lm.blocked_attention(q, k, v, window)[0],
+                             q, k, v, cot)
+        assert max(rel(a, b) for a, b in zip(got, want)) < 2e-6
+
+
+# ------------------------------------------------------------ dispatch by shape
+
+
+@pytest.mark.parametrize("t, heads", [(200, (2, 1, 2, 64, 128)), (256, (2, 2, 2, 32, 128)),
+                                      (256, (2, 2, 2, 64, 96))],
+                         ids=["length_no_multiple_of_128", "head_size_32", "value_size_96"])
+def test_a_shape_that_does_not_tile_takes_the_xla_loop(t, heads, monkeypatch):
+    """Also where the device is a TPU: the same results, the same count, no
+    kernel in the program."""
+    g, gv, r, d, dv = heads
+    assert fa.tiles(t, r, d, dv) is None
+    q, k, v, cot = inputs(t, heads, jnp.float32)
+    run = lambda: out_and_grads(lambda *a: hybrid_lm.blocked_attention(*a, 64)[0], q, k, v, cot)
+    want, want_blocks = run(), hybrid_lm.blocked_attention(q, k, v, 64)[1]
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention", None)   # not to be called
+    assert all(jnp.array_equal(a, b) for a, b in zip(run(), want))
+    assert hybrid_lm.blocked_attention(q, k, v, 64)[1] == want_blocks
+
+
+def test_blocked_attention_takes_the_kernels_where_the_shapes_tile_on_a_tpu(kernels_here):
+    """The program holds the two kernels by name, the counter is the
+    schedule's, and what comes out is what the XLA loop gives on the CPU."""
+    q, k, v, cot = inputs(256, HEADS["sambay_d64_dv128_r2"], jnp.float32)
+    attend = lambda *a: hybrid_lm.blocked_attention(*a, 64)
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(attend(*a)[0]), argnums=(0, 1, 2)))(
+        q, k, v))
+    assert "attn_flash_fwd" in text and "attn_flash_bwd_onesweep" in text
+    tq, tk = fa.tiles(256, 2, 64, 128, 64)
+    assert (tq, tk) == (256, 128)
+    assert attend(q, k, v)[1] == fa.key_blocks(256, tq, tk, 64, hybrid_lm.ATTN_KEY_BLOCK) == 2
+    got = out_and_grads(lambda *a: attend(*a)[0], q, k, v, cot)
+    with pytest.MonkeyPatch.context() as on_the_cpu:
+        on_the_cpu.setattr(fa, "on_tpu", lambda: False)
+        assert "attn_flash" not in str(jax.make_jaxpr(attend)(q, k, v))
+        want = out_and_grads(lambda *a: attend(*a)[0], q, k, v, cot)
+    assert max(rel(a, b) for a, b in zip(got, want)) < 2e-6
+
+
+def test_the_tiles_follow_the_shapes_and_the_window_alone():
+    assert fa.tiles(8192, 2, 64, 128) == fa.tiles(8192, 1, 64, 128) == (512, 1024)
+    assert fa.tiles(8192, 4, 128, 128) == (256, 1024)      # the folded rows stay at 1,024
+    assert fa.tiles(8192, 2, 64, 128, 512) == (512, 512)   # no key tile past the window
+    assert fa.tiles(8192, 2, 64, 128, 40) == (512, 128)
+    assert fa.tiles(8192, 2, 64, 128, 10 ** 6) == fa.tiles(8192, 2, 64, 128)
+    assert fa.tiles(384, 2, 64, 128) == (128, 128)
+    # a group's dq no longer resident: a longer sequence goes back to the loop
+    assert fa.tiles(8192, 4, 128, 128) and fa.tiles(32768, 4, 128, 128) is None
+
+
+# ------------------------------------------------------------------ the schedule
+
+
+def seen_mask(t, window):
+    q_pos, k_pos = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = k_pos <= q_pos
+    return seen if window is None else seen & (k_pos > q_pos - window)
+
+
+@pytest.mark.parametrize("t, tq, tk, window", [
+    (1024, 128, 128, None), (1024, 128, 128, 512), (1024, 256, 128, 100), (1024, 128, 256, 129),
+    (2048, 512, 512, 512), (2048, 256, 512, 1), (1536, 512, 256, 2000)])
+def test_the_schedule_is_the_tiles_whose_mask_has_a_true_entry(t, tq, tk, window):
+    """By brute force over the whole mask: a pair is a step exactly where
+    some query of the tile sees some key of the tile, is masked exactly where
+    it also holds a pair that is not seen, each order starts and ends its
+    runs at the outer tile's first and last pair, and the counter is the
+    steps in blocks of 128 keys."""
+    seen = seen_mask(t, window)
+    tile = lambda i, j: seen[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
+    want = {(i, j): not tile(i, j).all()
+            for i in range(t // tq) for j in range(t // tk) if tile(i, j).any()}
+    (qi, kj, flags), (kj2, qi2, flags2) = fa.schedule(t, tq, tk, window)
+    assert {(i, j): bool(f & fa._MASKED) for i, j, f in zip(qi, kj, flags)} == want
+    assert {(i, j): bool(f & fa._MASKED) for i, j, f in zip(qi2, kj2, flags2)} == want
+    for outer, inner, fl in ((qi, kj, flags), (kj2, qi2, flags2)):
+        assert all(a.dtype == np.int32 for a in (outer, inner, fl))
+        order = list(zip(outer.tolist(), inner.tolist()))
+        assert order == sorted(order)
+        starts = [n == 0 or outer[n] != outer[n - 1] for n in range(len(outer))]
+        ends = [n == len(outer) - 1 or outer[n] != outer[n + 1] for n in range(len(outer))]
+        assert [bool(f & fa._FIRST) for f in fl] == starts
+        assert [bool(f & fa._LAST) for f in fl] == ends
+    assert fa.key_blocks(t, tq, tk, window) == len(want) * tk // 128
+
+
+def test_the_windows_share_of_the_full_layers_key_blocks_at_8192_tokens():
+    """`window_keys_visited_pct.train` as PERF.md states it for the cell: the
+    counters' ratio at the tiles `phi4flash.train`'s shapes get. By the masks
+    alone it is 12.1%."""
+    window, full = (fa.key_blocks(8192, *fa.tiles(8192, 2, 64, 128, w), w) for w in (512, None))
+    assert (window, full) == (124, 576)   # 31 pairs of 512 x 512, 72 of 512 x 1,024
+    assert round(100 * window / full, 2) == 21.53
+    assert round(100 * seen_mask(8192, 512).sum() / seen_mask(8192, None).sum(), 1) == 12.1
+
+
+# ------------------------------------------- whole masked scores, and a SambaY stage
+
+
+def masked_softmax_attention(q, k, v, window=None):
+    """Whole [T, T] scores under the mask, value head g // (G / Gv): what the
+    tiles must add up to."""
+    t, g = q.shape[1], k.shape[2]
+    v = jnp.repeat(v, g // v.shape[2], axis=2)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k) * q.shape[-1] ** -0.5
+    return jnp.einsum("bgrqk,bkgd->bqgrd",
+                      jax.nn.softmax(jnp.where(seen_mask(t, window), s, -jnp.inf), -1), v)
+
+
+@pytest.mark.parametrize("window, same_as_full", [(384, True), (900, True), (100, False),
+                                                  (16, False), (1, False)])
+def test_a_window_at_least_the_length_is_full_attention_and_a_shorter_one_is_not(
+        window, same_as_full, kernels_here):
+    """Through `blocked_attention`, at three tiles of 128: results, gradients
+    and the count of key blocks. A window of 1 is every row's own key alone,
+    so each row's first tile is its last, and no row is a NaN."""
+    q, k, v, cot = inputs(384, HEADS["sambay_d64_dv128_r2"], jnp.float32, seed=1, bsz=2)
+    blocked = lambda window: jax.jit(lambda *a: hybrid_lm.blocked_attention(*a, window))
+    full, full_blocks = blocked(None)(q, k, v)
+    got, blocks = blocked(window)(q, k, v)
+    assert (rel(got, full) < 1e-6) == same_as_full
+    assert (blocks == full_blocks) == same_as_full and blocks <= full_blocks
+    grads = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * cot), argnums=(0, 1, 2)))(q, k, v)
+    got = (got,) + grads(lambda *a: hybrid_lm.blocked_attention(*a, window)[0])
+    want = (masked_softmax_attention(q, k, v, window),) + grads(
+        lambda *a: masked_softmax_attention(*a, window))
+    scale = float(jnp.linalg.norm(cot))   # under a window of 1 dq and dk are zero
+    for a, b in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.linalg.norm(a - b)) < 5e-6 * max(float(jnp.linalg.norm(b)), scale)
+
+
+# hidden 256 in 4 heads of 64 over one KV pair, 384 tokens: the shapes tile, so
+# with `kernels_here` the three attentions run the kernels at tiles of 128
+KERNEL_SHAPED = dataclasses.replace(
+    get_preset("sambay-tiny").model, layer_offset=2, num_hidden_layers=6,   # MWMFGX of N = 8
+    hidden_size=256, num_attention_heads=4, num_key_value_heads=2, sliding_window=160, seq_len=384)
+
+
+def test_no_position_of_a_sambay_stage_sees_a_later_token_through_the_kernels(kernels_here):
+    """tests/test_sambay.py's test of the same name, where the attentions are
+    the kernels: every token from position t on replaced, the logits before t
+    stay what they were, bit for bit, and the logits at t do not. t inside a
+    tile, at a tile's edge, past the window's reach, and the last. The
+    model's counters are the kernels' schedule, in the module's key blocks."""
+    cfg = KERNEL_SHAPED
+    w = ws.to_program_params(ws.make_weights(23, dataclasses.asdict(cfg)))
+
+    @jax.jit
+    def logits(ids):
+        x, counted = sambay.hidden_states(w, ids, cfg)
+        h = sambay.layer_norm(x, w["final_norm_w"], w["final_norm_b"], cfg.layer_norm_eps)
+        return jnp.einsum("btd,vd->btv", h, w["embed"]), counted
+
+    ids = jax.random.randint(jax.random.PRNGKey(41), (2, cfg.seq_len), 0, cfg.vocab_size)
+    assert "attn_flash_fwd" in str(jax.make_jaxpr(logits)(ids))
+    base, counted = logits(ids)
+    tiles = lambda window: fa.tiles(cfg.seq_len, 2, cfg.head_dim, 2 * cfg.head_dim, window)
+    assert tiles(None) == tiles(cfg.sliding_window) == (128, 128)
+    blocks = [c.get("attn_key_blocks_window", c.get("attn_key_blocks_full"))
+              for kind, c in zip(cfg.kinds, counted) if kind in "WFX"]
+    assert blocks == [fa.key_blocks(cfg.seq_len, *tiles(window), window, hybrid_lm.ATTN_KEY_BLOCK)
+                      for window in (cfg.sliding_window, None, None)] == [1 + 2 + 3, 6, 6]
+    for t in (7, 128, 200, 382):
+        later = ids.at[:, t:].set((ids[:, t:] + 1 + t) % cfg.vocab_size)
+        got, _ = logits(later)
+        assert jnp.array_equal(got[:, :t], base[:, :t]), t
+        assert not jnp.array_equal(got[:, t], base[:, t]), t
+
+
+# ----------------------------------------------- compiled for the chip, not run
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("heads, window", [((20, 10, 2, 64, 128), None), ((20, 10, 2, 64, 128), 512),
+                                           ((1, 1, 4, 128, 128), None)],
+                         ids=["phi4flash_full", "phi4flash_window", "nemotron3super"])
+def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes(one_chip, heads, window):
+    """8,192 tokens in bfloat16 at the tiles the shapes get: Mosaic takes the
+    layouts, the transposed product and the group's resident dq, which
+    interpret mode cannot say. Nothing runs."""
+    g, gv, r, d, dv = heads
+    t = 8192
+    tq, tk = fa.tiles(t, r, d, dv, window)
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda *a: out_and_grads(
+        lambda q, k, v: fa.flash_attention(q, k, v, window, tq=tq, tk=tk), *a)).lower(
+        arg(1, t, g, r, d), arg(1, t, g, d), arg(1, t, gv, dv), arg(1, t, g, r, dv)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert f"{t},{t}]" not in text and f"{tq},{t}]" not in text   # no [queries, keys] array

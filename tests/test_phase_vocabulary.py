@@ -15,11 +15,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from glom_tpu.tracing.spans import DEVICE_PHASES, HOST_PHASES, PHASES
+from glom_tpu.tracing.spans import DEVICE_PHASES, HOST_PHASES, LM_KERNELS, PHASES
 from glom_tpu.utils.config import GlomConfig, TrainConfig
 
 KERNELS = pathlib.Path(__file__).resolve().parent.parent / "glom_tpu" / "kernels"
-N_PALLAS_CALLS = 21
+N_PALLAS_CALLS = 23
 
 
 def _pallas_call_names():
@@ -50,9 +50,21 @@ class TestKernelNames:
 
     def test_names_start_with_a_phase_and_say_their_direction(self):
         for fname, line, name in _pallas_call_names():
-            assert any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), (
-                fname, line, name)
+            if fname != "flash_attention.py":   # the language models' are held by name, below
+                assert any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), (
+                    fname, line, name)
             assert {"fwd", "bwd"} & set(name.split("_")), (fname, line, name)
+
+    def test_the_language_models_kernels_are_the_vocabularys_and_no_routes_name(self):
+        """Every site of kernels/flash_attention.py goes by a name of
+        LM_KERNELS and every name has a site; each begins with `attn_`, so
+        none reads as a GLOM phase's kernel (`loop_*`, `ffw_*`,
+        `consensus_*`: what `route_kernels` forbids on the route `lm_xla`)."""
+        sites = [name for fname, _, name in _pallas_call_names() if fname == "flash_attention.py"]
+        assert sorted(sites) == sorted(LM_KERNELS)
+        for name in LM_KERNELS:
+            assert name.startswith("attn_")
+            assert not any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), name
 
     def test_vocabulary_is_host_then_device_without_repeats(self):
         assert PHASES == HOST_PHASES + DEVICE_PHASES
