@@ -15,7 +15,18 @@ Layout inside: heads before positions, q [B, G, R, T, D], k [B, G, T, D], v
 [B, Gv, T, Dv] (G KV heads of R query heads each; KV head g reads value head
 g // (G / Gv): SambaY's pairs share their values, and an index map does what
 a broadcast copy would). The R query heads of a KV head fold into the query
-tile's rows, so a key tile is loaded once a group.
+tile's rows, so a key tile is loaded once a group; where a whole group's dq
+would not stay resident in the backward (`head_parts`), the group folds in
+equal parts, each a grid row of its own that reads the same KV head by the
+same kind of index map, and the parts' dk are added outside.
+
+Shapes served: T a multiple of 128, D and Dv multiples of 64, and some equal
+part of a group whose dq [R / parts, T, max(D, 128)] float32 fits
+DQ_RESIDENT_BYTES twice. At D = 128 that is every R to T = 32,768 (T = 8,192:
+R = 4 and R = 6 whole, R = 8 in two parts of 4; T = 16,384: R = 4 in two parts,
+R = 6 in two parts of 3, R = 8 in four); at T = 65,536 and beyond a single
+head's dq is 64 MiB and the shape goes to the XLA loop, as does any length
+that is no multiple of 128 and any head size that is no multiple of 64.
 
 `schedule` is the one place that says which (query tile, key tile) pairs
 exist: the grids are its steps (scalar-prefetched, so a pair no query of
@@ -47,9 +58,11 @@ from jax.experimental.pallas import tpu as pltpu
 QUERY_TILES = (512, 256, 128)
 KEY_TILES = (1024, 512, 256, 128)
 QUERY_ROWS = 1024  # a query tile's rows, the group's R heads folded in
-# The backward keeps a group's whole dq [R, T, max(D, 128)] float32 in VMEM,
-# twice (the pipeline's two buffers); past this it does not fit beside the
-# score tiles, and `tiles` sends the shape to the XLA loop.
+# The backward keeps the dq [R, T, max(D, 128)] float32 of the query heads
+# folded into one grid row in VMEM, twice (the pipeline's two buffers); past
+# this it does not fit beside the score tiles, and `head_parts` folds the group
+# in parts, or, where one head's is too much, `tiles` sends the shape to the
+# XLA loop.
 DQ_RESIDENT_BYTES = 48 * 1024 * 1024
 VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 _NEG = -1e30  # a masked score: finite, so that exp(m - m) of a row not yet seen is no NaN
@@ -66,11 +79,21 @@ def on_tpu() -> bool:
 # ------------------------------------------------------------------- schedule
 
 
+def head_parts(t: int, r: int, d: int):
+    """In how many equal parts a KV head's R query heads fold into query
+    tiles: the fewest whose dq stays resident (1 where the whole group's
+    does: 2 * R * T * max(D, 128) * 4 bytes <= DQ_RESIDENT_BYTES, an equality
+    at R = 6, D = 128, T = 8,192), or None where one head's does not."""
+    return next((parts for parts in range(1, r + 1) if r % parts == 0
+                 and 2 * (r // parts) * t * max(d, 128) * 4 <= DQ_RESIDENT_BYTES), None)
+
+
 def tiles(t: int, r: int, d: int, dv: int, window=None):
     """(query tile, key tile) for T positions, R query heads a KV head and
     head sizes D and Dv, or None where the kernels do not serve the shape:
-    T a multiple of 128, D and Dv of 64, and the group's dq resident. The
-    query tile is the largest that keeps the folded rows at QUERY_ROWS. The
+    T a multiple of 128, D and Dv of 64, and some part of the group's dq
+    resident (`head_parts`; the module docstring lists what that serves). The
+    query tile is the largest that keeps a part's folded rows at QUERY_ROWS. The
     key tile is the largest there is, and under a window the largest no
     longer than the window: the forward takes its row maxima, which reduce
     across lanes, once a (row, key tile) whatever the tile's width (a SambaY
@@ -78,10 +101,10 @@ def tiles(t: int, r: int, d: int, dv: int, window=None):
     8.1 at 2,048, where the tiles the diagonal crosses waste more than the
     maxima save; PR 32's builder's chip runs), and a key tile past the window is
     keys that no query of the tile sees."""
-    if t % 128 or d % 64 or dv % 64:
+    parts = head_parts(t, r, d)
+    if t % 128 or d % 64 or dv % 64 or parts is None:
         return None
-    if 2 * r * t * max(d, 128) * 4 > DQ_RESIDENT_BYTES:
-        return None
+    r //= parts
     reach = t if window is None else max(window, 128)
     tq = next(tile for tile in QUERY_TILES if t % tile == 0 and (
         r * tile <= QUERY_ROWS or tile == QUERY_TILES[-1]))
@@ -186,11 +209,12 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _forward(q, k, v, window, tq, tk, interpret):
-    """Head-major q [B, G, R, T, D], k [B, G, T, D], v [B, Gv, T, Dv] ->
+    """Head-major q [B, G, R, T, D], k [B, Gk, T, D], v [B, Gv, T, Dv] (G a
+    multiple of Gk and of Gv: grid row h reads key head h // (G / Gk)) ->
     (o [B, G, R, T, Dv] in q's type, log-sum-exp [B, G, R, T, 1] float32)."""
     bsz, g, r, t, d = q.shape
     dv = v.shape[-1]
-    share = g // v.shape[1]
+    k_share, share = g // k.shape[1], g // v.shape[1]
     qi, kj, flags = schedule(t, tq, tk, window)[0]
     f32 = jnp.float32
     return pl.pallas_call(
@@ -202,7 +226,8 @@ def _forward(q, k, v, window, tq, tk, interpret):
             grid=(bsz, g, len(qi)),
             in_specs=[
                 pl.BlockSpec((1, 1, r, tq, d), lambda b, h, s, qi, kj, fl: (b, h, 0, qi[s], 0)),
-                pl.BlockSpec((1, 1, tk, d), lambda b, h, s, qi, kj, fl: (b, h, kj[s], 0)),
+                pl.BlockSpec((1, 1, tk, d),
+                             lambda b, h, s, qi, kj, fl: (b, h // k_share, kj[s], 0)),
                 pl.BlockSpec((1, 1, tk, dv),
                              lambda b, h, s, qi, kj, fl: (b, h // share, kj[s], 0)),
             ],
@@ -286,11 +311,11 @@ def _tile_rows(x, tq):
 
 def _backward(q, k, v, o, lse, do, window, tq, tk, interpret):
     """The three gradients, head-major: dq in float32 and unscaled (the
-    caller scales it as it casts), dk, and dv for every KV head (the caller
-    adds those that share a value head)."""
+    caller scales it as it casts), and dk and dv for every grid row (the
+    caller adds those that share a key head or a value head)."""
     bsz, g, r, t, d = q.shape
     dv = v.shape[-1]
-    share = g // v.shape[1]
+    k_share, share = g // k.shape[1], g // v.shape[1]
     kj, qi, flags = schedule(t, tq, tk, window)[1]
     f32 = jnp.float32
     delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)
@@ -302,14 +327,15 @@ def _backward(q, k, v, o, lse, do, window, tq, tk, interpret):
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=d ** -0.5, window=window, tq=tq, tk=tk),
         out_shape=(jax.ShapeDtypeStruct((bsz, g, r, t, d), f32),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct((bsz, g, t, d), k.dtype),
                    jax.ShapeDtypeStruct((bsz, g, t, dv), v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bsz, g, len(kj)),
             in_specs=[
                 q_spec(d),
-                k_spec(d),
+                pl.BlockSpec((1, 1, tk, d),
+                             lambda b, h, s, kj, qi, fl: (b, h // k_share, kj[s], 0)),
                 pl.BlockSpec((1, 1, tk, dv),
                              lambda b, h, s, kj, qi, fl: (b, h // share, kj[s], 0)),
                 q_spec(dv),   # do
@@ -347,10 +373,15 @@ def _flash_fwd(q, k, v, window, tq, tk, interpret):
 def _flash_bwd(window, tq, tk, interpret, kept, do):
     q, k, v, o, lse = kept
     dq, dk, dv = _backward(q, k, v, o, lse, do, window, tq, tk, interpret)
-    if dv.shape != v.shape:   # KV heads that share a value head
-        bsz, gv, t, width = v.shape
-        dv = jnp.sum(dv.reshape(bsz, gv, -1, t, width).astype(jnp.float32), axis=2)
-    return (dq * q.shape[-1] ** -0.5).astype(q.dtype), dk, dv.astype(v.dtype)
+
+    def shared(dx, x):   # grid rows that share a key head or a value head
+        if dx.shape == x.shape:
+            return dx
+        bsz, heads, t, width = x.shape
+        return jnp.sum(dx.reshape(bsz, heads, -1, t, width).astype(jnp.float32),
+                       axis=2).astype(x.dtype)
+
+    return (dq * q.shape[-1] ** -0.5).astype(q.dtype), shared(dk, k), shared(dv, v)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -359,7 +390,10 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, window=None, *, tq: int, tk: int, interpret: bool = False):
     """q [B, T, G, R, D], k [B, T, G, D], v [B, T, Gv, Dv] (G a multiple of
     Gv) -> [B, T, G, R, Dv], differentiable in all three, by the kernels at
-    the tiles given (`tiles` chooses them; T a multiple of both)."""
-    o = _flash(q.transpose(0, 2, 3, 1, 4), k.swapaxes(1, 2), v.swapaxes(1, 2),
-               window, tq, tk, interpret)
-    return o.transpose(0, 3, 1, 2, 4)
+    the tiles given (`tiles` chooses them; T a multiple of both). A group
+    folds into the query tiles in `head_parts` parts."""
+    bsz, t, g, r, d = q.shape
+    parts = head_parts(t, r, d)
+    o = _flash(q.transpose(0, 2, 3, 1, 4).reshape(bsz, g * parts, r // parts, t, d),
+               k.swapaxes(1, 2), v.swapaxes(1, 2), window, tq, tk, interpret)
+    return o.reshape(bsz, g, r, t, -1).transpose(0, 3, 1, 2, 4)

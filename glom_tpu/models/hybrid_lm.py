@@ -16,7 +16,9 @@ the experts held (`lax.ragged_dot`), a weighted combine. The rows are the
 smallest rung that holds this step's pairs, of a short static ladder chosen
 on the device (`row_rungs`); the last rung is the worst imbalance (every
 token choosing every held expert), so no pair is ever dropped; what the
-absent experts would have added is left out.
+absent experts would have added is left out. That routed part (`moe_routed`)
+is also `models/laguna.py`'s: what a group's rows go through and whether a
+latent step wraps it is its `family` (LATENT_RELU2 here, SWIGLU there).
 
 XLA, but for the attention's scores: where the shapes tile and the device is
 a TPU, `blocked_attention` runs the Pallas kernels of
@@ -312,7 +314,7 @@ def attention_mixer(p, x_in, cfg: HybridLMConfig, dtype):
 # ---------------------------------------------------- latent mixture of experts
 
 
-def route(p, u2, cfg: HybridLMConfig):
+def route(p, u2, cfg):
     """The router: u2 [N, d] -> the chosen experts [N, k] (of all the router
     scores) and their weights [N, k]. Scores are sigmoids of a float32
     product; the k largest are chosen (one group, so plain top-k; the
@@ -323,13 +325,13 @@ def route(p, u2, cfg: HybridLMConfig):
     return top_i, top_s / jnp.sum(top_s, axis=-1, keepdims=True) * cfg.routed_scaling_factor
 
 
-def row_rungs(n: int, cfg: HybridLMConfig) -> Tuple[int, ...]:
+def row_rungs(n: int, cfg, loads=RUNG_LOADS) -> Tuple[int, ...]:
     """The row counts the routed experts' part may run at for n tokens,
     ascending. The last is the full count, n * min(k, experts held) +
     experts held rounded up to ROW_TILE: room for every pair whatever the
     imbalance, and a row of room inside every expert's group, so that the
-    grouped product never meets an empty group. Before it, RUNG_LOADS times
-    the pairs a balanced router sends here (n * k * experts held / experts
+    grouped product never meets an empty group. Before it, `loads` times the
+    pairs a balanced router sends here (n * k * experts held / experts
     scored), rounded up likewise, where that is fewer rows than the full
     count: a share that holds most of the experts has one rung."""
     k, e = cfg.num_experts_per_tok, cfg.n_routed_experts
@@ -337,13 +339,13 @@ def row_rungs(n: int, cfg: HybridLMConfig) -> Tuple[int, ...]:
     # is no multiple of the tile is tiled by 8 (65,544 rows took 70 times
     # 65,536's time on a v5e)
     tile = lambda rows: -(-rows // ROW_TILE) * ROW_TILE
-    full = min(tile(n * min(k, e) + e), n * k + e)
+    full = tile(n * min(k, e) + e)  # past `dispatch`'s n * k + e rows where k <= e: padded
     balanced = n * k * e / cfg.n_routed_experts_total
-    small = sorted({tile(math.ceil(load * balanced)) for load in RUNG_LOADS})
+    small = sorted({tile(math.ceil(load * balanced)) for load in loads})
     return tuple(rows for rows in small if rows < full) + (full,)
 
 
-def dispatch(top_i, cfg: HybridLMConfig):
+def dispatch(top_i, cfg):
     """Sort the token-expert pairs by expert, those of the experts held here
     first, each expert's group closed by its row of room. Returns, for all
     N * k + experts held rows of the sorted order (the first
@@ -364,15 +366,35 @@ def dispatch(top_i, cfg: HybridLMConfig):
     return jnp.minimum(row, n * k - 1), valid, group_sizes
 
 
-def expert_rows(rows: int, k: int, diff, order):
+def relu2_experts(x, valid, group_sizes, w1, w2):
+    """A group's rows through its expert, W2 relu2(W1 x): the latent
+    mixture's."""
+    h = jax.lax.ragged_dot(x, w1.astype(x.dtype), group_sizes)
+    return jax.lax.ragged_dot(relu2(jnp.where(valid, h, 0)), w2.astype(x.dtype), group_sizes)
+
+
+def swiglu_experts(x, valid, group_sizes, w_gate, w_up, w_down):
+    """A group's rows through its expert, W_down (silu(W_gate x) * W_up x)."""
+    gate = jax.lax.ragged_dot(x, w_gate.astype(x.dtype), group_sizes)
+    up = jax.lax.ragged_dot(x, w_up.astype(x.dtype), group_sizes)
+    h = jax.nn.silu(jnp.where(valid, gate, 0)) * jnp.where(valid, up, 0)
+    return jax.lax.ragged_dot(h, w_down.astype(x.dtype), group_sizes)
+
+
+def expert_rows(rows: int, k: int, experts, diff, order):
     """The part of the routed experts whose arrays have a row a pair, at a
-    static count of `rows` that holds sum(group_sizes): gather, the two
-    grouped products, weights, scatter back, latent up-projection. `diff` =
-    (v [N, latent], top_w [N, k], and the parameters w1, w2, up, which
-    multiply in v's type); `order` is `dispatch`'s."""
-    v, top_w, w1, w2, up = diff
+    static count of `rows` that holds sum(group_sizes): gather, the grouped
+    products, weights, scatter back and, where there is a latent step, its
+    up-projection. `experts(x, valid, group_sizes, *weights)` is what a
+    group's rows go through (`relu2_experts`, `swiglu_experts`); `diff` = (v
+    [N, width], top_w [N, k], the experts' weights, and the latent
+    up-projection or None; the parameters multiply in v's type); `order` is
+    `dispatch`'s."""
+    v, top_w, weights, up = diff
     pair, valid, group_sizes = order
     with jax.named_scope("moe_dispatch"):
+        if rows > pair.shape[0]:   # the full count's rounding up, where k <= experts held
+            pair, valid = (jnp.pad(a, (0, rows - a.shape[0])) for a in (pair, valid))
         pair, valid = pair[:rows], valid[:rows, None]
         token = pair // k
         x = jnp.where(valid, v[token], 0)
@@ -381,66 +403,75 @@ def expert_rows(rows: int, k: int, diff, order):
         # product leaves them unwritten, forward and backward (NaN among
         # them): they are zeroed by a select wherever they come out, before
         # anything multiplies them.
-        h = jax.lax.ragged_dot(x, w1.astype(v.dtype), group_sizes)
-        h = relu2(jnp.where(valid, h, 0))
-        y = jax.lax.ragged_dot(h, w2.astype(v.dtype), group_sizes)
+        y = experts(x, valid, group_sizes, *weights)
     with jax.named_scope("moe_combine"):
         # masked before it is weighted: a product with an unwritten row would
         # carry its NaN into the weights' gradient, whatever the cotangent
         y = jnp.where(valid, y, 0) * top_w.reshape(-1)[pair][:, None]
         routed = jax.ops.segment_sum(y, token, num_segments=v.shape[0]).astype(v.dtype)
-        return _mm(routed, up.astype(v.dtype)).astype(v.dtype)
+        return routed if up is None else _mm(routed, up.astype(v.dtype)).astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def on_the_ladder(rungs, k, rung, diff, order):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def on_the_ladder(rungs, k, experts, rung, diff, order):
     """`expert_rows` at rungs[rung], chosen on the device. Differentiable in
     `diff`; what the backward pass keeps is the operands, which no rung
     sizes: it chooses the rung again and differentiates `expert_rows` inside
     the branch. (Differentiating the `switch` itself makes every branch
     return every branch's intermediates, the full rung's among them, as
     zeros where it did not run.)"""
-    return jax.lax.switch(rung, [functools.partial(expert_rows, rows, k) for rows in rungs],
-                          diff, order)
+    return jax.lax.switch(
+        rung, [functools.partial(expert_rows, rows, k, experts) for rows in rungs], diff, order)
 
 
-def _ladder_fwd(rungs, k, rung, diff, order):
-    return on_the_ladder(rungs, k, rung, diff, order), (rung, diff, order)
+def _ladder_fwd(rungs, k, experts, rung, diff, order):
+    return on_the_ladder(rungs, k, experts, rung, diff, order), (rung, diff, order)
 
 
-def _ladder_bwd(rungs, k, kept, g):
+def _ladder_bwd(rungs, k, experts, kept, g):
     rung, diff, order = kept
 
     def pull(rows):
         return lambda diff, order, g: jax.vjp(
-            lambda diff: expert_rows(rows, k, diff, order), diff)[1](g)[0]
+            lambda diff: expert_rows(rows, k, experts, diff, order), diff)[1](g)[0]
 
     return None, jax.lax.switch(rung, [pull(rows) for rows in rungs], diff, order, g), None
 
 
 on_the_ladder.defvjp(_ladder_fwd, _ladder_bwd)
 
+# What a family's routed experts are: the function a group's rows go through,
+# the names of its weights in the layer's parameters, and the latent down- and
+# up-projections that wrap it, or None.
+LATENT_RELU2 = (relu2_experts, ("w1", "w2"), ("down", "up"))
+SWIGLU = (swiglu_experts, ("e_gate", "e_up", "e_down"), None)
 
-def moe_routed(p, u2, cfg: HybridLMConfig, dtype, choices=None):
+
+def moe_routed(p, u2, cfg, dtype, choices=None, family=LATENT_RELU2, rung_loads=RUNG_LOADS):
     """The routed experts' part, for the experts held here: u2 [N, d] ->
-    ([N, d], counters). `choices` (top_i, weights) replaces the router's.
-    The row-sized part runs at the smallest of `row_rungs` that holds this
-    step's pairs and the rows of room."""
+    ([N, d], counters, the router's choices). `choices` (top_i, weights)
+    replaces the router's. `family` says what the experts are (LATENT_RELU2,
+    SWIGLU); `cfg` is any configuration with the router's and the share's
+    counts (`route`, `dispatch`, `row_rungs`). The row-sized part runs at the
+    smallest of `row_rungs` that holds this step's pairs and the rows of room;
+    `rung_loads` is for a job whose router is known to be out of balance (a
+    configuration's to say, not a family's: `LagunaConfig.moe_rung_loads`)."""
+    experts, names, latent = family
     k = cfg.num_experts_per_tok
-    rungs = row_rungs(u2.shape[0], cfg)
+    rungs = row_rungs(u2.shape[0], cfg, rung_loads)
     with jax.named_scope("moe_router"):
         top_i, top_w = route(p, u2, cfg) if choices is None else choices
     with jax.named_scope("moe_dispatch"):
-        v = _mm(u2, _cast(p["down"], dtype)).astype(u2.dtype)
+        v = u2 if latent is None else _mm(u2, _cast(p[latent[0]], dtype)).astype(u2.dtype)
         order = _, valid, group_sizes = dispatch(top_i, cfg)
         # the first rung that holds the pairs and the rows of room
         rung = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(rungs[:-1], jnp.int32),
                        dtype=jnp.int32)
-    diff = (v, top_w, p["w1"], p["w2"], p["up"])
+    diff = (v, top_w, tuple(p[name] for name in names), None if latent is None else p[latent[1]])
     if len(rungs) == 1:
-        out = expert_rows(rungs[0], k, diff, order)
+        out = expert_rows(rungs[0], k, experts, diff, order)
     else:
-        out = on_the_ladder(rungs, k, rung, diff, order)
+        out = on_the_ladder(rungs, k, experts, rung, diff, order)
     with jax.named_scope("step_metrics"):
         counters = {
             "moe_pairs_here": jnp.sum(valid).astype(jnp.float32),
@@ -449,6 +480,17 @@ def moe_routed(p, u2, cfg: HybridLMConfig, dtype, choices=None):
             "moe_max_expert_load": (jnp.max(group_sizes) - 1).astype(jnp.float32),
         }
     return out, counters, top_i
+
+
+def merge_counters(counters) -> dict:
+    """One dict of COUNTERS an expert layer -> each the mean over the layers,
+    the fullest expert's load the maximum; {} where there is no expert layer."""
+    merged = {}
+    if counters:
+        for name in COUNTERS:
+            vals = jnp.stack([c[name] for c in counters])
+            merged[name] = jnp.max(vals) if name == "moe_max_expert_load" else jnp.mean(vals)
+    return merged
 
 
 def moe_shared(p, u2, dtype):
@@ -555,11 +597,7 @@ def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
             -1, x.shape[-1])
         loss = next_token_loss(h, _cast(params["head"], compute_dtype), ids)
     with jax.named_scope("step_metrics"):
-        merged = {}
-        if counters:
-            for name in COUNTERS:
-                vals = jnp.stack([c[name] for c in counters])
-                merged[name] = jnp.max(vals) if name == "moe_max_expert_load" else jnp.mean(vals)
+        merged = merge_counters(counters)
     return loss, merged
 
 

@@ -109,11 +109,42 @@ SAMBAY_DEVICE_PHASES = (
     "lm_head_loss",
 )
 
+# The device scopes of the Laguna language model's step (models/laguna.py).
+# `embed`, the four `moe_*` scopes of the routed part, `moe_shared` and
+# `lm_head_loss` mean what they mean in LM_DEVICE_PHASES (the routed part is
+# the same code; it has no latent step here, and `moe_router` holds the
+# layer's second norm); `window_attention` and `full_attention` are a layer's
+# whole first half by its kind: input norm, projections, the rotation, the
+# scores (the kernels' calls), the gate, the out-projection; `dense_mlp` is
+# the leading layers' SwiGLU with its norm.
+LAGUNA_DEVICE_PHASES = (
+    "embed",
+    "window_attention",
+    "full_attention",
+    "dense_mlp",
+    "moe_router",
+    "moe_dispatch",
+    "moe_experts",
+    "moe_combine",
+    "moe_shared",
+    "lm_head_loss",
+)
+
+# Scopes opened inside an attention scope of LAGUNA_DEVICE_PHASES: an op
+# under one of them belongs to the attention phase round it, and a reader that
+# wants the part alone finds it by this name in the op's scope path.
+LAGUNA_INNER_SCOPES = (
+    # the rotary positions on q and k (float32 cos and sin, YaRN or default)
+    "rope",
+    # the sigmoid gate a query head on the attention's output, with its product
+    "attn_gate",
+)
+
 # The language models' Pallas kernels, by their `name=`
 # (kernels/flash_attention.py). They open no scope of their own: a call runs
 # inside the model's attention scope (`attention`; `window_attention`,
-# `full_attention`, `cross_attention`), and its device time belongs to that
-# scope. All begin with `attn_`; none matches `loop_*`, `ffw_*`,
+# `full_attention`, `cross_attention`; Laguna's two), and its device time
+# belongs to that scope. All begin with `attn_`; none matches `loop_*`, `ffw_*`,
 # `consensus_*` or `ragged-dot*`, the names by which the benchmark tells the
 # routes apart.
 LM_KERNELS = (

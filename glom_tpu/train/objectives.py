@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from glom_tpu.models.core import ConsensusFn, GlomParams, glom_forward, init_glom
 from glom_tpu.ops.patch import LinearParams, init_linear, tokens_to_image
-from glom_tpu.utils.config import GlomConfig, SambaYConfig
+from glom_tpu.utils.config import GlomConfig, LagunaConfig, SambaYConfig
 
 
 class DenoiseParams(NamedTuple):
@@ -68,13 +68,17 @@ class Objective(NamedTuple):
 
 
 def _lm_family(cfg):
-    """(init, loss) of the language-model family `cfg` configures: both
+    """(init, loss) of the language-model family `cfg` configures: the
     families share a stack and a loss, `init(key, cfg)` and `loss(params,
     ids, cfg, compute_dtype=, remat=) -> (loss, counters)`."""
     if isinstance(cfg, SambaYConfig):
         from glom_tpu.models.sambay import init_sambay, lm_loss
 
         return init_sambay, lm_loss
+    if isinstance(cfg, LagunaConfig):
+        from glom_tpu.models.laguna import init_laguna, lm_loss
+
+        return init_laguna, lm_loss
     from glom_tpu.models.hybrid_lm import init_hybrid_lm, lm_loss
 
     return init_hybrid_lm, lm_loss
@@ -88,10 +92,11 @@ def init_params(key: jax.Array, cfg):
 
 
 def lm_objective(cfg, tcfg) -> Objective:
-    """Next-token cross-entropy of a language model (models/hybrid_lm.py or
-    models/sambay.py, by the configuration's type) on [batch, seq_len] token
-    ids. Nothing is drawn; the model's step counters are the aux. One route:
-    XLA but for attention's scores, per-layer recomputation by `tcfg.remat`."""
+    """Next-token cross-entropy of a language model (models/hybrid_lm.py,
+    models/sambay.py or models/laguna.py, by the configuration's type) on
+    [batch, seq_len] token ids. Nothing is drawn; the model's step counters
+    are the aux. One route: XLA but for attention's scores, per-layer
+    recomputation by `tcfg.remat`."""
     lm_loss = _lm_family(cfg)[1]
 
     if tcfg.compute_dtype not in ("float32", "bfloat16"):

@@ -228,6 +228,111 @@ def layer_kind(i: int, n: int) -> str:
 
 
 @dataclasses.dataclass(frozen=True)
+class LagunaConfig:
+    """A Laguna language model (models/laguna.py, `model_type: laguna`):
+    pre-norm residual layers `h += Attn(RMSNorm(h)); h += MLP(RMSNorm(h))`
+    whose attention is, by the published layer index, causal and full (`F`
+    in `layer_types`: `num_attention_heads` query heads, the first
+    `partial_rotary_factor` of a head's dimensions rotated by YaRN
+    frequencies) or under a window of `sliding_window` keys (`S`:
+    `num_sliding_attention_heads` query heads, every dimension rotated by the
+    default frequencies), over `num_key_value_heads` KV heads either way, with
+    a sigmoid gate a query head on its output; and whose MLP is a dense
+    SwiGLU (`D` in `mlp_layer_types`) or a router over `num_experts_total`
+    SwiGLU experts, `num_experts_per_tok` a token, beside one shared expert
+    (`E`). Field names are those of the published config.json where it has
+    them (its per-layer lists are the two strings and the two head counts;
+    its `rope_parameters` the `rope_*` and `yarn_*` fields); the defaults
+    are Laguna-XS.2's.
+
+    `num_hidden_layers` layers from `layer_offset` on, experts
+    `expert_offset .. expert_offset + num_experts` of the `num_experts_total`
+    the router scores, and `vocab_size` rows of the embedding and of the
+    untied head are what THIS chip holds. Widths and head counts are never a
+    share. `moe_rung_loads` sizes the routed experts' small row count in
+    balanced loads (`hybrid_lm.row_rungs`; the default is `hybrid_lm.RUNG_LOADS`,
+    one rung of two): a job whose router is out of balance says more, and pays
+    for the rows."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 8192
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 100352
+    layer_types: str = "FSSS" * 10
+    mlp_layer_types: str = "D" + "E" * 39
+    layer_offset: int = 0
+    num_hidden_layers: int = 40
+    num_hidden_layers_total: int = 40
+    # attention
+    num_attention_heads: int = 48
+    num_sliding_attention_heads: int = 64
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 512
+    partial_rotary_factor: float = 0.5
+    rope_theta_full: float = 500000.0
+    rope_theta_sliding: float = 10000.0
+    yarn_factor: float = 64.0
+    yarn_original_max_position_embeddings: int = 4096
+    yarn_beta_fast: float = 64.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.4158883083359672
+    # experts
+    num_experts: int = 256
+    num_experts_total: int = 256
+    expert_offset: int = 0
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    moe_routed_scaling_factor: float = 2.5
+    moe_rung_loads: int = 2
+    # the training sequence: tokens of one packed row of the batch
+    seq_len: int = 8192
+
+    def __post_init__(self):
+        n, first = self.num_hidden_layers_total, self.layer_offset
+        if (len(self.layer_types) != n or len(self.mlp_layer_types) != n
+                or set(self.layer_types) - set("FS") or set(self.mlp_layer_types) - set("DE")):
+            raise ValueError(f"{n} layers want {n} letters of 'F', 'S' and of 'D', 'E'")
+        if first < 0 or self.num_hidden_layers < 1 or first + self.num_hidden_layers > n:
+            raise ValueError(f"layers {first}..{first + self.num_hidden_layers} of {n}")
+        if (self.num_attention_heads % self.num_key_value_heads
+                or self.num_sliding_attention_heads % self.num_key_value_heads):
+            raise ValueError("query heads must divide evenly over the KV heads")
+        if self.expert_offset + self.num_experts > self.num_experts_total:
+            raise ValueError("the experts held lie outside the router's width")
+        if self.rotary_dim("F") % 2 or self.rotary_dim("F") > self.head_dim:
+            raise ValueError("the rotated part of a head is an even count of its dimensions")
+
+    @property
+    def kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(attention, MLP) letters of the layers held here, in order."""
+        held = slice(self.layer_offset, self.layer_offset + self.num_hidden_layers)
+        return tuple(zip(self.layer_types[held], self.mlp_layer_types[held]))
+
+    def heads(self, attention: str) -> int:
+        """Query heads of a layer of attention kind `F` or `S`."""
+        return self.num_attention_heads if attention == "F" else self.num_sliding_attention_heads
+
+    def rotary_dim(self, attention: str) -> int:
+        return int(self.head_dim * self.partial_rotary_factor) if attention == "F" else (
+            self.head_dim)
+
+    # what `hybrid_lm`'s router, sort and row ladder read of a configuration
+    @property
+    def n_routed_experts(self) -> int:
+        return self.num_experts
+
+    @property
+    def n_routed_experts_total(self) -> int:
+        return self.num_experts_total
+
+    @property
+    def routed_scaling_factor(self) -> float:
+        return self.moe_routed_scaling_factor
+
+
+@dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Parallelism layout. Axis sizes of 1 disable an axis.
 

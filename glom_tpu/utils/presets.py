@@ -14,6 +14,7 @@ from typing import Dict, Union
 from glom_tpu.utils.config import (
     GlomConfig,
     HybridLMConfig,
+    LagunaConfig,
     MeshConfig,
     SambaYConfig,
     ServeConfig,
@@ -28,7 +29,7 @@ class Preset:
     description: str
     # The family the preset trains: its type picks the objective
     # (train/trainer.objective_for).
-    model: Union[GlomConfig, HybridLMConfig, SambaYConfig]
+    model: Union[GlomConfig, HybridLMConfig, SambaYConfig, LagunaConfig]
     train: TrainConfig
     mesh: MeshConfig
     sp_strategy: str = "none"  # none | ring | ulysses | halo | auto
@@ -68,8 +69,8 @@ class Preset:
 
 
 PRESETS: Dict[str, Preset] = {}
-# The presets of the language-model families (a HybridLMConfig or a
-# SambaYConfig model), in a table of
+# The presets of the language-model families (a HybridLMConfig, a
+# SambaYConfig or a LagunaConfig model), in a table of
 # their own: PRESETS stays GLOM's driver configurations, which is what the
 # sharded trainers and the serving stack iterate; `get_preset` finds both.
 LM_PRESETS: Dict[str, Preset] = {}
@@ -365,6 +366,63 @@ _register(
             hidden_size=64, intermediate_size=128, num_attention_heads=8,
             num_key_value_heads=4, sliding_window=16, vocab_size=128,
             num_hidden_layers=8, num_hidden_layers_total=8, seq_len=80,
+        ),
+        train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
+        mesh=MeshConfig(),
+    )
+)
+
+
+# 8. A fourth family: Laguna-XS.2 (laguna: window and full attention mixed by
+# layer with 64 and 48 query heads over 8 KV heads, two rotary schemes, a gate
+# a head on the attention's output, top-8 of 256 SwiGLU experts beside a
+# shared one), as ONE chip of 8 that share each layer sees it: the routed
+# experts divided 8 ways (32 of 256 here, experts 96-127), the embedding and
+# the untied head by rows 8 ways (12,544 of 100,352); attention, the gate, the
+# router, the shared expert and the dense MLP whole. Depth: published layers
+# 0-4, the leading dense layer and one whole period after it (full + dense,
+# sliding, sliding, sliding, full). Every width and every head is the
+# published one. 692M parameters held; two packed sequences of 8,192 tokens a
+# step. `moe_rung_loads=4` is for the benchmark's traffic, not for the
+# model: on uniform random ids, with the zero selection bias `assumed`, nothing
+# balances this router, from about the tenth step every token of a layer
+# chooses the same 8 experts on many steps, and those held here then get whole
+# multiples of the 16,384 tokens. Two of the 8 overflow a rung of two loads by
+# its rows of room and run the full count, 40 ms a layer longer; four loads
+# hold three (four are 1% of such steps) for 13 ms a layer more than two
+# (PERF.md section 6, PR 36). A job whose router is balanced leaves the default.
+_register(
+    Preset(
+        name="laguna-xs2-ep8vp8",
+        description="Laguna-XS.2: one chip of 8 a layer (32/256 experts, 1/8 of "
+        "the vocabulary), layers 0-4, two 8k-token sequences a step",
+        model=LagunaConfig(
+            num_hidden_layers=5, num_experts=32, expert_offset=96, vocab_size=12544,
+            moe_rung_loads=4, seq_len=8192,
+        ),
+        train=TrainConfig(
+            batch_size=2, learning_rate=3e-4, compute_dtype="bfloat16", remat=True,
+        ),
+        mesh=MeshConfig(),
+    )
+)
+
+# 8b. The same family at a size the CPU holds: both kinds of attention with
+# their own head counts, the dense layer and expert layers, a share of the
+# experts (4 of 16, offset 4), a window shorter than the sequence.
+_register(
+    Preset(
+        name="laguna-tiny",
+        description="Laguna LM, hidden 64, 5 layers F+D S S S F, 4 of 16 experts, "
+        "window 16 — CPU drives",
+        model=LagunaConfig(
+            hidden_size=64, intermediate_size=160, vocab_size=128,
+            layer_types="FSSSF", mlp_layer_types="DEEEE", num_hidden_layers=5,
+            num_hidden_layers_total=5, num_attention_heads=4, num_sliding_attention_heads=8,
+            num_key_value_heads=2, head_dim=16, sliding_window=16,
+            yarn_original_max_position_embeddings=32,
+            num_experts=4, num_experts_total=16, expert_offset=4, num_experts_per_tok=4,
+            moe_intermediate_size=48, shared_expert_intermediate_size=48, seq_len=80,
         ),
         train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
         mesh=MeshConfig(),

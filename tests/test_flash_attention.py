@@ -95,6 +95,56 @@ def test_query_and_key_tiles_of_different_sizes(tq, tk):
         assert max(rel(a, b) for a, b in zip(got, want)) < 2e-6
 
 
+# (KV heads, value heads, query heads a KV head, D, Dv): Laguna's sliding and
+# full layers, 8 and 6 query heads a KV head of 128
+LAGUNA_HEADS = {"laguna_sliding_r8": (2, 2, 8, 128, 128), "laguna_full_r6": (2, 2, 6, 128, 128)}
+
+
+@pytest.mark.parametrize("parts", [1, 2], ids=["group_whole", "group_in_two_parts"])
+@pytest.mark.parametrize("window", [None, 200], ids=["full", "window"])
+@pytest.mark.parametrize("heads", list(LAGUNA_HEADS.values()), ids=list(LAGUNA_HEADS))
+def test_a_group_of_eight_or_six_heads_of_128_whole_and_folded_in_parts(
+        heads, window, parts, monkeypatch):
+    """Against `_attend` over the whole length, forward and in every gradient.
+    At 8,192 positions a group of 8 folds in two parts of 4 (`head_parts`);
+    here the resident bytes are cut so that 384 positions do the same: each
+    part is a grid row of its own that reads the group's KV head, and the
+    parts' dk are added."""
+    g, gv, r, d, dv = heads
+    t = 3 * TILE
+    if parts > 1:
+        monkeypatch.setattr(fa, "DQ_RESIDENT_BYTES", 2 * (r // parts) * t * d * 4)
+    assert fa.head_parts(t, r, d) == parts
+    q, k, v, cot = inputs(t, heads, jnp.float32, seed=5, bsz=2)
+    got = out_and_grads(
+        lambda q, k, v: fa.flash_attention(q, k, v, window, tq=TILE, tk=TILE, interpret=True),
+        q, k, v, cot)
+    want = out_and_grads(lambda q, k, v: hybrid_lm._attend(q, k, v, 0, 0, window), q, k, v, cot)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape and rel(a, b) < 2e-6, (name, rel(a, b))
+
+
+@pytest.mark.parametrize("t, r, parts, tiles", [
+    (8192, 6, 1, (128, 1024)),    # 2 * 6 * 8192 * 128 * 4 = 48 MiB exactly: whole, by an equality
+    (8192, 8, 2, (256, 1024)),    # 64 MiB whole: two parts of 4
+    (8192, 4, 1, (256, 1024)),    # the other language model's group, as it was
+    (16384, 4, 2, (512, 1024)),   # 64 MiB whole: two parts of 2
+    (16384, 6, 2, (256, 1024)),   # 96 MiB whole: two parts of 3, again by an equality
+    (16384, 8, 4, (512, 1024)),
+    (65536, 1, None, None),       # one head's dq is 64 MiB: the XLA loop
+], ids=lambda v: str(v))
+def test_the_resident_dq_decides_the_parts_and_the_equality_at_six_heads_holds(
+        t, r, parts, tiles):
+    """DQ_RESIDENT_BYTES is 48 MiB and the comparison admits equality: a
+    later change of either that dropped a layer of 6 query heads a KV head at
+    8,192 positions to the XLA loop, or split it, fails here."""
+    assert fa.DQ_RESIDENT_BYTES == 48 * 1024 * 1024
+    assert fa.head_parts(t, r, 128) == parts
+    assert fa.tiles(t, r, 128, 128) == tiles
+    if tiles:
+        assert fa.tiles(t, r, 128, 128, 512) == (tiles[0], 512)
+
+
 # ------------------------------------------------------------ dispatch by shape
 
 
@@ -141,8 +191,11 @@ def test_the_tiles_follow_the_shapes_and_the_window_alone():
     assert fa.tiles(8192, 2, 64, 128, 40) == (512, 128)
     assert fa.tiles(8192, 2, 64, 128, 10 ** 6) == fa.tiles(8192, 2, 64, 128)
     assert fa.tiles(384, 2, 64, 128) == (128, 128)
-    # a group's dq no longer resident: a longer sequence goes back to the loop
-    assert fa.tiles(8192, 4, 128, 128) and fa.tiles(32768, 4, 128, 128) is None
+    # a group's dq no longer resident: the group folds in parts, whose rows
+    # the query tile follows; one head's no longer resident: back to the loop
+    assert fa.tiles(8192, 8, 128, 128) == fa.tiles(8192, 4, 128, 128)
+    assert fa.tiles(32768, 4, 128, 128) == (512, 1024)
+    assert fa.tiles(8192, 4, 128, 128) and fa.tiles(65536, 4, 128, 128) is None
 
 
 # ------------------------------------------------------------------ the schedule
@@ -280,8 +333,10 @@ def one_chip():
 
 
 @pytest.mark.parametrize("heads, window", [((20, 10, 2, 64, 128), None), ((20, 10, 2, 64, 128), 512),
-                                           ((1, 1, 4, 128, 128), None)],
-                         ids=["phi4flash_full", "phi4flash_window", "nemotron3super"])
+                                           ((1, 1, 4, 128, 128), None),
+                                           ((8, 8, 8, 128, 128), 512), ((8, 8, 6, 128, 128), None)],
+                         ids=["phi4flash_full", "phi4flash_window", "nemotron3super",
+                              "lagunaxs2_sliding", "lagunaxs2_full"])
 def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes(one_chip, heads, window):
     """8,192 tokens in bfloat16 at the tiles the shapes get: Mosaic takes the
     layouts, the transposed product and the group's resident dq, which

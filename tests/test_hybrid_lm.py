@@ -362,7 +362,8 @@ def test_no_pair_is_dropped_under_any_imbalance(cfg, case, monkeypatch):
     assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(grads))
     if len(rungs) > 1:
         # the single program at the full count gives the same, whichever rung ran
-        monkeypatch.setattr(lm, "row_rungs", lambda n, cfg, real=lm.row_rungs: real(n, cfg)[-1:])
+        monkeypatch.setattr(lm, "row_rungs",
+                            lambda n, cfg, *loads, real=lm.row_rungs: real(n, cfg, *loads)[-1:])
         want_out, want_counters, want_grads = run()
         assert float(want_counters["moe_rows_computed"]) == rungs[-1]
         for a, b in zip(jax.tree_util.tree_leaves((out, grads)),
@@ -475,8 +476,9 @@ def test_only_the_full_rung_holds_arrays_of_the_full_row_count():
 
     def plain(w, u2):
         top_i, top_w = lm.route(w, u2, cfg)
-        diff = (u2 @ w["down"], top_w, w["w1"], w["w2"], w["up"])
-        branches = [functools.partial(lm.expert_rows, rows, k) for rows in rungs]
+        diff = (u2 @ w["down"], top_w, (w["w1"], w["w2"]), w["up"])
+        branches = [functools.partial(lm.expert_rows, rows, k, lm.relu2_experts)
+                    for rows in rungs]
         return jnp.sum(jax.lax.switch(0, branches, diff, lm.dispatch(top_i, cfg)))
 
     u2 = jax.ShapeDtypeStruct((LADDER_TOKENS, cfg.hidden_size), jnp.float32)

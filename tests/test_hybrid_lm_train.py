@@ -173,12 +173,12 @@ def test_fit_emits_the_three_host_spans_and_the_prefetch_workers(fitted):
 def test_the_logging_records_carry_the_routing_counters(fitted, tiny):
     cfg = tiny[0]
     _, history, _ = fitted
-    from glom_tpu.models.hybrid_lm import row_rungs
+    from glom_tpu.models.hybrid_lm import ROW_TILE, row_rungs
 
     n, k, e = 2 * cfg.seq_len, cfg.num_experts_per_tok, cfg.n_routed_experts
     layers = cfg.pattern.count("E")
     rungs = row_rungs(n, cfg)
-    assert rungs[-1] == n * min(k, e) + e
+    assert rungs[-1] == -(-(n * min(k, e) + e) // ROW_TILE) * ROW_TILE   # whole row tiles
     for r in history:
         # the mean over the expert layers of the rung each ran, which holds
         # its pairs and the experts' rows of room
@@ -233,7 +233,8 @@ def test_the_trainers_static_record_counts_the_language_models_bytes(fitted, tin
 
 def test_the_language_model_presets_have_a_table_of_their_own():
     assert set(LM_PRESETS) == {"nemotron3-super-ep64tp8", "hybrid-lm-tiny",
-                               "phi4-mini-flash-stage6vp8", "sambay-tiny"}
+                               "phi4-mini-flash-stage6vp8", "sambay-tiny",
+                               "laguna-xs2-ep8vp8", "laguna-tiny"}
     assert not set(LM_PRESETS) & set(PRESETS)
     assert all(isinstance(p.model, GlomConfig) for p in PRESETS.values())
     full = get_preset("nemotron3-super-ep64tp8")

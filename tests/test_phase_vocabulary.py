@@ -15,7 +15,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from glom_tpu.tracing.spans import DEVICE_PHASES, HOST_PHASES, LM_KERNELS, PHASES
+from glom_tpu.tracing.spans import (
+    DEVICE_PHASES,
+    HOST_PHASES,
+    LAGUNA_DEVICE_PHASES,
+    LAGUNA_INNER_SCOPES,
+    LM_DEVICE_PHASES,
+    LM_KERNELS,
+    PHASES,
+    SAMBAY_DEVICE_PHASES,
+)
 from glom_tpu.utils.config import GlomConfig, TrainConfig
 
 KERNELS = pathlib.Path(__file__).resolve().parent.parent / "glom_tpu" / "kernels"
@@ -169,3 +178,39 @@ def test_compiled_step_carries_every_phase(builder):
     assert not missing, (missing, counts)
     assert not ({"step_metrics"} & set(counts)) or "step_metrics" in expected, counts
     assert counts["(none)"] < 0.05 * named, counts
+
+
+# ------------------------------------------ the language-model families' tuples
+
+FAMILIES = {"hybrid_lm": LM_DEVICE_PHASES, "sambay": SAMBAY_DEVICE_PHASES,
+            "laguna": LAGUNA_DEVICE_PHASES}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_familys_tuple_is_its_own_and_shares_names_only_where_they_mean_the_same(family):
+    phases = FAMILIES[family]
+    assert len(set(phases)) == len(phases)
+    assert all(re.fullmatch(r"[a-z][a-z0-9]*(_[a-z0-9]+)*", p) for p in phases)
+    assert not set(phases) & set(PHASES)                 # none reads as a phase of GLOM's
+    assert phases[0] == "embed" and phases[-1] == "lm_head_loss"
+    assert not set(phases) & set(LAGUNA_INNER_SCOPES)    # an inner scope is no phase
+    assert not set(LM_KERNELS) & set(phases)
+
+
+def test_lagunas_scopes_counters_and_inner_scopes_are_registered():
+    """The routed part's four scopes are the second family's (one code), the
+    two attention scopes SambaY's names for the same two masks; `rope` and
+    `attn_gate` are inner scopes; the records' counters are the routed part's
+    four and the attentions' two."""
+    from glom_tpu.models import hybrid_lm, laguna, sambay
+
+    assert set(LAGUNA_DEVICE_PHASES) - set(LM_DEVICE_PHASES) - set(SAMBAY_DEVICE_PHASES) == {
+        "dense_mlp"}
+    assert {"moe_router", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"} <= (
+        set(LAGUNA_DEVICE_PHASES) & set(LM_DEVICE_PHASES))
+    assert LAGUNA_INNER_SCOPES == ("rope", "attn_gate")
+    assert set(laguna.ATTENTION_SCOPE.values()) == {"window_attention", "full_attention"} <= (
+        set(sambay.ATTENTION_SCOPE.values()))
+    assert laguna.COUNTERS == hybrid_lm.COUNTERS + (
+        "attn_key_blocks_window", "attn_key_blocks_full")
+    assert set(laguna.COUNTERS[-2:]) <= set(sambay.COUNTERS)
