@@ -125,19 +125,57 @@ def rope_frequencies(attention: str, cfg: LagunaConfig) -> Tuple[np.ndarray, flo
     return freq.astype(np.float32), cfg.yarn_attention_factor
 
 
+def rope_tables(attention: str, cfg: LagunaConfig, length: int):
+    """(cos, sin [length, D] float32, the factor folded in; the signed
+    permutation P [D, D] with (x @ P)[i] = -x[i + half], (x @ P)[i + half] =
+    x[i] over the rotated dimensions) of a layer of kind `attention`. Over the
+    dimensions that pass untouched cos = 1, sin = 0 and P is zero. sin is
+    equal on paired dimensions and P^T = -P."""
+    freq, factor = rope_frequencies(attention, cfg)
+    half, dim = freq.shape[0], cfg.head_dim
+    rotated = np.arange(dim) < 2 * half
+    paired = np.zeros(dim, np.float32)
+    paired[:half] = paired[half:2 * half] = freq
+    angle = jnp.arange(length, dtype=jnp.float32)[:, None] * paired           # [length, D]
+    cos = jnp.where(rotated, jnp.cos(angle) * factor, 1.0)
+    sin = jnp.where(rotated, jnp.sin(angle) * factor, 0.0)
+    perm = np.zeros((dim, dim), np.float32)
+    i = np.arange(half)
+    perm[i + half, i], perm[i, i + half] = -1.0, 1.0
+    return cos, sin, perm
+
+
+def _rotate(x, attention: str, cfg: LagunaConfig, transposed: bool):
+    """x * cos + (x @ P) * sin (P^T where `transposed`) in one pass: x's type
+    in and out, float32 between. The product is exact in any type: each of
+    its outputs is one input times +-1."""
+    with jax.named_scope("rope"):
+        cos, sin, perm = rope_tables(attention, cfg, x.shape[1])
+        shape = (x.shape[1],) + (1,) * (x.ndim - 3) + (x.shape[-1],)
+        # float32 operands at the chip's default precision would be rounded to bfloat16
+        turned = jnp.matmul(x, jnp.asarray(perm.T if transposed else perm, x.dtype),
+                            precision=None if x.dtype == jnp.bfloat16 else "highest",
+                            preferred_element_type=jnp.float32)
+        return (x.astype(jnp.float32) * cos.reshape(shape)
+                + turned * sin.reshape(shape)).astype(x.dtype)
+
+
 def rope(x, attention: str, cfg: LagunaConfig):
     """Rotate x [B, T, ..., D] at positions 0..T-1: of the first `rotary_dim`
     dimensions of a head, dimension i pairs with i + rotary_dim / 2; the rest
-    pass untouched. Float32 inside, x's type out."""
-    freq, factor = rope_frequencies(attention, cfg)
-    half = freq.shape[0]
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq       # [T, half]
-    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
-    cos, sin = (jnp.cos(angle) * factor).reshape(shape), (jnp.sin(angle) * factor).reshape(shape)
-    x32 = x.astype(jnp.float32)
-    x1, x2, rest = x32[..., :half], x32[..., half:2 * half], x32[..., 2 * half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                           axis=-1).astype(x.dtype)
+    pass untouched. One pass, `x * cos + (x @ P) * sin` over whole heads
+    (`rope_tables`): no slice and no concatenate of a head's parts, float32
+    inside, x's type in and out. Its transpose is the same pass with P^T on
+    the cotangent, summed in float32 and rounded once."""
+
+    @jax.custom_vjp
+    def turn(x):
+        return _rotate(x, attention, cfg, False)
+
+    # (dy * sin) @ P^T = (dy @ P^T) * sin: sin is equal on paired dimensions
+    turn.defvjp(lambda x: (_rotate(x, attention, cfg, False), None),
+                lambda _, dy: (_rotate(dy, attention, cfg, True),))
+    return turn(x)
 
 
 # ------------------------------------------------------------------ the layer
@@ -159,8 +197,7 @@ def attention_mixer(attention: str, p, x_in, cfg: LagunaConfig, dtype):
         q = _mm(u, _cast(p["q"], dtype)).astype(u.dtype).reshape(bsz, t, g, heads // g, dh)
         k = _mm(u, _cast(p["k"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
         v = _mm(u, _cast(p["v"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
-        with jax.named_scope("rope"):
-            q, k = rope(q, attention, cfg), rope(k, attention, cfg)
+        q, k = rope(q, attention, cfg), rope(k, attention, cfg)
         a, key_blocks = blocked_attention(
             q, k, v, cfg.sliding_window if attention == "S" else None)
         with jax.named_scope("attn_gate"):

@@ -134,7 +134,9 @@ LAGUNA_DEVICE_PHASES = (
 # under one of them belongs to the attention phase round it, and a reader that
 # wants the part alone finds it by this name in the op's scope path.
 LAGUNA_INNER_SCOPES = (
-    # the rotary positions on q and k (float32 cos and sin, YaRN or default)
+    # the rotary positions on q and k (float32 cos and sin, YaRN or default): one
+    # pass a tensor, x * cos + (x @ P) * sin over whole heads with P the signed
+    # permutation of a head's halves; the pass's transpose opens the scope too
     "rope",
     # the sigmoid gate a query head on the attention's output, with its product
     "attn_gate",

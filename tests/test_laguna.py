@@ -231,6 +231,77 @@ def test_a_partial_rotation_leaves_the_upper_half_of_a_head_untouched():
                         rtol=1e-4, atol=1e-4)
 
 
+def sliced_rope(x, attention, cfg):
+    """The rotation as the program wrote it before PR 37, kept as the oracle:
+    a head's halves sliced apart, rotated in float32 and concatenated."""
+    freq, factor = laguna.rope_frequencies(attention, cfg)
+    half = freq.shape[0]
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = (jnp.cos(angle) * factor).reshape(shape), (jnp.sin(angle) * factor).reshape(shape)
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = x32[..., :half], x32[..., half:2 * half], x32[..., 2 * half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+                           axis=-1).astype(x.dtype)
+
+
+# q of the layer's kind (48 or 64 query heads over 8 KV heads) and k; 200 tokens
+# are no multiple of 128
+ROPE_SHAPES = {("F", "q"): (2, 200, 8, 6, 128), ("S", "q"): (2, 200, 8, 8, 128),
+               ("F", "k"): (2, 200, 8, 128), ("S", "k"): (2, 200, 8, 128)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kind,which", sorted(ROPE_SHAPES))
+def test_the_one_pass_rotation_is_the_sliced_one(kind, which, dtype):
+    """Outputs and gradients, op by op on the CPU (under `jit` its compiler
+    contracts the two formulas' multiply-adds differently, by one float32
+    rounding): bit for bit in bfloat16, where the product with the permutation
+    is exact and both round the float32 sum once; within 1e-6 in float32."""
+    shape = ROPE_SHAPES[kind, which]
+    x = jax.random.normal(jax.random.PRNGKey(1), shape).astype(dtype)
+    dy = jax.random.normal(jax.random.PRNGKey(2), shape).astype(dtype)
+    got, got_vjp = jax.vjp(lambda x: laguna.rope(x, kind, FULL), x)
+    want, want_vjp = jax.vjp(lambda x: sliced_rope(x, kind, FULL), x)
+    (got_dx,), (want_dx,) = got_vjp(dy), want_vjp(dy)
+    assert got.dtype == got_dx.dtype == x.dtype and got.shape == got_dx.shape == shape
+    if dtype == "bfloat16":
+        bits = lambda a: np.asarray(a).view(np.uint16)
+        assert np.array_equal(bits(got), bits(want))
+        assert np.array_equal(bits(got_dx), bits(want_dx))
+    else:
+        assert np.allclose(got, want, rtol=0, atol=1e-6)
+        assert np.allclose(got_dx, want_dx, rtol=0, atol=1e-6)
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from equations(sub)
+
+
+@pytest.mark.parametrize("kind", ["F", "S"])
+def test_the_rotation_neither_slices_nor_concatenates_a_head(kind):
+    """The pin on structure: forward and transpose are products with a
+    [128, 128] permutation and elementwise work over whole heads. A slice, a
+    pad (a slice's transpose), a concatenate or a gather of a head's parts is
+    what sent float32 arrays through HBM (PERF.md, PR 37)."""
+    x = jnp.zeros(ROPE_SHAPES[kind, "q"], jnp.bfloat16)
+    forward = jax.make_jaxpr(lambda x: laguna.rope(x, kind, FULL))(x)
+    backward = jax.make_jaxpr(lambda x, dy: jax.vjp(lambda x: laguna.rope(x, kind, FULL), x)[1](dy))(x, x)
+    # the second traces the forward too, whose output it does not use
+    for jaxpr, passes in ((forward, 1), (backward, 2)):
+        names = [eqn.primitive.name for eqn in equations(jaxpr.jaxpr)]
+        assert names.count("dot_general") == passes, names
+        assert not {"slice", "dynamic_slice", "pad", "concatenate", "gather", "scatter-add",
+                    "dynamic_update_slice"} & set(names), names
+        # bfloat16 at both ends: the only arrays of x's shape in another type are float32
+        # values between the product and the rounding
+        assert [str(v.aval.dtype) for v in jaxpr.jaxpr.outvars] == ["bfloat16"]
+
+
 # ------------------------------------------------------- causality, the window
 
 

@@ -214,3 +214,41 @@ def test_lagunas_scopes_counters_and_inner_scopes_are_registered():
     assert laguna.COUNTERS == hybrid_lm.COUNTERS + (
         "attn_key_blocks_window", "attn_key_blocks_full")
     assert set(laguna.COUNTERS[-2:]) <= set(sambay.COUNTERS)
+
+
+def test_every_op_of_lagunas_rotation_lies_under_rope_inside_an_attention_scope():
+    """The rotation runs three times a layer on q and on k: forward, in the
+    layer's recomputation, and transposed (a `custom_vjp` whose backward opens
+    `rope` itself). In the compiled step of the tiny preset each of its ops
+    (the tables' sines and cosines, which nothing else in the model takes, and
+    whatever reads the [head_dim, head_dim] permutation) carries `rope` inside
+    `window_attention` or `full_attention`; none falls under no scope."""
+    from glom_tpu.train.trainer import create_train_state, default_optimizer, make_train_step
+    from glom_tpu.utils.presets import get_preset
+
+    preset = get_preset("laguna-tiny")
+    cfg, tcfg = preset.model, preset.train
+    opt = default_optimizer(tcfg)
+    state, _ = create_train_state(jax.random.PRNGKey(0), cfg, tcfg, opt)
+    ids = jnp.zeros((tcfg.batch_size, cfg.seq_len), jnp.int32)
+    text = jax.jit(make_train_step(cfg, tcfg, opt)).lower(
+        state, ids, jax.random.PRNGKey(0)).compile().as_text()
+    square = rf"f32\[{cfg.head_dim},{cfg.head_dim}\]"
+    permutations = set(re.findall(rf"(%[\w.\-]+) = {square}\S* constant\(", text))
+    assert permutations
+    inside = re.compile(r"\b(window|full)_attention\b.*\brope\b")
+    forms = collections.Counter()
+    for line in text.splitlines():
+        op = re.search(r" = [^=]*? ([a-z][a-z0-9\-]*)\(([^)]*)\)", line)
+        if not op or not (op.group(1) in ("sine", "cosine")
+                          or permutations & set(re.findall(r"%[\w.\-]+", op.group(2)))):
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        assert name and inside.search(name.group(1)), line
+        if op.group(1) == "dot":
+            path = name.group(1)
+            forms["recomputed" if "rematted_computation" in path
+                  else "backward" if "transpose(" in path else "forward"] += 1
+    # q and k of every layer, in each of the three passes
+    assert forms == {form: 2 * cfg.num_hidden_layers
+                     for form in ("forward", "recomputed", "backward")}, forms
