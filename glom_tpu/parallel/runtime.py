@@ -489,6 +489,10 @@ class DistributedTrainer:
         # Persistent across fit() calls: span 2+ of a checkpointed run is
         # warm, and its first steps are steady-state samples, not compiles.
         self._compile_tracker = set()
+        # As persistent: the time between logging boundaries (fit_loop).
+        from glom_tpu.tracing.spans import IntervalAccount
+
+        self._interval_account = IntervalAccount()
 
         # Static observability record, computed AFTER build() so the
         # comm-volume model prices the grad_accum the step actually runs
@@ -710,4 +714,5 @@ class DistributedTrainer:
                 self.collective_time_records
                 if self.collective_sampler is not None else None
             ),
+            interval_account=self._interval_account,
         )

@@ -11,6 +11,7 @@ in reference; README recipe only). TPU-native design:
 
 from __future__ import annotations
 
+import sys
 import time
 from contextlib import nullcontext
 from typing import Any, Callable, Iterator, NamedTuple, Optional, Tuple
@@ -537,6 +538,7 @@ def fit_loop(
     trace_capture=None,
     memory_probe: Optional[Callable[[], dict]] = None,
     aux_records_probe: Optional[Callable[[], list]] = None,
+    interval_account=None,
 ) -> list[dict]:
     """Shared training loop: pull batches, step, log every `log_every`.
     Used by both the single-device Trainer and the DistributedTrainer.
@@ -560,6 +562,19 @@ def fit_loop(
     checkpoint span): the jit cache is warm in span 2+, and a fresh
     tracker would mislabel each span's first steps as compiles, faking a
     compile_time_s and dropping real samples from the percentiles.
+
+    interval_account: the same, for the time between logging boundaries —
+    a tracing.spans.IntervalAccount the trainers own and hand to every
+    call (without one the call makes its own and remembers nothing of the
+    last). Every logging record carries its interval, from this account's
+    previous boundary (or its first entry here) to the end of this
+    boundary's host_log_fetch: interval_steps, interval_ms,
+    interval_other_ms (the wall outside the loop's three spans),
+    host_gc_ms / host_gc_collections / host_gc_gen2, host_cpu_ms,
+    host_run_delay_ms (where /proc has it), host_nivcsw, host_majflt, and
+    stall_ms with, where it is not 0, stall_phase and stall_cause
+    (spans.judge_interval); a stalled interval also writes one line to
+    standard error.
 
     Tracing hooks (glom_tpu/tracing/, docs/OBSERVABILITY.md):
       * host spans — host_data_next / host_step_dispatch / host_log_fetch
@@ -588,11 +603,13 @@ def fit_loop(
     from glom_tpu.telemetry import schema
     from glom_tpu.telemetry.sinks import StepTimeStats
     from glom_tpu.tracing import flight
-    from glom_tpu.tracing.spans import SpanAggregator, span
+    from glom_tpu.tracing.spans import IntervalAccount, SpanAggregator, span
 
     history = []
     stats = StepTimeStats()
     spans = SpanAggregator()
+    account = interval_account or IntervalAccount()
+    account.enter()
     # Which jit variant's compile step was seen, keyed by role (bound
     # methods get fresh ids per access, so identity keys wouldn't survive
     # a second fit() call even with a shared tracker).
@@ -622,14 +639,14 @@ def fit_loop(
             # dispatch's time, and the window's first dispatch is a span the
             # trace holds.
             with trace_capture.unit() if trace_capture is not None else nullcontext():
-                t_step = time.perf_counter()
-                with span("host_step_dispatch", aggregator=spans, step=i):
+                with span("host_step_dispatch", aggregator=spans, step=i) as dispatch:
                     metrics = fn(batch)
-                # Each jit variant's first call is trace+compile — both the
-                # fast step's (iteration 0) and the logging step's (first
-                # log boundary) — and must not pollute the steady-state
-                # percentiles.
-                stats.observe(time.perf_counter() - t_step, is_compile=first_call)
+            # Each jit variant's first call is trace+compile — both the
+            # fast step's (iteration 0) and the logging step's (first
+            # log boundary) — and must not pollute the steady-state
+            # percentiles.
+            stats.observe(dispatch.dur_s, is_compile=first_call)
+            account.step(first_call)
             if "nonfinite_step" in metrics and not logging_step:
                 pending_flags.append((i, metrics["nonfinite_step"]))
             if not logging_step:
@@ -637,8 +654,17 @@ def fit_loop(
             with span("host_log_fetch", aggregator=spans, step=i):
                 metrics = diag.split_level_agreement(metrics)
                 metrics = {k: _jsonable(v) for k, v in metrics.items()}
+            t_boundary = time.perf_counter()
+            at_step = {"step": metrics.get("step", float(i))}
+            span_recs = spans.records(extra=at_step)
+            interval, stall_line = account.close(
+                t_boundary, at_step["step"],
+                {r["name"]: 1e3 * r["dur_s"] for r in span_recs})
+            if stall_line is not None:
+                print(stall_line, file=sys.stderr, flush=True)
             metrics["steps_per_sec"] = (i + 1) / (time.perf_counter() - t0)
             metrics.update(stats.summary())
+            metrics.update(interval)
             if memory_probe is not None:
                 metrics.update(memory_probe() or {})
             rec = schema.stamp(metrics, kind="train_step")
@@ -649,8 +675,6 @@ def fit_loop(
                 # No writer: feed the flight recorder directly so a crash
                 # in a writerless run still has a postmortem trail.
                 flight.observe_event(rec)
-            at_step = {"step": rec.get("step", float(i))}
-            span_recs = spans.records(extra=at_step)
             if data_span_records is not None:
                 span_recs += data_span_records(extra=at_step)
             for srec in span_recs:
@@ -774,6 +798,11 @@ class Trainer:
         # Persistent across fit() calls: span 2+ of a checkpointed run is
         # warm, and its first steps are steady-state samples, not compiles.
         self._compile_tracker = set()
+        # As persistent, for the time between logging boundaries: what an
+        # interval usually costs outlives the fit() call that measured it.
+        from glom_tpu.tracing.spans import IntervalAccount
+
+        self._interval_account = IntervalAccount()
 
     def _annotate(self, metrics) -> dict:
         """Static routing facts, attached OUTSIDE jit (strings can't ride
@@ -845,4 +874,5 @@ class Trainer:
             compile_tracker=self._compile_tracker,
             trace_capture=trace_capture,
             memory_probe=self._memory_record,
+            interval_account=self._interval_account,
         )

@@ -9,8 +9,12 @@ to the tier-1 budget and runs without a profiler backend — which is the
 spans' and flight recorder's own contract.
 """
 
+import gc
+import importlib.util
 import json
+import os
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -29,7 +33,14 @@ from glom_tpu.tracing.memory import (
     memory_record,
     model_live_bytes_total,
 )
-from glom_tpu.tracing.spans import SpanAggregator, current_span, span
+from glom_tpu.tracing import spans as spans_mod
+from glom_tpu.tracing.spans import (
+    IntervalAccount,
+    SpanAggregator,
+    current_span,
+    judge_interval,
+    span,
+)
 
 
 class ListWriter:
@@ -640,17 +651,32 @@ class TestPrefetchRollupsAtLogBoundaries:
         from glom_tpu.data.prefetch import prefetch_to_device
         from glom_tpu.train.trainer import fit_loop
 
-        pulled = []
+        # The worker pulls a batch when the loop's step lets it, and the
+        # step returns once the worker is back at the source for the next:
+        # by then the batch it let through was pulled, staged and queued, so
+        # every interval between boundaries holds both worker spans whatever
+        # the machine's other work does to the threads.
+        pulled, entered, gate = [], [], threading.Semaphore(1)
 
         def source():
             while True:
+                entered.append(1)
+                gate.acquire()
                 pulled.append(1)
                 yield np.ones((2, 3), np.float32)
 
+        def step(batch):
+            n = len(entered)
+            gate.release()
+            deadline = time.monotonic() + 60.0
+            while len(entered) == n:
+                assert time.monotonic() < deadline, "the worker never came back"
+                time.sleep(0.001)
+            return {"loss": 1.0, "step": 0.0}
+
         end_sink, w = ListWriter(), ListWriter()
         data = prefetch_to_device(source(), size=2, metrics_writer=end_sink)
-        fit_loop(lambda b: {"loss": 1.0, "step": 0.0}, data, 6, log_every=2,
-                 metrics_writer=w)
+        fit_loop(step, data, 6, log_every=2, metrics_writer=w)
         kinds = [(r["kind"], r.get("name")) for r in w.records]
         boundaries = [i for i, k in enumerate(kinds) if k[0] == "train_step"]
         assert len(boundaries) == 3
@@ -664,6 +690,7 @@ class TestPrefetchRollupsAtLogBoundaries:
             if r.get("name", "").startswith("host_prefetch_"):
                 assert r["source"] == "prefetch_to_device" and "step" in r
         assert end_sink.records == []  # the stream has not ended
+        gate.release(100)  # the worker runs free again, so that it can be stopped
         data.close()
         # The end of the stream reports what no boundary has: each batch the
         # worker pulled and staged is counted exactly once over both sinks.
@@ -682,6 +709,305 @@ class TestPrefetchRollupsAtLogBoundaries:
                  metrics_writer=w)
         names = {r["name"] for r in w.records if r["kind"] == "span"}
         assert names == {"host_data_next", "host_step_dispatch", "host_log_fetch"}
+
+
+def _made_up(wall, data=0.2, dispatch=3.0, fetch=296.0, gc_ms=0.0,
+             run_delay=0.0, majflt=0):
+    """An interval of three steps as `judge_interval` takes it; what the
+    three spans do not cover is `other`."""
+    return {"wall_ms": wall,
+            "phases": {"host_data_next": data, "host_step_dispatch": dispatch,
+                       "host_log_fetch": fetch,
+                       "other": wall - data - dispatch - fetch},
+            "gc_ms": gc_ms, "run_delay_ms": run_delay, "majflt": majflt}
+
+
+USUAL = {"wall_ms": 300.0,
+         "phases": {"host_data_next": 0.2, "host_step_dispatch": 3.0,
+                    "host_log_fetch": 296.0, "other": 0.8}}
+
+
+class TestStallRule:
+    """`judge_interval` is a pure function of an interval and its reference:
+    no clock, no counter is read here."""
+
+    @pytest.mark.parametrize("interval,reference,expected", [
+        pytest.param(_made_up(420.0, dispatch=123.0, gc_ms=110.0), USUAL,
+                     (120.0, "host_step_dispatch", "gc"), id="gc"),
+        pytest.param(_made_up(420.0, dispatch=123.0, gc_ms=59.0, run_delay=61.0), USUAL,
+                     (120.0, "host_step_dispatch", "descheduled"), id="descheduled"),
+        pytest.param(_made_up(420.0, dispatch=123.0, gc_ms=59.0, run_delay=None), USUAL,
+                     (120.0, "host_step_dispatch", "dispatch"), id="run-delay-unread"),
+        pytest.param(_made_up(420.0, data=120.2, majflt=3), USUAL,
+                     (120.0, "host_data_next", "page_fault"), id="page_fault"),
+        pytest.param(_made_up(420.0, fetch=416.0, majflt=3), USUAL,
+                     (120.0, "host_log_fetch", "device_or_unknown"),
+                     id="page-faults-while-waiting-for-the-device-cost-nothing"),
+        pytest.param(_made_up(420.0, data=120.2), USUAL,
+                     (120.0, "host_data_next", "data_wait"), id="data_wait"),
+        pytest.param(_made_up(420.0, dispatch=123.0), USUAL,
+                     (120.0, "host_step_dispatch", "dispatch"), id="dispatch"),
+        pytest.param(_made_up(420.0), USUAL,
+                     (120.0, "other", "between_spans"), id="between_spans"),
+        pytest.param(_made_up(420.0, fetch=416.0), USUAL,
+                     (120.0, "host_log_fetch", "device_or_unknown"), id="device_or_unknown"),
+        pytest.param(_made_up(420.0, fetch=416.0, gc_ms=70.0), USUAL,
+                     (120.0, "host_log_fetch", "gc"),
+                     id="the-counters-come-before-the-phase"),
+        pytest.param(_made_up(324.0, dispatch=27.0, gc_ms=24.0), USUAL,
+                     (0.0, None, None), id="under-the-threshold"),
+        pytest.param(_made_up(2045.0, fetch=2041.0),
+                     {"wall_ms": 2000.0, "phases": dict(USUAL["phases"], host_log_fetch=1996.0)},
+                     (0.0, None, None), id="under-its-share-of-a-long-interval"),
+        pytest.param(_made_up(2055.0, fetch=2051.0),
+                     {"wall_ms": 2000.0, "phases": dict(USUAL["phases"], host_log_fetch=1996.0)},
+                     (55.0, "host_log_fetch", "device_or_unknown"),
+                     id="over-its-share-of-a-long-interval"),
+        pytest.param(_made_up(5000.0, dispatch=4700.0, gc_ms=4000.0), None,
+                     (0.0, None, None), id="no-reference-yet"),
+    ])
+    def test_the_rule(self, interval, reference, expected):
+        verdict = judge_interval(interval, reference)
+        stall_ms, phase, cause = expected
+        assert verdict["stall_ms"] == pytest.approx(stall_ms, abs=0.01)
+        assert verdict.get("stall_phase") == phase
+        assert verdict.get("stall_cause") == cause
+        if not stall_ms:
+            assert verdict == {"stall_ms": 0.0}
+
+    def test_the_reference_is_the_median_of_four_or_more_of_the_same_step_count(self):
+        acc = IntervalAccount()
+        phases = dict(USUAL["phases"])
+        for wall in (300.0, 310.0, 9000.0):  # one of them a stall: the median forgets it
+            acc._history.append((3, wall, phases))
+        acc._history.append((1, 100.0, phases))
+        assert acc.reference(3) is None and acc.reference(1) is None
+        acc._history.append((3, 320.0, phases))
+        assert acc.reference(3)["wall_ms"] == 315.0
+        assert acc.reference(3)["phases"] == phases
+        for _ in range(100):
+            acc._history.append((3, 1.0, phases))
+        assert len(acc._history) == spans_mod.HISTORY_INTERVALS
+
+
+INTERVAL_FIELDS = {
+    "interval_steps", "interval_ms", "interval_other_ms", "host_gc_ms",
+    "host_gc_collections", "host_gc_gen2", "host_cpu_ms", "host_run_delay_ms",
+    "host_nivcsw", "host_majflt", "stall_ms",
+}
+
+
+class TestIntervalAccount:
+    """Every logging record accounts for its interval, and a disturbed
+    interval says where and why. Each test disturbs ONE interval by 0.3 s
+    and asserts on that interval alone: what a busy machine does to the
+    others does not matter."""
+
+    def _run(self, n_calls, steps, *, step=None, data=None, between=None,
+             log_every=None):
+        """`n_calls` fit_loop calls of `steps` steps over one account and one
+        compile tracker, as a trainer's fit makes them; returns the logging
+        records and everything written."""
+        from glom_tpu.train.trainer import fit_loop
+
+        w, account, tracker, history = ListWriter(), IntervalAccount(), set(), []
+        data = data if data is not None else _endless()
+        for call in range(n_calls):
+            if between is not None:
+                between(call)
+            history += fit_loop(
+                step or (lambda b: {"loss": 1.0, "step": 0.0}), data, steps,
+                log_every=log_every or steps, metrics_writer=w,
+                compile_tracker=tracker, interval_account=account)
+        return history, w.records
+
+    def _check_stream(self, records):
+        for r in records:
+            assert schema.validate_record(r) == [], r
+        steps = [r for r in records if r["kind"] == "train_step"]
+        for r in steps:
+            assert INTERVAL_FIELDS <= set(r), sorted(INTERVAL_FIELDS - set(r))
+            if not r["stall_ms"]:
+                assert "stall_phase" not in r and "stall_cause" not in r
+        # the span records of a boundary are what they were
+        names = {r["name"] for r in records if r["kind"] == "span"}
+        assert names == {"host_data_next", "host_step_dispatch", "host_log_fetch"}
+        assert spans_mod.HOST_PHASES == (
+            "host_data_next", "host_step_dispatch", "host_log_fetch",
+            "host_prefetch_next", "host_prefetch_stage")
+
+    def test_a_slow_dispatch(self, capsys):
+        calls = [0]
+
+        def step(batch):
+            calls[0] += 1
+            if calls[0] == 8:
+                time.sleep(0.3)
+            return {"loss": 1.0, "step": float(calls[0] - 1)}
+
+        history, records = self._run(1, 10, step=step, log_every=1)
+        assert [r["interval_steps"] for r in history] == [1] * 10
+        hit = history[7]
+        assert hit["stall_ms"] >= 250.0
+        assert (hit["stall_phase"], hit["stall_cause"]) == ("host_step_dispatch", "dispatch")
+        assert hit["interval_ms"] >= 300.0
+        # the first interval compiled (a variant's first call): never judged
+        assert history[0]["stall_ms"] == 0.0
+        self._check_stream(records)
+        # one line on standard error for the stalled interval
+        lines = [l for l in capsys.readouterr().err.splitlines()
+                 if l.startswith("glom_tpu stall: step 7:")]
+        assert len(lines) == 1
+        assert "in host_step_dispatch, cause dispatch" in lines[0]
+        for word in ("host_data_next", "host_log_fetch", "other", "gc ", "cpu ",
+                     "run delay", "involuntary switches", "major faults"):
+            assert word in lines[0]
+        # the histogram is fed the span's own duration: one timing, two readers
+        span_max = max(r["max_ms"] for r in records
+                       if r.get("name") == "host_step_dispatch" and r["step"] == 7.0)
+        assert history[-1]["step_time_max_ms"] == pytest.approx(span_max, abs=2e-3)
+
+    def test_a_slow_source(self):
+        def source():
+            for n in range(100):
+                if n == 7:
+                    time.sleep(0.3)
+                yield None
+
+        history, records = self._run(1, 10, data=source(), log_every=1)
+        hit = history[7]
+        assert hit["stall_ms"] >= 250.0
+        assert (hit["stall_phase"], hit["stall_cause"]) == ("host_data_next", "data_wait")
+        self._check_stream(records)
+
+    def test_a_pause_between_two_fit_calls_and_the_account_outlives_the_call(self):
+        def between(call):
+            if call == 7:
+                time.sleep(0.3)
+
+        history, records = self._run(10, 3, between=between)
+        # ten calls of three steps: ten intervals, each from the last call's
+        # boundary, and a reference no single call could have had
+        assert [r["interval_steps"] for r in history] == [3] * 10
+        hit = history[7]
+        assert hit["stall_ms"] >= 250.0
+        assert (hit["stall_phase"], hit["stall_cause"]) == ("other", "between_spans")
+        assert hit["interval_other_ms"] >= 300.0
+        self._check_stream(records)
+
+    def test_a_fit_loop_without_an_account_makes_its_own(self):
+        from glom_tpu.train.trainer import fit_loop
+
+        history = fit_loop(lambda b: {"loss": 1.0, "step": 0.0}, _endless(), 4,
+                           log_every=2)
+        assert [r["interval_steps"] for r in history] == [2, 2]
+        assert all(INTERVAL_FIELDS <= set(r) for r in history)
+
+    def test_a_collection_is_counted_charged_and_annotated(self, monkeypatch, capsys):
+        log = []
+        monkeypatch.setattr(RecordingAnnotation, "log", log)
+        monkeypatch.setattr(spans_mod.gc_watch(), "annotation", RecordingAnnotation)
+        calls = [0]
+
+        def step(batch):
+            calls[0] += 1
+            if calls[0] == 3:
+                # a large cyclic heap that only a collection frees, made
+                # with the collector off so that its pause is one pause
+                gc.disable()
+                for _ in range(500_000):
+                    a = []
+                    a.append([a])
+            if calls[0] == 8:
+                gc.collect()
+                gc.enable()
+            return {"loss": 1.0, "step": float(calls[0] - 1)}
+
+        try:
+            history, records = self._run(1, 10, step=step, log_every=1)
+        finally:
+            gc.enable()
+        hit = history[7]
+        assert hit["host_gc_ms"] >= 50.0
+        assert hit["host_gc_collections"] >= 1 and hit["host_gc_gen2"] >= 1
+        assert hit["stall_ms"] >= 50.0  # the pause itself, 200 ms here, is over the rule's 25
+        assert (hit["stall_phase"], hit["stall_cause"]) == ("host_step_dispatch", "gc")
+        self._check_stream(records)
+        (line,) = [l for l in capsys.readouterr().err.splitlines()
+                   if l.startswith("glom_tpu stall: step 7:")]
+        assert "under host_step_dispatch" in line  # the pause is charged to the open span
+        # the collection lies on this thread of a profiler trace, start to stop
+        me = threading.get_ident()
+        assert (me, "enter", "host_gc", {"generation": 2}) in log
+        assert (me, "exit", "host_gc", {"generation": 2}) in log
+
+    def test_one_hook_a_process(self):
+        watch = spans_mod.gc_watch()
+        IntervalAccount(), IntervalAccount()
+        assert spans_mod.gc_watch() is watch
+        assert gc.callbacks.count(watch) == 1
+
+
+def _reader(name):
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmark", "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location("reader_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def _window(**over):
+    """A window's records as the benchmark's collector holds them: the
+    first interval began before the window and is left out by every reader;
+    the counted three are 1,000 ms and 9 steps."""
+    quiet = {"kind": "train_step", "interval_steps": 3, "interval_ms": 300.0,
+             "interval_other_ms": 0.6, "host_gc_ms": 0.0, "host_nivcsw": 0,
+             "stall_ms": 0.0}
+    recs = [dict(quiet, interval_ms=9000.0, stall_ms=8700.0, stall_cause="between_spans",
+                 host_gc_ms=500.0, host_nivcsw=40, interval_other_ms=8700.0),
+            dict(quiet),
+            dict(quiet, interval_ms=400.0, stall_ms=100.0, stall_cause="gc",
+                 stall_phase="host_step_dispatch", host_gc_ms=90.0, host_nivcsw=7),
+            dict(quiet, interval_ms=300.0, stall_ms=60.0, stall_cause="device_or_unknown",
+                 stall_phase="host_log_fetch", interval_other_ms=3.9)]
+    recs = [dict(r, **over) for r in recs]
+    spans_between = [{"kind": "span", "name": "host_log_fetch", "dur_s": 0.3}]
+    return {"kind": "train", "records": spans_between + recs, "steps": 12}
+
+
+RATIO_READERS = ["window_stall_pct.train", "window_stall_unexplained_pct.train",
+                 "host_gc_pause_pct.train", "host_preempted_per_step.train"]
+
+
+class TestIntervalReaders:
+    """The five per-layer metrics that read the records' intervals
+    (benchmark/layer_metrics/): a number in every run of this program, 0
+    where nothing happened, and nothing from a program without the fields."""
+
+    @pytest.mark.parametrize("name,expected", [
+        ("window_stall_pct.train", 16.0),
+        ("window_stall_unexplained_pct.train", 6.0),
+        ("host_gc_pause_pct.train", 9.0),
+        ("host_preempted_per_step.train", 7 / 9),
+        ("host_loop_other_ms.train", 5.1 / 9),
+    ])
+    def test_reads_the_windows_intervals_but_the_first(self, name, expected):
+        assert _reader(name)(_window()) == pytest.approx(expected)
+
+    @pytest.mark.parametrize("name", RATIO_READERS)
+    def test_a_quiet_window_reads_zero_and_not_nothing(self, name):
+        ctx = _window(stall_ms=0.0, stall_cause=None, host_gc_ms=0.0, host_nivcsw=0)
+        assert _reader(name)(ctx) == 0.0
+
+    @pytest.mark.parametrize("name", RATIO_READERS + ["host_loop_other_ms.train"])
+    def test_a_program_without_the_account_gives_nothing_to_read(self, name):
+        parent = {"kind": "train", "steps": 6, "records": [
+            {"kind": "train_step", "step": 2.0, "loss": 1.0},
+            {"kind": "span", "name": "host_log_fetch", "dur_s": 0.3},
+            {"kind": "train_step", "step": 5.0, "loss": 1.0}]}
+        assert _reader(name)(parent) is None
+        assert _reader(name)({"kind": "train", "records": []}) is None
 
 
 class TestProfilingShim:
