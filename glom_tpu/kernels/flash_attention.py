@@ -42,7 +42,14 @@ saved log-sum-exp and `delta = rowsum(do * o)` are rows, lane-dense),
 (dp - delta)`, `dk += ds^T q` in scratch, and `dq += ds k` into a float32
 block of the whole group that stays in VMEM until the group ends: five
 products a pair, the scores computed once. What the custom VJP keeps is q,
-k, v, the output and the log-sum-exp.
+k, v, the output and the log-sum-exp as rows, [B, G, R, T] float32: the
+forward kernel writes a column, [..., T, 1], whose last dimension HBM pads to
+128 lanes (537 MB for a Laguna sliding layer of two sequences where the rows
+are 4 MB), and the column is sliced as it leaves the kernel, so that it is
+never a residual. The output and the rows carry the checkpoint names
+KEPT_OUTPUT and KEPT_LSE: a `jax.checkpoint` whose policy saves those two
+names (`hybrid_lm.run_stack`'s) recomputes q, k and v for the backward kernel
+and reads the forward kernel's results where it would have run it again.
 """
 
 from __future__ import annotations
@@ -52,6 +59,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -65,6 +73,10 @@ QUERY_ROWS = 1024  # a query tile's rows, the group's R heads folded in
 # XLA loop.
 DQ_RESIDENT_BYTES = 48 * 1024 * 1024
 VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+# The checkpoint names of what the forward kernel made and the backward reads:
+# the output, head-major in the operands' type, and the log-sum-exp rows.
+KEPT_OUTPUT = "attn_flash_out"
+KEPT_LSE = "attn_flash_lse"
 _NEG = -1e30  # a masked score: finite, so that exp(m - m) of a row not yet seen is no NaN
 _FIRST, _LAST, _MASKED = 1, 2, 4
 _LANES = 128
@@ -310,9 +322,10 @@ def _tile_rows(x, tq):
 
 
 def _backward(q, k, v, o, lse, do, window, tq, tk, interpret):
-    """The three gradients, head-major: dq in float32 and unscaled (the
-    caller scales it as it casts), and dk and dv for every grid row (the
-    caller adds those that share a key head or a value head)."""
+    """The three gradients, head-major, from the forward's output and its
+    log-sum-exp rows [B, G, R, T]: dq in float32 and unscaled (the caller
+    scales it as it casts), and dk and dv for every grid row (the caller adds
+    those that share a key head or a value head)."""
     bsz, g, r, t, d = q.shape
     dv = v.shape[-1]
     k_share, share = g // k.shape[1], g // v.shape[1]
@@ -354,7 +367,7 @@ def _backward(q, k, v, o, lse, do, window, tq, tk, interpret):
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="attn_flash_bwd_onesweep",
-    )(kj, qi, flags, q, k, v, do, _tile_rows(lse[..., 0], tq), _tile_rows(delta, tq))
+    )(kj, qi, flags, q, k, v, do, _tile_rows(lse, tq), _tile_rows(delta, tq))
 
 
 # ------------------------------------------------------------------ the op
@@ -367,7 +380,8 @@ def _flash(q, k, v, window, tq, tk, interpret):
 
 def _flash_fwd(q, k, v, window, tq, tk, interpret):
     o, lse = _forward(q, k, v, window, tq, tk, interpret)
-    return o, (q, k, v, o, lse)
+    o = checkpoint_name(o, KEPT_OUTPUT)
+    return o, (q, k, v, o, checkpoint_name(lse[..., 0], KEPT_LSE))
 
 
 def _flash_bwd(window, tq, tk, interpret, kept, do):

@@ -47,8 +47,9 @@ ATTN_KEY_BLOCK = 128  # the unit `blocked_attention` counts the keys it multipli
 LOSS_ROW_BLOCK = 2048
 ROW_TILE = 1024  # the experts' rows come in multiples of this
 RUNG_LOADS = (2,)  # the small row counts, in balanced loads (`row_rungs`)
-COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_rows_full_share",
-            "moe_max_expert_load")
+ROUTED_COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_rows_full_share",
+                   "moe_max_expert_load")
+COUNTERS = ROUTED_COUNTERS + ("attn_forward_kept",)
 
 
 # ----------------------------------------------------------------- parameters
@@ -271,7 +272,8 @@ def blocked_attention(q, k, v, window=None):
     here nor in the backward pass; no [T, T] array is kept. q [B, T, G, R,
     D], k [B, T, G, D], v [B, T, Gv, Dv] (KV head g reads value head g // (G /
     Gv)) -> ([B, T, G, R, Dv], the key blocks of ATTN_KEY_BLOCK keys that
-    were multiplied, summed over the query blocks).
+    were multiplied, summed over the query blocks, and 1 where the kernels
+    ran, else 0).
 
     Where the shapes tile (`flash_attention.tiles`) and the device is a TPU,
     the blocks are the Pallas kernels' tiles and the scores never leave VMEM;
@@ -282,7 +284,7 @@ def blocked_attention(q, k, v, window=None):
     if tiled and flash_attention.on_tpu():
         tq, tk = tiled
         out = flash_attention.flash_attention(q, k, v, window, tq=tq, tk=tk)
-        return out, flash_attention.key_blocks(t, tq, tk, window, ATTN_KEY_BLOCK)
+        return out, flash_attention.key_blocks(t, tq, tk, window, ATTN_KEY_BLOCK), 1
     if v.shape[2] != g:
         v = jnp.repeat(v, g // v.shape[2], axis=2)
     out, key_blocks = [], 0
@@ -293,12 +295,12 @@ def blocked_attention(q, k, v, window=None):
             _attend, first=first, key_first=low, window=window))
         out.append(block(q[:, first:last], k[:, low:last], v[:, low:last]))
         key_blocks += -(-(last - low) // ATTN_KEY_BLOCK)
-    return jnp.concatenate(out, axis=1), key_blocks
+    return jnp.concatenate(out, axis=1), key_blocks, 0
 
 
 def attention_mixer(p, x_in, cfg: HybridLMConfig, dtype):
     """Grouped-query causal attention, no positions, no bias, by
-    `blocked_attention`."""
+    `blocked_attention`: (the mixer's output, 1 where the kernels ran)."""
     g, dh = cfg.num_key_value_heads, cfg.head_dim
     r = cfg.num_attention_heads // g
     bsz, t = x_in.shape[:2]
@@ -307,8 +309,9 @@ def attention_mixer(p, x_in, cfg: HybridLMConfig, dtype):
         q = _mm(u, _cast(p["q"], dtype)).astype(u.dtype).reshape(bsz, t, g, r, dh)
         k = _mm(u, _cast(p["k"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
         v = _mm(u, _cast(p["v"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
-        o = blocked_attention(q, k, v)[0].reshape(bsz, t, g * r * dh)
-        return _mm(o, _cast(p["o"], dtype)).astype(u.dtype)
+        o, _, on_kernels = blocked_attention(q, k, v)
+        o = _mm(o.reshape(bsz, t, g * r * dh), _cast(p["o"], dtype)).astype(u.dtype)
+    return o, on_kernels
 
 
 # ---------------------------------------------------- latent mixture of experts
@@ -482,12 +485,14 @@ def moe_routed(p, u2, cfg, dtype, choices=None, family=LATENT_RELU2, rung_loads=
     return out, counters, top_i
 
 
-def merge_counters(counters) -> dict:
-    """One dict of COUNTERS an expert layer -> each the mean over the layers,
-    the fullest expert's load the maximum; {} where there is no expert layer."""
+def merge_counters(counted) -> dict:
+    """One dict a layer, an expert layer's holding ROUTED_COUNTERS -> each
+    the mean over the expert layers, the fullest expert's load the maximum;
+    {} where there is no expert layer."""
     merged = {}
+    counters = [c for c in counted if "moe_pairs_here" in c]
     if counters:
-        for name in COUNTERS:
+        for name in ROUTED_COUNTERS:
             vals = jnp.stack([c[name] for c in counters])
             merged[name] = jnp.max(vals) if name == "moe_max_expert_load" else jnp.mean(vals)
     return merged
@@ -515,7 +520,8 @@ def layer(kind: str, p, x, cfg: HybridLMConfig, dtype):
     if kind == "M":
         return x + mamba_mixer(p, x, cfg, dtype), {}, None
     if kind == "*":
-        return x + attention_mixer(p, x, cfg, dtype), {}, None
+        out, on_kernels = attention_mixer(p, x, cfg, dtype)
+        return x + out, {"attn_on_kernels": on_kernels}, None
     out, counters, top_i = moe_mixer(p, x, cfg, dtype)
     return x + out, counters, top_i
 
@@ -528,20 +534,41 @@ def run_stack(params: Params, ids, layers, *, compute_dtype=None, remat: bool = 
     `f(p, x, side) -> (x, side, aux)`: `side` is what a layer hands the
     layers after it beside the residual stream. A recomputed layer takes it
     as an input, so its gradient flows back to the layer that made it.
-    Returns (the last layer's output [B, T, d], every layer's aux)."""
+    Returns (the last layer's output [B, T, d], every layer's aux).
+
+    A recomputed layer keeps its inputs and, where its attention ran the
+    kernels, the two arrays `flash_attention` names: the forward kernel's
+    output (B x heads x T x Dv in the compute dtype) and its log-sum-exp
+    rows, which are all the backward kernel reads of the forward's. Keeping
+    them costs the bytes a second run of the kernel would write again, and
+    saves the run: the recomputation rebuilds q, k, v and everything round
+    the kernel, and the kernel's call falls out of the backward's program as
+    dead code (`forward_kept` counts the layers). The XLA loop names nothing,
+    so nothing of it is kept."""
     with jax.named_scope("embed"):
         x = _cast(params["embed"][ids], compute_dtype)
+    keep = jax.checkpoint_policies.save_only_these_names(
+        flash_attention.KEPT_OUTPUT, flash_attention.KEPT_LSE)
     aux = []
     for f, p in zip(layers, params["layers"]):
-        x, side, a = (jax.checkpoint(f) if remat else f)(p, x, side)
+        x, side, a = (jax.checkpoint(f, policy=keep) if remat else f)(p, x, side)
         aux.append(a)
     return x, aux
 
 
+def forward_kept(counted, remat: bool):
+    """The records' `attn_forward_kept`: the attention layers of the step
+    whose forward kernel's output the layer's recomputation reads and does
+    not rebuild. `counted` is one dict a layer, an attention layer's holding
+    `blocked_attention`'s flag under `attn_on_kernels`; without `remat`
+    nothing is recomputed, and on the XLA loop nothing is kept."""
+    return jnp.float32(sum(c.get("attn_on_kernels", 0) for c in counted) if remat else 0)
+
+
 def hidden_states(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
                   remat: bool = True):
-    """ids [B, T] -> (the last layer's output [B, T, d], one counters dict
-    an `E` layer, the routers' choices [E layers, B * T, k])."""
+    """ids [B, T] -> (the last layer's output [B, T, d], one counters dict a
+    layer, the routers' choices [E layers, B * T, k])."""
 
     def held(kind):
         def f(p, x, side):
@@ -551,8 +578,7 @@ def hidden_states(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=Non
 
     x, aux = run_stack(params, ids, [held(kind) for kind in cfg.pattern],
                        compute_dtype=compute_dtype, remat=remat)
-    experts = [a for kind, a in zip(cfg.pattern, aux) if kind == "E"]
-    return x, [c for c, _ in experts], [top_i for _, top_i in experts]
+    return x, [c for c, _ in aux], [top_i for _, top_i in aux if top_i is not None]
 
 
 def routing_choices(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None):
@@ -589,16 +615,17 @@ def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
     """Next-token cross-entropy over the vocabulary rows held here
     (`next_token_loss`). Returns (loss, counters): pairs routed to the
     experts held, rows of the rung the grouped product ran at and whether
-    that was the full one, each the mean over the `E` layers, and the
-    fullest expert's load over all of them."""
-    x, counters, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
+    that was the full one, each the mean over the `E` layers, the fullest
+    expert's load over all of them, and `forward_kept`."""
+    x, counted, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = rms_norm(x, params["final_norm"], cfg.layer_norm_epsilon).reshape(
             -1, x.shape[-1])
         loss = next_token_loss(h, _cast(params["head"], compute_dtype), ids)
     with jax.named_scope("step_metrics"):
-        merged = merge_counters(counters)
-    return loss, merged
+        counters = merge_counters(counted)
+        counters["attn_forward_kept"] = forward_kept(counted, remat)
+    return loss, counters
 
 
 def count_shapes(shapes) -> int:

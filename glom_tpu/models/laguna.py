@@ -189,7 +189,7 @@ def head_gate(p, u, dtype):
 
 def attention_mixer(attention: str, p, x_in, cfg: LagunaConfig, dtype):
     """The layer's input [B, T, d] -> (the attention's output [B, T, d], key
-    blocks multiplied)."""
+    blocks multiplied, 1 where the kernels ran)."""
     g, dh, heads = cfg.num_key_value_heads, cfg.head_dim, cfg.heads(attention)
     bsz, t = x_in.shape[:2]
     with jax.named_scope(ATTENTION_SCOPE[attention]):
@@ -198,12 +198,12 @@ def attention_mixer(attention: str, p, x_in, cfg: LagunaConfig, dtype):
         k = _mm(u, _cast(p["k"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
         v = _mm(u, _cast(p["v"], dtype)).astype(u.dtype).reshape(bsz, t, g, dh)
         q, k = rope(q, attention, cfg), rope(k, attention, cfg)
-        a, key_blocks = blocked_attention(
+        a, key_blocks, on_kernels = blocked_attention(
             q, k, v, cfg.sliding_window if attention == "S" else None)
         with jax.named_scope("attn_gate"):
             a = a * head_gate(p, u, dtype).reshape(bsz, t, g, heads // g, 1)
         out = _mm(a.reshape(bsz, t, heads * dh), _cast(p["o"], dtype)).astype(u.dtype)
-    return out, key_blocks
+    return out, key_blocks, on_kernels
 
 
 def swiglu(u, w_gate, w_up, w_down, dtype):
@@ -229,11 +229,11 @@ def mlp(kind: str, p, x, cfg: LagunaConfig, dtype):
 
 def layer(attention: str, mlp_kind: str, p, x, cfg: LagunaConfig, dtype):
     """One layer: (x, the layer's counters, the router's choices or None)."""
-    out, key_blocks = attention_mixer(attention, p, x, cfg, dtype)
+    out, key_blocks, on_kernels = attention_mixer(attention, p, x, cfg, dtype)
     x = x + out
     out, counters, top_i = mlp(mlp_kind, p, x, cfg, dtype)
     name = "attn_key_blocks_window" if attention == "S" else "attn_key_blocks_full"
-    return x + out, {**counters, name: key_blocks}, top_i
+    return x + out, {**counters, name: key_blocks, "attn_on_kernels": on_kernels}, top_i
 
 
 # ------------------------------------------------------------------ the stack
@@ -264,14 +264,16 @@ def lm_loss(params, ids, cfg: LagunaConfig, *, compute_dtype=None,
             remat: bool = True) -> Tuple[jnp.ndarray, dict]:
     """Next-token cross-entropy over the vocabulary rows held here
     (`hybrid_lm.next_token_loss`). Returns (loss, counters): the routed
-    part's four over the `E` layers (`hybrid_lm.merge_counters`), and the key
-    blocks the window layers and the full layers multiplied this step."""
+    part's four over the `E` layers (`hybrid_lm.merge_counters`), the key
+    blocks the window layers and the full layers multiplied this step, and
+    `hybrid_lm.forward_kept`."""
     x, counted, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).reshape(-1, x.shape[-1])
         loss = next_token_loss(h, _cast(params["head"], compute_dtype), ids)
     with jax.named_scope("step_metrics"):
-        counters = hybrid_lm.merge_counters([c for c in counted if "moe_pairs_here" in c])
+        counters = hybrid_lm.merge_counters(counted)
+        counters["attn_forward_kept"] = hybrid_lm.forward_kept(counted, remat)
         for name in ("attn_key_blocks_window", "attn_key_blocks_full"):
             counters[name] = jnp.float32(sum(c.get(name, 0) for c in counted))
     return loss, counters

@@ -46,6 +46,7 @@ from glom_tpu.models.hybrid_lm import (
     blocked_attention,
     causal_conv,
     count_shapes,
+    forward_kept,
     init_tree,
     next_token_loss,
     rms_norm,
@@ -53,7 +54,8 @@ from glom_tpu.models.hybrid_lm import (
 )
 from glom_tpu.utils.config import SambaYConfig
 
-COUNTERS = ("attn_key_blocks_window", "attn_key_blocks_full", "scan_chunks")
+COUNTERS = ("attn_key_blocks_window", "attn_key_blocks_full", "scan_chunks",
+            "attn_forward_kept")
 # The selective scan's schedule: positions a carried state (a chunk, recomputed
 # whole in the backward pass), and positions a segment (a chunk's segments are
 # scanned side by side).
@@ -251,18 +253,19 @@ def gmu_mixer(p, x_in, memory, cfg: SambaYConfig, dtype):
 
 def differential_attention(p, q, k, v, cfg: SambaYConfig, index: int, dtype, window=None):
     """q [B, T, heads x D], k and v [B, T, KV heads x D] -> ([B, T, d], key
-    blocks multiplied). Heads pair up in their order: query heads (2j, 2j+1)
-    are pair j's (q1, q2), KV heads (2g, 2g+1) pair g's (k1, k2), whose
-    values lie side by side; query pair j reads KV pair j // (pairs a KV
-    pair). Both softmaxes go through one `blocked_attention`: KV head (g, s)
-    is read by the members s of its pair's query pairs, against the pair's
-    values, which it is handed once a pair."""
+    blocks multiplied, 1 where the kernels ran). Heads pair up in their
+    order: query heads (2j, 2j+1) are pair j's (q1, q2), KV heads (2g, 2g+1)
+    pair g's (k1, k2), whose values lie side by side; query pair j reads KV
+    pair j // (pairs a KV pair). Both softmaxes go through one
+    `blocked_attention`: KV head (g, s) is read by the members s of its
+    pair's query pairs, against the pair's values, which it is handed once a
+    pair."""
     hq, hkv, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     bsz, t = q.shape[:2]
     pairs, per = hkv // 2, hq // hkv
     q = q.reshape(bsz, t, pairs, per, 2, dh).swapaxes(3, 4).reshape(bsz, t, hkv, per, dh)
     k = k.reshape(bsz, t, hkv, dh)
-    a, key_blocks = blocked_attention(q, k, v.reshape(bsz, t, pairs, 2 * dh), window)
+    a, key_blocks, on_kernels = blocked_attention(q, k, v.reshape(bsz, t, pairs, 2 * dh), window)
     a = a.reshape(bsz, t, pairs, 2, per, 2 * dh)
     lam0 = lambda_init(index)
     lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
@@ -270,12 +273,13 @@ def differential_attention(p, q, k, v, cfg: SambaYConfig, index: int, dtype, win
     o = a[:, :, :, 0] - lam.astype(a.dtype) * a[:, :, :, 1]    # [B, T, pairs, per, 2 D]
     o = rms_norm(o, p["subln"], cfg.layer_norm_eps) * (1.0 - lam0)
     out = _mm(o.reshape(bsz, t, hq * dh).astype(q.dtype), _cast(p["o"], dtype))
-    return (out + p["o_b"]).astype(q.dtype), key_blocks
+    return (out + p["o_b"]).astype(q.dtype), key_blocks, on_kernels
 
 
 def attention_mixer(kind: str, index: int, p, x_in, shared_kv, cfg: SambaYConfig, dtype):
     """`W`, `F` (own keys and values) or `X` (`shared_kv`'s). Returns (the
-    mixer's output, the keys and values it read, key blocks multiplied)."""
+    mixer's output, the keys and values it read, key blocks multiplied, 1
+    where the kernels ran)."""
     q_width = cfg.num_attention_heads * cfg.head_dim
     kv_width = cfg.num_key_value_heads * cfg.head_dim
     with jax.named_scope(ATTENTION_SCOPE[kind]):
@@ -286,9 +290,9 @@ def attention_mixer(kind: str, index: int, p, x_in, shared_kv, cfg: SambaYConfig
         else:
             qkv = (_mm(u, _cast(p["qkv"], dtype)) + p["qkv_b"]).astype(u.dtype)
             q, k, v = jnp.split(qkv, [q_width, q_width + kv_width], axis=-1)
-        out, key_blocks = differential_attention(
+        out, key_blocks, on_kernels = differential_attention(
             p, q, k, v, cfg, index, dtype, cfg.sliding_window if kind == "W" else None)
-    return out, (k, v), key_blocks
+    return out, (k, v), key_blocks, on_kernels
 
 
 # ------------------------------------------------------------------ the stack
@@ -309,8 +313,10 @@ def layer(kind: str, index: int, p, x, side, cfg: SambaYConfig, dtype):
     elif kind == "G":
         out = gmu_mixer(p, x, memory, cfg, dtype)
     else:
-        out, kv, key_blocks = attention_mixer(kind, index, p, x, shared_kv, cfg, dtype)
+        out, kv, key_blocks, on_kernels = attention_mixer(
+            kind, index, p, x, shared_kv, cfg, dtype)
         counters["attn_key_blocks_window" if kind == "W" else "attn_key_blocks_full"] = key_blocks
+        counters["attn_on_kernels"] = on_kernels
         if index == n // 2 + 1:
             shared_kv = kv
     x = x + out
@@ -331,8 +337,8 @@ def lm_loss(params, ids, cfg: SambaYConfig, *, compute_dtype=None,
     """Next-token cross-entropy over the vocabulary rows held here, under the
     tied embedding (`hybrid_lm.next_token_loss`). Returns (loss, counters):
     the key blocks the window layers and the full-length layers (`F`, `X`)
-    multiplied this step, and the chunks of the recurrence a Mamba layer
-    ran."""
+    multiplied this step, the chunks of the recurrence a Mamba layer ran,
+    and `hybrid_lm.forward_kept`."""
     x, counted = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = layer_norm(x, params["final_norm_w"], params["final_norm_b"],
@@ -342,5 +348,6 @@ def lm_loss(params, ids, cfg: SambaYConfig, *, compute_dtype=None,
         of = lambda name: jnp.stack([jnp.float32(c.get(name, 0)) for c in counted])
         counters = {"attn_key_blocks_window": jnp.sum(of("attn_key_blocks_window")),
                     "attn_key_blocks_full": jnp.sum(of("attn_key_blocks_full")),
-                    "scan_chunks": jnp.max(of("scan_chunks"))}
+                    "scan_chunks": jnp.max(of("scan_chunks")),
+                    "attn_forward_kept": forward_kept(counted, remat)}
     return loss, counters

@@ -18,8 +18,11 @@ and the backward pass rounds `ds` once where XLA's rounds it in the product
 (under 0.4% measured, 1% allowed).
 """
 
+import contextlib
 import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +31,7 @@ import pytest
 
 from benchmark import weights_sambay as ws
 from glom_tpu.kernels import flash_attention as fa
-from glom_tpu.models import hybrid_lm, sambay
+from glom_tpu.models import hybrid_lm, laguna, sambay
 from glom_tpu.utils.presets import get_preset
 
 # (KV heads, value heads, query heads a KV head, D, Dv): SambaY's pairs share
@@ -266,10 +269,10 @@ def test_a_window_at_least_the_length_is_full_attention_and_a_shorter_one_is_not
     so each row's first tile is its last, and no row is a NaN."""
     q, k, v, cot = inputs(384, HEADS["sambay_d64_dv128_r2"], jnp.float32, seed=1, bsz=2)
     blocked = lambda window: jax.jit(lambda *a: hybrid_lm.blocked_attention(*a, window))
-    full, full_blocks = blocked(None)(q, k, v)
-    got, blocks = blocked(window)(q, k, v)
+    full, full_blocks, on_kernels = blocked(None)(q, k, v)
+    got, blocks, _ = blocked(window)(q, k, v)
     assert (rel(got, full) < 1e-6) == same_as_full
-    assert (blocks == full_blocks) == same_as_full and blocks <= full_blocks
+    assert (blocks == full_blocks) == same_as_full and blocks <= full_blocks and on_kernels == 1
     grads = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * cot), argnums=(0, 1, 2)))(q, k, v)
     got = (got,) + grads(lambda *a: hybrid_lm.blocked_attention(*a, window)[0])
     want = (masked_softmax_attention(q, k, v, window),) + grads(
@@ -316,6 +319,135 @@ def test_no_position_of_a_sambay_stage_sees_a_later_token_through_the_kernels(ke
         got, _ = logits(later)
         assert jnp.array_equal(got[:, :t], base[:, :t]), t
         assert not jnp.array_equal(got[:, t], base[:, t]), t
+
+
+# ------------------------------ what a recomputed layer keeps (`hybrid_lm.run_stack`)
+
+
+def _tiny(preset, **shapes):
+    return dataclasses.replace(get_preset(preset).model, **shapes)
+
+
+# The three families' tiny presets at shapes that tile (heads of 64, 128
+# tokens, one tile): (the model's module, its init, the configuration, its
+# attention layers, the same configuration cut to one attention layer).
+FAMILIES = {
+    "laguna": (laguna, laguna.init_laguna, _tiny("laguna-tiny", head_dim=64, seq_len=128), 5,
+               dict(layer_offset=1, num_hidden_layers=1)),                      # S + E
+    "sambay": (sambay, sambay.init_sambay,
+               dataclasses.replace(KERNEL_SHAPED, sliding_window=64, seq_len=128), 3,   # MWMFGX
+               dict(layer_offset=1, num_hidden_layers=1, num_hidden_layers_total=8)),   # W
+    "hybrid_lm": (hybrid_lm, hybrid_lm.init_hybrid_lm,
+                  _tiny("hybrid-lm-tiny", head_dim=64, seq_len=128), 1,         # ME*EM
+                  dict(layer_offset=2, num_hidden_layers=1)),                   # *
+}
+
+
+@contextlib.contextmanager
+def parents_policy():
+    """`run_stack` as it was: a recomputed layer keeps its inputs alone."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                      lambda *names: jax.checkpoint_policies.nothing_saveable)
+        yield
+
+
+def _params_and_ids(family, cfg):
+    params = FAMILIES[family][1](jax.random.PRNGKey(3), cfg)
+    return params, jax.random.randint(jax.random.PRNGKey(4), (2, cfg.seq_len), 0, cfg.vocab_size)
+
+
+def _loss_and_params(family, dtype=jnp.bfloat16, **kw):
+    model, _, cfg, _, _ = FAMILIES[family]
+    params, ids = _params_and_ids(family, cfg)
+    return (lambda p: model.lm_loss(p, ids, cfg, compute_dtype=dtype, **kw)), params
+
+
+def _kernel_calls(loss, params):
+    text = str(jax.make_jaxpr(jax.grad(loss, has_aux=True))(params))
+    return text.count("name=attn_flash_fwd"), text.count("name=attn_flash_bwd_onesweep")
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no_remat"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_the_recomputation_reads_the_forward_kernels_results_and_does_not_run_it_again(
+        family, remat, kernels_here):
+    """The gradient's program holds one forward and one backward kernel call
+    an attention layer, recomputed or not, and the records' counter says how
+    many layers' recomputation read what the forward kept."""
+    layers = FAMILIES[family][3]
+    loss, params = _loss_and_params(family, remat=remat)
+    assert _kernel_calls(loss, params) == (layers, layers)
+    assert float(jax.jit(loss)(params)[1]["attn_forward_kept"]) == (layers if remat else 0)
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_the_kept_results_change_no_bit_of_a_gradient(family, kernels_here):
+    """Against `run_stack` with the parent's policy, which runs the forward
+    kernel twice a layer: the same loss and the same gradients, bit for bit.
+    In float32: under `jit` the CPU keeps a bfloat16 intermediate in float32
+    where it fuses its producer with its consumer, so a result that is kept
+    (rounded) and one that is rebuilt beside its consumer differ there by a
+    rounding (0.5% of a gradient; none op by op, none without
+    `xla_allow_excess_precision`), which is the compiler's and not the
+    policy's."""
+    layers = FAMILIES[family][3]
+    loss, params = _loss_and_params(family, dtype=None, remat=True)
+    value_and_grad = lambda: jax.jit(jax.value_and_grad(lambda p: loss(p)[0]))(params)
+    got = value_and_grad()
+    with parents_policy():
+        assert _kernel_calls(loss, params) == (2 * layers, layers)
+        want = value_and_grad()
+    assert all(jnp.array_equal(a, b) for a, b in zip(jax.tree_util.tree_leaves(got),
+                                                     jax.tree_util.tree_leaves(want)))
+
+
+def _saved(family, capsys):
+    """What differentiating one recomputed attention layer keeps beyond the
+    arguments and the constants, as `print_saved_residuals` words it, without
+    the source's place; and the layer's configuration."""
+    model, _, whole, _, one_layer = FAMILIES[family]
+    cfg = dataclasses.replace(whole, **one_layer)
+    params, ids = _params_and_ids(family, cfg)
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(lambda p: jnp.sum(model.hidden_states(
+        p, ids, cfg, compute_dtype=jnp.bfloat16, remat=True)[0].astype(jnp.float32)), params)
+    return cfg, [line.split(" from /")[0] for line in capsys.readouterr().out.splitlines()
+                 if " from the argument " not in line and " from a constant" not in line]
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_recomputed_attention_layer_keeps_the_output_and_the_log_sum_exp_rows(
+        family, kernels_here, capsys):
+    """Beyond the layer's input (the embedding's rows, and the ids that took
+    them): one array of B x heads x T x Dv in the compute type, head-major,
+    and one float32 array of B x heads x T. No [..., T, 1] column, no q, k or
+    v, nothing of the layer's second half."""
+    cfg, saved = _saved(family, capsys)
+    heads = cfg.heads("S") if family == "laguna" else cfg.num_attention_heads
+    dv = 2 * cfg.head_dim if family == "sambay" else cfg.head_dim   # a pair's values
+    inputs = ["i32[2,128,1] output of broadcast_in_dim",
+              f"bf16[2,128,{cfg.hidden_size}] output of convert_element_type"]
+    assert saved[:2] == inputs and len(saved) == 4, saved
+    # a residual that the forward pass reads too comes out of the
+    # `reduce_precision` that `jax.checkpoint` puts on it, and loses its name
+    size = lambda line: math.prod(int(n) for n in re.match(r"\w+\[([\d,]+)\] ", line)[1].split(","))
+    assert saved[2].startswith("bf16[") and saved[2].endswith("output of reduce_precision")
+    assert size(saved[2]) == 2 * heads * 128 * dv
+    assert saved[3].startswith("f32[") and saved[3].endswith(f"named '{fa.KEPT_LSE}'")
+    assert size(saved[3]) == 2 * heads * 128
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_on_the_xla_loop_a_recomputed_layer_keeps_what_it_kept(family, capsys):
+    """No kernel, no name: the policy keeps nothing, the counter reads 0, and
+    the residuals are the parent's, the layer's input alone."""
+    _, saved = _saved(family, capsys)
+    assert len(saved) == 2 and not any("attn_flash" in line for line in saved), saved
+    with parents_policy():
+        assert _saved(family, capsys)[1] == saved
+    loss, params = _loss_and_params(family, remat=True)
+    assert float(jax.jit(loss)(params)[1]["attn_forward_kept"]) == 0
 
 
 # ----------------------------------------------- compiled for the chip, not run

@@ -67,7 +67,7 @@ def stream(seed=1, t=64, d=None):
 def test_a_layer_matches_the_reference(weights, kind, dtype, tol):
     w = ref.layer_weights(weights, first_layer(kind))
     x = stream()
-    mixer = {"M": lm.mamba_mixer, "*": lm.attention_mixer,
+    mixer = {"M": lm.mamba_mixer, "*": lambda *a: lm.attention_mixer(*a)[0],
              "E": lambda *a: lm.moe_mixer(*a)[0]}[kind]
     # the mixer's own output, without the residual it is added to
     got = mixer(w, x if dtype is None else x.astype(dtype), CFG, dtype)
@@ -271,7 +271,7 @@ def test_the_attention_head_shares_add_up_to_the_uncut_layer():
     for s in range(2):
         mine = dict(w, q=w["q"][:, s * q:(s + 1) * q], k=w["k"][:, s * kv:(s + 1) * kv],
                     v=w["v"][:, s * kv:(s + 1) * kv], o=w["o"][s * q:(s + 1) * q])
-        total = total + lm.attention_mixer(mine, x, share, None)
+        total = total + lm.attention_mixer(mine, x, share, None)[0]
     want = jnp.stack([ref.layer("*", w, x[b], model)[0] - x[b] for b in range(2)])
     assert rel(total, want) < F32_TOL
 

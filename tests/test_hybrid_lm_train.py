@@ -190,6 +190,7 @@ def test_the_logging_records_carry_the_routing_counters(fitted, tiny):
         assert 0 < r["moe_pairs_here"] <= n * min(k, e)
         assert r["moe_pairs_here"] / layers <= r["moe_max_expert_load"] * e
         assert r["moe_max_expert_load"] <= n
+        assert r["attn_forward_kept"] == 0     # the XLA loop names nothing for the recomputation
 
 
 def test_the_records_pass_the_telemetry_schema(fitted):

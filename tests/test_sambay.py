@@ -210,8 +210,9 @@ def test_a_window_at_least_the_length_is_full_attention_and_a_shorter_one_is_not
         window, same_as_full):
     q, k, v = attention_inputs(80)
     blocked = jax.jit(hybrid_lm.blocked_attention, static_argnums=3)
-    full, full_blocks = blocked(q, k, v, None)
-    got, blocks = blocked(q, k, v, window)
+    full, full_blocks, on_kernels = blocked(q, k, v, None)
+    got, blocks, _ = blocked(q, k, v, window)
+    assert on_kernels == 0   # the XLA loop
     assert (rel(got, full) < 1e-6) == same_as_full
     assert (blocks == full_blocks) == same_as_full and blocks <= full_blocks
     assert rel(got, masked_softmax_attention(q, k, v, window)) < 2e-6
