@@ -21,12 +21,15 @@ equal parts, each a grid row of its own that reads the same KV head by the
 same kind of index map, and the parts' dk are added outside.
 
 Shapes served: T a multiple of 128, D and Dv multiples of 64, and some equal
-part of a group whose dq [R / parts, T, max(D, 128)] float32 fits
-DQ_RESIDENT_BYTES twice. At D = 128 that is every R to T = 32,768 (T = 8,192:
-R = 4 and R = 6 whole, R = 8 in two parts of 4; T = 16,384: R = 4 in two parts,
-R = 6 in two parts of 3, R = 8 in four); at T = 65,536 and beyond a single
-head's dq is 64 MiB and the shape goes to the XLA loop, as does any length
-that is no multiple of 128 and any head size that is no multiple of 64.
+part of a group whose dq [R / parts, T, D in whole registers of 128 lanes]
+float32 fits DQ_RESIDENT_BYTES twice. At D = 128 that is every R to T = 32,768
+(T = 8,192: R = 4 and R = 6 whole, R = 8 in two parts of 4; T = 16,384: R = 4
+in two parts, R = 6 in two parts of 3, R = 8 in four); at T = 65,536 and beyond
+a single head's dq is 64 MiB and the shape goes to the XLA loop, as does any
+length that is no multiple of 128 and any head size that is no multiple of 64.
+At D = 192, Dv = 128 (a latent attention's 128 + 64 key dimensions: one query
+head a KV head) a head's dq is 256 lanes wide, 32 MiB twice at T = 16,384,
+and is served to T = 16,384.
 
 `schedule` is the one place that says which (query tile, key tile) pairs
 exist: the grids are its steps (scalar-prefetched, so a pair no query of
@@ -66,7 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 QUERY_TILES = (512, 256, 128)
 KEY_TILES = (1024, 512, 256, 128)
 QUERY_ROWS = 1024  # a query tile's rows, the group's R heads folded in
-# The backward keeps the dq [R, T, max(D, 128)] float32 of the query heads
+# The backward keeps the dq [R, T, D in whole 128-lane registers] float32 of the query heads
 # folded into one grid row in VMEM, twice (the pipeline's two buffers); past
 # this it does not fit beside the score tiles, and `head_parts` folds the group
 # in parts, or, where one head's is too much, `tiles` sends the shape to the
@@ -94,10 +97,13 @@ def on_tpu() -> bool:
 def head_parts(t: int, r: int, d: int):
     """In how many equal parts a KV head's R query heads fold into query
     tiles: the fewest whose dq stays resident (1 where the whole group's
-    does: 2 * R * T * max(D, 128) * 4 bytes <= DQ_RESIDENT_BYTES, an equality
-    at R = 6, D = 128, T = 8,192), or None where one head's does not."""
+    does: 2 * R * T * lanes(D) * 4 bytes <= DQ_RESIDENT_BYTES with lanes(D) =
+    D rounded up to whole registers of 128 lanes, which is what VMEM holds of
+    a row: 128 for D = 64 and 128, 256 for D = 192; an equality at R = 6, D =
+    128, T = 8,192), or None where one head's does not."""
+    lanes = -(-d // _LANES) * _LANES
     return next((parts for parts in range(1, r + 1) if r % parts == 0
-                 and 2 * (r // parts) * t * max(d, 128) * 4 <= DQ_RESIDENT_BYTES), None)
+                 and 2 * (r // parts) * t * lanes * 4 <= DQ_RESIDENT_BYTES), None)
 
 
 def tiles(t: int, r: int, d: int, dv: int, window=None):

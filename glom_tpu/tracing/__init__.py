@@ -29,6 +29,7 @@ _EXPORTS = {
     "DEVICE_PHASES": "spans",
     "LM_DEVICE_PHASES": "spans",
     "SAMBAY_DEVICE_PHASES": "spans",
+    "KIMI_DEVICE_PHASES": "spans",
     "SpanAggregator": "spans",
     "span": "spans",
     "spanned": "spans",

@@ -154,10 +154,35 @@ LAGUNA_INNER_SCOPES = (
     "attn_gate",
 )
 
+# The device scopes of the Kimi Linear language model's step
+# (models/kimi_linear.py). `embed`, the four `moe_*` scopes of the routed
+# part, `moe_shared`, `dense_mlp` and `lm_head_loss` mean what they mean in
+# LAGUNA_DEVICE_PHASES (the routed part is the same code). A Kimi Delta
+# Attention layer's first half is `kda_in` (input norm, the three projections
+# with their convolutions, the decay's, beta's and the gate's projections),
+# `kda_scan` (`kda_chunked` alone: the delta rule in chunks) and `kda_out`
+# (head norm, gate, out-projection); `latent_attention` is a latent layer's
+# whole first half: input norm, query and latent projections, the latent's
+# norm and expansion, the scores (the kernels' calls), the out-projection.
+KIMI_DEVICE_PHASES = (
+    "embed",
+    "kda_in",
+    "kda_scan",
+    "kda_out",
+    "latent_attention",
+    "dense_mlp",
+    "moe_router",
+    "moe_dispatch",
+    "moe_experts",
+    "moe_combine",
+    "moe_shared",
+    "lm_head_loss",
+)
+
 # The language models' Pallas kernels, by their `name=`
 # (kernels/flash_attention.py). They open no scope of their own: a call runs
 # inside the model's attention scope (`attention`; `window_attention`,
-# `full_attention`, `cross_attention`; Laguna's two), and its device time
+# `full_attention`, `cross_attention`; Laguna's two; `latent_attention`), and its device time
 # belongs to that scope. All begin with `attn_`; none matches `loop_*`, `ffw_*`,
 # `consensus_*` or `ragged-dot*`, the names by which the benchmark tells the
 # routes apart.

@@ -20,6 +20,7 @@ memory before remat even enters the picture.
 
 from __future__ import annotations
 
+import importlib
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -27,7 +28,13 @@ import jax.numpy as jnp
 
 from glom_tpu.models.core import ConsensusFn, GlomParams, glom_forward, init_glom
 from glom_tpu.ops.patch import LinearParams, init_linear, tokens_to_image
-from glom_tpu.utils.config import GlomConfig, LagunaConfig, SambaYConfig
+from glom_tpu.utils.config import (
+    GlomConfig,
+    HybridLMConfig,
+    KimiLinearConfig,
+    LagunaConfig,
+    SambaYConfig,
+)
 
 
 class DenoiseParams(NamedTuple):
@@ -67,21 +74,26 @@ class Objective(NamedTuple):
     vjp_path: str
 
 
+# The language-model families: configuration type -> (the model's module, its
+# init's name). The families share a stack and a loss: `init(key, cfg)` and the
+# module's `lm_loss(params, ids, cfg, compute_dtype=, remat=) -> (loss,
+# counters)`. A module is imported when its family is asked for.
+_LM_FAMILIES = {
+    HybridLMConfig: ("glom_tpu.models.hybrid_lm", "init_hybrid_lm"),
+    SambaYConfig: ("glom_tpu.models.sambay", "init_sambay"),
+    LagunaConfig: ("glom_tpu.models.laguna", "init_laguna"),
+    KimiLinearConfig: ("glom_tpu.models.kimi_linear", "init_kimi_linear"),
+}
+
+
 def _lm_family(cfg):
-    """(init, loss) of the language-model family `cfg` configures: the
-    families share a stack and a loss, `init(key, cfg)` and `loss(params,
-    ids, cfg, compute_dtype=, remat=) -> (loss, counters)`."""
-    if isinstance(cfg, SambaYConfig):
-        from glom_tpu.models.sambay import init_sambay, lm_loss
-
-        return init_sambay, lm_loss
-    if isinstance(cfg, LagunaConfig):
-        from glom_tpu.models.laguna import init_laguna, lm_loss
-
-        return init_laguna, lm_loss
-    from glom_tpu.models.hybrid_lm import init_hybrid_lm, lm_loss
-
-    return init_hybrid_lm, lm_loss
+    """(init, loss) of the language-model family `cfg` configures."""
+    found = next((f for kind, f in _LM_FAMILIES.items() if isinstance(cfg, kind)), None)
+    if found is None:
+        raise TypeError(f"no language-model family is configured by a {type(cfg).__name__}")
+    module, init = found
+    model = importlib.import_module(module)
+    return getattr(model, init), model.lm_loss
 
 
 def init_params(key: jax.Array, cfg):
@@ -92,8 +104,8 @@ def init_params(key: jax.Array, cfg):
 
 
 def lm_objective(cfg, tcfg) -> Objective:
-    """Next-token cross-entropy of a language model (models/hybrid_lm.py,
-    models/sambay.py or models/laguna.py, by the configuration's type) on
+    """Next-token cross-entropy of a language model (one of `_LM_FAMILIES`, by
+    the configuration's type) on
     [batch, seq_len] token ids. Nothing is drawn; the model's step counters
     are the aux. One route: XLA but for attention's scores, per-layer
     recomputation by `tcfg.remat`."""

@@ -333,6 +333,98 @@ class LagunaConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class KimiLinearConfig:
+    """A Kimi Linear language model (models/kimi_linear.py, `model_type:
+    kimi_linear`): pre-norm residual layers `h += Mixer(RMSNorm(h)); h +=
+    MLP(RMSNorm(h))` whose mixer is, by the published layer number, Kimi
+    Delta Attention (`K` in `layer_types`: `linear_num_heads` heads whose
+    state is a `linear_head_dim` x `linear_head_dim` matrix, decayed a key
+    channel at a time and corrected by a rank-one delta-rule update, behind
+    three causal convolutions of `short_conv_kernel_size`) or latent
+    attention without positions (`A`: `num_attention_heads` heads, queries
+    of `qk_nope_head_dim + qk_rope_head_dim` straight from the hidden state,
+    keys and values expanded from a normed latent of `kv_lora_rank` beside a
+    `qk_rope_head_dim`-wide key part shared by the heads, nothing rotated:
+    `mla_use_nope`); and whose MLP is a dense SwiGLU (the first
+    `first_k_dense_replace` published layers) or a router over
+    `num_experts_total` SwiGLU experts, `num_experts_per_token` a token,
+    beside `num_shared_experts` shared ones. Field names are those of the
+    published config.json where it has them (`linear_num_heads`,
+    `linear_head_dim` and `short_conv_kernel_size` are its
+    `linear_attn_config`'s `num_heads`, `head_dim` and the same;
+    `layer_types` spells its `kda_layers` and `full_attn_layers`, a letter a
+    layer from published layer 1 on); the defaults are
+    Kimi-Linear-48B-A3B-Instruct's.
+
+    `num_hidden_layers` layers from `layer_offset` on (0 is published layer
+    1), experts `expert_offset .. expert_offset + num_experts` of the
+    `num_experts_total` the router scores, and `vocab_size` rows of the
+    embedding and of the untied head are what THIS chip holds. Widths and
+    head counts are never a share. `moe_rung_loads` is `LagunaConfig`'s."""
+
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    rms_norm_eps: float = 1e-5
+    vocab_size: int = 163840
+    layer_types: str = "KKKA" * 6 + "KKA"
+    first_k_dense_replace: int = 1
+    layer_offset: int = 0
+    num_hidden_layers: int = 27
+    num_hidden_layers_total: int = 27
+    # Kimi Delta Attention
+    linear_num_heads: int = 32
+    linear_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    # latent attention
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # experts
+    num_experts: int = 256
+    num_experts_total: int = 256
+    expert_offset: int = 0
+    num_experts_per_token: int = 8
+    moe_intermediate_size: int = 1024
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.446
+    moe_rung_loads: int = 2
+    # the training sequence: tokens of one packed row of the batch
+    seq_len: int = 8192
+
+    def __post_init__(self):
+        n, first = self.num_hidden_layers_total, self.layer_offset
+        if len(self.layer_types) != n or set(self.layer_types) - set("KA"):
+            raise ValueError(f"{n} layers want {n} letters of 'K' and 'A'")
+        if first < 0 or self.num_hidden_layers < 1 or first + self.num_hidden_layers > n:
+            raise ValueError(f"layers {first}..{first + self.num_hidden_layers} of {n}")
+        if self.expert_offset + self.num_experts > self.num_experts_total:
+            raise ValueError("the experts held lie outside the router's width")
+
+    @property
+    def kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, MLP) letters of the layers held here, in order: `K` or `A`,
+        and `D` dense or `E` experts."""
+        held = range(self.layer_offset, self.layer_offset + self.num_hidden_layers)
+        return tuple((self.layer_types[i], "D" if i < self.first_k_dense_replace else "E")
+                     for i in held)
+
+    # what `hybrid_lm`'s router, sort and row ladder read of a configuration
+    @property
+    def n_routed_experts(self) -> int:
+        return self.num_experts
+
+    @property
+    def n_routed_experts_total(self) -> int:
+        return self.num_experts_total
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.num_experts_per_token
+
+
+@dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Parallelism layout. Axis sizes of 1 disable an axis.
 

@@ -14,6 +14,7 @@ from typing import Dict, Union
 from glom_tpu.utils.config import (
     GlomConfig,
     HybridLMConfig,
+    KimiLinearConfig,
     LagunaConfig,
     MeshConfig,
     SambaYConfig,
@@ -29,7 +30,7 @@ class Preset:
     description: str
     # The family the preset trains: its type picks the objective
     # (train/trainer.objective_for).
-    model: Union[GlomConfig, HybridLMConfig, SambaYConfig, LagunaConfig]
+    model: Union[GlomConfig, HybridLMConfig, SambaYConfig, LagunaConfig, KimiLinearConfig]
     train: TrainConfig
     mesh: MeshConfig
     sp_strategy: str = "none"  # none | ring | ulysses | halo | auto
@@ -70,7 +71,7 @@ class Preset:
 
 PRESETS: Dict[str, Preset] = {}
 # The presets of the language-model families (a HybridLMConfig, a
-# SambaYConfig or a LagunaConfig model), in a table of
+# SambaYConfig, a LagunaConfig or a KimiLinearConfig model), in a table of
 # their own: PRESETS stays GLOM's driver configurations, which is what the
 # sharded trainers and the serving stack iterate; `get_preset` finds both.
 LM_PRESETS: Dict[str, Preset] = {}
@@ -423,6 +424,58 @@ _register(
             yarn_original_max_position_embeddings=32,
             num_experts=4, num_experts_total=16, expert_offset=4, num_experts_per_tok=4,
             moe_intermediate_size=48, shared_expert_intermediate_size=48, seq_len=80,
+        ),
+        train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
+        mesh=MeshConfig(),
+    )
+)
+
+
+# 9. A fifth family: Kimi-Linear-48B-A3B-Instruct (kimi_linear: Kimi Delta
+# Attention, a gated delta rule with a decay a key channel, in three layers of
+# four; latent attention without positions in the fourth; top-8 of 256
+# sigmoid-routed SwiGLU experts beside a shared one), as ONE chip of 32 that
+# share each layer sees it: the routed experts divided 32 ways (8 of 256 here,
+# experts 88-95: chip 11 of its group), the embedding and the untied head by
+# rows 8 ways (20,480 of 163,840); both mixers with all their heads, the
+# router, the shared expert and the dense MLP whole. Depth: published layers
+# 1-5, the leading dense layer and one whole period after it (KDA + dense,
+# KDA, KDA, latent attention, KDA). Every width and every head is the
+# published one. 602M parameters held; one packed sequence of 16,384 tokens a
+# step. `moe_rung_loads=5` is for the benchmark's traffic as Laguna's 4 is (its
+# router collapses the same way, PERF.md trap 16): a held expert gets all
+# 16,384 tokens or none, a balanced load here is 4,096 pairs, and a small rung
+# of five, 20,480 rows, holds one such expert with the rows of room.
+_register(
+    Preset(
+        name="kimi-linear-ep32vp8",
+        description="Kimi-Linear-48B-A3B: one chip of 32 a layer (8/256 experts, 1/8 of "
+        "the vocabulary), layers 1-5, one 16k-token sequence a step",
+        model=KimiLinearConfig(
+            num_hidden_layers=5, num_experts=8, expert_offset=88, vocab_size=20480,
+            moe_rung_loads=5, seq_len=16384,
+        ),
+        train=TrainConfig(
+            batch_size=1, learning_rate=3e-4, compute_dtype="bfloat16", remat=True,
+        ),
+        mesh=MeshConfig(),
+    )
+)
+
+# 9b. The same family at a size the CPU holds: both mixers (K K K A K, the
+# first layer dense), 4 of 16 experts (offset 4), 80 tokens.
+_register(
+    Preset(
+        name="kimi-linear-tiny",
+        description="Kimi Linear LM, hidden 64, 5 layers K+D K K A K, 4 of 16 experts "
+        "— CPU drives",
+        model=KimiLinearConfig(
+            hidden_size=64, intermediate_size=160, vocab_size=128,
+            layer_types="KKKAK", num_hidden_layers=5, num_hidden_layers_total=5,
+            linear_num_heads=4, linear_head_dim=16, num_attention_heads=4,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            num_experts=4, num_experts_total=16, expert_offset=4, num_experts_per_token=4,
+            moe_intermediate_size=48, seq_len=80,
         ),
         train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
         mesh=MeshConfig(),

@@ -35,8 +35,11 @@ from glom_tpu.models import hybrid_lm, laguna, sambay
 from glom_tpu.utils.presets import get_preset
 
 # (KV heads, value heads, query heads a KV head, D, Dv): SambaY's pairs share
-# their values; the other model's one group of four
-HEADS = {"sambay_d64_dv128_r2": (2, 1, 2, 64, 128), "nemotron_d128_dv128_r4": (1, 1, 4, 128, 128)}
+# their values; the other model's one group of four,
+# their own group of four; Kimi Linear's latent attention, one query head a KV head with 128 +
+# 64 key dimensions against 128 of values
+HEADS = {"sambay_d64_dv128_r2": (2, 1, 2, 64, 128), "nemotron_d128_dv128_r4": (1, 1, 4, 128, 128),
+         "kimi_latent_d192_dv128_r1": (3, 3, 1, 192, 128)}
 TILE = 128
 
 
@@ -199,6 +202,11 @@ def test_the_tiles_follow_the_shapes_and_the_window_alone():
     assert fa.tiles(8192, 8, 128, 128) == fa.tiles(8192, 4, 128, 128)
     assert fa.tiles(32768, 4, 128, 128) == (512, 1024)
     assert fa.tiles(8192, 4, 128, 128) and fa.tiles(65536, 4, 128, 128) is None
+    # a latent attention's head: 192 key dimensions are two registers of lanes, so one head's
+    # dq is resident to 16,384 positions (32 MiB twice) and not at 32,768
+    assert fa.head_parts(16384, 1, 192) == 1 and fa.tiles(16384, 1, 192, 128) == (512, 1024)
+    assert fa.head_parts(32768, 1, 192) is None and fa.tiles(32768, 1, 192, 128) is None
+    assert fa.head_parts(32768, 1, 128) == 1 and fa.head_parts(8192, 6, 64) == 1
 
 
 # ------------------------------------------------------------------ the schedule
@@ -464,17 +472,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads, window", [((20, 10, 2, 64, 128), None), ((20, 10, 2, 64, 128), 512),
-                                           ((1, 1, 4, 128, 128), None),
-                                           ((8, 8, 8, 128, 128), 512), ((8, 8, 6, 128, 128), None)],
-                         ids=["phi4flash_full", "phi4flash_window", "nemotron3super",
-                              "lagunaxs2_sliding", "lagunaxs2_full"])
-def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes(one_chip, heads, window):
-    """8,192 tokens in bfloat16 at the tiles the shapes get: Mosaic takes the
-    layouts, the transposed product and the group's resident dq, which
-    interpret mode cannot say. Nothing runs."""
+@pytest.mark.parametrize("heads, window, t", [
+    ((20, 10, 2, 64, 128), None, 8192), ((20, 10, 2, 64, 128), 512, 8192),
+    ((1, 1, 4, 128, 128), None, 8192), ((8, 8, 8, 128, 128), 512, 8192),
+    ((8, 8, 6, 128, 128), None, 8192), ((32, 32, 1, 192, 128), None, 16384)],
+    ids=["phi4flash_full", "phi4flash_window", "nemotron3super", "lagunaxs2_sliding",
+         "lagunaxs2_full", "kimilinear_latent"])
+def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes(one_chip, heads, window, t):
+    """The cells' tokens (8,192; Kimi Linear's row 16,384) in bfloat16 at the
+    tiles the shapes get: Mosaic takes the layouts (a head of 192 is a register
+    and a half of lanes), the transposed product and the group's resident dq,
+    which interpret mode cannot say. Nothing runs."""
     g, gv, r, d, dv = heads
-    t = 8192
     tq, tk = fa.tiles(t, r, d, dv, window)
     arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(lambda *a: out_and_grads(

@@ -18,6 +18,7 @@ import pytest
 from glom_tpu.tracing.spans import (
     DEVICE_PHASES,
     HOST_PHASES,
+    KIMI_DEVICE_PHASES,
     LAGUNA_DEVICE_PHASES,
     LAGUNA_INNER_SCOPES,
     LM_DEVICE_PHASES,
@@ -183,7 +184,7 @@ def test_compiled_step_carries_every_phase(builder):
 # ------------------------------------------ the language-model families' tuples
 
 FAMILIES = {"hybrid_lm": LM_DEVICE_PHASES, "sambay": SAMBAY_DEVICE_PHASES,
-            "laguna": LAGUNA_DEVICE_PHASES}
+            "laguna": LAGUNA_DEVICE_PHASES, "kimi_linear": KIMI_DEVICE_PHASES}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -214,6 +215,25 @@ def test_lagunas_scopes_counters_and_inner_scopes_are_registered():
     assert laguna.COUNTERS == hybrid_lm.COUNTERS + (
         "attn_key_blocks_window", "attn_key_blocks_full")
     assert set(laguna.COUNTERS[-2:]) <= set(sambay.COUNTERS)
+
+
+def test_kimi_linears_scopes_and_counters_are_registered():
+    """The routed part's scopes, `moe_shared`, `embed` and `lm_head_loss` are
+    the other families' on purpose (one code; `moe_routed_time_pct.train`'s
+    reader reads the cell unedited), `dense_mlp` Laguna's; the delta rule's
+    three scopes and the latent attention's are its own, and no family's
+    attention or scan scope is borrowed for them. The records' counters are
+    the routed part's, the kept forward's, the latent layers' key blocks and
+    the delta rule's two."""
+    from glom_tpu.models import hybrid_lm, kimi_linear
+
+    own = set(KIMI_DEVICE_PHASES) - set(LM_DEVICE_PHASES) - set(LAGUNA_DEVICE_PHASES)
+    assert own == {"kda_in", "kda_scan", "kda_out", "latent_attention"}
+    assert not own & (set(SAMBAY_DEVICE_PHASES) | set(DEVICE_PHASES) | set(HOST_PHASES))
+    assert {"moe_router", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"} <= (
+        set(KIMI_DEVICE_PHASES) & set(LM_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES))
+    assert kimi_linear.COUNTERS == hybrid_lm.COUNTERS + (
+        "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min")
 
 
 def test_every_op_of_lagunas_rotation_lies_under_rope_inside_an_attention_scope():
