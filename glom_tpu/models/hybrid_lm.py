@@ -50,6 +50,13 @@ RUNG_LOADS = (2,)  # the small row counts, in balanced loads (`row_rungs`)
 ROUTED_COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_rows_full_share",
                    "moe_max_expert_load")
 COUNTERS = ROUTED_COUNTERS + ("attn_forward_kept",)
+# What a recomputed layer keeps of its first forward pass beside its inputs (`run_stack`):
+# the `checkpoint_name`s of the attention kernel's output and log-sum-exp rows, and of the
+# delta rule's output and of the states entering its segments (`kimi_linear.kda_chunked`,
+# which imports this module and reads its two names here).
+KDA_KEPT_OUTPUT, KDA_KEPT_STATES = "kda_scan_out", "kda_scan_states"
+KEPT_NAMES = (flash_attention.KEPT_OUTPUT, flash_attention.KEPT_LSE,
+              KDA_KEPT_OUTPUT, KDA_KEPT_STATES)
 
 
 # ----------------------------------------------------------------- parameters
@@ -536,19 +543,23 @@ def run_stack(params: Params, ids, layers, *, compute_dtype=None, remat: bool = 
     as an input, so its gradient flows back to the layer that made it.
     Returns (the last layer's output [B, T, d], every layer's aux).
 
-    A recomputed layer keeps its inputs and, where its attention ran the
-    kernels, the two arrays `flash_attention` names: the forward kernel's
-    output (B x heads x T x Dv in the compute dtype) and its log-sum-exp
-    rows, which are all the backward kernel reads of the forward's. Keeping
-    them costs the bytes a second run of the kernel would write again, and
-    saves the run: the recomputation rebuilds q, k, v and everything round
-    the kernel, and the kernel's call falls out of the backward's program as
-    dead code (`forward_kept` counts the layers). The XLA loop names nothing,
-    so nothing of it is kept."""
+    A recomputed layer keeps its inputs and what carries one of KEPT_NAMES.
+    Where its attention ran the kernels, the two arrays `flash_attention`
+    names: the forward kernel's output (B x heads x T x Dv in the compute
+    dtype) and its log-sum-exp rows, which are all the backward kernel reads
+    of the forward's. Keeping them costs the bytes a second run of the kernel
+    would write again, and saves the run: the recomputation rebuilds q, k, v
+    and everything round the kernel, and the kernel's call falls out of the
+    backward's program as dead code (`forward_kept` counts the layers). The
+    XLA loop names nothing, so nothing of it is kept. Where its mixer is the
+    delta rule (`kimi_linear.kda_chunked`), the rule's output (B x T x heads
+    x D in the compute dtype) and the float32 states entering its segments,
+    as many bytes again: the pass over the segments, which the recomputation
+    would run for these two alone, falls out the same way. A family that
+    names nothing keeps nothing."""
     with jax.named_scope("embed"):
         x = _cast(params["embed"][ids], compute_dtype)
-    keep = jax.checkpoint_policies.save_only_these_names(
-        flash_attention.KEPT_OUTPUT, flash_attention.KEPT_LSE)
+    keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
     aux = []
     for f, p in zip(layers, params["layers"]):
         x, side, a = (jax.checkpoint(f, policy=keep) if remat else f)(p, x, side)

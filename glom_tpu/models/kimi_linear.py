@@ -46,6 +46,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from glom_tpu.models import hybrid_lm
 from glom_tpu.models.hybrid_lm import (
@@ -62,7 +63,8 @@ from glom_tpu.models.hybrid_lm import (
 from glom_tpu.models.laguna import swiglu
 from glom_tpu.utils.config import KimiLinearConfig
 
-COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_full", "kda_chunks", "kda_log_decay_min")
+COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_full", "kda_chunks", "kda_log_decay_min",
+                                 "kda_forward_kept")
 # The sub-chunk and the segment are the fastest of those tried on a v5e at the benchmark's
 # size (16,384 positions, 32 heads of 128: 122-133 ms a layer forward, recomputed and
 # backward at segments of 1-4 chunks and sub-chunks of 8; 174 at sub-chunks of 4, 148 at 16;
@@ -310,7 +312,20 @@ def kda_chunked(q, k, v, g, beta):
     size: 7.7 GB at 16,384 tokens) is held for one segment at a time, beside
     the states at the segments' edges. A length that is no whole number of
     segments is padded with steps of k = 0, beta = 0, g = 0, which leave the
-    state as it is. All in float32 at SCAN_PRECISION."""
+    state as it is. All in float32 at SCAN_PRECISION.
+
+    The pass over the segments has a derivative of its own (`jax.custom_vjp`
+    over the arrays in the segments' layout). Its forward keeps the state
+    entering every segment [segments, B x H, D, D] in SCAN_STATE_DTYPE under
+    the name `hybrid_lm.KDA_KEPT_STATES`, and o as it is returned carries
+    `hybrid_lm.KDA_KEPT_OUTPUT`; its backward goes over the segments from last
+    to first, `jax.vjp` of `_kda_segment` at the kept entering state and the
+    segment's slices, fed the cotangent of its o and of the state it leaves
+    (what `jax.checkpoint` of a segment under `lax.scan` would build). So a
+    layer recomputed under a policy that saves the two names
+    (`hybrid_lm.run_stack`) rebuilds q, k, v, g, beta and reads the rest: the
+    forward pass over the segments is not run a second time. The second
+    result has no gradient."""
     bsz, t, h, d = q.shape
     chunk = min(KDA_CHUNK, -(-t // KDA_SUBCHUNK) * KDA_SUBCHUNK)
     segments = -(-t // (chunk * KDA_SEGMENT))
@@ -322,15 +337,34 @@ def kda_chunked(q, k, v, g, beta):
         x = jnp.moveaxis(x.reshape(bsz, segments, z, chunk, *x.shape[2:]), 4, 1)
         return jnp.moveaxis(x.reshape(bsz * h, segments, z, chunk, *x.shape[5:]), 1, 0)
 
-    @jax.checkpoint   # of a function of this call's own: no earlier trace at other constants is found
-    def segment(state, xs):
-        state, o, lowest = _kda_segment(state, *xs)
-        return state, (o, lowest)
+    def forward(*xs):
+        def segment(state, xs):
+            leaving, o, lowest = _kda_segment(state, *xs)
+            return leaving, (state, o, lowest)
 
-    _, (o, lowest) = jax.lax.scan(segment, jnp.zeros((bsz * h, d, d), SCAN_STATE_DTYPE),
-                                  tuple(layout(x) for x in (q, k, v, g, beta)))
+        _, (entering, o, lowest) = jax.lax.scan(
+            segment, jnp.zeros((bsz * h, d, d), SCAN_STATE_DTYPE), xs)
+        return (o, jnp.min(lowest)), (checkpoint_name(entering, hybrid_lm.KDA_KEPT_STATES), xs)
+
+    def backward(kept, cotangents):
+        entering, xs = kept
+
+        def segment(d_leaving, inp):
+            state, xs, d_o = inp
+            _, pull = jax.vjp(lambda state, *xs: _kda_segment(state, *xs)[:2], state, *xs)
+            d_state, *d_xs = pull((d_leaving, d_o))
+            return d_state, tuple(d_xs)
+
+        _, d_xs = jax.lax.scan(segment, jnp.zeros_like(entering[0]),
+                               (entering, xs, cotangents[0]), reverse=True)
+        return d_xs
+
+    # functions of this call's own: no earlier trace at other constants is found
+    over_segments = jax.custom_vjp(lambda *xs: forward(*xs)[0])
+    over_segments.defvjp(forward, backward)
+    o, lowest = over_segments(*(layout(x) for x in (q, k, v, g, beta)))
     o = jnp.moveaxis(o, 0, 1).reshape(bsz, h, segments * z * chunk, d)
-    return jnp.moveaxis(o, 1, 2)[:, :t], jax.lax.stop_gradient(jnp.min(lowest))
+    return checkpoint_name(jnp.moveaxis(o, 1, 2)[:, :t], hybrid_lm.KDA_KEPT_OUTPUT), lowest
 
 
 # ------------------------------------------------------------------ the mixers
@@ -357,7 +391,11 @@ def kda_mixer(p, x_in, cfg: KimiLinearConfig, dtype):
     the decays' float32 intermediates (a dozen arrays of 0.13-0.27 GB at
     16,384 tokens, which lay beside the routed part's full rung at the step's
     peak). Without the two the cell's step computed a wrong gradient and a
-    right loss (`recomputed_half`)."""
+    right loss (`recomputed_half`). Of the first forward pass the recomputed
+    layer keeps the two arrays `kda_chunked` names (`run_stack`): o, which
+    `after` reads, and the states entering the segments, which the delta
+    rule's backward reads; 0.27 GB a layer at 16,384 tokens, for one pass
+    over the segments that is not run again."""
     h, dk = cfg.linear_num_heads, cfg.linear_head_dim
     bsz, t = x_in.shape[:2]
     by_head = lambda x: x.reshape(bsz, t, h, dk)
@@ -476,7 +514,10 @@ def lm_loss(params, ids, cfg: KimiLinearConfig, *, compute_dtype=None,
     part's four over the `E` layers (`hybrid_lm.merge_counters`), the chunks
     of the delta rule over the KDA layers, the most negative cumulative
     log-decay inside any of them, the key blocks the latent layers multiplied,
-    and `hybrid_lm.forward_kept`."""
+    `hybrid_lm.forward_kept`, and `kda_forward_kept`: the KDA layers whose
+    recomputation reads the delta rule's kept output and states and does not
+    run its forward pass again (all of them under `remat`, which is what
+    recomputes; none without)."""
     x, counted, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).reshape(-1, x.shape[-1])
@@ -487,6 +528,8 @@ def lm_loss(params, ids, cfg: KimiLinearConfig, *, compute_dtype=None,
         counters["attn_key_blocks_full"] = jnp.float32(
             sum(c.get("attn_key_blocks_full", 0) for c in counted))
         counters["kda_chunks"] = jnp.float32(kda_chunks(cfg, *ids.shape))
+        counters["kda_forward_kept"] = jnp.float32(
+            sum(m == "K" for m, _ in cfg.kinds) if remat else 0)
         decays = [c["kda_log_decay_min"] for c in counted if "kda_log_decay_min" in c]
         if decays:
             counters["kda_log_decay_min"] = jnp.min(jnp.stack(decays))

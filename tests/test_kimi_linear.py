@@ -38,6 +38,7 @@ from glom_tpu.models import hybrid_lm
 from glom_tpu.models import kimi_linear as kl
 from glom_tpu.utils.config import KimiLinearConfig
 from glom_tpu.utils.presets import get_preset
+from tests.test_flash_attention import parents_policy
 
 TINY = get_preset("kimi-linear-tiny").model
 FULL = get_preset("kimi-linear-ep32vp8").model
@@ -290,18 +291,183 @@ def test_the_in_chunk_solve_inverts_a_unit_lower_triangle(c):
         assert float(jnp.max(jnp.abs(jnp.triu(inv, 1)))) == 0.0
 
 
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.tree_util.tree_leaves(list(eqn.params.values()),
+                                             is_leaf=lambda x: hasattr(x, "eqns")):
+            sub = getattr(sub, "jaxpr", sub)          # a closed jaxpr's
+            if hasattr(sub, "eqns"):
+                yield from equations(sub)
+
+
+def segment_runs(f, *args):
+    """(forward, transposed) runs of `_kda_segment` in f's jaxpr, however
+    deep: a segment takes one cumulative sum of its log-decays and nothing
+    else here takes one; its transpose is the same sum reversed."""
+    reverse = [eqn.params["reverse"] for eqn in equations(jax.make_jaxpr(f)(*args).jaxpr)
+               if eqn.primitive.name == "cumsum"]
+    return reverse.count(False), reverse.count(True)
+
+
 def test_the_chunks_go_in_segments_that_are_recomputed_and_come_out_the_same(monkeypatch):
     """200 positions in chunks of 32: seven chunks as one segment, and as four
     segments of two (the last chunk padding), the state handed from segment to
-    segment; each segment is a `jax.checkpoint` of its own."""
+    segment. The forward pass runs the segments once, in one loop, and keeps
+    nothing of their insides; the gradient runs them once more, inside the
+    backward rule's loop from the last segment to the first, and transposes
+    that run."""
     args = scan_inputs(200, 1.0, seed=4)
     monkeypatch.setattr(kl, "KDA_SEGMENT", 8)
     whole, lowest = kl.kda_chunked(*args)
     monkeypatch.setattr(kl, "KDA_SEGMENT", 2)
-    text = str(jax.make_jaxpr(kl.kda_chunked)(*args))
     parts, lowest_parts = kl.kda_chunked(*args)
-    assert "remat" in text and rel(parts, whole) < 1e-6
+    assert rel(parts, whole) < 1e-6
     assert float(lowest) == float(lowest_parts)
+    # the loops by (trips, reversed): the segments', and the chunks' inside a segment
+    loops = lambda f: sorted((eqn.params["length"], eqn.params["reverse"])
+                             for eqn in equations(jax.make_jaxpr(f)(*args).jaxpr)
+                             if eqn.primitive.name == "scan")
+    assert "custom_vjp_call" in str(jax.make_jaxpr(kl.kda_chunked)(*args))
+    assert loops(kl.kda_chunked) == [(2, False), (4, False)]
+    assert segment_runs(kl.kda_chunked, *args) == (1, 0)
+    grad = jax.grad(lambda *a: jnp.sum(kl.kda_chunked(*a)[0]), argnums=(0, 1, 2, 3, 4))
+    assert segment_runs(grad, *args) == (2, 1)
+    assert loops(grad) == [(2, False), (2, False), (2, True), (4, False), (4, True)]
+
+
+def checkpointed_scan(q, k, v, g, beta):
+    """`kda_chunked` as it was before it had a derivative of its own: the same
+    layout, each segment a `jax.checkpoint` under one `lax.scan`, which JAX
+    differentiates. The oracle of the backward rule."""
+    bsz, t, h, d = q.shape
+    chunk = min(kl.KDA_CHUNK, -(-t // kl.KDA_SUBCHUNK) * kl.KDA_SUBCHUNK)
+    segments = -(-t // (chunk * kl.KDA_SEGMENT))
+    z = -(-t // (chunk * segments))
+    pad = segments * z * chunk - t
+
+    def layout(x):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = jnp.moveaxis(x.reshape(bsz, segments, z, chunk, *x.shape[2:]), 4, 1)
+        return jnp.moveaxis(x.reshape(bsz * h, segments, z, chunk, *x.shape[5:]), 1, 0)
+
+    @jax.checkpoint
+    def segment(state, xs):
+        state, o, lowest = kl._kda_segment(state, *xs)
+        return state, (o, lowest)
+
+    _, (o, lowest) = jax.lax.scan(segment, jnp.zeros((bsz * h, d, d), kl.SCAN_STATE_DTYPE),
+                                  tuple(layout(x) for x in (q, k, v, g, beta)))
+    o = jnp.moveaxis(o, 0, 1).reshape(bsz, h, segments * z * chunk, d)
+    return jnp.moveaxis(o, 1, 2)[:, :t], jax.lax.stop_gradient(jnp.min(lowest))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [128, 200], ids=["whole_segments", "padded"])
+def test_the_backward_rule_is_the_checkpointed_scans_gradient(t, dtype):
+    """Both results and the gradients to q, k, v, g and beta against the form
+    the rule replaces, at a length of two whole segments and at one whose last
+    chunk is part padding and whose last segment half: the same operations in
+    the same order, so the same bits, with q, k, v in float32 and in the
+    cell's bfloat16 (g and beta are float32 in both)."""
+    q, k, v, g, beta = scan_inputs(t, 1.0, seed=t)
+    args = (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
+    cot = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+
+    def both(f):
+        def loss(*a):
+            o, lowest = f(*a)
+            return jnp.sum(o.astype(jnp.float32) * cot), (o, lowest)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    got, (o, lowest) = both(kl.kda_chunked)
+    want, (want_o, want_lowest) = both(checkpointed_scan)
+    assert o.dtype == want_o.dtype == dtype and jnp.array_equal(o, want_o)
+    assert float(lowest) == float(want_lowest)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert float(jnp.linalg.norm(b.astype(jnp.float32))) > 0, name
+        assert jnp.array_equal(a, b), (name, rel(a, b))
+
+
+def test_the_second_result_has_no_gradient():
+    """`kda_log_decay_min` is a counter: a loss that reads it moves nothing."""
+    args = scan_inputs(64, 1.0, seed=6)
+    grads = jax.grad(lambda *a: kl.kda_chunked(*a)[1], argnums=(0, 1, 2, 3, 4))(*args)
+    assert all(float(jnp.max(jnp.abs(x))) == 0.0 for x in grads)
+
+
+# ------------------------------------------- what a recomputed KDA layer keeps
+
+
+def tiny_loss(dtype=None, remat=True, seed=7):
+    w, ids = wk.to_program_params(wk.make_weights(seed, dataclasses.asdict(TINY))), ids_for(TINY, 2)
+    return (lambda p: kl.lm_loss(p, ids, TINY, compute_dtype=dtype, remat=remat)), w
+
+
+KDA_LAYERS = sum(m == "K" for m, _ in TINY.kinds)
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no_remat"])
+def test_the_recomputation_reads_the_delta_rules_results_and_does_not_run_it_again(remat):
+    """The gradient's program holds two forward runs of a segment a KDA layer
+    (the forward pass's, and the backward rule's own recomputation of the
+    segment it transposes) and one transposed, recomputed or not; the records'
+    counter says how many layers' recomputation read what the forward kept.
+    Under the parent's policy, which keeps a layer's inputs alone, the layer's
+    recomputation runs the segments a third time."""
+    loss, params = tiny_loss(jnp.bfloat16, remat)
+    grad = lambda: jax.grad(lambda p: loss(p)[0])     # a new function a count: a new trace
+    assert segment_runs(grad(), params) == (2 * KDA_LAYERS, KDA_LAYERS)
+    assert float(jax.jit(loss)(params)[1]["kda_forward_kept"]) == (KDA_LAYERS if remat else 0)
+    with parents_policy():
+        assert segment_runs(grad(), params) == ((3 if remat else 2) * KDA_LAYERS, KDA_LAYERS)
+
+
+def test_the_kept_results_change_no_bit_of_a_gradient():
+    """Against `run_stack` with the parent's policy: the same loss and the
+    same gradient leaves, bit for bit, in float32 (in bfloat16 the CPU keeps a
+    rebuilt intermediate in float32 where it fuses it with its consumer:
+    `test_flash_attention.test_the_kept_results_change_no_bit_of_a_gradient`)."""
+    loss, params = tiny_loss()
+    value_and_grad = lambda: jax.jit(jax.value_and_grad(lambda p: loss(p)[0]))(params)
+    got = value_and_grad()
+    with parents_policy():
+        want = value_and_grad()
+    leaves = jax.tree_util.tree_leaves
+    assert len(leaves(got)) == len(leaves(want)) > 60
+    assert all(jnp.array_equal(a, b) for a, b in zip(leaves(got), leaves(want)))
+
+
+def test_a_recomputed_kda_layer_keeps_the_output_and_the_entering_states(capsys):
+    """Beyond the layer's input: o [B, T, H, D] in the compute type and the
+    states entering the segments [segments, B x H, D, D] in float32, under
+    their two names; none of q, k, v, g, beta and nothing of a segment's
+    insides. With the parent's policy, neither."""
+    cfg = dataclasses.replace(TINY, layer_offset=1, num_hidden_layers=1)   # one KDA layer, experts
+    assert cfg.kinds == (("K", "E"),)
+    params = kl.init_kimi_linear(jax.random.PRNGKey(3), cfg)
+    ids = ids_for(cfg, 4)
+
+    def saved():
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(lambda p: jnp.sum(kl.hidden_states(
+            p, ids, cfg, compute_dtype=jnp.bfloat16, remat=True)[0].astype(jnp.float32)), params)
+        return [line.split(" from /")[0] for line in capsys.readouterr().out.splitlines()
+                if " from the argument " not in line and " from a constant" not in line]
+
+    h, d, t = cfg.linear_num_heads, cfg.linear_head_dim, cfg.seq_len
+    segments = -(-t // (32 * 2))
+    inputs = [f"i32[2,{t},1] output of broadcast_in_dim",
+              f"bf16[2,{t},{cfg.hidden_size}] output of convert_element_type"]
+    # o, which the forward pass reads too (`after`, a recomputation of its own), comes out
+    # of that and loses its name, as `flash_attention`'s output does
+    assert saved() == inputs + [
+        f"f32[{segments},{2 * h},{d},{d}] named '{hybrid_lm.KDA_KEPT_STATES}'",
+        f"bf16[2,{t},{h},{d}] output of remat2"]
+    with parents_policy():
+        assert saved() == inputs
 
 
 # ------------------------------------------------- causality, the latent attention

@@ -120,6 +120,9 @@ def test_the_records_carry_the_counters(fitted, tiny):
         assert (r["kda_chunks"], r["attn_key_blocks_full"]) == (2 * 2 * 4, 1)
         assert -300 < r["kda_log_decay_min"] < -5      # a fast channel over a chunk of 64
         assert r["attn_forward_kept"] == 0     # the XLA loop names nothing for the recomputation
+        # the preset trains with `remat`: every KDA layer's recomputation reads the delta
+        # rule's kept output and states
+        assert r["kda_forward_kept"] == sum(m == "K" for m, _ in cfg.kinds) == 4
         assert r["moe_pairs_here"] + e <= r["moe_rows_computed"] <= rungs[-1]
         assert 0 < r["moe_pairs_here"] <= n * min(k, e)
         assert 0.0 <= r["moe_rows_full_share"] <= 1.0 and r["moe_max_expert_load"] <= n

@@ -224,7 +224,7 @@ def test_kimi_linears_scopes_and_counters_are_registered():
     three scopes and the latent attention's are its own, and no family's
     attention or scan scope is borrowed for them. The records' counters are
     the routed part's, the kept forward's, the latent layers' key blocks and
-    the delta rule's two."""
+    the delta rule's three."""
     from glom_tpu.models import hybrid_lm, kimi_linear
 
     own = set(KIMI_DEVICE_PHASES) - set(LM_DEVICE_PHASES) - set(LAGUNA_DEVICE_PHASES)
@@ -233,7 +233,7 @@ def test_kimi_linears_scopes_and_counters_are_registered():
     assert {"moe_router", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"} <= (
         set(KIMI_DEVICE_PHASES) & set(LM_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES))
     assert kimi_linear.COUNTERS == hybrid_lm.COUNTERS + (
-        "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min")
+        "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min", "kda_forward_kept")
 
 
 def test_every_op_of_lagunas_rotation_lies_under_rope_inside_an_attention_scope():
