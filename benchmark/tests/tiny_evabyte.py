@@ -1,0 +1,24 @@
+"""The EvaByte cell cut to a size a CPU test can hold: the committed cell's
+files with the `evabyte-tiny` preset's model laid over them. Never a
+measurement."""
+import dataclasses
+
+from benchmark import harness
+
+
+def tiny_evabyte_cell(name: str = "evabyte.train", *, compute_dtype: str = "float32",
+                      on_kernels: bool = False) -> dict:
+    """`on_kernels` keeps the cell's own requirement that every layer's
+    attention ran in the kernels, which no CPU run meets."""
+    from glom_tpu.utils.presets import get_preset
+
+    cell = harness.load_cell(name)
+    preset = get_preset("evabyte-tiny")
+    cf = cell["config_file"]
+    cf["preset"] = "evabyte-tiny"
+    cf["model"] = dataclasses.asdict(preset.model)
+    cf["train"] = {"batch_per_chip": 2, "learning_rate": 3e-4,
+                   "compute_dtype": compute_dtype, "remat": True}
+    cf["bench"]["attention_on_kernels"] = on_kernels
+    cell["traffic_file"].update(seq_len=preset.model.seq_len)
+    return cell

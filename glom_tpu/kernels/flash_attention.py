@@ -31,6 +31,16 @@ At D = 192, Dv = 128 (a latent attention's 128 + 64 key dimensions: one query
 head a KV head) a head's dq is 256 lanes wide, 32 MiB twice at T = 16,384,
 and is served to T = 16,384.
 
+A second mask kind, `Aligned(window, chunk, length)` in `window`'s place, is
+EVA attention's (`models/evabyte.py`): the keys are two segments laid end to
+end, `length` positions' own keys and then `length / chunk` summary keys, one a
+chunk of `chunk` positions, under one softmax. A query at t in the aligned
+window n = t // window sees the keys n window .. t of its own window and the
+summaries of every chunk of the windows before it, j chunk < n window: not a
+sliding window, and a summary never expires. The kernels are the same two: a
+key tile is a tile of one segment or the other (`tiles` takes key tiles that
+divide both), and dk and dv of the summary rows come back with the keys'.
+
 `schedule` is the one place that says which (query tile, key tile) pairs
 exist: the grids are its steps (scalar-prefetched, so a pair no query of
 the tile can see is neither a grid step nor a DMA), its flags say where a
@@ -58,6 +68,7 @@ and reads the forward kernel's results where it would have run it again.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +96,18 @@ _FIRST, _LAST, _MASKED = 1, 2, 4
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))  # a [m, d] x b [n, d] -> [m, n]
 _TN = (((0,), (0,)), ((), ()))  # a [k, m] x b [k, n] -> [m, n]
+
+
+class Aligned(NamedTuple):
+    """EVA attention's mask over keys [length own keys; length / chunk
+    summaries]: see the module docstring. Whole windows and whole chunks."""
+    window: int
+    chunk: int
+    length: int
+
+    @property
+    def summaries(self) -> int:
+        return self.length // self.chunk
 
 
 def on_tpu() -> bool:
@@ -123,11 +146,32 @@ def tiles(t: int, r: int, d: int, dv: int, window=None):
     if t % 128 or d % 64 or dv % 64 or parts is None:
         return None
     r //= parts
+    if isinstance(window, Aligned):
+        return _aligned_tiles(r, window)
     reach = t if window is None else max(window, 128)
     tq = next(tile for tile in QUERY_TILES if t % tile == 0 and (
         r * tile <= QUERY_ROWS or tile == QUERY_TILES[-1]))
     tk = next(tile for tile in KEY_TILES if t % tile == 0 and tile <= reach)
     return tq, tk
+
+
+def _aligned_tiles(r: int, mask: Aligned):
+    """`tiles` under an `Aligned` mask: a query tile lies in one window and a
+    key tile in one window or among the summaries, so both divide the window
+    and the key tile the summaries' count too (window and chunk powers of two:
+    the kernels take a position's window by a mask of bits). The key tile is
+    the query tile's size at most: a window's tiles below its diagonal are
+    then whole, and only the diagonal's own are masked (at 512 the kernels
+    visit 1.11 of the key blocks the mask needs at 16,384 positions, at 1,024
+    1.41)."""
+    w, c, t = mask
+    if w & (w - 1) or c & (c - 1) or t % w or w % c:
+        return None
+    tq = next((tile for tile in QUERY_TILES if w % tile == 0 and (
+        r * tile <= QUERY_ROWS or tile == QUERY_TILES[-1])), None)
+    tk = tq and next((tile for tile in KEY_TILES if tile <= tq and w % tile == 0
+                      and mask.summaries % tile == 0), None)
+    return (tq, tk) if tk else None
 
 
 @functools.lru_cache(maxsize=None)
@@ -141,6 +185,9 @@ def schedule(t: int, tq: int, tk: int, window=None):
     pairs = []
     for i in range(t // tq):
         q_lo, q_hi = i * tq, (i + 1) * tq - 1
+        if isinstance(window, Aligned):
+            pairs += [(i, j, flag) for j, flag in _aligned_key_tiles(q_lo, q_hi, tk, window)]
+            continue
         first_key = 0 if window is None else max(0, q_lo - window + 1)
         for j in range(first_key // tk, q_hi // tk + 1):
             k_lo, k_hi = j * tk, (j + 1) * tk - 1
@@ -158,13 +205,41 @@ def schedule(t: int, tq: int, tk: int, window=None):
     return ordered(0), ordered(1)
 
 
+def _aligned_key_tiles(q_lo: int, q_hi: int, tk: int, mask: Aligned):
+    """(key tile, _MASKED or 0) for the queries q_lo..q_hi under `mask`: the
+    tiles of own keys from the first query's window's start to the last
+    query, then the summary tiles (numbered on from length / tk) that hold a
+    chunk of a window before the last query's. A tile is masked where some
+    query of the tile does not see some key of it."""
+    w, c, t = mask
+    first, last = q_lo // w * w, q_hi // w * w     # the windows' starts
+    for j in range(first // tk, q_hi // tk + 1):
+        k_lo, k_hi = j * tk, (j + 1) * tk - 1
+        yield j, _MASKED if k_hi > q_lo or k_lo < last else 0
+    for j in range(-(-(last // c) // tk)):         # summaries 0 .. last / c - 1 are seen by some
+        yield t // tk + j, _MASKED if (j + 1) * tk > first // c else 0
+
+
 def key_blocks(t: int, tq: int, tk: int, window=None, block: int = 128) -> int:
     """Key blocks of `block` keys the kernels visit, summed over the query
     tiles: the schedule's steps, in the model's unit."""
     return len(schedule(t, tq, tk, window)[0][0]) * tk // block
 
 
+def aligned_key_blocks(tq: int, tk: int, mask: Aligned, block: int = 128):
+    """`key_blocks` under an `Aligned` mask, by segment: (blocks of own keys,
+    blocks of summary keys)."""
+    kj = schedule(mask.length, tq, tk, mask)[0][1]
+    own = int(np.sum(kj < mask.length // tk))
+    return own * tk // block, (len(kj) - own) * tk // block
+
+
 def _seen(q_pos, k_pos, window):
+    if isinstance(window, Aligned):
+        w, c, t = window
+        start = q_pos & ~(w - 1)   # the window's start: `tiles` takes powers of two alone
+        own = (k_pos < t) & (k_pos <= q_pos) & (k_pos >= start)
+        return own | ((k_pos >= t) & ((k_pos - t) * c < start))
     seen = k_pos <= q_pos
     if window is not None:
         seen &= k_pos > q_pos - window
@@ -346,8 +421,8 @@ def _backward(q, k, v, o, lse, do, window, tq, tk, interpret):
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=d ** -0.5, window=window, tq=tq, tk=tk),
         out_shape=(jax.ShapeDtypeStruct((bsz, g, r, t, d), f32),
-                   jax.ShapeDtypeStruct((bsz, g, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((bsz, g, t, dv), v.dtype)),
+                   jax.ShapeDtypeStruct((bsz, g, k.shape[2], d), k.dtype),
+                   jax.ShapeDtypeStruct((bsz, g, k.shape[2], dv), v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bsz, g, len(kj)),
@@ -393,6 +468,12 @@ def _flash_fwd(q, k, v, window, tq, tk, interpret):
 def _flash_bwd(window, tq, tk, interpret, kept, do):
     q, k, v, o, lse = kept
     dq, dk, dv = _backward(q, k, v, o, lse, do, window, tq, tk, interpret)
+    if isinstance(window, Aligned):
+        # no query sees a summary of the last window: a key tile of those alone is no grid
+        # step, and its rows of dk and dv are never written
+        w, c, t = window
+        seen = (jnp.arange(k.shape[2]) < t + (t - 1) // w * w // c)[:, None]
+        dk, dv = jnp.where(seen, dk, 0), jnp.where(seen, dv, 0)
 
     def shared(dx, x):   # grid rows that share a key head or a value head
         if dx.shape == x.shape:
@@ -411,7 +492,9 @@ def flash_attention(q, k, v, window=None, *, tq: int, tk: int, interpret: bool =
     """q [B, T, G, R, D], k [B, T, G, D], v [B, T, Gv, Dv] (G a multiple of
     Gv) -> [B, T, G, R, Dv], differentiable in all three, by the kernels at
     the tiles given (`tiles` chooses them; T a multiple of both). A group
-    folds into the query tiles in `head_parts` parts."""
+    folds into the query tiles in `head_parts` parts. Under an `Aligned` mask
+    in `window`'s place k and v hold T + T / chunk rows, the summaries after
+    the positions' own."""
     bsz, t, g, r, d = q.shape
     parts = head_parts(t, r, d)
     o = _flash(q.transpose(0, 2, 3, 1, 4).reshape(bsz, g * parts, r // parts, t, d),

@@ -599,26 +599,37 @@ def routing_choices(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=N
 
 
 def _block_nll(h, head, targets):
-    logits = _mm(h, head)                          # float32 [rows, V]
+    """targets [rows], or [rows, K] for K heads side by side in the head's columns."""
+    logits = _mm(h, head)                          # float32 [rows, V] or [rows, K V]
+    if targets.ndim == 2:
+        logits = logits.reshape(h.shape[0], targets.shape[1], -1)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    return lse - jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
+    return lse - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
 
 
-def next_token_loss(h, head, ids):
+def next_token_loss(h, head, ids, pred_heads: int = 1):
     """Next-token cross-entropy of the normed hidden states h [B * T, d]
     under the head [d, V] (the vocabulary rows held here): float32 logits, a
     block of LOSS_ROW_BLOCK rows at a time, each block recomputed in the
     backward pass; the mean over the B * (T - 1) positions that have a next
-    token."""
+    token. With `pred_heads` K > 1 the head is [d, K V], K heads side by
+    side, head m of position t predicting the token at t + 1 + m: the mean
+    over the heads and the positions that have such a token, every head
+    weighing the same."""
     bsz, t = ids.shape
-    targets = jnp.roll(ids, -1, axis=1).reshape(-1)
-    has_next = jnp.tile(jnp.arange(t) < t - 1, bsz)
+    shifted = [jnp.roll(ids, -(1 + m), axis=1) for m in range(pred_heads)]
+    if pred_heads == 1:
+        targets = shifted[0].reshape(-1)
+        has_next = jnp.tile(jnp.arange(t) < t - 1, bsz)
+    else:
+        targets = jnp.stack(shifted, axis=-1).reshape(-1, pred_heads)
+        has_next = jnp.tile(jnp.arange(t)[:, None] + jnp.arange(pred_heads) < t - 1, (bsz, 1))
     total = jnp.zeros((), jnp.float32)
     for first in range(0, bsz * t, LOSS_ROW_BLOCK):
         rows = slice(first, min(bsz * t, first + LOSS_ROW_BLOCK))
         nll = jax.checkpoint(_block_nll)(h[rows], head, targets[rows])
         total = total + jnp.sum(jnp.where(has_next[rows], nll, 0.0))
-    return total / (bsz * (t - 1))
+    return total / (bsz * sum(t - 1 - m for m in range(pred_heads)))
 
 
 def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,

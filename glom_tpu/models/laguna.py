@@ -125,14 +125,14 @@ def rope_frequencies(attention: str, cfg: LagunaConfig) -> Tuple[np.ndarray, flo
     return freq.astype(np.float32), cfg.yarn_attention_factor
 
 
-def rope_tables(attention: str, cfg: LagunaConfig, length: int):
+def rotary_tables(freq: np.ndarray, factor: float, dim: int, length: int):
     """(cos, sin [length, D] float32, the factor folded in; the signed
     permutation P [D, D] with (x @ P)[i] = -x[i + half], (x @ P)[i + half] =
-    x[i] over the rotated dimensions) of a layer of kind `attention`. Over the
-    dimensions that pass untouched cos = 1, sin = 0 and P is zero. sin is
-    equal on paired dimensions and P^T = -P."""
-    freq, factor = rope_frequencies(attention, cfg)
-    half, dim = freq.shape[0], cfg.head_dim
+    x[i] over the rotated dimensions) for inverse frequencies `freq` [rotated
+    dimensions / 2] in a head of `dim`. Over the dimensions that pass
+    untouched cos = 1, sin = 0 and P is zero. sin is equal on paired
+    dimensions and P^T = -P."""
+    half = freq.shape[0]
     rotated = np.arange(dim) < 2 * half
     paired = np.zeros(dim, np.float32)
     paired[:half] = paired[half:2 * half] = freq
@@ -145,12 +145,17 @@ def rope_tables(attention: str, cfg: LagunaConfig, length: int):
     return cos, sin, perm
 
 
-def _rotate(x, attention: str, cfg: LagunaConfig, transposed: bool):
+def rope_tables(attention: str, cfg: LagunaConfig, length: int):
+    """`rotary_tables` of a layer of kind `attention`."""
+    return rotary_tables(*rope_frequencies(attention, cfg), cfg.head_dim, length)
+
+
+def _rotate(x, tables, transposed: bool):
     """x * cos + (x @ P) * sin (P^T where `transposed`) in one pass: x's type
-    in and out, float32 between. The product is exact in any type: each of
-    its outputs is one input times +-1."""
+    in and out, float32 between; `tables(length)` gives cos, sin and P. The
+    product is exact in any type: each of its outputs is one input times +-1."""
     with jax.named_scope("rope"):
-        cos, sin, perm = rope_tables(attention, cfg, x.shape[1])
+        cos, sin, perm = tables(x.shape[1])
         shape = (x.shape[1],) + (1,) * (x.ndim - 3) + (x.shape[-1],)
         # float32 operands at the chip's default precision would be rounded to bfloat16
         turned = jnp.matmul(x, jnp.asarray(perm.T if transposed else perm, x.dtype),
@@ -160,22 +165,28 @@ def _rotate(x, attention: str, cfg: LagunaConfig, transposed: bool):
                 + turned * sin.reshape(shape)).astype(x.dtype)
 
 
-def rope(x, attention: str, cfg: LagunaConfig):
-    """Rotate x [B, T, ..., D] at positions 0..T-1: of the first `rotary_dim`
-    dimensions of a head, dimension i pairs with i + rotary_dim / 2; the rest
-    pass untouched. One pass, `x * cos + (x @ P) * sin` over whole heads
-    (`rope_tables`): no slice and no concatenate of a head's parts, float32
-    inside, x's type in and out. Its transpose is the same pass with P^T on
-    the cotangent, summed in float32 and rounded once."""
+def rotate_by(x, tables):
+    """Rotate x [B, T, ..., D] at positions 0..T-1 by `tables(T)`
+    (`rotary_tables`): one pass, `x * cos + (x @ P) * sin` over whole heads,
+    no slice and no concatenate of a head's parts, float32 inside, x's type in
+    and out. Its transpose is the same pass with P^T on the cotangent, summed
+    in float32 and rounded once."""
 
     @jax.custom_vjp
     def turn(x):
-        return _rotate(x, attention, cfg, False)
+        return _rotate(x, tables, False)
 
     # (dy * sin) @ P^T = (dy @ P^T) * sin: sin is equal on paired dimensions
-    turn.defvjp(lambda x: (_rotate(x, attention, cfg, False), None),
-                lambda _, dy: (_rotate(dy, attention, cfg, True),))
+    turn.defvjp(lambda x: (_rotate(x, tables, False), None),
+                lambda _, dy: (_rotate(dy, tables, True),))
     return turn(x)
+
+
+def rope(x, attention: str, cfg: LagunaConfig):
+    """`rotate_by` the tables of a layer of kind `attention`: of the first
+    `rotary_dim` dimensions of a head, dimension i pairs with i + rotary_dim /
+    2; the rest pass untouched."""
+    return rotate_by(x, functools.partial(rope_tables, attention, cfg))
 
 
 # ------------------------------------------------------------------ the layer

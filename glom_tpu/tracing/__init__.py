@@ -30,6 +30,7 @@ _EXPORTS = {
     "LM_DEVICE_PHASES": "spans",
     "SAMBAY_DEVICE_PHASES": "spans",
     "KIMI_DEVICE_PHASES": "spans",
+    "EVABYTE_DEVICE_PHASES": "spans",
     "SpanAggregator": "spans",
     "span": "spans",
     "spanned": "spans",

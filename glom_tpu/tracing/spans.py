@@ -179,10 +179,29 @@ KIMI_DEVICE_PHASES = (
     "lm_head_loss",
 )
 
+# The device scopes of the EvaByte language model's step (models/evabyte.py).
+# `embed`, `dense_mlp` (the layer's second norm and its SwiGLU, with the
+# float32 add) and `lm_head_loss` (final norm, the eight heads, the loss) mean
+# what they mean above. A layer's first half is `eva_in` (input norm, the
+# three projections, the rotation of q and k), `eva_summary` (the chunk
+# summariser: one softmax of 16 a chunk a head and two weighted sums, forward
+# and backward), `eva_attention` (EVA attention alone: the two key segments
+# laid end to end, the kernels' calls or the XLA loop, and what joins them)
+# and `eva_out` (the out-projection and the float32 add into the stream).
+EVABYTE_DEVICE_PHASES = (
+    "embed",
+    "eva_in",
+    "eva_summary",
+    "eva_attention",
+    "eva_out",
+    "dense_mlp",
+    "lm_head_loss",
+)
+
 # The language models' Pallas kernels, by their `name=`
 # (kernels/flash_attention.py). They open no scope of their own: a call runs
 # inside the model's attention scope (`attention`; `window_attention`,
-# `full_attention`, `cross_attention`; Laguna's two; `latent_attention`), and its device time
+# `full_attention`, `cross_attention`; Laguna's two; `latent_attention`; `eva_attention`), and its device time
 # belongs to that scope. All begin with `attn_`; none matches `loop_*`, `ffw_*`,
 # `consensus_*` or `ragged-dot*`, the names by which the benchmark tells the
 # routes apart.

@@ -31,6 +31,7 @@ from glom_tpu.ops.patch import LinearParams, init_linear, tokens_to_image
 from glom_tpu.utils.config import (
     GlomConfig,
     HybridLMConfig,
+    EvaByteConfig,
     KimiLinearConfig,
     LagunaConfig,
     SambaYConfig,
@@ -83,6 +84,7 @@ _LM_FAMILIES = {
     SambaYConfig: ("glom_tpu.models.sambay", "init_sambay"),
     LagunaConfig: ("glom_tpu.models.laguna", "init_laguna"),
     KimiLinearConfig: ("glom_tpu.models.kimi_linear", "init_kimi_linear"),
+    EvaByteConfig: ("glom_tpu.models.evabyte", "init_evabyte"),
 }
 
 

@@ -425,6 +425,58 @@ class KimiLinearConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class EvaByteConfig:
+    """An EvaByte language model (models/evabyte.py, `model_type: evabyte`,
+    `attention_class: eva`): a byte-level model of pre-norm residual layers
+    `h += Attn(RMSNorm(h)); h += MLP(RMSNorm(h))` over a float32 residual
+    stream (`fp32_skip_add`). Attention is EVA: a query at t in the aligned
+    window n = t // `window_size` attends, in one softmax, the keys of its own
+    window up to t and one learned summary key and value for every chunk of
+    `chunk_size` positions of the windows before it; multi-head (a key and
+    value head a query head), every dimension of a head rotated at
+    `rope_theta`. The norms multiply by 1 + weight (`norm_add_unit_offset`).
+    The MLP is a dense SwiGLU of `intermediate_size`. After the stack
+    `num_pred_heads` heads predict the bytes at t + 1 .. t + `num_pred_heads`
+    from position t, logits in float32 (`fp32_logits`). Field names are the
+    published config.json's; the defaults are EvaByte's (6.5B).
+
+    `num_hidden_layers` layers from `layer_offset` on and `num_attention_heads`
+    of the `num_attention_heads_total` heads a layer (with their rows of the
+    out-projection) are what THIS chip holds; the vocabulary's 320 rows are
+    never sliced. Widths are never a share."""
+
+    hidden_size: int = 4096
+    intermediate_size: int = 11008
+    rms_norm_eps: float = 1e-5
+    vocab_size: int = 320
+    layer_offset: int = 0
+    num_hidden_layers: int = 32
+    num_hidden_layers_total: int = 32
+    # EVA attention
+    num_attention_heads: int = 32
+    num_attention_heads_total: int = 32
+    head_dim: int = 128
+    window_size: int = 2048
+    chunk_size: int = 16
+    rope_theta: float = 100000.0
+    # the byte-prediction heads, side by side in the head's columns
+    num_pred_heads: int = 8
+    # the training sequence: bytes of one packed row of the batch
+    seq_len: int = 32768
+
+    def __post_init__(self):
+        n, first = self.num_hidden_layers_total, self.layer_offset
+        if first < 0 or self.num_hidden_layers < 1 or first + self.num_hidden_layers > n:
+            raise ValueError(f"layers {first}..{first + self.num_hidden_layers} of {n}")
+        if not 1 <= self.num_attention_heads <= self.num_attention_heads_total:
+            raise ValueError("the heads held are at least one and at most all")
+        if self.window_size % self.chunk_size or self.head_dim % 2:
+            raise ValueError("a window is whole chunks and a head's dimensions pair up")
+        if self.num_pred_heads < 1:
+            raise ValueError("at least the next byte is predicted")
+
+
+@dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Parallelism layout. Axis sizes of 1 disable an axis.
 

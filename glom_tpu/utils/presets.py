@@ -12,6 +12,7 @@ import dataclasses
 from typing import Dict, Union
 
 from glom_tpu.utils.config import (
+    EvaByteConfig,
     GlomConfig,
     HybridLMConfig,
     KimiLinearConfig,
@@ -30,7 +31,8 @@ class Preset:
     description: str
     # The family the preset trains: its type picks the objective
     # (train/trainer.objective_for).
-    model: Union[GlomConfig, HybridLMConfig, SambaYConfig, LagunaConfig, KimiLinearConfig]
+    model: Union[GlomConfig, HybridLMConfig, SambaYConfig, LagunaConfig, KimiLinearConfig,
+                 EvaByteConfig]
     train: TrainConfig
     mesh: MeshConfig
     sp_strategy: str = "none"  # none | ring | ulysses | halo | auto
@@ -71,7 +73,7 @@ class Preset:
 
 PRESETS: Dict[str, Preset] = {}
 # The presets of the language-model families (a HybridLMConfig, a
-# SambaYConfig, a LagunaConfig or a KimiLinearConfig model), in a table of
+# SambaYConfig, a LagunaConfig, a KimiLinearConfig or an EvaByteConfig model), in a table of
 # their own: PRESETS stays GLOM's driver configurations, which is what the
 # sharded trainers and the serving stack iterate; `get_preset` finds both.
 LM_PRESETS: Dict[str, Preset] = {}
@@ -476,6 +478,46 @@ _register(
             kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             num_experts=4, num_experts_total=16, expert_offset=4, num_experts_per_token=4,
             moe_intermediate_size=48, seq_len=80,
+        ),
+        train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
+        mesh=MeshConfig(),
+    )
+)
+
+
+# 10. A sixth family: EvaByte (evabyte: a byte-level model whose attention is
+# EVA, exact softmax inside aligned windows of 2,048 bytes joined in one
+# softmax with a learned summary of every 16-byte chunk of the windows before;
+# a float32 residual stream; eight byte-prediction heads), as ONE chip of 4
+# that share each layer by heads sees it: 8 of the 32 heads with their rows of
+# the out-projection; the MLP's 11,008, the norms, the embedding and the eight
+# heads over all 320 rows whole. Depth: four consecutive published layers (all
+# 32 are alike). Every width is the published one. 620M parameters held; one
+# packed row of 16,384 bytes a step.
+_register(
+    Preset(
+        name="evabyte-stage4tp4",
+        description="EvaByte 6.5B: one chip of 4 a layer by heads (8/32 heads, the MLP "
+        "whole), four layers, one 16k-byte row a step",
+        model=EvaByteConfig(num_hidden_layers=4, num_attention_heads=8, seq_len=16384),
+        train=TrainConfig(
+            batch_size=1, learning_rate=3e-4, compute_dtype="bfloat16", remat=True,
+        ),
+        mesh=MeshConfig(),
+    )
+)
+
+# 10b. The same family at a size the CPU holds: windows of 32 bytes, chunks of
+# 4, three prediction heads, 80 bytes a row (two whole windows and a part).
+_register(
+    Preset(
+        name="evabyte-tiny",
+        description="EvaByte LM, hidden 64, 3 layers, 2 of 4 heads of 16, windows of 32, "
+        "chunks of 4, 3 prediction heads — CPU drives",
+        model=EvaByteConfig(
+            hidden_size=64, intermediate_size=160, vocab_size=40, num_hidden_layers=3,
+            num_hidden_layers_total=3, num_attention_heads=2, num_attention_heads_total=4,
+            head_dim=16, window_size=32, chunk_size=4, num_pred_heads=3, seq_len=80,
         ),
         train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
         mesh=MeshConfig(),

@@ -18,6 +18,7 @@ and the backward pass rounds `ds` once where XLA's rounds it in the product
 (under 0.4% measured, 1% allowed).
 """
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -31,7 +32,8 @@ import pytest
 
 from benchmark import weights_sambay as ws
 from glom_tpu.kernels import flash_attention as fa
-from glom_tpu.models import hybrid_lm, laguna, sambay
+from glom_tpu.models import evabyte, hybrid_lm, laguna, sambay
+from glom_tpu.utils.config import EvaByteConfig
 from glom_tpu.utils.presets import get_preset
 
 # (KV heads, value heads, query heads a KV head, D, Dv): SambaY's pairs share
@@ -350,6 +352,13 @@ FAMILIES = {
                   dict(layer_offset=2, num_hidden_layers=1)),                   # *
 }
 
+# EvaByte's tiny preset at shapes that tile under the aligned mask: two windows of 128, chunks of
+# 2 (128 summaries: one key tile), heads of 64. The two tests that read the residuals' shapes know
+# the older families' (a bfloat16 stream of 128 tokens); the two that count kernels take every one.
+KEPT_FAMILIES = {**FAMILIES, "evabyte": (
+    evabyte, evabyte.init_evabyte,
+    _tiny("evabyte-tiny", head_dim=64, window_size=128, chunk_size=2, seq_len=256), 3, None)}
+
 
 @contextlib.contextmanager
 def parents_policy():
@@ -361,35 +370,47 @@ def parents_policy():
 
 
 def _params_and_ids(family, cfg):
-    params = FAMILIES[family][1](jax.random.PRNGKey(3), cfg)
+    params = KEPT_FAMILIES[family][1](jax.random.PRNGKey(3), cfg)
     return params, jax.random.randint(jax.random.PRNGKey(4), (2, cfg.seq_len), 0, cfg.vocab_size)
 
 
 def _loss_and_params(family, dtype=jnp.bfloat16, **kw):
-    model, _, cfg, _, _ = FAMILIES[family]
+    model, _, cfg, _, _ = KEPT_FAMILIES[family]
     params, ids = _params_and_ids(family, cfg)
     return (lambda p: model.lm_loss(p, ids, cfg, compute_dtype=dtype, **kw)), params
 
 
 def _kernel_calls(loss, params):
-    text = str(jax.make_jaxpr(jax.grad(loss, has_aux=True))(params))
-    return text.count("name=attn_flash_fwd"), text.count("name=attn_flash_bwd_onesweep")
+    """(forward, backward) kernel calls in the gradient's program, every
+    sub-program counted as often as it is called (the printed jaxpr shows a
+    sub-program that several layers share once)."""
+    calls = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, has_aux=True))(params).jaxpr)
+    return calls["attn_flash_fwd"], calls["attn_flash_bwd_onesweep"]
 
 
 @pytest.mark.parametrize("remat", [True, False], ids=["remat", "no_remat"])
-@pytest.mark.parametrize("family", list(FAMILIES))
+@pytest.mark.parametrize("family", list(KEPT_FAMILIES))
 def test_the_recomputation_reads_the_forward_kernels_results_and_does_not_run_it_again(
         family, remat, kernels_here):
     """The gradient's program holds one forward and one backward kernel call
     an attention layer, recomputed or not, and the records' counter says how
     many layers' recomputation read what the forward kept."""
-    layers = FAMILIES[family][3]
+    layers = KEPT_FAMILIES[family][3]
     loss, params = _loss_and_params(family, remat=remat)
     assert _kernel_calls(loss, params) == (layers, layers)
     assert float(jax.jit(loss)(params)[1]["attn_forward_kept"]) == (layers if remat else 0)
 
 
-@pytest.mark.parametrize("family", list(FAMILIES))
+@pytest.mark.parametrize("family", list(KEPT_FAMILIES))
 def test_the_kept_results_change_no_bit_of_a_gradient(family, kernels_here):
     """Against `run_stack` with the parent's policy, which runs the forward
     kernel twice a layer: the same loss and the same gradients, bit for bit.
@@ -399,7 +420,7 @@ def test_the_kept_results_change_no_bit_of_a_gradient(family, kernels_here):
     rounding (0.5% of a gradient; none op by op, none without
     `xla_allow_excess_precision`), which is the compiler's and not the
     policy's."""
-    layers = FAMILIES[family][3]
+    layers = KEPT_FAMILIES[family][3]
     loss, params = _loss_and_params(family, dtype=None, remat=True)
     value_and_grad = lambda: jax.jit(jax.value_and_grad(lambda p: loss(p)[0]))(params)
     got = value_and_grad()
@@ -458,6 +479,124 @@ def test_on_the_xla_loop_a_recomputed_layer_keeps_what_it_kept(family, capsys):
     assert float(jax.jit(loss)(params)[1]["attn_forward_kept"]) == 0
 
 
+# ------------------------------------- the aligned mask (EVA attention, `fa.Aligned`)
+
+
+def eva_config(t, window, chunk, heads=2, d=64):
+    return EvaByteConfig(hidden_size=64, num_attention_heads=heads, num_attention_heads_total=heads,
+                         head_dim=d, window_size=window, chunk_size=chunk, num_hidden_layers=1,
+                         num_hidden_layers_total=1, seq_len=t)
+
+
+def eva_inputs(cfg, dtype, seed=0):
+    t, h, d = cfg.seq_len, cfg.num_attention_heads, cfg.head_dim
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k, v, cot = (jax.random.normal(key, (1, t, h, d), dtype) for key in ks[:4])
+    return q, k, v, jax.random.normal(ks[4], (h, d)), jax.random.normal(ks[5], (h, d)), cot
+
+
+def eva_by_the_kernels(cfg, tq, tk):
+    """`evabyte.eva_attention`'s kernel branch at the tiles given, in interpret mode."""
+    def f(q, k, v, phi, mu):
+        khat, vhat = evabyte.summarise(k, v, phi, mu, cfg)
+        mask = fa.Aligned(cfg.window_size, cfg.chunk_size, cfg.seq_len)
+        return fa.flash_attention(q[:, :, :, None], jnp.concatenate([k, khat], axis=1),
+                                  jnp.concatenate([v, vhat], axis=1), mask, tq=tq, tk=tk,
+                                  interpret=True)[:, :, :, 0]
+    return f
+
+
+def eva_by_the_xla_loop(cfg):
+    return lambda q, k, v, phi, mu: evabyte.eva_attention(
+        q, k, v, *evabyte.summarise(k, v, phi, mu, cfg), cfg)[0]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("t, window, chunk, tq, tk", [
+    (512, 256, 4, 128, 128), (512, 128, 2, 128, 128), (1024, 512, 4, 256, 256),
+    (512, 256, 2, 256, 128), (768, 256, 2, 128, 128)],
+    ids=["two_windows", "four_windows", "key_tile_of_256", "query_tile_over_key_tile",
+         "three_windows"])
+def test_under_the_aligned_mask_the_kernels_match_the_xla_loop_in_every_gradient(
+        t, window, chunk, tq, tk, dtype):
+    """Both key segments in one online softmax: the output, dq, and through
+    the concatenated keys' dk and dv the gradients of k, v and of the
+    summariser's `phi` and `mu` (the summary rows' dk and dv)."""
+    cfg = eva_config(t, window, chunk)
+    *xs, cot = eva_inputs(cfg, dtype)
+    grads = lambda f: jax.jit(lambda *a: (lambda o, pull: (o,) + pull(cot))(*jax.vjp(f, *a)))(*xs)
+    got, want = grads(eva_by_the_kernels(cfg, tq, tk)), grads(eva_by_the_xla_loop(cfg))
+    tol = 3e-6 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv", "dphi", "dmu"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert rel(a, b) < tol, (name, rel(a, b))
+    assert rel(got[4], 0 * got[4]) > 0 and rel(got[5], 0 * got[5]) > 0   # the summaries matter
+
+
+def aligned_seen(mask):
+    q_pos = np.arange(mask.length)[:, None]
+    k_pos = np.arange(mask.length + mask.summaries)[None, :]
+    return np.asarray(fa._seen(jnp.asarray(q_pos), jnp.asarray(k_pos), mask))
+
+
+@pytest.mark.parametrize("t, window, chunk, tq, tk", [
+    (1024, 256, 8, 128, 128), (2048, 512, 4, 256, 128), (2048, 1024, 8, 256, 256),
+    (1024, 256, 2, 128, 128), (4096, 1024, 8, 512, 512), (2048, 512, 16, 512, 128)])
+def test_the_aligned_schedule_visits_no_tile_the_mask_empties(t, window, chunk, tq, tk):
+    """By brute force over the whole mask of both segments: a pair is a step
+    exactly where some query of the tile sees some key of it, masked exactly
+    where it also holds a pair that is not seen; the counters split the steps
+    by segment."""
+    mask = fa.Aligned(window, chunk, t)
+    seen = aligned_seen(mask)
+    tile = lambda i, j: seen[i * tq:(i + 1) * tq, j * tk:(j + 1) * tk]
+    want = {(i, j): not tile(i, j).all() for i in range(t // tq)
+            for j in range((t + mask.summaries) // tk) if tile(i, j).any()}
+    (qi, kj, flags), (kj2, qi2, flags2) = fa.schedule(t, tq, tk, mask)
+    assert {(i, j): bool(f & fa._MASKED) for i, j, f in zip(qi, kj, flags)} == want
+    assert {(i, j): bool(f & fa._MASKED) for i, j, f in zip(qi2, kj2, flags2)} == want
+    own = sum(j < t // tk for _, j in want)
+    assert fa.aligned_key_blocks(tq, tk, mask) == (own * tk // 128, (len(want) - own) * tk // 128)
+    assert fa.key_blocks(t, tq, tk, mask) == len(want) * tk // 128
+
+
+def test_the_aligned_mask_case_by_case():
+    """A window's first query sees one key of its own and every earlier
+    chunk's summary; the first window sees no summary; no query sees a
+    summary of its own window or a key of another."""
+    mask = fa.Aligned(256, 16, 1024)
+    seen = aligned_seen(mask)
+    own, summary = seen[:, :1024], seen[:, 1024:]
+    assert not summary[:256].any() and (own[:256, :256] == np.tril(np.ones((256, 256), bool))).all()
+    for n in range(4):
+        t = 256 * n
+        assert own[t].sum() == 1 and own[t, t] and summary[t].sum() == n * 16
+        assert own[t + 255].sum() == 256 and own[t + 255, t:t + 256].all()
+        assert summary[t + 255].sum() == n * 16 and summary[t + 255, :n * 16].all()
+    assert summary.shape[1] == 64 and not summary[:, 48:].any()   # the last window's: seen by none
+
+
+def test_the_cells_tiles_and_key_blocks_under_the_aligned_mask():
+    """`evabyte.train`'s shapes: 16,384 positions in windows of 2,048, chunks
+    of 16, one query head a KV head of 128: tiles of 512 by 512, 320 blocks
+    of own keys and 160 of summaries a layer where the mask needs 320 and 112
+    (`eva_key_blocks_visited_pct.train` 111.1); a window or a chunk that is no
+    power of two, or summaries that no key tile divides, take the XLA loop;
+    the older masks' tiles are what they were."""
+    mask = fa.Aligned(2048, 16, 16384)
+    assert fa.tiles(16384, 1, 128, 128, mask) == (512, 512)
+    assert fa.aligned_key_blocks(512, 512, mask) == (320, 160)
+    from benchmark import flops_evabyte
+    model = dataclasses.asdict(get_preset("evabyte-stage4tp4").model)
+    assert flops_evabyte.needed_key_blocks(model, 16384) == (320, 112)
+    assert fa.tiles(6144, 1, 128, 128, fa.Aligned(1536, 16, 6144)) is None
+    assert fa.tiles(4096, 1, 128, 128, fa.Aligned(2048, 24, 4096)) is None
+    assert fa.tiles(2048, 1, 128, 128, fa.Aligned(1024, 32, 2048)) is None   # 64 summaries
+    assert fa.tiles(2048, 1, 128, 128, fa.Aligned(1024, 16, 2048)) == (512, 128)
+    assert fa.tiles(8192, 8, 128, 128, 512) == (256, 512) and fa.tiles(8192, 4, 128, 128) == (
+        256, 1024)
+
+
 # ----------------------------------------------- compiled for the chip, not run
 
 
@@ -492,3 +631,21 @@ def test_the_kernels_compile_for_a_v5e_at_the_cells_sizes(one_chip, heads, windo
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
     assert f"{t},{t}]" not in text and f"{tq},{t}]" not in text   # no [queries, keys] array
+
+
+def test_the_kernels_compile_for_a_v5e_under_the_aligned_mask_at_the_cells_size(one_chip):
+    """`evabyte.train`'s attention: 8 heads of 128, 16,384 positions and 1,024
+    summaries after them, windows of 2,048, in bfloat16 (the step's) and in
+    float32 (`correct`'s pass of the attention alone). Nothing runs."""
+    t, h, d = 16384, 8, 128
+    mask = fa.Aligned(2048, 16, t)
+    tq, tk = fa.tiles(t, 1, d, d, mask)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        arg = lambda *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        compiled = jax.jit(lambda *a: out_and_grads(
+            lambda q, k, v: fa.flash_attention(q, k, v, mask, tq=tq, tk=tk), *a)).lower(
+            arg(1, t, h, 1, d), arg(1, t + t // 16, h, d), arg(1, t + t // 16, h, d),
+            arg(1, t, h, 1, d)).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2
+        assert f"{tq},{t}]" not in text and f"{t},{t + t // 16}]" not in text

@@ -17,6 +17,7 @@ import pytest
 
 from glom_tpu.tracing.spans import (
     DEVICE_PHASES,
+    EVABYTE_DEVICE_PHASES,
     HOST_PHASES,
     KIMI_DEVICE_PHASES,
     LAGUNA_DEVICE_PHASES,
@@ -184,7 +185,8 @@ def test_compiled_step_carries_every_phase(builder):
 # ------------------------------------------ the language-model families' tuples
 
 FAMILIES = {"hybrid_lm": LM_DEVICE_PHASES, "sambay": SAMBAY_DEVICE_PHASES,
-            "laguna": LAGUNA_DEVICE_PHASES, "kimi_linear": KIMI_DEVICE_PHASES}
+            "laguna": LAGUNA_DEVICE_PHASES, "kimi_linear": KIMI_DEVICE_PHASES,
+            "evabyte": EVABYTE_DEVICE_PHASES}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -234,6 +236,27 @@ def test_kimi_linears_scopes_and_counters_are_registered():
         set(KIMI_DEVICE_PHASES) & set(LM_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES))
     assert kimi_linear.COUNTERS == hybrid_lm.COUNTERS + (
         "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min", "kda_forward_kept")
+
+
+def test_evabytes_scopes_and_counters_are_registered():
+    """`embed`, `dense_mlp` and `lm_head_loss` are the other families' on
+    purpose; a layer's first half is four scopes of its own (projections and
+    rotation, the chunk summariser, EVA attention alone, the out-projection
+    with the float32 add), and no family's attention scope is borrowed for
+    them. The attention kernels are the language models' two, under their
+    names. The records' counters are the kept forward's, the two key
+    segments' blocks, the summary keys formed and the prediction heads."""
+    from glom_tpu.models import evabyte
+
+    own = set(EVABYTE_DEVICE_PHASES) - set(LAGUNA_DEVICE_PHASES)
+    assert own == {"eva_in", "eva_summary", "eva_attention", "eva_out"}
+    assert not own & (set(LM_DEVICE_PHASES) | set(SAMBAY_DEVICE_PHASES) | set(KIMI_DEVICE_PHASES)
+                      | set(DEVICE_PHASES) | set(HOST_PHASES))
+    assert set(EVABYTE_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES) == {
+        "embed", "dense_mlp", "lm_head_loss"}
+    assert evabyte.COUNTERS == ("attn_forward_kept", "attn_key_blocks_local",
+                                "attn_key_blocks_summary", "eva_summary_keys", "lm_pred_heads")
+    assert all(name.startswith("attn_") for name in LM_KERNELS)
 
 
 def test_every_op_of_lagunas_rotation_lies_under_rope_inside_an_attention_scope():
