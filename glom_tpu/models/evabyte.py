@@ -61,11 +61,11 @@ from glom_tpu.models.hybrid_lm import (
     rms_norm,
     run_stack,
 )
-from glom_tpu.models.laguna import rotary_tables, rotate_by, swiglu
+from glom_tpu.models.laguna import rotary_tables, rotate_by, swiglu, swiglu_backward_staged
 from glom_tpu.utils.config import EvaByteConfig
 
 COUNTERS = ("attn_forward_kept", "attn_key_blocks_local", "attn_key_blocks_summary",
-            "eva_summary_keys", "lm_pred_heads")
+            "eva_summary_keys", "lm_pred_heads", "swiglu_backward_staged")
 INIT_STD = 0.01275
 
 
@@ -222,7 +222,7 @@ def layer(p, x, cfg: EvaByteConfig, dtype):
         u2 = offset_norm(x, p["norm2"], cfg.rms_norm_eps, dtype)
         x = x + swiglu(u2, p["w_gate"], p["w_up"], p["w_down"], dtype)
     return x, {"attn_key_blocks_local": own_blocks, "attn_key_blocks_summary": summary_blocks,
-               "attn_on_kernels": on_kernels}
+               "attn_on_kernels": on_kernels, "swiglu_calls": 1}
 
 
 # ------------------------------------------------------------------ the stack
@@ -258,8 +258,9 @@ def lm_loss(params, ids, cfg: EvaByteConfig, *, compute_dtype=None,
     position t predicting the byte at t + 1 + m (`hybrid_lm.next_token_loss`).
     Returns (loss, counters): the key blocks of own keys and of summary keys
     the layers multiplied this step, the summary keys they formed, the
-    prediction heads, and the layers whose recomputation reads the attention
-    forward kernel's kept output (`hybrid_lm.forward_kept`'s count)."""
+    prediction heads, the layers whose recomputation reads the attention
+    forward kernel's kept output (`hybrid_lm.forward_kept`'s count), and
+    `laguna.swiglu_backward_staged`."""
     x, counted = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         loss = next_token_loss(normed(params, x, cfg, compute_dtype),
@@ -273,6 +274,7 @@ def lm_loss(params, ids, cfg: EvaByteConfig, *, compute_dtype=None,
             "eva_summary_keys": jnp.float32(
                 len(counted) * ids.shape[0] * (ids.shape[1] // cfg.chunk_size)),
             "lm_pred_heads": jnp.float32(cfg.num_pred_heads),
+            "swiglu_backward_staged": swiglu_backward_staged(counted),
         }
     return loss, counters
 

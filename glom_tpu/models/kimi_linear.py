@@ -60,11 +60,11 @@ from glom_tpu.models.hybrid_lm import (
     rms_norm,
     run_stack,
 )
-from glom_tpu.models.laguna import swiglu
+from glom_tpu.models.laguna import swiglu, swiglu_backward_staged
 from glom_tpu.utils.config import KimiLinearConfig
 
 COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_full", "kda_chunks", "kda_log_decay_min",
-                                 "kda_forward_kept")
+                                 "kda_forward_kept", "swiglu_backward_staged")
 # The sub-chunk and the segment are the fastest of those tried on a v5e at the benchmark's
 # size (16,384 positions, 32 heads of 128: 122-133 ms a layer forward, recomputed and
 # backward at segments of 1-4 chunks and sub-chunks of 8; 174 at sub-chunks of 4, 148 at 16;
@@ -451,18 +451,18 @@ def mla_mixer(p, x_in, cfg: KimiLinearConfig, dtype):
 
 def mlp(kind: str, p, x, cfg: KimiLinearConfig, dtype):
     """The layer's second half: x [B, T, d] -> (its output, the routed
-    part's counters or {}, the router's choices or None)."""
+    part's counters and `swiglu_calls`, the router's choices or None)."""
     if kind == "D":
         with jax.named_scope("dense_mlp"):
             u2 = rms_norm(x, p["norm2"], cfg.rms_norm_eps)
-            return swiglu(u2, p["w_gate"], p["w_up"], p["w_down"], dtype), {}, None
+            return swiglu(u2, p["w_gate"], p["w_up"], p["w_down"], dtype), {"swiglu_calls": 1}, None
     with jax.named_scope("moe_router"):
         u2 = rms_norm(x, p["norm2"], cfg.rms_norm_eps).reshape(-1, x.shape[-1])
     routed, counters, top_i = hybrid_lm.moe_routed(
         p, u2, cfg, dtype, family=hybrid_lm.SWIGLU, rung_loads=(cfg.moe_rung_loads,))
     with jax.named_scope("moe_shared"):
         shared = swiglu(u2, p["s_gate"], p["s_up"], p["s_down"], dtype)
-    return (routed + shared).reshape(x.shape), counters, top_i
+    return (routed + shared).reshape(x.shape), {**counters, "swiglu_calls": 1}, top_i
 
 
 def layer(mixer: str, mlp_kind: str, p, x, cfg: KimiLinearConfig, dtype):
@@ -517,7 +517,7 @@ def lm_loss(params, ids, cfg: KimiLinearConfig, *, compute_dtype=None,
     `hybrid_lm.forward_kept`, and `kda_forward_kept`: the KDA layers whose
     recomputation reads the delta rule's kept output and states and does not
     run its forward pass again (all of them under `remat`, which is what
-    recomputes; none without)."""
+    recomputes; none without), and `laguna.swiglu_backward_staged`."""
     x, counted, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).reshape(-1, x.shape[-1])
@@ -530,6 +530,7 @@ def lm_loss(params, ids, cfg: KimiLinearConfig, *, compute_dtype=None,
         counters["kda_chunks"] = jnp.float32(kda_chunks(cfg, *ids.shape))
         counters["kda_forward_kept"] = jnp.float32(
             sum(m == "K" for m, _ in cfg.kinds) if remat else 0)
+        counters["swiglu_backward_staged"] = swiglu_backward_staged(counted)
         decays = [c["kda_log_decay_min"] for c in counted if "kda_log_decay_min" in c]
         if decays:
             counters["kda_log_decay_min"] = jnp.min(jnp.stack(decays))

@@ -52,7 +52,8 @@ from glom_tpu.models.hybrid_lm import (
 )
 from glom_tpu.utils.config import LagunaConfig
 
-COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_window", "attn_key_blocks_full")
+COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_window", "attn_key_blocks_full",
+                                 "swiglu_backward_staged")
 ATTENTION_SCOPE = {"S": "window_attention", "F": "full_attention"}
 
 
@@ -217,25 +218,80 @@ def attention_mixer(attention: str, p, x_in, cfg: LagunaConfig, dtype):
     return out, key_blocks, on_kernels
 
 
+def _gated(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def swiglu(u, w_gate, w_up, w_down, dtype):
-    h = jax.nn.silu(_mm(u, _cast(w_gate, dtype))) * _mm(u, _cast(w_up, dtype))
-    return _mm(h.astype(u.dtype), _cast(w_down, dtype)).astype(u.dtype)
+    """`(silu(u W_gate) * (u W_up)) W_down` in u's type: gate and up leave
+    their products in float32, h = silu(gate) * up is rounded to u's type
+    for the down product. The weights are cast to `dtype` at each product.
+
+    Its derivative is its own (`jax.custom_vjp`). Kept: u, the weights, gate
+    and up in float32, which is what autodiff kept (under `run_stack`'s
+    recomputation the two products that make them run again and the down
+    product falls out). The backward holds every operand of its six products
+    as an ARRAY in u's type, behind `lax.optimization_barrier`s: dy as it
+    arrives (in EvaByte the float32 stream's cotangent, rounded); then dh =
+    dy W_down^T, rounded as autodiff rounded it, and ONE float32 elementwise
+    pass over gate, up and dh that writes h, dgate = dh * up * silu'(gate)
+    and dup = dh * silu(gate). The three weights' gradients and du's two
+    products read those. Without the barriers the compiler fuses each
+    expression into every product as its operand's producer and evaluates it
+    from float32 once for every output tile that crosses a row: two to five
+    times an operand's bytes, and a `silu`, a tile (PERF.md section 7, trap
+    20). A default-precision product rounds a float32 operand to bfloat16 on
+    the chip anyway, so the products multiply what they multiplied; in
+    float32 nothing is rounded and the gradients are autodiff's."""
+    return _swiglu_fwd(u, w_gate, w_up, w_down, dtype)[0]
+
+
+def _swiglu_fwd(u, w_gate, w_up, w_down, dtype):
+    gate, up = _mm(u, _cast(w_gate, dtype)), _mm(u, _cast(w_up, dtype))
+    out = _mm(_gated(gate, up).astype(u.dtype), _cast(w_down, dtype)).astype(u.dtype)
+    return out, (u, w_gate, w_up, w_down, gate, up)
+
+
+def _swiglu_bwd(dtype, kept, dy):
+    u, w_gate, w_up, w_down, gate, up = kept
+    back = lambda d, w: jnp.einsum("...n,kn->...k", d, _cast(w, dtype),
+                                   preferred_element_type=jnp.float32)
+    onto = lambda a, d: jnp.einsum("...k,...n->kn", a, d, preferred_element_type=jnp.float32)
+    dy = jax.lax.optimization_barrier(dy)
+    dh = back(dy, w_down).astype(u.dtype)
+    h, pull = jax.vjp(_gated, gate, up)
+    staged = (h, *pull(dh.astype(jnp.float32)))
+    h, dgate, dup = jax.lax.optimization_barrier(tuple(a.astype(u.dtype) for a in staged))
+    du = (back(dgate, w_gate) + back(dup, w_up)).astype(u.dtype)
+    return (du, onto(u, dgate).astype(w_gate.dtype), onto(u, dup).astype(w_up.dtype),
+            onto(h, dy).astype(w_down.dtype))
+
+
+swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+def swiglu_backward_staged(counted):
+    """The records' `swiglu_backward_staged`: the `swiglu` calls of the step,
+    each of which reads staged operands in its backward. `counted` is one
+    dict a layer, holding `swiglu_calls` where the layer called it."""
+    return jnp.float32(sum(c.get("swiglu_calls", 0) for c in counted))
 
 
 def mlp(kind: str, p, x, cfg: LagunaConfig, dtype):
     """The layer's second half: x [B, T, d] -> (its output, the routed
-    part's counters or {}, the router's choices or None)."""
+    part's counters and `swiglu_calls`, the router's choices or None)."""
     if kind == "D":
         with jax.named_scope("dense_mlp"):
             u2 = rms_norm(x, p["norm2"], cfg.rms_norm_eps)
-            return swiglu(u2, p["w_gate"], p["w_up"], p["w_down"], dtype), {}, None
+            return swiglu(u2, p["w_gate"], p["w_up"], p["w_down"], dtype), {"swiglu_calls": 1}, None
     with jax.named_scope("moe_router"):
         u2 = rms_norm(x, p["norm2"], cfg.rms_norm_eps).reshape(-1, x.shape[-1])
     routed, counters, top_i = hybrid_lm.moe_routed(
         p, u2, cfg, dtype, family=hybrid_lm.SWIGLU, rung_loads=(cfg.moe_rung_loads,))
     with jax.named_scope("moe_shared"):
         shared = swiglu(u2, p["s_gate"], p["s_up"], p["s_down"], dtype)
-    return (routed + shared).reshape(x.shape), counters, top_i
+    return (routed + shared).reshape(x.shape), {**counters, "swiglu_calls": 1}, top_i
 
 
 def layer(attention: str, mlp_kind: str, p, x, cfg: LagunaConfig, dtype):
@@ -276,8 +332,8 @@ def lm_loss(params, ids, cfg: LagunaConfig, *, compute_dtype=None,
     """Next-token cross-entropy over the vocabulary rows held here
     (`hybrid_lm.next_token_loss`). Returns (loss, counters): the routed
     part's four over the `E` layers (`hybrid_lm.merge_counters`), the key
-    blocks the window layers and the full layers multiplied this step, and
-    `hybrid_lm.forward_kept`."""
+    blocks the window layers and the full layers multiplied this step,
+    `hybrid_lm.forward_kept` and `swiglu_backward_staged`."""
     x, counted, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).reshape(-1, x.shape[-1])
@@ -285,6 +341,7 @@ def lm_loss(params, ids, cfg: LagunaConfig, *, compute_dtype=None,
     with jax.named_scope("step_metrics"):
         counters = hybrid_lm.merge_counters(counted)
         counters["attn_forward_kept"] = hybrid_lm.forward_kept(counted, remat)
+        counters["swiglu_backward_staged"] = swiglu_backward_staged(counted)
         for name in ("attn_key_blocks_window", "attn_key_blocks_full"):
             counters[name] = jnp.float32(sum(c.get(name, 0) for c in counted))
     return loss, counters

@@ -91,6 +91,7 @@ def test_the_records_carry_the_counters(fitted, tiny):
         assert r["eva_summary_keys"] == 3 * 2 * (80 // cfg.chunk_size)
         assert r["lm_pred_heads"] == cfg.num_pred_heads == 3
         assert r["attn_forward_kept"] == 0     # the XLA loop names nothing for the recomputation
+        assert r["swiglu_backward_staged"] == cfg.num_hidden_layers == 3   # a dense MLP a layer
     assert set(evabyte.COUNTERS) <= set(history[-1])
 
 

@@ -123,6 +123,7 @@ def test_the_records_carry_the_counters(fitted, tiny):
         # the preset trains with `remat`: every KDA layer's recomputation reads the delta
         # rule's kept output and states
         assert r["kda_forward_kept"] == sum(m == "K" for m, _ in cfg.kinds) == 4
+        assert r["swiglu_backward_staged"] == 5    # one dense layer's MLP, four shared experts
         assert r["moe_pairs_here"] + e <= r["moe_rows_computed"] <= rungs[-1]
         assert 0 < r["moe_pairs_here"] <= n * min(k, e)
         assert 0.0 <= r["moe_rows_full_share"] <= 1.0 and r["moe_max_expert_load"] <= n

@@ -93,6 +93,7 @@ def test_the_records_carry_the_counters(fitted, tiny):
         # 80 tokens are one query block here: ceil(80 / 128) = 1 key block a layer
         assert (r["attn_key_blocks_window"], r["attn_key_blocks_full"]) == (3, 2)
         assert r["attn_forward_kept"] == 0     # the XLA loop names nothing for the recomputation
+        assert r["swiglu_backward_staged"] == 5    # one dense layer's MLP, four shared experts
         assert r["moe_pairs_here"] + e <= r["moe_rows_computed"] <= rungs[-1]
         assert 0 < r["moe_pairs_here"] <= n * min(k, e)
         assert 0.0 <= r["moe_rows_full_share"] <= 1.0 and r["moe_max_expert_load"] <= n

@@ -215,8 +215,10 @@ def test_lagunas_scopes_counters_and_inner_scopes_are_registered():
     assert set(laguna.ATTENTION_SCOPE.values()) == {"window_attention", "full_attention"} <= (
         set(sambay.ATTENTION_SCOPE.values()))
     assert laguna.COUNTERS == hybrid_lm.COUNTERS + (
-        "attn_key_blocks_window", "attn_key_blocks_full")
-    assert set(laguna.COUNTERS[-2:]) <= set(sambay.COUNTERS)
+        "attn_key_blocks_window", "attn_key_blocks_full", "swiglu_backward_staged")
+    assert set(laguna.COUNTERS[-3:-1]) <= set(sambay.COUNTERS)
+    # `laguna.swiglu` is the three families' that call it; the two others run no SwiGLU of it
+    assert "swiglu_backward_staged" not in hybrid_lm.COUNTERS + sambay.COUNTERS
 
 
 def test_kimi_linears_scopes_and_counters_are_registered():
@@ -235,7 +237,8 @@ def test_kimi_linears_scopes_and_counters_are_registered():
     assert {"moe_router", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"} <= (
         set(KIMI_DEVICE_PHASES) & set(LM_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES))
     assert kimi_linear.COUNTERS == hybrid_lm.COUNTERS + (
-        "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min", "kda_forward_kept")
+        "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min", "kda_forward_kept",
+        "swiglu_backward_staged")
 
 
 def test_evabytes_scopes_and_counters_are_registered():
@@ -255,7 +258,8 @@ def test_evabytes_scopes_and_counters_are_registered():
     assert set(EVABYTE_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES) == {
         "embed", "dense_mlp", "lm_head_loss"}
     assert evabyte.COUNTERS == ("attn_forward_kept", "attn_key_blocks_local",
-                                "attn_key_blocks_summary", "eva_summary_keys", "lm_pred_heads")
+                                "attn_key_blocks_summary", "eva_summary_keys", "lm_pred_heads",
+                                "swiglu_backward_staged")
     assert all(name.startswith("attn_") for name in LM_KERNELS)
 
 
