@@ -39,6 +39,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from glom_tpu.kernels import selective_scan as scan_kernels
 from glom_tpu.models.hybrid_lm import init_leaf as hybrid_init_leaf
 from glom_tpu.models.hybrid_lm import (
     _cast,
@@ -55,10 +56,11 @@ from glom_tpu.models.hybrid_lm import (
 from glom_tpu.utils.config import SambaYConfig
 
 COUNTERS = ("attn_key_blocks_window", "attn_key_blocks_full", "scan_chunks",
-            "attn_forward_kept")
-# The selective scan's schedule: positions a carried state (a chunk, recomputed
-# whole in the backward pass), and positions a segment (a chunk's segments are
-# scanned side by side).
+            "attn_forward_kept", "scan_on_kernels")
+# The selective scan's schedule in its XLA form alone (the kernels' blocks are
+# `kernels/selective_scan.blocks`): positions a carried state (a chunk,
+# recomputed whole in the backward pass), and positions a segment (a chunk's
+# segments are scanned side by side).
 SCAN_CHUNK = 1024
 SCAN_SEGMENT = 32
 ATTENTION_SCOPE = {"W": "window_attention", "F": "full_attention", "X": "cross_attention"}
@@ -158,23 +160,52 @@ def mlp(p, x_in, cfg: SambaYConfig, dtype):
 # -------------------------------------------------------------------- Mamba-1
 
 
+def scan_kernel_blocks(t: int, channels: int, states: int):
+    """The (time block, channel block) at which the Pallas kernels run
+    `selective_scan`'s recurrence, or None where the XLA form runs it: off a
+    TPU, and at a width that does not tile (`kernels/selective_scan.blocks`)."""
+    tiled = scan_kernels.blocks(t, channels, states)
+    return tiled if tiled and scan_kernels.on_tpu() else None
+
+
 def selective_scan(x, dt, a, b, c):
     """The selective state-space recurrence of Mamba-1,
         s_t = exp(dt_t A) s_{t-1} + (dt_t x_t) B_t^T,   y_t = s_t C_t,
     with a decay of its own for every channel and state: x [B, T, C], dt
     [B, T, C] float32, a [C, N] float32 (negative), b and c [B, T, N]. Returns
-    (y [B, T, C] in x's type, the number of chunks).
+    (y [B, T, C] in x's type, the number of chunks). Float32 state, exp,
+    products and sums on both paths; x, b and c are read in their own type
+    and widened.
 
-    A `lax.scan` over chunks of SCAN_CHUNK positions carries the state [B, N, C]
+    On a TPU, with the channels a whole number of 128-lane registers and the
+    states of 8 sublanes (`scan_kernel_blocks`), the two kernels of
+    `kernels/selective_scan.py` run it a position at a time with the state [N,
+    channel block] in VMEM for the whole pass; a chunk is a time block of
+    theirs. For the backward they keep the inputs and the state entering
+    every time block ([T / block, B, N, C] float32) and make a block's states
+    again in VMEM. A length that is no whole number of time blocks is padded
+    with steps of dt = 0, which neither decay the state nor add to it.
+
+    Anywhere else (the CPU, a width that does not tile) the XLA form below
+    runs, which is also what the kernels are tested against. A `lax.scan` over
+    chunks of SCAN_CHUNK positions carries the state [B, N, C]
     (states before channels: the channels fill the lanes) and recomputes a
     chunk whole in the backward pass, so that what is kept of the [T, N, C]
     states is one a chunk. Within a chunk its segments of SCAN_SEGMENT positions
     are scanned side by side from a zero state (that many steps over [B,
     segments, N, C]); a second short scan carries the states over the
     segments' ends, and what the state entering a segment gives each of its
-    positions, C_t . (exp(A sum_{u <= t} dt_u) * s), is added. A length that
-    is no multiple of the chunk is padded with steps of dt = 0, which
-    neither decay the state nor add to it."""
+    positions, C_t . (exp(A sum_{u <= t} dt_u) * s), is added. The length is
+    padded to whole chunks likewise."""
+    tiled = scan_kernel_blocks(x.shape[1], x.shape[2], a.shape[1])
+    if tiled:
+        t, (block, channel_block) = x.shape[1], tiled
+        pad = -t % block
+        if pad:
+            x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad), (0, 0))) for v in (x, dt, b, c))
+        y = scan_kernels.selective_scan(x, dt, a, b, c, time_block=block,
+                                        channel_block=channel_block)
+        return y[:, :t], (t + pad) // block
     chunk, segment = SCAN_CHUNK, SCAN_SEGMENT
     if chunk % segment:
         raise ValueError(f"a chunk of {chunk} positions is no whole number of segments "
@@ -223,7 +254,8 @@ def selective_scan(x, dt, a, b, c):
 
 def mamba_mixer(p, x_in, cfg: SambaYConfig, dtype):
     """The layer's input [B, T, d] -> (the mixer's output [B, T, d], the
-    scan's output before the gate [B, T, 2d], the scan's chunks)."""
+    scan's output before the gate [B, T, 2d], the scan's chunks, 1 where the
+    scan ran the kernels)."""
     di, n, r = cfg.mamba_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
     with jax.named_scope("mamba_in"):
         u = layer_norm(x_in, p["norm1_w"], p["norm1_b"], cfg.layer_norm_eps)
@@ -235,10 +267,11 @@ def mamba_mixer(p, x_in, cfg: SambaYConfig, dtype):
         a = -jnp.exp(p["A_log"])
     with jax.named_scope("selective_scan"):
         y, chunks = selective_scan(x, dt, a, b, c)
+        on_kernels = int(scan_kernel_blocks(x.shape[1], di, n) is not None)
     with jax.named_scope("mamba_out"):
         y = y + _cast(p["D"], dtype) * x
         out = _mm(y * jax.nn.silu(z), _cast(p["out_proj"], dtype)).astype(u.dtype)
-    return out, y, chunks
+    return out, y, chunks, on_kernels
 
 
 def gmu_mixer(p, x_in, memory, cfg: SambaYConfig, dtype):
@@ -306,8 +339,8 @@ def layer(kind: str, index: int, p, x, side, cfg: SambaYConfig, dtype):
     n = cfg.num_hidden_layers_total
     counters = {}
     if kind == "M":
-        out, y, chunks = mamba_mixer(p, x, cfg, dtype)
-        counters["scan_chunks"] = chunks
+        out, y, chunks, on_kernels = mamba_mixer(p, x, cfg, dtype)
+        counters.update(scan_chunks=chunks, scan_on_kernels=on_kernels)
         if index == n // 2:
             memory = y
     elif kind == "G":
@@ -337,8 +370,9 @@ def lm_loss(params, ids, cfg: SambaYConfig, *, compute_dtype=None,
     """Next-token cross-entropy over the vocabulary rows held here, under the
     tied embedding (`hybrid_lm.next_token_loss`). Returns (loss, counters):
     the key blocks the window layers and the full-length layers (`F`, `X`)
-    multiplied this step, the chunks of the recurrence a Mamba layer ran,
-    and `hybrid_lm.forward_kept`."""
+    multiplied this step, the chunks of the recurrence a Mamba layer ran, the
+    Mamba layers whose recurrence ran the kernels, and
+    `hybrid_lm.forward_kept`."""
     x, counted = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = layer_norm(x, params["final_norm_w"], params["final_norm_b"],
@@ -349,5 +383,6 @@ def lm_loss(params, ids, cfg: SambaYConfig, *, compute_dtype=None,
         counters = {"attn_key_blocks_window": jnp.sum(of("attn_key_blocks_window")),
                     "attn_key_blocks_full": jnp.sum(of("attn_key_blocks_full")),
                     "scan_chunks": jnp.max(of("scan_chunks")),
+                    "scan_on_kernels": jnp.sum(of("scan_on_kernels")),
                     "attn_forward_kept": forward_kept(counted, remat)}
     return loss, counters

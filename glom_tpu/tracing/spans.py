@@ -212,6 +212,17 @@ LM_KERNELS = (
     "attn_flash_bwd_onesweep",
 )
 
+# SambaY's Mamba-1 recurrence as Pallas kernels, by their `name=`
+# (kernels/selective_scan.py). A call runs inside the mixer's `selective_scan`
+# scope, whose name each begins with; none matches a route's pattern above, and
+# none begins with `attn_flash`, whose calls the attention readers sum.
+SCAN_KERNELS = (
+    # the recurrence a position at a time, the state in VMEM; keeps the state entering each block
+    "selective_scan_fwd",
+    # its backward: a block's states made again in VMEM, then walked in reverse carrying ds
+    "selective_scan_bwd",
+)
+
 # The serving stack's host phases (glom_tpu/serve): one request's path is
 # enqueue -> (gathered into a) batch -> dispatch (the compiled forward) ->
 # fetch (device->host of the valid rows). The batcher aggregates these the

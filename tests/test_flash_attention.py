@@ -649,3 +649,29 @@ def test_the_kernels_compile_for_a_v5e_under_the_aligned_mask_at_the_cells_size(
         text = compiled.as_text()
         assert text.count("tpu_custom_call") >= 2
         assert f"{tq},{t}]" not in text and f"{t},{t + t // 16}]" not in text
+
+
+def test_the_selective_scans_kernels_compile_for_a_v5e_at_the_cells_size(one_chip):
+    """`phi4flash.train`'s Mamba-1 scan (kernels/selective_scan.py; here for
+    this file's one description of the chip): one row of 8,192 positions, 5,120
+    channels, 16 states, x, b and c in bfloat16, at the blocks the shape gets.
+    Mosaic takes the group's sublane rotations, the columns laid across a
+    register's lanes and the 16 MB of a block's states and decays in VMEM;
+    no [T, N, C] array is an operand or a result. Nothing runs."""
+    from glom_tpu.kernels import selective_scan as ss
+
+    t, ch, n = 8192, 5120, 16
+    tb, cb = ss.blocks(t, ch, n)
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def out_and_five_grads(x, dt, a, b, c, cot):
+        y, pull = jax.vjp(lambda *v: ss.selective_scan(*v, time_block=tb, channel_block=cb),
+                          x, dt, a, b, c)
+        return (y,) + pull(cot)
+
+    text = jax.jit(out_and_five_grads).lower(
+        arg(bf, 1, t, ch), arg(f32, 1, t, ch), arg(f32, ch, n), arg(bf, 1, t, n),
+        arg(bf, 1, t, n), arg(bf, 1, t, ch)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert f"{t},{n},{ch}]" not in text and f"{n},{t},{ch}]" not in text

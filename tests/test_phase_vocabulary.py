@@ -26,11 +26,12 @@ from glom_tpu.tracing.spans import (
     LM_KERNELS,
     PHASES,
     SAMBAY_DEVICE_PHASES,
+    SCAN_KERNELS,
 )
 from glom_tpu.utils.config import GlomConfig, TrainConfig
 
 KERNELS = pathlib.Path(__file__).resolve().parent.parent / "glom_tpu" / "kernels"
-N_PALLAS_CALLS = 23
+N_PALLAS_CALLS = 25
 
 
 def _pallas_call_names():
@@ -61,7 +62,8 @@ class TestKernelNames:
 
     def test_names_start_with_a_phase_and_say_their_direction(self):
         for fname, line, name in _pallas_call_names():
-            if fname != "flash_attention.py":   # the language models' are held by name, below
+            # the language models' are held by name, below
+            if fname not in ("flash_attention.py", "selective_scan.py"):
                 assert any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), (
                     fname, line, name)
             assert {"fwd", "bwd"} & set(name.split("_")), (fname, line, name)
@@ -75,6 +77,18 @@ class TestKernelNames:
         assert sorted(sites) == sorted(LM_KERNELS)
         for name in LM_KERNELS:
             assert name.startswith("attn_")
+            assert not any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), name
+
+    def test_the_scans_kernels_are_the_vocabularys_and_no_routes_name(self):
+        """Every site of kernels/selective_scan.py goes by a name of
+        SCAN_KERNELS and every name has a site; each begins with the scope it
+        runs in, `selective_scan`, which is no GLOM phase and not `attn_flash`
+        (whose calls the attention readers sum)."""
+        sites = [name for fname, _, name in _pallas_call_names() if fname == "selective_scan.py"]
+        assert sorted(sites) == sorted(SCAN_KERNELS)
+        assert "selective_scan" in SAMBAY_DEVICE_PHASES
+        for name in SCAN_KERNELS:
+            assert name.startswith("selective_scan_")
             assert not any(name == p or name.startswith(p + "_") for p in DEVICE_PHASES), name
 
     def test_vocabulary_is_host_then_device_without_repeats(self):
