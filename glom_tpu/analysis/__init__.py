@@ -16,9 +16,7 @@ workflow):
                          their lock (runtime companion: tests/test_races)
 
 Pure stdlib — the pass runs where jax is wedged, which is when the
-evidence trail matters most. CI runs it as the `lint` job;
-run_hw_queue.sh runs it as pre-flight step 0 so a hardware window can
-never start on code with a known collective/schema violation.
+evidence trail matters most. CI runs it as the `lint` job.
 """
 
 from glom_tpu.analysis.core import (

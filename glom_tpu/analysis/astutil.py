@@ -2,8 +2,7 @@
 
 Everything here is deliberately SIMPLE static analysis: lexical scope
 chains, dotted-name rendering, statement-order walks. The checkers trade
-soundness for zero-dependency CPU-cheap checks that run in CI and as the
-hardware queue's pre-flight — a miss is acceptable, a crash or a jax
+soundness for zero-dependency CPU-cheap checks that run in CI — a miss is acceptable, a crash or a jax
 import is not (the pass must run on a box where jax is broken, which is
 exactly when you most want to lint the evidence trail). Pure stdlib.
 """

@@ -15,7 +15,7 @@ Two rules, both static mirrors of runtime invariants PR 1-2 established:
      it on CPU.
 
   2. REGISTRATION (wire-accounted modules only — parallel/manual.py and
-     parallel/quantized.py): every wire-moving collective
+     parallel/serve_mesh.py): every wire-moving collective
      (psum/psum_scatter/pmean/all_gather) call site must sit in a function
      that also calls telemetry.counters.record_collective — the static
      mirror of the runtime comm_model_drift reconciliation, which only

@@ -139,7 +139,6 @@ class Context:
     # collective must be registered with telemetry.counters.
     registration_modules: Sequence[str] = (
         "parallel/manual.py",
-        "parallel/quantized.py",
         "parallel/serve_mesh.py",
     )
     # kind registry for the schema-emit checker (filled by the checker on

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: fused per-group MLP (the grouped feed-forward hot op).
 
-Profiling (see bench.py methodology) shows the per-iteration cost of the
-scanned GLOM update is dominated by the two grouped FFWs; XLA materializes
+The per-iteration cost of the scanned GLOM update is dominated by the two
+grouped FFWs (PERF.md, section 5: the FFW kernels' share of a step); XLA materializes
 the [.., G, 4d] hidden activations in HBM between the two matmuls. This
 kernel computes  out = gelu(x @ w1 + b1) @ w2 + b2  per group with the
 hidden tile resident in VMEM — HBM sees only x, the weights, and out.

@@ -579,7 +579,6 @@ def make_manual_zero_train_step(
     sp_strategy: str = "none",
     with_grad_norm: bool = True,
     interpret: bool = False,
-    quantized_reduce: Optional[bool] = None,
 ):
     """The EXPLICIT form of the ZeRO weight update (the GSPMD form lives in
     train.trainer.make_train_step): one shard_map over (data, seq, model)
@@ -602,9 +601,7 @@ def make_manual_zero_train_step(
          'data' back to the replicated params the next forward reads.
 
     Stage 2 moves step 3 inside the microbatch scan so the accumulator
-    only ever holds the owned shard. tcfg.quantized_reduce inserts the
-    EQuARX-style int8 wire emulation on each leaf's LOCAL contribution
-    before it enters the reduction (one quantization hop).
+    only ever holds the owned shard.
 
     Requires model == 1: composing the ownership partition with TP-sharded
     weight shards is routed to the GSPMD form by DistributedTrainer."""
@@ -628,16 +625,9 @@ def make_manual_zero_train_step(
         mesh, cfg, tcfg, sp_strategy=sp_strategy, interpret=interpret
     )
     shard_axes = _zero_shard_axes(zero_pspecs)
-    quantized = (
-        bool(tcfg.quantized_reduce)
-        if quantized_reduce is None
-        else quantized_reduce
-    )
     level = diag.resolve_telemetry_level(tcfg, supports_full=False)
 
-    # The explicit collective pipeline, split so the telemetry hooks land
-    # between its stages: seq pre-reduction -> one quantization wire hop
-    # (with the error probe when it sees the FULL tree) -> per-leaf
+    # The explicit collective pipeline: seq pre-reduction -> per-leaf
     # scatter/pmean. Every site reports its measured per-replica ring wire
     # bytes to telemetry.counters (recorded once, at trace time, inside
     # DistributedTrainer's counting eval_shape — see counters.recording).
@@ -655,11 +645,6 @@ def make_manual_zero_train_step(
 
         return jax.tree_util.tree_map(leaf, grads)
 
-    def quantize_tree(grads):
-        from glom_tpu.parallel.quantized import quantize_dequantize
-
-        return jax.tree_util.tree_map(quantize_dequantize, grads)
-
     def scatter_leaf(g, ax):
         if ax < 0:
             # No dp-divisible axis: the leaf stays replicated via a full
@@ -668,44 +653,24 @@ def make_manual_zero_train_step(
             # counter is what keeps the drift honest.
             return tele_counters.timed_collective(
                 "zero_pmean_fallback", DATA_AXIS, "reduce",
-                tele_counters.ring_reduce_scatter_bytes(
-                    g, dp, quantized=quantized
-                ) * 2,
+                tele_counters.ring_reduce_scatter_bytes(g, dp) * 2,
                 lambda x: lax.pmean(x, DATA_AXIS), g, collective="pmean",
             )
         return tele_counters.timed_collective(
             "zero_psum_scatter", DATA_AXIS, "reduce",
-            tele_counters.ring_reduce_scatter_bytes(g, dp, quantized=quantized),
+            tele_counters.ring_reduce_scatter_bytes(g, dp),
             lambda x: lax.psum_scatter(
                 x, DATA_AXIS, scatter_dimension=ax, tiled=True
             ) / dp,
             g, collective="psum_scatter", dim=ax,
         )
 
-    def reduce_full(grads):
-        """The whole-tree form (non-accumulated / post-accumulation):
-        returns (g_shards, quant_rel_err or None)."""
-        grads = seq_reduce(grads)
-        qerr = None
-        if quantized:
-            dq = quantize_tree(grads)
-            if level != "off":
-                qerr = diag.quantization_error(grads, dq)
-            grads = dq
-        return (
-            jax.tree_util.tree_map(scatter_leaf, grads, shard_axes),
-            qerr,
-        )
-
     def reduce_scatter_tree(grads):
-        """The per-microbatch stage-2 hook: same pipeline, no probe (the
-        hook's contract is tree -> tree; the per-microbatch error never
-        sees the full accumulated gradient, so stamping it would claim a
-        measurement that wasn't made)."""
-        grads = seq_reduce(grads)
-        if quantized:
-            grads = quantize_tree(grads)
-        return jax.tree_util.tree_map(scatter_leaf, grads, shard_axes)
+        """Whole tree (non-accumulated / post-accumulation) or one
+        microbatch of the stage-2 hook: tree -> tree of owned shards."""
+        return jax.tree_util.tree_map(
+            scatter_leaf, seq_reduce(grads), shard_axes
+        )
 
     def shard_zeros(p, ax):
         if ax < 0:
@@ -749,15 +714,7 @@ def make_manual_zero_train_step(
                 sq_scattered = sq_scattered + s
         return jnp.sqrt(lax.psum(sq_scattered, DATA_AXIS) + sq_replicated)
 
-    # The quant-error probe exists only where the hop sees the full
-    # accumulated gradient (reduce_full); the stage-2-with-accum corner
-    # quantizes per microbatch inside the scan and stamps no error.
-    probe_quant = (
-        quantized and level != "off" and not (zero_stage >= 2 and accum > 1)
-    )
-
     def update_body(params, opt_state, img, noise):
-        qerr = None
         if accum > 1:
             # trainer.accumulate_grads on the LOCAL band — the strided
             # grouping applies per shard exactly as it does globally
@@ -791,11 +748,11 @@ def make_manual_zero_train_step(
                 g_shards = grads
             else:
                 with jax.named_scope("grad_reduce"):
-                    g_shards, qerr = reduce_full(grads)
+                    g_shards = reduce_scatter_tree(grads)
         else:
             loss_loc, grads = jax.value_and_grad(local_loss)(params, img, noise)
             with jax.named_scope("grad_reduce"):
-                g_shards, qerr = reduce_full(grads)
+                g_shards = reduce_scatter_tree(grads)
 
         with jax.named_scope("optimizer"):
             p_shards = jax.tree_util.tree_map(slice_shard, params, shard_axes)
@@ -834,8 +791,6 @@ def make_manual_zero_train_step(
                     new_opt = diag.guard_update(nonfinite, new_opt, opt_state)
                     metrics["skipped_nonfinite"] = nonfinite.astype(jnp.int32)
                 metrics["nonfinite_step"] = nonfinite.astype(jnp.int32)
-                if probe_quant:
-                    metrics["quant_rel_err"] = qerr
         return new_params, new_opt, metrics
 
     batch_spec = P(DATA_AXIS)
@@ -847,8 +802,6 @@ def make_manual_zero_train_step(
         metric_keys += ["update_norm", "param_norm", "nonfinite_step"]
         if tcfg.nonfinite_policy == "skip":
             metric_keys.append("skipped_nonfinite")
-        if probe_quant:
-            metric_keys.append("quant_rel_err")
     update_sm = jax.shard_map(
         update_body,
         mesh=mesh,

@@ -49,7 +49,6 @@ from glom_tpu.train.trainer import (
     fit_loop,
     make_train_step,
     pinned_grad_accum,
-    resolve_quantized_reduce,
     resolve_zero_stage,
 )
 from glom_tpu.utils.config import GlomConfig, MeshConfig, TrainConfig
@@ -77,10 +76,9 @@ SP_STRATEGIES = ("none", "ring", "ulysses", "halo", "auto")
 # sides — each instance's resident similarity block is still n^2 * 4
 # (instances stream through VMEM sequentially; total bytes touched grow
 # with b but the per-instance working set that decides spill does not).
-# The committed rows are B=1; bench_sp_crossover.py carries the pod
-# (d=1024, L=12), L=6, and batched (B=8) shapes so every independence
-# claim stays re-measurable. tests/test_parallel.py asserts this
-# predicate against every measured row of the committed table.
+# The committed rows are B=1 and unstamped (they predate the benchmark).
+# tests/test_parallel.py asserts this predicate against every measured row
+# of the committed table.
 _ULYSSES_SIM_BUDGET = 16 * 1024 * 1024
 
 
@@ -372,7 +370,6 @@ class DistributedTrainer:
         # state layout is built — the stage decides the optimizer-state
         # sharding the train state is device_put into.
         self.zero_stage = resolve_zero_stage(tcfg, mesh_cfg.data)
-        self.quantized_reduce = resolve_quantized_reduce(tcfg, mesh_cfg.data)
         if (
             self.zero_stage >= 1
             and self.use_manual
@@ -389,19 +386,6 @@ class DistributedTrainer:
                 stacklevel=2,
             )
             self.zero_stage = 0
-        if self.quantized_reduce and self.use_manual and self.zero_stage == 0:
-            # The plain manual step's DP grad reduction is the shard_map
-            # transpose psum — there is no hook to quantize each local
-            # contribution before it (the manual ZeRO step has one, and
-            # the GSPMD step emulates the receive side). Degrade loudly
-            # rather than stamp an emulation that didn't run.
-            warnings.warn(
-                "quantized_reduce on the manual path requires zero_stage "
-                ">= 1 (the explicit reduce-scatter carries the emulation "
-                "hook); running with exact f32 reduction",
-                stacklevel=2,
-            )
-            self.quantized_reduce = False
 
         # Host-side init, then device_put into the sharded layout. (At true
         # pod scale you would jit the init with out_shardings instead; this
@@ -448,7 +432,6 @@ class DistributedTrainer:
                     opt_pspecs=opt_specs,
                     sp_strategy=sp_strategy,
                     with_grad_norm=with_grad_norm,
-                    quantized_reduce=self.quantized_reduce,
                 )
             elif self.use_manual:
                 fn = make_manual_train_step(
@@ -466,7 +449,6 @@ class DistributedTrainer:
                     with_grad_norm=with_grad_norm,
                     zero_stage=self.zero_stage,
                     zero_shardings=self.zero_shardings,
-                    quantized_reduce=self.quantized_reduce,
                     scan_only=True,
                 )
                 # A GSPMD SP consensus_fn means the backward runs the
@@ -551,7 +533,6 @@ class DistributedTrainer:
         self.collective_sampler = None
         self._static_record = {
             "zero_stage": self.zero_stage,
-            "quantized_reduce": self.quantized_reduce,
             "telemetry_level": self.telemetry_level,
             "collective_timing": self.collective_timing,
             **mem,
@@ -560,7 +541,6 @@ class DistributedTrainer:
                 wire_bytes,
                 self.mesh_cfg.data,
                 self.zero_stage,
-                quantized=self.quantized_reduce,
                 grad_accum=self.grad_accum,
             ),
         }

@@ -187,8 +187,8 @@ class JaxDistributedTransport:
     the TPU coordinator service carries the messages). Construction
     requires jax.distributed.initialize() to have run; the CPU tier-1
     suite never touches this class (DirectoryTransport covers the
-    protocol), and the hardware queue's first multi-process window is
-    where it earns its keep."""
+    protocol), and the first multi-process chip run is where it earns
+    its keep."""
 
     def __init__(self, *, timeout_ms: int = 60_000):
         import jax

@@ -928,24 +928,6 @@ class InferenceEngine:
             out[b] = 0.0 if already else time.perf_counter() - t0
         return out
 
-    def warmup_ragged(
-        self, pages: Optional[Tuple[int, ...]] = None
-    ) -> dict:
-        """Precompile the RAGGED page-count ladder (and, with a pool,
-        the bucket route's PAGED warm signatures ride warmup(warm=...)
-        as usual). Returns {page_count: compile_seconds}."""
-        out = {}
-        for p in pages if pages is not None else self.ragged_page_buckets:
-            sig = self.signature(
-                self._ragged_key(p),
-                warm="pool" if self.pool is not None else "ragged",
-            )
-            already = sig in self._compiled
-            t0 = time.perf_counter()
-            self._compile_ragged(p)
-            out[p] = 0.0 if already else time.perf_counter() - t0
-        return out
-
     # -- dispatch ----------------------------------------------------------
 
     def _serve_shardings(self, warm) -> Tuple:

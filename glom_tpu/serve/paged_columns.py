@@ -21,8 +21,8 @@ indirection — this module keeps warm column state WHERE IT IS USED:
     the columns never visit the host), and the warm dispatch assembles
     `levels0` IN-GRAPH via a page-index take (engine.py's paged
     signatures) — zero host<->device levels0 transfer on the warm path,
-    the number `bench_serve.py --ragged` asserts via the engine's
-    transfer counters;
+    which tests/test_paged_columns.py asserts via the engine's transfer
+    counters;
   * pages are PINNED while a dispatch reads them (`pin`/`unpin`): the
     cache's eviction policy skips pinned blocks, so an in-flight gather
     can never read pages a concurrent eviction re-issued. Engine death

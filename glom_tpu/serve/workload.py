@@ -14,15 +14,14 @@ Three producers, one consumer:
   * `WorkloadRecorder` rides the batcher event tap
     (DynamicBatcher.add_event_tap) and stitches per-request admission
     ("admit"), shed, and terminal ("settle"/"resolve") events into the
-    artifact — recordable from any live server or bench run
-    (`--record-workload`).
+    artifact — recordable from any live server (`--record-workload`).
   * The scenario generators (`gen_diurnal`, `gen_flash_crowd`,
     `gen_rolling_outage`) synthesize the same artifact from a seed —
     pure stdlib (random + math), outcome "offered", so chaos-grade
     elastic scenarios are reproducible from JSONL alone.
   * `replay()` re-offers any artifact with faithful inter-arrival
-    pacing and session structure (`bench_serve.py --replay`,
-    `python -m glom_tpu.serve --replay`). Clock and sleep are
+    pacing and session structure (`python -m glom_tpu.serve --replay`).
+    Clock and sleep are
     injectable, so the tier-1 round-trip test drives a fake clock and
     asserts pacing exactly — no wall-clock flake.
 

@@ -963,7 +963,7 @@ def aggregate_main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument(
         "--strict", action="store_true",
         help="exit nonzero on clock-family violations or broken barrier "
-        "chains (the hw-queue / chaos gating mode)",
+        "chains (the chaos gating mode)",
     )
     args = ap.parse_args(argv)
     hosts = expand_paths(args.paths)

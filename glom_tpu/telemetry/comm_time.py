@@ -106,8 +106,7 @@ def collective_time_records(
     """Stamped schema-v7 "collective_time" rows from raw site samples
     ({site, axis, collective, wire_bytes, wall_ms[, calls, wall_ms_max]}).
     The α-β model is fitted from THESE points unless a pre-fitted one is
-    passed (the hw-queue re-fit step passes last window's model to price
-    drift against it), and every row stamps its own model drift; a final
+    passed (an earlier window's model, to price drift against it), and every row stamps its own model drift; a final
     `comm_time_model` row carries the fit itself plus the aggregate
     drift — the one-number health signal the compare gate tracks."""
     if not samples:

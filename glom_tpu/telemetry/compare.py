@@ -19,9 +19,8 @@ logs the way the trajectory should be read:
     (default 5%, ~2x the chained-timing error bound in utils/timing.py)
     in the regressing direction fails the gate.
 
-Exit code: 1 when any regression beyond threshold survives, else 0 —
-run_hw_queue.sh wires it after the bench steps so a slow row cannot land
-silently. Pure stdlib, like the linter.
+Exit code: 1 when any regression beyond threshold survives, else 0. Pure
+stdlib, like the linter.
 """
 
 from __future__ import annotations

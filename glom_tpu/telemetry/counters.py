@@ -21,10 +21,6 @@ Wire formulas (ring algorithms, matching comm_volume_model's pricing):
   psum_scatter       (k-1)/k   * B
   pmean fallback     2*(k-1)/k * B      (replicated leaf: full allreduce)
   all_gather         (k-1)     * B_sh   B_sh = per-shard bytes
-Quantized-reduce arms price the REDUCE payload at the int8+scales wire
-size (`quantized_wire_bytes`) — the same hypothetical-real-collective
-convention the model uses; the gather stays f32 (EQuARX quantizes the
-reduce, not the weights).
 """
 
 from __future__ import annotations
@@ -363,17 +359,8 @@ def ring_allreduce_bytes(x, k: int) -> int:
     return int(2 * (k - 1) / k * _nbytes(x)) if k > 1 else 0
 
 
-def ring_reduce_scatter_bytes(x, k: int, *, quantized: bool = False) -> int:
-    if k <= 1:
-        return 0
-    nbytes = _nbytes(x)
-    if quantized:
-        from glom_tpu.parallel.quantized import quantized_wire_bytes
-
-        # f32 elements -> int8 payload + per-block scales (the wire the
-        # real quantized collective would carry).
-        nbytes = quantized_wire_bytes(nbytes // 4)
-    return int((k - 1) / k * nbytes)
+def ring_reduce_scatter_bytes(x, k: int) -> int:
+    return int((k - 1) / k * _nbytes(x)) if k > 1 else 0
 
 
 def ring_all_gather_bytes(x_shard, k: int) -> int:

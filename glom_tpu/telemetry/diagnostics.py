@@ -93,25 +93,6 @@ def level_agreement(final: jnp.ndarray) -> jnp.ndarray:
     return jnp.mean(jnp.sum(xhat * mhat, axis=-1), axis=(0, 1))  # [L]
 
 
-def quantization_error(grads, dq_grads) -> jnp.ndarray:
-    """Relative L2 error of one quantize-dequantize wire hop over the whole
-    gradient tree — the in-graph probe that keeps the EQuARX emulation's
-    accuracy cost on the record (PAPERS.md: quantized-collective rollouts
-    need per-step error telemetry before they can be trusted)."""
-    err_sq = sum(
-        jnp.sum(jnp.square(g.astype(jnp.float32) - q.astype(jnp.float32)))
-        for g, q in zip(
-            jax.tree_util.tree_leaves(grads),
-            jax.tree_util.tree_leaves(dq_grads),
-        )
-    )
-    ref_sq = sum(
-        jnp.sum(jnp.square(g.astype(jnp.float32)))
-        for g in jax.tree_util.tree_leaves(grads)
-    )
-    return jnp.sqrt(err_sq) / (jnp.sqrt(ref_sq) + 1e-12)
-
-
 def scalar_taps(
     *,
     loss: jnp.ndarray,

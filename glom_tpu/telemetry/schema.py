@@ -8,12 +8,12 @@ the framework writes — trainer metrics, bench lines, watchdog transitions,
 anomaly events — carries `schema_version` and a `kind`, and validates
 against the field contract below. `python -m glom_tpu.telemetry.schema
 FILE...` lints any log (JSON lines mixed with shell noise are fine; noise
-is skipped, stamped records must validate) — run_hw_queue.sh and CI both
-call it on bench output.
+is skipped, stamped records must validate) — CI calls it on the serve
+and training CLIs' output.
 
 Versioning: SCHEMA_VERSION bumps on any breaking field change; readers
 accept records with version <= theirs. Pure stdlib — importable from
-conftest-less subprocesses and the hw queue without touching jax.
+conftest-less subprocesses without touching jax.
 """
 
 from __future__ import annotations
@@ -74,8 +74,7 @@ from typing import Iterable, List, Optional, Tuple
 # "ragged:<pages>p" | "delta:CxHxW"), and `outcome` ("served" | "shed" |
 # "failed" | "unresolved" | "offered" — the last is a scenario-generated
 # request not yet realized); a workload JSONL artifact replays
-# deterministically (bench_serve.py --replay, python -m glom_tpu.serve
-# --replay). The new "forecast" kind is one scored short-horizon
+# deterministically (python -m glom_tpu.serve --replay). The new "forecast" kind is one scored short-horizon
 # prediction — `metric` names the forecast series ("arrival_rate_rps",
 # "service_rate_rps", "spawn_lead_time"), `horizon_s` how far ahead it
 # looked, and the `forecast_abs_err` KEY must be PRESENT on every
@@ -400,7 +399,7 @@ def assert_valid(rec: dict) -> dict:
 
 def iter_json_lines(lines: Iterable[str]) -> Iterable[Tuple[int, dict]]:
     """(lineno, record) for every line that parses as a JSON object —
-    shell noise, timestamps, and tracebacks interleaved in hw-queue logs
+    shell noise, timestamps, and tracebacks interleaved in a run's log
     are skipped, not errors."""
     for i, line in enumerate(lines, 1):
         line = line.strip()
@@ -451,8 +450,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument(
         "--allow-unstamped", action="store_true",
         help="skip records without schema_version instead of failing them; "
-        "also tolerates files with no JSON records at all (the hw-queue "
-        "sweep over mixed shell logs)",
+        "also tolerates files with no JSON records at all (a sweep "
+        "over mixed shell logs)",
     )
     args = ap.parse_args(argv)
     rc = 0

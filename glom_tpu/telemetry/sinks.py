@@ -15,8 +15,8 @@ Two host-side pieces that complete the telemetry loop:
 
   * emit() — the benches' print(json.dumps(...)) replacement: stamps
     schema_version/kind and the current watchdog backend state on the
-    record, so driver-parsed bench lines, trainer JSONL, and hw-queue rows
-    are one schema (`python -m glom_tpu.telemetry.schema` lints them all).
+    record, so driver-parsed bench lines and trainer JSONL are one
+    schema (`python -m glom_tpu.telemetry.schema` lints them all).
 
   * bench_bootstrap() — the shared gate every bench entrypoint runs
     first: place the compile cache, probe the backend through the

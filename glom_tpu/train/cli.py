@@ -54,11 +54,6 @@ def build_parser() -> argparse.ArgumentParser:
         "(docs/PARALLELISM.md, ZeRO section)",
     )
     p.add_argument(
-        "--quantized-reduce", action="store_true",
-        help="EXPERIMENTAL int8 block-scaled quantized-reduce emulation "
-        "(EQuARX-style; changes gradient numerics ~1e-2 rel)",
-    )
-    p.add_argument(
         "--telemetry-level", choices=["off", "scalars", "full"], default=None,
         help="in-graph diagnostics depth (docs/OBSERVABILITY.md): scalars "
         "= grad/update/param norms + NaN/Inf guard inside the jitted step; "
@@ -211,8 +206,6 @@ def main(argv=None) -> int:
         overrides["grad_accum"] = args.grad_accum
     if args.zero_stage is not None:
         overrides["zero_stage"] = args.zero_stage
-    if args.quantized_reduce:
-        overrides["quantized_reduce"] = True
     if args.telemetry_level is not None:
         overrides["telemetry_level"] = args.telemetry_level
     if args.nonfinite_policy is not None:

@@ -250,16 +250,6 @@ def resolve_zero_stage(tcfg: TrainConfig, dp: int) -> int:
     return tcfg.zero_stage
 
 
-def resolve_quantized_reduce(tcfg: TrainConfig, dp: int) -> bool:
-    """Effective quantized-reduce flag — same single-source discipline as
-    resolve_zero_stage: dp == 1 has no cross-replica reduction to emulate
-    a wire hop on, so the flag resolves OFF (quantizing there would
-    degrade gradients ~1e-2 rel for nothing while the comm counters
-    correctly read zero). The resolved value is what the trainers apply
-    AND stamp, so a record can never claim an emulation that didn't run."""
-    return bool(tcfg.quantized_reduce) and dp > 1
-
-
 class ZeroShardings(NamedTuple):
     """The two NamedSharding trees the GSPMD ZeRO step constrains with:
     `grads` (param-shaped, 'data'-sharded on each leaf's zero_shard_axis —
@@ -369,7 +359,6 @@ def make_train_step(
     with_grad_norm: bool = True,
     zero_stage: int = 0,
     zero_shardings: Optional[ZeroShardings] = None,
-    quantized_reduce: Optional[bool] = None,
     scan_only: bool = False,
 ) -> Callable[[TrainState, jnp.ndarray, jax.Array], Tuple[TrainState, dict]]:
     """Build the pure train step. Noise is generated ON DEVICE from the rng
@@ -390,18 +379,6 @@ def make_train_step(
     additionally pushes the constraint inside the microbatch accumulation
     so the grad buffer itself lives sharded.
 
-    quantized_reduce (None -> resolve from tcfg; trainers pass the
-    resolve_quantized_reduce output) inserts the EQuARX-style int8
-    wire-hop emulation. NOTE the GSPMD asymmetry vs the manual step: in
-    SPMD tracing there is no per-replica gradient-contribution tensor
-    (the compiler inserts the cross-replica reduction wherever the
-    partitioner places it), so the hop here applies to the REDUCED
-    gradient — the receive side of the wire — whereas the manual region
-    quantizes each replica's local contribution before its explicit
-    psum_scatter (the more faithful send-side form). Both are one
-    quantization hop; comm_volume_model prices the hypothetical real
-    quantized collective, not the emulation's op placement.
-
     scan_only=True (the GSPMD DistributedTrainer build) keeps both the
     fused-loop dispatch AND the auto grad-accum off this step — the Pallas
     whole-loop custom_vjp is illegal on GSPMD-sharded arrays.
@@ -410,16 +387,11 @@ def make_train_step(
     (telemetry/diagnostics.py): grad/update/param norms and the NaN/Inf
     guard on EVERY variant including the fast one (a guard that only runs
     on logging steps misses 9 of every 10 anomalies), plus per-level
-    consensus agreement and the quantization-error probe at "full"."""
+    consensus agreement at "full"."""
     objective = objective_for(
         cfg, tcfg, consensus_fn=consensus_fn, scan_only=scan_only
     )
     grad_accum, vjp_path = objective.grad_accum, objective.vjp_path
-    quantized = (
-        bool(tcfg.quantized_reduce)
-        if quantized_reduce is None
-        else quantized_reduce
-    )
     level = diag.resolve_telemetry_level(tcfg)
 
     def train_step(state: TrainState, batch: jnp.ndarray, rng: jax.Array):
@@ -450,18 +422,6 @@ def make_train_step(
         if objective.has_aux:
             loss, aux = loss
         metrics = {}
-        if quantized:
-            from glom_tpu.parallel.quantized import quantize_dequantize
-
-            with jax.named_scope("grad_reduce"):
-                dq = jax.tree_util.tree_map(quantize_dequantize, grads)
-            if level != "off":
-                # EQuARX wire-hop accuracy probe: what one quantized ride
-                # cost THIS step's gradient, on the record next to the
-                # loss it perturbs.
-                with jax.named_scope("step_metrics"):
-                    metrics["quant_rel_err"] = diag.quantization_error(grads, dq)
-            grads = dq
         with jax.named_scope("optimizer"):
             if zero_stage >= 1 and zero_shardings is not None:
                 # Reduce-scatter: the cross-replica grad reduction lands
@@ -753,16 +713,13 @@ class Trainer:
         self.rng, init_key = jax.random.split(key)
         self.state, self.optimizer = create_train_state(init_key, cfg, tcfg, optimizer)
         # Single device: dp == 1, so ZeRO resolves to 0 (validating the
-        # configured value), quantized_reduce resolves OFF (no wire to
-        # emulate a hop on), and the live-bytes model reports the fully
+        # configured value), and the live-bytes model reports the fully
         # replicated layout with zero collective traffic — the baseline
         # row the distributed records are compared against.
         self.zero_stage = resolve_zero_stage(tcfg, 1)
-        self.quantized_reduce = resolve_quantized_reduce(tcfg, 1)
         self.telemetry_level = diag.resolve_telemetry_level(tcfg)
         step_fn = make_train_step(
             cfg, tcfg, self.optimizer, consensus_fn=consensus_fn,
-            quantized_reduce=self.quantized_reduce,
         )
         self.vjp_path = step_fn.vjp_path
         self.grad_accum = step_fn.grad_accum
@@ -774,7 +731,6 @@ class Trainer:
         )
         self._static_record = {
             "zero_stage": self.zero_stage,
-            "quantized_reduce": self.quantized_reduce,
             "telemetry_level": self.telemetry_level,
             **mem,
             **comm_volume_model(
@@ -791,7 +747,6 @@ class Trainer:
         fast_fn = make_train_step(
             cfg, tcfg, self.optimizer,
             consensus_fn=consensus_fn, with_grad_norm=False,
-            quantized_reduce=self.quantized_reduce,
         )
         self._step_fast = jax.jit(fast_fn, donate_argnums=(0,))
         self.metrics_writer = metrics_writer

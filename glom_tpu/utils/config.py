@@ -705,18 +705,16 @@ class ServeConfig:
     # and every downstream serve record (dispatch, continuation, shed,
     # failover, retry, cache, resolve) carries the context, so
     # `python -m glom_tpu.telemetry trace` reconstructs the causal tree.
-    # Default ON — the measured overhead bar is <2% at full stamping
-    # (`bench_serve.py --trace-ab`). False stamps the context keys as
-    # null (explicitly untraced — the schema still lints).
+    # Default ON. False stamps the context keys as null (explicitly
+    # untraced — the schema still lints).
     trace_requests: bool = True
     # Serve latency decomposition (docs/OBSERVABILITY.md, "Capacity
     # observatory"): every dispatch record splits latency_ms into
     # queue_wait / pack / h2d / device / resolve phase fields that sum to
     # it BIT-EXACTLY (and accumulate into the per-request resolve leaf),
     # so `telemetry trace` shows where each request's time went across
-    # hops. Default ON — the bar is <2% (`bench_serve.py --phase-ab`);
-    # False stamps the phase keys as null and reverts latency_ms to the
-    # bare engine dispatch wall (the pre-v7 reading).
+    # hops. Default ON; False stamps the phase keys as null and reverts
+    # latency_ms to the bare engine dispatch wall (the pre-v7 reading).
     phase_split: bool = True
     # Per-collective wall-time on the serve mesh (telemetry/comm_time.py,
     # resolved by counters.resolve_collective_timing — the
@@ -1091,13 +1089,6 @@ class TrainConfig:
     # Resolution (dp==1 -> 0) is resolve_zero_stage in train/trainer.py —
     # the single source both trainers stamp into every metrics record.
     zero_stage: int = 0
-    # EQuARX-style int8 block-scaled quantized all-reduce (arXiv:2506.17615)
-    # — EXPERIMENTAL, and on this codebase an EMULATION: gradients are
-    # block-quantized to int8 and dequantized before the reduction
-    # collective, modeling one wire-quantization hop (the real thing
-    # quantizes inside XLA's collective; that needs a compiler hook).
-    # Changes numerics (~1e-2 relative on gradients); never on by default.
-    quantized_reduce: bool = False
     # Telemetry depth (glom_tpu/telemetry, docs/OBSERVABILITY.md):
     #   "off"     — no in-graph diagnostics beyond the loss (the sustained-
     #               throughput default; static analytics still stamped);

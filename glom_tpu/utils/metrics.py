@@ -162,7 +162,6 @@ def comm_volume_model(
     dp: int,
     zero_stage: int,
     *,
-    quantized: bool = False,
     grad_accum: int = 1,
 ) -> dict:
     """Per-replica per-step collective wire bytes of the gradient/update
@@ -173,13 +172,7 @@ def comm_volume_model(
       stage 1 — reduce-scatter G + all-gather P: (dp-1)/dp * (G + P)
       stage 2 — the reduce-scatter happens once PER MICROBATCH (that is
                 what keeps the accumulator sharded): (dp-1)/dp *
-                (accum * G + P)
-
-    Quantized reduce carries the gradient payload as int8 + block scales
-    (~G/4 + G/512); the param all-gather stays f32 (EQuARX quantizes the
-    reduce, not the weights)."""
-    from glom_tpu.parallel.quantized import DEFAULT_BLOCK
-
+                (accum * G + P)"""
     if dp <= 1:
         return {
             "comm_reduce_bytes_per_step": 0,
@@ -187,16 +180,12 @@ def comm_volume_model(
             "comm_bytes_per_step": 0,
         }
     frac = (dp - 1) / dp
-    wire_grad = grad_bytes
-    if quantized:
-        elems = grad_bytes // 4
-        wire_grad = elems + (-(-elems // DEFAULT_BLOCK)) * 4
     if zero_stage == 0:
-        reduce_bytes = int(2 * frac * wire_grad)
+        reduce_bytes = int(2 * frac * grad_bytes)
         gather_bytes = 0
     else:
         n_scatters = grad_accum if zero_stage >= 2 else 1
-        reduce_bytes = int(frac * wire_grad * n_scatters)
+        reduce_bytes = int(frac * grad_bytes * n_scatters)
         gather_bytes = int(frac * param_bytes)
     return {
         "comm_reduce_bytes_per_step": reduce_bytes,

@@ -199,10 +199,9 @@ _register(
             # tokens x 6 levels x 512 dim x bf16 = 384 KiB/page (~682
             # full-resolution streams at 4 pages each), warm frames
             # assembled in-graph with ZERO host->device levels0 bytes.
-            # Ragged admission stays a workload opt-in (bench_serve.py
-            # --ragged / --banded-ab; it composes with the continuation
-            # queue on the auto route — stragglers re-enter ragged with
-            # their remaining budget). When opted in, the BANDED
+            # Ragged admission stays a workload opt-in (it composes with
+            # the continuation queue on the auto route — stragglers
+            # re-enter ragged with their remaining budget). When opted in, the BANDED
             # consensus route prices the duplicated k/v working set per
             # PAGE instead of per token (64x smaller here), which is
             # what lets a 16-row ragged signature fit one chip at all;

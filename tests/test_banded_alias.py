@@ -85,9 +85,12 @@ class TestBandedParityMatrix:
             rng.normal(size=(T, CFG.levels, CFG.dim)).astype(np.float32)
         )
 
-    def test_attention_bitwise_per_row_span(self):
-        """One attention application: banded == windowed bitwise on
-        every row span, window == the largest row's page band."""
+    def test_attention_matches_per_row_span(self):
+        """One attention application: banded == windowed on every row
+        span, window == the largest row's page band, to float32 rounding:
+        two programs whose softmax sums run in different orders on this
+        JAX (largest difference seen 1.8e-7 at values up to 2.3; 59% of
+        elements differ), so not bit for bit."""
         row_start, row_len, T, starts = _layout(self.COUNTS)
         lv = self._levels(T)
         window = pages_for_tokens(max(self.COUNTS), PT) * PT
@@ -102,12 +105,15 @@ class TestBandedParityMatrix:
             _spans(win, self.COUNTS, starts),
             _spans(band, self.COUNTS, starts),
         ):
-            np.testing.assert_array_equal(a, b)
+            np.testing.assert_allclose(a, b, rtol=0, atol=7e-7)
 
-    def test_engine_threshold0_bitwise_windowed_vs_banded(self):
+    def test_engine_threshold0_windowed_matches_banded(self):
         """Cross-route at the engine: a threshold-0 mixed dispatch lands
-        on bitwise the same row spans under both attentions, at the same
-        iteration count, for every iteration budget."""
+        on the same row spans under both attentions, at the same
+        iteration count, for every iteration budget — to float32
+        rounding (the two attentions' reduction orders differ on this
+        JAX: largest difference seen 1.8e-7 over budgets 1, 3, 6, up to
+        65% of elements)."""
         params = init_glom(jax.random.PRNGKey(0), CFG)
         ew = InferenceEngine(CFG, SCFG, params=params, name="w")
         eb = InferenceEngine(
@@ -132,7 +138,7 @@ class TestBandedParityMatrix:
                 _spans(rw.levels, counts, starts),
                 _spans(rb.levels, counts, starts),
             ):
-                np.testing.assert_array_equal(a, b)
+                np.testing.assert_allclose(a, b, rtol=0, atol=7e-7)
 
     def test_banded_full_res_row_bitwise_equals_dense_cold(self):
         """The banded route keeps the windowed route's cross-route lock:

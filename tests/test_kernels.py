@@ -981,8 +981,8 @@ class TestFusedLoop:
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     # Env-gated special mode, ~25-35s of interpret-mode backward: slow-
-    # marked for the tier-1 budget; CI runs it unfiltered and the hw
-    # queue's tpu_validate covers the real-chip variant.
+    # marked for the tier-1 budget; CI runs it unfiltered and
+    # tpu_validate.py covers the real-chip variant.
     @pytest.mark.slow
     def test_unchained_backward_matches(self, monkeypatch):
         """The unchained backward variant (pod per-TP-rank d=1024-class

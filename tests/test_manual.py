@@ -313,7 +313,7 @@ class TestShardFusedLoop:
     # The heaviest single test in the suite (interpret-mode whole-loop VJP
     # under shard_map, ~60-75s): both variants are slow-marked for the
     # tier-1 budget — CI's unfiltered run and tpu_validate keep the
-    # manual fused-loop parity gated on every push / hardware window.
+    # manual fused-loop parity gated on every push / chip run.
     @pytest.mark.slow
     @pytest.mark.parametrize(
         "remat", [False, pytest.param(True, marks=pytest.mark.slow)]

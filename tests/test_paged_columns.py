@@ -222,18 +222,23 @@ class TestRaggedParity:
             m[16:20], np.asarray(lone_b.levels)[0:4]
         )
 
-    def test_full_res_ragged_bitwise_equals_dense_cold(self, engine):
+    def test_full_res_ragged_matches_dense_cold(self, engine):
         """Cross-route: a full-resolution ragged row reproduces the
-        dense engine's cold dispatch bitwise (same embed, same update
-        ops, W == n so even the softmax axis length matches)."""
+        dense engine's cold dispatch (same embed, same update ops, W == n
+        so even the softmax axis length matches) to float32 rounding, not
+        bit for bit: they are two compiled programs, and on this JAX XLA
+        sums their reductions in different orders (largest difference
+        seen 1.9e-6 = one ulp at the values' 23; 64% of elements differ).
+        A wrong route would miss by orders more."""
         rng = np.random.default_rng(8)
         img = _imgs(rng)[0]
         dense = engine.infer(img[None], n_valid=1)
         ragged = engine.infer_ragged(
             *_flat([_patchify_host(img, 4)], pages_sig=4)
         )
-        np.testing.assert_array_equal(
-            np.asarray(dense.levels[0]), np.asarray(ragged.levels)[0:16]
+        np.testing.assert_allclose(
+            np.asarray(dense.levels[0]), np.asarray(ragged.levels)[0:16],
+            rtol=0, atol=7.6e-6,
         )
         assert ragged.levels0_h2d_bytes == 0
 

@@ -5,6 +5,7 @@ per device."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -135,6 +136,24 @@ class TestNoHiddenPlatform:
             assert proc.returncode != 0, script
             assert "needs a TPU" in proc.stderr, (script, proc.stderr[-500:])
             assert '"ok"' not in proc.stdout, script
+
+    def test_scripts_the_documents_name_exist(self):
+        """Every `name.py` / `name.sh` that README.md or docs/*.md names
+        without a directory is a file at the root of the tree, or the
+        bare name of a module under glom_tpu/, benchmark/ or tests/."""
+        modules = {
+            p.name
+            for d in ("glom_tpu", "benchmark", "tests")
+            for p in (REPO / d).rglob("*.py")
+        }
+        bare = re.compile(r"(?<![\w/.*-])[A-Za-z_]\w*\.(?:py|sh)\b")
+        missing = sorted(
+            f"{doc.relative_to(REPO)}: {name}"
+            for doc in [REPO / "README.md", *sorted((REPO / "docs").glob("*.md"))]
+            for name in set(bare.findall(doc.read_text()))
+            if name not in modules and not (REPO / name).is_file()
+        )
+        assert not missing, missing
 
 
 class TestInProcessProbe:

@@ -481,36 +481,6 @@ class TestCollectiveCounters:
         assert "comm_bytes_per_step" in r
         assert "comm_measured_bytes_per_step" not in r
 
-    def test_quant_probe_stamped_on_quantized_step(self):
-        """The manual ZeRO step with quantized_reduce must stamp the
-        in-graph quantization-error probe, and its value must respect the
-        block-scaling bound's order of magnitude."""
-        from glom_tpu.parallel import DistributedTrainer
-
-        cfg = GlomConfig(dim=16, levels=3, image_size=8, patch_size=4)
-        tcfg = TrainConfig(
-            batch_size=8, learning_rate=1e-3, use_pallas=True, zero_stage=1,
-            quantized_reduce=True, telemetry_level="scalars",
-        )
-        tr = DistributedTrainer(cfg, tcfg, MeshConfig(data=8))
-        img = np.random.default_rng(0).normal(size=(8, 3, 8, 8)).astype(np.float32)
-        m = tr.step(img)
-        assert "quant_rel_err" in m
-        assert 0.0 < float(m["quant_rel_err"]) < 0.05
-
-    def test_quant_probe_on_gspmd_step(self):
-        from glom_tpu.parallel import DistributedTrainer
-
-        cfg = GlomConfig(dim=16, levels=3, image_size=8, patch_size=4)
-        tcfg = TrainConfig(
-            batch_size=8, learning_rate=1e-3, quantized_reduce=True,
-            telemetry_level="scalars",
-        )
-        tr = DistributedTrainer(cfg, tcfg, MeshConfig(data=8))
-        img = np.random.default_rng(0).normal(size=(8, 3, 8, 8)).astype(np.float32)
-        m = tr.step(img)
-        assert 0.0 < float(m["quant_rel_err"]) < 0.05
-
 
 class TestWatchdog:
     def _wd(self, probes, **kw):
@@ -769,8 +739,8 @@ class TestOverheadBudget:
     def test_scalars_overhead_under_budget(self):
         """CPU smoke A/B: telemetry_level=scalars must stay within the 2%
         per-step budget (generous 10% runtime guard against shared-runner
-        noise; the 2% bar itself is enforced on real hardware by the
-        hw-queue's telemetry_ab step — this keeps gross regressions out).
+        noise; the 2% bar itself is not measured on the chip by any
+        cell — this keeps gross regressions out).
         Arms INTERLEAVE per repeat, min per arm — sequential arms on a
         multi-tenant runner confound the A/B with clock drift (measured
         +24% sequential vs +1.3% interleaved for the same pair)."""
