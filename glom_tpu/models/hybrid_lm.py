@@ -534,7 +534,7 @@ def layer(kind: str, p, x, cfg: HybridLMConfig, dtype):
 
 
 def run_stack(params: Params, ids, layers, *, compute_dtype=None, remat: bool = True,
-              side=None):
+              side=None, passes: int = 1, close=None):
     """The stack every language-model family here shares: the embedding's
     rows of `ids` [B, T], then the layers in order, each recomputed whole in
     the backward pass under `remat`. `layers` holds one function a layer,
@@ -542,6 +542,19 @@ def run_stack(params: Params, ids, layers, *, compute_dtype=None, remat: bool = 
     layers after it beside the residual stream. A recomputed layer takes it
     as an input, so its gradient flows back to the layer that made it.
     Returns (the last layer's output [B, T, d], every layer's aux).
+
+    A looped model (`models/ouro.py`) gives `close(x) -> x`, which ends a
+    pass, and runs the same layers `passes` times as a `lax.scan` over one
+    body of the layers and `close`: the next pass starts from what `close`
+    returns, and the first value returned is then the `passes` closed states
+    [passes, B, T, d] instead of the last layer's output. Every application
+    reads the SAME entry of `params["layers"]` (a looped weight is one leaf),
+    is recomputed on its own, and leaves an aux of its own (`passes` x layers
+    in all, in the order run; scalars). The scan is what adds a looped
+    weight's gradient up in place, pass by pass: unrolled, the compiler keeps
+    every pass's float32 partial until one fusion adds them (Ouro's step for
+    a v5e: 8.63 GB of temporaries unrolled, four partials of 1.64 GB among
+    them; PERF.md section 6, PR 46), and compiles the layers `passes` times.
 
     A recomputed layer keeps its inputs and what carries one of KEPT_NAMES.
     Where its attention ran the kernels, the two arrays `flash_attention`
@@ -560,11 +573,27 @@ def run_stack(params: Params, ids, layers, *, compute_dtype=None, remat: bool = 
     with jax.named_scope("embed"):
         x = _cast(params["embed"][ids], compute_dtype)
     keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
-    aux = []
-    for f, p in zip(layers, params["layers"]):
-        x, side, a = (jax.checkpoint(f, policy=keep) if remat else f)(p, x, side)
-        aux.append(a)
-    return x, aux
+
+    def one_pass(x, side):
+        aux = []
+        for f, p in zip(layers, params["layers"]):
+            x, side, a = (jax.checkpoint(f, policy=keep) if remat else f)(p, x, side)
+            aux.append(a)
+        return x, side, aux
+
+    if close is None:
+        x, _, aux = one_pass(x, side)
+        return x, aux
+
+    def looped(carry, _):
+        x, side, aux = one_pass(*carry)
+        # recomputed like a layer: else the scan keeps `close`'s float32 intermediates, a pass each
+        x = (jax.checkpoint(close) if remat else close)(x)
+        return (x, side), (x, aux)
+
+    _, (closed, aux) = jax.lax.scan(looped, (x, side), None, length=passes)
+    at = lambda t: lambda a: jax.tree_util.tree_map(lambda v: v[t], a)
+    return closed, [a for t in range(passes) for a in map(at(t), aux)]
 
 
 def forward_kept(counted, remat: bool):
@@ -607,7 +636,7 @@ def _block_nll(h, head, targets):
     return lse - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
 
 
-def next_token_loss(h, head, ids, pred_heads: int = 1):
+def next_token_loss(h, head, ids, pred_heads: int = 1, weights=None):
     """Next-token cross-entropy of the normed hidden states h [B * T, d]
     under the head [d, V] (the vocabulary rows held here): float32 logits, a
     block of LOSS_ROW_BLOCK rows at a time, each block recomputed in the
@@ -615,7 +644,17 @@ def next_token_loss(h, head, ids, pred_heads: int = 1):
     token. With `pred_heads` K > 1 the head is [d, K V], K heads side by
     side, head m of position t predicting the token at t + 1 + m: the mean
     over the heads and the positions that have such a token, every head
-    weighing the same."""
+    weighing the same.
+
+    With `weights` [P * B * T] float32 (one head), h [P * B * T, d] holds P states of
+    every position, one set after another (a looped model's passes), and each
+    state's cross-entropy is multiplied by its weight before the sum; the
+    divisor stays B * (T - 1), so unit weights on one set are the plain mean.
+    The weights are differentiated like h (an exit distribution learns through
+    them). The blocks are then the trips of one `lax.scan`, which adds the
+    head's gradient up in place: the unrolled loop's block-sized partials (a
+    head's size each) all wait for one fusion to add them, which four or
+    eight blocks can afford and a looped model's P times as many cannot."""
     bsz, t = ids.shape
     shifted = [jnp.roll(ids, -(1 + m), axis=1) for m in range(pred_heads)]
     if pred_heads == 1:
@@ -624,12 +663,34 @@ def next_token_loss(h, head, ids, pred_heads: int = 1):
     else:
         targets = jnp.stack(shifted, axis=-1).reshape(-1, pred_heads)
         has_next = jnp.tile(jnp.arange(t)[:, None] + jnp.arange(pred_heads) < t - 1, (bsz, 1))
+    count = bsz * sum(t - 1 - m for m in range(pred_heads))
+    if weights is not None:
+        assert pred_heads == 1, "weights are one head's"
+        return _weighed_blocks(h, head, targets, has_next, weights) / count
     total = jnp.zeros((), jnp.float32)
     for first in range(0, bsz * t, LOSS_ROW_BLOCK):
         rows = slice(first, min(bsz * t, first + LOSS_ROW_BLOCK))
         nll = jax.checkpoint(_block_nll)(h[rows], head, targets[rows])
         total = total + jnp.sum(jnp.where(has_next[rows], nll, 0.0))
-    return total / (bsz * sum(t - 1 - m for m in range(pred_heads)))
+    return total / count
+
+
+def _weighed_blocks(h, head, targets, has_next, weights):
+    """sum of weights * cross-entropy over the rows of h [P * N, d] whose
+    position has a next token (`targets`, `has_next` [N]: one head), a block
+    of LOSS_ROW_BLOCK rows a trip of one scan; rows of padding weigh zero."""
+    sets = h.shape[0] // targets.shape[0]
+    weights = jnp.where(jnp.tile(has_next, sets), weights, 0.0)
+    pad = -h.shape[0] % LOSS_ROW_BLOCK
+    blocks = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+        -1, LOSS_ROW_BLOCK, *a.shape[1:])
+
+    def add_block(total, block):
+        h_rows, t_rows, w_rows = block
+        return total + jnp.sum(jax.checkpoint(_block_nll)(h_rows, head, t_rows) * w_rows), None
+
+    return jax.lax.scan(add_block, jnp.zeros((), jnp.float32),
+                        (blocks(h), blocks(jnp.tile(targets, sets)), blocks(weights)))[0]
 
 
 def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,

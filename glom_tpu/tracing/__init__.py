@@ -31,6 +31,7 @@ _EXPORTS = {
     "SAMBAY_DEVICE_PHASES": "spans",
     "KIMI_DEVICE_PHASES": "spans",
     "EVABYTE_DEVICE_PHASES": "spans",
+    "OURO_DEVICE_PHASES": "spans",
     "SpanAggregator": "spans",
     "span": "spans",
     "spanned": "spans",

@@ -198,10 +198,33 @@ EVABYTE_DEVICE_PHASES = (
     "lm_head_loss",
 )
 
+# The device scopes of the Ouro looped language model's step (models/ouro.py).
+# `embed`, `full_attention` (here the causal scores alone: the kernels' calls
+# or the XLA loop), `dense_mlp` (the layer's third norm and its SwiGLU) and
+# `lm_head_loss` (the head's product and the weighed cross-entropy of every
+# pass's state) mean what they mean above. A layer's first half is `ouro_in`
+# (first norm, the three projections, the rotation of q and k) and `ouro_out`
+# (the out-projection); `sandwich_norm` is the two norms on the branches' outputs
+# with their adds into the stream; `ut_close` the norm that closes a pass and
+# the loop's own ops (the copies that stack a pass's kept arrays for the
+# backward pass); `exit_gate` the gate's sigmoids, the exit distribution and
+# its entropy.
+OURO_DEVICE_PHASES = (
+    "embed",
+    "ouro_in",
+    "full_attention",
+    "ouro_out",
+    "sandwich_norm",
+    "dense_mlp",
+    "ut_close",
+    "exit_gate",
+    "lm_head_loss",
+)
+
 # The language models' Pallas kernels, by their `name=`
 # (kernels/flash_attention.py). They open no scope of their own: a call runs
 # inside the model's attention scope (`attention`; `window_attention`,
-# `full_attention`, `cross_attention`; Laguna's two; `latent_attention`; `eva_attention`), and its device time
+# `full_attention`, `cross_attention`; Laguna's two; `latent_attention`; `eva_attention`; Ouro's `full_attention`), and its device time
 # belongs to that scope. All begin with `attn_`; none matches `loop_*`, `ffw_*`,
 # `consensus_*` or `ragged-dot*`, the names by which the benchmark tells the
 # routes apart.

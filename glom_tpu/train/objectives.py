@@ -34,6 +34,7 @@ from glom_tpu.utils.config import (
     EvaByteConfig,
     KimiLinearConfig,
     LagunaConfig,
+    OuroConfig,
     SambaYConfig,
 )
 
@@ -85,6 +86,7 @@ _LM_FAMILIES = {
     LagunaConfig: ("glom_tpu.models.laguna", "init_laguna"),
     KimiLinearConfig: ("glom_tpu.models.kimi_linear", "init_kimi_linear"),
     EvaByteConfig: ("glom_tpu.models.evabyte", "init_evabyte"),
+    OuroConfig: ("glom_tpu.models.ouro", "init_ouro"),
 }
 
 

@@ -477,6 +477,53 @@ class EvaByteConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class OuroConfig:
+    """An Ouro looped language model (models/ouro.py, `model_type: ouro`; "Scaling
+    Latent Reasoning via Looped Language Models"): ONE stack of layers run
+    `total_ut_steps` times over the same weights. A layer is a sandwich of four
+    RMSNorms round causal attention (every dimension of a head rotated at
+    `rope_theta`, no bias, no window) and a dense SwiGLU of `intermediate_size`:
+    a norm before each branch and one on its output before the add. The final
+    norm closes every pass: its output is what the untied head reads and what
+    the next pass starts from. An exit gate (a sigmoid of one learned direction
+    of that output) gives each position a distribution over the passes, and the
+    loss is the passes' cross-entropies weighed by it less `exit_entropy_beta`
+    times its entropy. Field names are the published config.json's; the
+    defaults are Ouro-2.6B's.
+
+    `num_hidden_layers` layers from `layer_offset` on are what THIS chip holds
+    (a pipeline stage round which a row passes `total_ut_steps` times); the
+    heads and the vocabulary are whole. Widths are never a share, and the
+    passes are never cut."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 5632
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 49152
+    layer_offset: int = 0
+    num_hidden_layers: int = 48
+    num_hidden_layers_total: int = 48
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    head_dim: int = 128
+    rope_theta: float = 1000000.0
+    # the loop: passes over the held layers, and the entropy term's weight in the loss
+    total_ut_steps: int = 4
+    exit_entropy_beta: float = 0.05
+    # the training sequence: tokens of one packed row of the batch
+    seq_len: int = 4096
+
+    def __post_init__(self):
+        n, first = self.num_hidden_layers_total, self.layer_offset
+        if first < 0 or self.num_hidden_layers < 1 or first + self.num_hidden_layers > n:
+            raise ValueError(f"layers {first}..{first + self.num_hidden_layers} of {n}")
+        if self.num_attention_heads % self.num_key_value_heads or self.head_dim % 2:
+            raise ValueError("query heads share KV heads evenly and a head's dimensions pair up")
+        if self.total_ut_steps < 1:
+            raise ValueError("the stack runs at least once")
+
+
+@dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Parallelism layout. Axis sizes of 1 disable an axis.
 

@@ -18,6 +18,7 @@ from glom_tpu.utils.config import (
     KimiLinearConfig,
     LagunaConfig,
     MeshConfig,
+    OuroConfig,
     SambaYConfig,
     ServeConfig,
     TrainConfig,
@@ -32,7 +33,7 @@ class Preset:
     # The family the preset trains: its type picks the objective
     # (train/trainer.objective_for).
     model: Union[GlomConfig, HybridLMConfig, SambaYConfig, LagunaConfig, KimiLinearConfig,
-                 EvaByteConfig]
+                 EvaByteConfig, OuroConfig]
     train: TrainConfig
     mesh: MeshConfig
     sp_strategy: str = "none"  # none | ring | ulysses | halo | auto
@@ -73,7 +74,7 @@ class Preset:
 
 PRESETS: Dict[str, Preset] = {}
 # The presets of the language-model families (a HybridLMConfig, a
-# SambaYConfig, a LagunaConfig, a KimiLinearConfig or an EvaByteConfig model), in a table of
+# SambaYConfig, a LagunaConfig, a KimiLinearConfig, an EvaByteConfig or an OuroConfig model), in a table of
 # their own: PRESETS stays GLOM's driver configurations, which is what the
 # sharded trainers and the serving stack iterate; `get_preset` finds both.
 LM_PRESETS: Dict[str, Preset] = {}
@@ -517,6 +518,43 @@ _register(
             hidden_size=64, intermediate_size=160, vocab_size=40, num_hidden_layers=3,
             num_hidden_layers_total=3, num_attention_heads=2, num_attention_heads_total=4,
             head_dim=16, window_size=32, chunk_size=4, num_pred_heads=3, seq_len=80,
+        ),
+        train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
+        mesh=MeshConfig(),
+    )
+)
+
+
+# 11. A seventh family: Ouro (ouro: a looped language model, one stack of
+# sandwich-norm layers run four times over the same weights, the final norm
+# closing every pass, an exit gate, a loss that weighs the four passes'
+# cross-entropies by the gate's exit distribution), as ONE pipeline stage of 6
+# sees it: 8 consecutive layers of the 48 (all are alike), round which a row
+# passes four times; this chip also holds the embedding and the head over all
+# 49,152 rows. Every width, every head and every pass is the published one.
+# 612M parameters held; two packed rows of 4,096 tokens a step.
+_register(
+    Preset(
+        name="ouro-2.6b-stage8",
+        description="Ouro-2.6B: one pipeline stage of 6 (8/48 layers, all heads, the whole "
+        "vocabulary), four passes over the same weights, two 4k-token rows a step",
+        model=OuroConfig(num_hidden_layers=8, seq_len=4096),
+        train=TrainConfig(
+            batch_size=2, learning_rate=3e-4, compute_dtype="bfloat16", remat=True,
+        ),
+        mesh=MeshConfig(),
+    )
+)
+
+# 11b. The same family at a size the CPU holds: two layers run four times.
+_register(
+    Preset(
+        name="ouro-tiny",
+        description="Ouro LM, hidden 64, 2 layers x 4 passes, 4 heads of 16 — CPU drives",
+        model=OuroConfig(
+            hidden_size=64, intermediate_size=160, vocab_size=128, num_hidden_layers=2,
+            num_hidden_layers_total=2, num_attention_heads=4, num_key_value_heads=4,
+            head_dim=16, total_ut_steps=4, seq_len=80,
         ),
         train=TrainConfig(batch_size=2, learning_rate=3e-4, remat=True),
         mesh=MeshConfig(),

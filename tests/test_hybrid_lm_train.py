@@ -237,7 +237,8 @@ def test_the_language_model_presets_have_a_table_of_their_own():
                                "phi4-mini-flash-stage6vp8", "sambay-tiny",
                                "laguna-xs2-ep8vp8", "laguna-tiny",
                                "kimi-linear-ep32vp8", "kimi-linear-tiny",
-                               "evabyte-stage4tp4", "evabyte-tiny"}
+                               "evabyte-stage4tp4", "evabyte-tiny",
+                               "ouro-2.6b-stage8", "ouro-tiny"}
     assert not set(LM_PRESETS) & set(PRESETS)
     assert all(isinstance(p.model, GlomConfig) for p in PRESETS.values())
     full = get_preset("nemotron3-super-ep64tp8")

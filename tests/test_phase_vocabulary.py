@@ -24,6 +24,7 @@ from glom_tpu.tracing.spans import (
     LAGUNA_INNER_SCOPES,
     LM_DEVICE_PHASES,
     LM_KERNELS,
+    OURO_DEVICE_PHASES,
     PHASES,
     SAMBAY_DEVICE_PHASES,
     SCAN_KERNELS,
@@ -200,7 +201,7 @@ def test_compiled_step_carries_every_phase(builder):
 
 FAMILIES = {"hybrid_lm": LM_DEVICE_PHASES, "sambay": SAMBAY_DEVICE_PHASES,
             "laguna": LAGUNA_DEVICE_PHASES, "kimi_linear": KIMI_DEVICE_PHASES,
-            "evabyte": EVABYTE_DEVICE_PHASES}
+            "evabyte": EVABYTE_DEVICE_PHASES, "ouro": OURO_DEVICE_PHASES}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -275,6 +276,27 @@ def test_evabytes_scopes_and_counters_are_registered():
                                 "attn_key_blocks_summary", "eva_summary_keys", "lm_pred_heads",
                                 "swiglu_backward_staged")
     assert all(name.startswith("attn_") for name in LM_KERNELS)
+
+
+def test_ouros_scopes_and_counters_are_registered():
+    """`embed`, `dense_mlp` and `lm_head_loss` are the other families' on
+    purpose, and `full_attention` is SambaY's and Laguna's name for the causal
+    mask without a window (here the scores alone). The loop's own are five:
+    the layer's two projection scopes, the norms on the branches' outputs, the
+    norm that closes a pass and the exit gate; no family's scope is borrowed
+    for them. The records' counters are the loop's two, the kept forward's,
+    the key blocks', the exit distribution's two and the staged SwiGLU's."""
+    from glom_tpu.models import ouro
+
+    own = set(OURO_DEVICE_PHASES) - set(LAGUNA_DEVICE_PHASES)
+    assert own == {"ouro_in", "ouro_out", "sandwich_norm", "ut_close", "exit_gate"}
+    assert not own & (set(LM_DEVICE_PHASES) | set(SAMBAY_DEVICE_PHASES) | set(KIMI_DEVICE_PHASES)
+                      | set(EVABYTE_DEVICE_PHASES) | set(DEVICE_PHASES) | set(HOST_PHASES))
+    assert set(OURO_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES) == {
+        "embed", "full_attention", "dense_mlp", "lm_head_loss"}
+    assert ouro.COUNTERS == ("ut_steps", "layer_applications", "attn_forward_kept",
+                             "attn_key_blocks_full", "exit_entropy", "exit_mass_last",
+                             "swiglu_backward_staged")
 
 
 def test_every_op_of_lagunas_rotation_lies_under_rope_inside_an_attention_scope():
