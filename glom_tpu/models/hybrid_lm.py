@@ -49,7 +49,8 @@ ROW_TILE = 1024  # the experts' rows come in multiples of this
 RUNG_LOADS = (2,)  # the small row counts, in balanced loads (`row_rungs`)
 ROUTED_COUNTERS = ("moe_pairs_here", "moe_rows_computed", "moe_rows_full_share",
                    "moe_max_expert_load")
-COUNTERS = ROUTED_COUNTERS + ("attn_forward_kept",)
+STACK_COUNTERS = ROUTED_COUNTERS + ("attn_forward_kept",)  # what Laguna's and Kimi Linear's add to
+COUNTERS = STACK_COUNTERS + ("shared_backward_staged",)
 # What a recomputed layer keeps of its first forward pass beside its inputs (`run_stack`):
 # the `checkpoint_name`s of the attention kernel's output and log-sum-exp rows, and of the
 # delta rule's output and of the states entering its segments (`kimi_linear.kda_chunked`,
@@ -163,6 +164,16 @@ def relu2(x):
 def _mm(a, b):
     """a [..., k] @ b [k, n], float32 accumulation, in the operands' type."""
     return jnp.einsum("...k,kn->...n", a, b, preferred_element_type=jnp.float32)
+
+
+def _mm_back(d, b):
+    """`_mm`'s cotangent to a: d [..., n] @ b [k, n]^T."""
+    return jnp.einsum("...n,kn->...k", d, b, preferred_element_type=jnp.float32)
+
+
+def _mm_onto(a, d):
+    """`_mm`'s cotangent to b: a [..., k]^T d [..., n], summed over the rows."""
+    return jnp.einsum("...k,...n->kn", a, d, preferred_element_type=jnp.float32)
 
 
 # -------------------------------------------------------------------- Mamba-2
@@ -505,17 +516,69 @@ def merge_counters(counted) -> dict:
     return merged
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def relu2_mlp(u, w1, w2, dtype):
+    """`relu(u W1)^2 W2` in u's type: the up product leaves pre in float32, h
+    = relu2(pre) is rounded to u's type for the down product. The weights are
+    cast to `dtype` at each product.
+
+    Its derivative is its own, of the shape of `laguna.swiglu`'s. Kept: u,
+    the weights and pre in float32, which is what autodiff kept (under
+    `run_stack`'s recomputation the up product runs again and the down
+    product falls out). The backward holds every operand of its four products
+    as an ARRAY in u's type, behind `lax.optimization_barrier`s: dy as it
+    arrives; then dh = dy W2^T, rounded as autodiff rounded it, and ONE
+    float32 elementwise pass over pre and dh that writes h and dpre = dh * 2
+    relu(pre). dW2 = h^T dy, du = dpre W1^T and dW1 = u^T dpre read those.
+    Without the barrier the compiler fuses relu2, its derivative and the cast
+    into each of the three as its operand's producer and evaluates them from
+    float32 pre once for every output tile that crosses a row (PERF.md
+    section 7, trap 20). A default-precision product rounds a float32 operand
+    to bfloat16 on the chip anyway, so the products multiply what they
+    multiplied; in float32 nothing is rounded and the gradients are
+    autodiff's."""
+    return _relu2_mlp_fwd(u, w1, w2, dtype)[0]
+
+
+def _relu2_mlp_fwd(u, w1, w2, dtype):
+    pre = _mm(u, _cast(w1, dtype))
+    out = _mm(relu2(pre).astype(u.dtype), _cast(w2, dtype)).astype(u.dtype)
+    return out, (u, w1, w2, pre)
+
+
+def _relu2_mlp_bwd(dtype, kept, dy):
+    u, w1, w2, pre = kept
+    dy = jax.lax.optimization_barrier(dy)
+    dh = _mm_back(dy, _cast(w2, dtype)).astype(u.dtype)
+    h, pull = jax.vjp(relu2, pre)
+    staged = (h, *pull(dh.astype(jnp.float32)))
+    h, dpre = jax.lax.optimization_barrier(tuple(a.astype(u.dtype) for a in staged))
+    return (_mm_back(dpre, _cast(w1, dtype)).astype(u.dtype), _mm_onto(u, dpre).astype(w1.dtype),
+            _mm_onto(h, dy).astype(w2.dtype))
+
+
+relu2_mlp.defvjp(_relu2_mlp_fwd, _relu2_mlp_bwd)
+
+
 def moe_shared(p, u2, dtype):
     with jax.named_scope("moe_shared"):
-        h = relu2(_mm(u2, _cast(p["s1"], dtype))).astype(u2.dtype)
-        return _mm(h, _cast(p["s2"], dtype)).astype(u2.dtype)
+        return relu2_mlp(u2, p["s1"], p["s2"], dtype)
+
+
+def shared_backward_staged(counted):
+    """The records' `shared_backward_staged`: the `E` layers of the step,
+    each of whose shared expert reads staged operands in its backward
+    (`relu2_mlp`). `counted` is one dict a layer, holding `relu2_mlp_calls`
+    where the layer called it."""
+    return jnp.float32(sum(c.get("relu2_mlp_calls", 0) for c in counted))
 
 
 def moe_mixer(p, x_in, cfg: HybridLMConfig, dtype):
     with jax.named_scope("moe_router"):
         u2 = rms_norm(x_in, p["norm"], cfg.layer_norm_epsilon).reshape(-1, x_in.shape[-1])
     routed, counters, top_i = moe_routed(p, u2, cfg, dtype)
-    return (routed + moe_shared(p, u2, dtype)).reshape(x_in.shape), counters, top_i
+    out = (routed + moe_shared(p, u2, dtype)).reshape(x_in.shape)
+    return out, {**counters, "relu2_mlp_calls": 1}, top_i
 
 
 # ------------------------------------------------------------------ the stack
@@ -699,7 +762,8 @@ def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
     (`next_token_loss`). Returns (loss, counters): pairs routed to the
     experts held, rows of the rung the grouped product ran at and whether
     that was the full one, each the mean over the `E` layers, the fullest
-    expert's load over all of them, and `forward_kept`."""
+    expert's load over all of them, `forward_kept` and
+    `shared_backward_staged`."""
     x, counted, _ = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = rms_norm(x, params["final_norm"], cfg.layer_norm_epsilon).reshape(
@@ -708,6 +772,7 @@ def lm_loss(params: Params, ids, cfg: HybridLMConfig, *, compute_dtype=None,
     with jax.named_scope("step_metrics"):
         counters = merge_counters(counted)
         counters["attn_forward_kept"] = forward_kept(counted, remat)
+        counters["shared_backward_staged"] = shared_backward_staged(counted)
     return loss, counters
 
 

@@ -63,8 +63,9 @@ from glom_tpu.models.hybrid_lm import (
 from glom_tpu.models.laguna import swiglu, swiglu_backward_staged
 from glom_tpu.utils.config import KimiLinearConfig
 
-COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_full", "kda_chunks", "kda_log_decay_min",
-                                 "kda_forward_kept", "swiglu_backward_staged")
+COUNTERS = hybrid_lm.STACK_COUNTERS + ("attn_key_blocks_full", "kda_chunks",
+                                       "kda_log_decay_min", "kda_forward_kept",
+                                       "swiglu_backward_staged")
 # The sub-chunk and the segment are the fastest of those tried on a v5e at the benchmark's
 # size (16,384 positions, 32 heads of 128: 122-133 ms a layer forward, recomputed and
 # backward at segments of 1-4 chunks and sub-chunks of 8; 174 at sub-chunks of 4, 148 at 16;

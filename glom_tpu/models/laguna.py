@@ -43,6 +43,8 @@ from glom_tpu.models import hybrid_lm
 from glom_tpu.models.hybrid_lm import (
     _cast,
     _mm,
+    _mm_back,
+    _mm_onto,
     blocked_attention,
     count_shapes,
     init_tree,
@@ -52,8 +54,8 @@ from glom_tpu.models.hybrid_lm import (
 )
 from glom_tpu.utils.config import LagunaConfig
 
-COUNTERS = hybrid_lm.COUNTERS + ("attn_key_blocks_window", "attn_key_blocks_full",
-                                 "swiglu_backward_staged")
+COUNTERS = hybrid_lm.STACK_COUNTERS + ("attn_key_blocks_window", "attn_key_blocks_full",
+                                       "swiglu_backward_staged")
 ATTENTION_SCOPE = {"S": "window_attention", "F": "full_attention"}
 
 
@@ -255,17 +257,15 @@ def _swiglu_fwd(u, w_gate, w_up, w_down, dtype):
 
 def _swiglu_bwd(dtype, kept, dy):
     u, w_gate, w_up, w_down, gate, up = kept
-    back = lambda d, w: jnp.einsum("...n,kn->...k", d, _cast(w, dtype),
-                                   preferred_element_type=jnp.float32)
-    onto = lambda a, d: jnp.einsum("...k,...n->kn", a, d, preferred_element_type=jnp.float32)
+    back = lambda d, w: _mm_back(d, _cast(w, dtype))
     dy = jax.lax.optimization_barrier(dy)
     dh = back(dy, w_down).astype(u.dtype)
     h, pull = jax.vjp(_gated, gate, up)
     staged = (h, *pull(dh.astype(jnp.float32)))
     h, dgate, dup = jax.lax.optimization_barrier(tuple(a.astype(u.dtype) for a in staged))
     du = (back(dgate, w_gate) + back(dup, w_up)).astype(u.dtype)
-    return (du, onto(u, dgate).astype(w_gate.dtype), onto(u, dup).astype(w_up.dtype),
-            onto(h, dy).astype(w_down.dtype))
+    return (du, _mm_onto(u, dgate).astype(w_gate.dtype), _mm_onto(u, dup).astype(w_up.dtype),
+            _mm_onto(h, dy).astype(w_down.dtype))
 
 
 swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)

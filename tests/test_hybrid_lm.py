@@ -29,6 +29,7 @@ from benchmark.reference import nemotron_h_ref as ref
 from glom_tpu.models import hybrid_lm as lm
 from glom_tpu.utils.config import HybridLMConfig
 from glom_tpu.utils.presets import get_preset
+from tests.test_swiglu import eqns_of
 
 CFG = get_preset("hybrid-lm-tiny").model
 MODEL = dataclasses.asdict(CFG)
@@ -201,6 +202,129 @@ def test_the_chunked_scans_gradients_are_the_recurrences():
 def test_the_chunk_size_changes_nothing():
     x, dt, a, b, c = scan_inputs(48, seed=2)
     assert rel(lm.ssd_chunked(x, dt, a, b, c, 16), lm.ssd_chunked(x, dt, a, b, c, 4)) < 1e-5
+
+
+# -------------------------------------------- the shared expert's backward rule
+
+
+KEEP = jax.checkpoint_policies.save_only_these_names(*lm.KEPT_NAMES)  # run_stack's
+SHARED_ROWS, (SHARED_D, SHARED_F) = 96, (64, 84)  # the cell's 8,192 x 4,096 x 5,376, cut
+# bfloat16, a gradient against the float64 gradient of the same rounded inputs: a rounding
+# to bfloat16 is at most 2^-9 of each element. Autodiff of the plain expression rounds u,
+# the weights, dh and h; the rule rounds dpre as well, ONE more cast (which the chip's
+# default precision does to the plain form's float32 operand too, and the CPU's does not).
+# 2^-8 holds both, and the rule may stand over the plain form's own error by that one
+# cast, 2^-9, and no further.
+SHARED_BF16_TOL, ONE_CAST = 2.0 ** -8, 2.0 ** -9
+
+
+def plain_shared(p, u2, dtype):
+    """`moe_shared` as it stood before it had a backward rule of its own."""
+    h = lm.relu2(lm._mm(u2, lm._cast(p["s1"], dtype))).astype(u2.dtype)
+    return lm._mm(h, lm._cast(p["s2"], dtype)).astype(u2.dtype)
+
+
+def shared_inputs(dtype, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p = {"s1": jax.random.normal(k[0], (SHARED_D, SHARED_F)) * SHARED_D ** -0.5,
+         "s2": jax.random.normal(k[1], (SHARED_F, SHARED_D)) * SHARED_F ** -0.5}
+    u2 = jax.random.normal(k[2], (SHARED_ROWS, SHARED_D)).astype(dtype or jnp.float32)
+    return p, u2, jax.random.normal(k[3], (SHARED_ROWS, SHARED_D)).astype(u2.dtype)
+
+
+def shared_grads(fn, p, u2, dy, dtype, remat=False):
+    call = lambda p, u2: fn(p, u2, dtype)
+    if remat:
+        call = jax.checkpoint(call, policy=KEEP)
+    return jax.jit(jax.grad(lambda p, u2: jnp.sum((call(p, u2) * dy).astype(jnp.float32)),
+                            argnums=(0, 1)))(p, u2)
+
+
+def shared_float64_grads(p, u2, dy, dtype):
+    """The gradient of sum(out * dy) in float64, from the inputs as the
+    products see them (u2 as it is, the weights cast to `dtype`)."""
+    as64 = lambda a: np.asarray(a.astype(jnp.float32), np.float64)
+    w1, w2 = as64(lm._cast(p["s1"], dtype)), as64(lm._cast(p["s2"], dtype))
+    u, dy = as64(u2), as64(dy)
+    pre = u @ w1
+    dpre = (dy @ w2.T) * 2.0 * np.maximum(pre, 0.0)
+    return {"s1": u.T @ dpre, "s2": np.square(np.maximum(pre, 0.0)).T @ dy}, dpre @ w1.T
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["alone", "recomputed"])
+@pytest.mark.parametrize("dtype", [None, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_shared_experts_rule_is_autodiff_of_the_plain_expression(dtype, remat):
+    """The forward bit for bit in both types, jitted or under differentiation
+    (where the forward rule runs). float32: nothing is rounded, the rule's
+    three products are autodiff's, and every gradient is autodiff's bit for
+    bit. bfloat16: each gradient within 2^-8 of the float64 one and no more
+    than one cast (2^-9) over the plain form's own error."""
+    p, u2, dy = shared_inputs(dtype)
+    theirs = plain_shared(p, u2, dtype)
+    for ours in (jax.jit(lm.moe_shared, static_argnums=2)(p, u2, dtype),
+                 jax.vjp(lambda p, u2: lm.moe_shared(p, u2, dtype), p, u2)[0]):
+        assert ours.dtype == theirs.dtype == u2.dtype
+        assert np.array_equal(np.asarray(ours, np.float32), np.asarray(theirs, np.float32))
+    (gp, gu), (wp, wu) = (shared_grads(lm.moe_shared, p, u2, dy, dtype, remat),
+                          shared_grads(plain_shared, p, u2, dy, dtype))
+    ep, eu = shared_float64_grads(p, u2, dy, dtype)
+    for a, b, x, e in ((gp["s1"], wp["s1"], p["s1"], ep["s1"]),
+                       (gp["s2"], wp["s2"], p["s2"], ep["s2"]), (gu, wu, u2, eu)):
+        assert a.dtype == b.dtype == x.dtype and a.shape == x.shape
+        if dtype is None:
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+            continue
+        if a.dtype == jnp.bfloat16:     # du2's own rounding on the way out, in both
+            e = np.asarray(jnp.asarray(e, jnp.float32).astype(jnp.bfloat16), np.float64)
+        err = lambda g: rel(np.asarray(g, np.float32), e)
+        assert err(a) < SHARED_BF16_TOL and err(a) < err(b) + ONE_CAST
+
+
+def test_an_expert_layers_backward_reads_staged_operands(weights):
+    """The backward of one `E` layer, recomputed as `run_stack` recomputes it,
+    in bfloat16: two barriers, dy [rows, d] as it arrives and then h and dpre
+    [rows, fs] in the compute type, whose inputs are roundings (no product's
+    result) and whose outputs the three products after dh's read and nothing
+    else does. Every product with an operand of the shared expert's width
+    (the recomputed up product, dh's and those three) takes two bfloat16
+    operands: none reads a float32 array of that width."""
+    w = ref.layer_weights(weights, first_layer("E"))
+    x = stream().astype(jnp.bfloat16)
+    fs, rows = CFG.moe_shared_expert_intermediate_size, x.shape[0] * x.shape[1]
+    held = jax.checkpoint(lambda w, x: lm.layer("E", w, x, CFG, jnp.bfloat16)[0], policy=KEEP)
+    _, pull = jax.vjp(held, w, x)
+    eqns = list(eqns_of(jax.make_jaxpr(pull)(x).jaxpr))
+    shape = lambda v: getattr(v.aval, "shape", ())
+    wide = [e for e in eqns if e.primitive.name == "dot_general"
+            and any(fs in shape(v) for v in e.invars)]
+    assert len(wide) == 5
+    for e in wide:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16, jnp.bfloat16]
+        assert e.params["preferred_element_type"] == jnp.float32
+    first, barrier = [e for e in eqns if e.primitive.name == "optimization_barrier"]
+    kind = lambda n: [((rows, n), jnp.bfloat16)]
+    assert [(shape(v), v.aval.dtype) for v in first.outvars] == kind(CFG.hidden_size)
+    assert [(shape(v), v.aval.dtype) for v in barrier.outvars] == kind(fs) * 2
+    made_by = {id(v): e.primitive.name for e in eqns for v in e.outvars}
+    assert all(made_by[id(v)] == "convert_element_type" for v in barrier.invars)
+    reads = lambda e: [sum(v is s for v in e.invars) for s in barrier.outvars]
+    readers = [e for e in eqns if any(reads(e))]
+    assert len(readers) == 3 and all(e in wide for e in readers)
+    # h feeds W2's gradient; dpre W1's gradient and du2
+    assert [sum(reads(e)[i] for e in readers) for i in range(2)] == [1, 2]
+    before = [e for e in wide if eqns.index(e) < eqns.index(barrier)]
+    assert len(before) == 2 and not any(e in readers for e in before)
+
+
+def test_the_counter_counts_the_expert_layers(weights, ids):
+    """`shared_backward_staged`: the `E` layers, whether recomputed or not
+    (the rule is the function's)."""
+    params = weights_lm.to_program_params(weights)
+    for remat in (False, True):
+        counters = jax.jit(lambda p: lm.lm_loss(p, ids, CFG, remat=remat)[1])(params)
+        assert float(counters["shared_backward_staged"]) == CFG.pattern.count("E") == 2
+    assert float(lm.shared_backward_staged([{}, {"attn_on_kernels": 1}])) == 0
+    assert float(lm.shared_backward_staged([{"relu2_mlp_calls": 1}, {}])) == 1
 
 
 # ------------------------------------------------------------------ the shares

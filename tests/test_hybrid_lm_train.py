@@ -193,6 +193,33 @@ def test_the_logging_records_carry_the_routing_counters(fitted, tiny):
         assert r["attn_forward_kept"] == 0     # the XLA loop names nothing for the recomputation
 
 
+def test_the_logging_records_count_the_staged_shared_experts(fitted, tiny):
+    """`shared_backward_staged`: the `E` layers of the step, each of whose
+    shared expert's backward reads staged operands (`hybrid_lm.relu2_mlp`)."""
+    _, history, _ = fitted
+    assert len(history) == 3
+    assert all(r["shared_backward_staged"] == tiny[0].pattern.count("E") == 2 for r in history)
+
+
+@pytest.mark.parametrize("preset", ["laguna-tiny", "kimi-linear-tiny"])
+def test_the_counter_does_not_reach_the_families_that_run_swiglu(preset):
+    """Laguna's and Kimi Linear's counters are built from
+    `hybrid_lm.STACK_COUNTERS`, and their shared expert is `laguna.swiglu`
+    under their own `moe_shared` scope: what their objective hands the
+    trainer for a record (the loss's aux) does not carry the name."""
+    from glom_tpu.models import hybrid_lm, kimi_linear, laguna
+    from glom_tpu.train.objectives import init_params
+
+    p = get_preset(preset)
+    family = {"laguna-tiny": laguna, "kimi-linear-tiny": kimi_linear}[preset]
+    objective = objective_for(p.model, p.train)
+    params = jax.eval_shape(lambda k: init_params(k, p.model), jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((p.train.batch_size, p.model.seq_len), jnp.int32)
+    _, aux = jax.eval_shape(lambda w, i: objective.loss(w, i, ()), params, ids)
+    assert set(aux) == set(family.COUNTERS) >= set(hybrid_lm.STACK_COUNTERS)
+    assert "shared_backward_staged" not in aux
+
+
 def test_the_records_pass_the_telemetry_schema(fitted):
     from glom_tpu.telemetry import schema
 

@@ -201,6 +201,7 @@ def test_the_cli_trains_the_tiny_preset_by_the_same_command(tmp_path):
     steps = [r for r in recs if r.get("kind") == "train_step"]
     assert len(steps) == 2 and all(r["vjp_path"] == "lm_xla" for r in steps)
     assert all(set(kimi_linear.COUNTERS) <= set(r) for r in steps)
+    assert not any("shared_backward_staged" in r for r in steps)   # `hybrid_lm.relu2_mlp`'s
 
 
 @pytest.mark.parametrize("flag", [["--distributed"], ["--check-parity"], ["--data-dir", "x"]])

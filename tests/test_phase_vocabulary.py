@@ -229,8 +229,10 @@ def test_lagunas_scopes_counters_and_inner_scopes_are_registered():
     assert LAGUNA_INNER_SCOPES == ("rope", "attn_gate")
     assert set(laguna.ATTENTION_SCOPE.values()) == {"window_attention", "full_attention"} <= (
         set(sambay.ATTENTION_SCOPE.values()))
-    assert laguna.COUNTERS == hybrid_lm.COUNTERS + (
+    assert laguna.COUNTERS == hybrid_lm.STACK_COUNTERS + (
         "attn_key_blocks_window", "attn_key_blocks_full", "swiglu_backward_staged")
+    # the relu2 shared expert's counter is the first family's alone
+    assert hybrid_lm.COUNTERS == hybrid_lm.STACK_COUNTERS + ("shared_backward_staged",)
     assert set(laguna.COUNTERS[-3:-1]) <= set(sambay.COUNTERS)
     # `laguna.swiglu` is the three families' that call it; the two others run no SwiGLU of it
     assert "swiglu_backward_staged" not in hybrid_lm.COUNTERS + sambay.COUNTERS
@@ -251,7 +253,7 @@ def test_kimi_linears_scopes_and_counters_are_registered():
     assert not own & (set(SAMBAY_DEVICE_PHASES) | set(DEVICE_PHASES) | set(HOST_PHASES))
     assert {"moe_router", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"} <= (
         set(KIMI_DEVICE_PHASES) & set(LM_DEVICE_PHASES) & set(LAGUNA_DEVICE_PHASES))
-    assert kimi_linear.COUNTERS == hybrid_lm.COUNTERS + (
+    assert kimi_linear.COUNTERS == hybrid_lm.STACK_COUNTERS + (
         "attn_key_blocks_full", "kda_chunks", "kda_log_decay_min", "kda_forward_kept",
         "swiglu_backward_staged")
 
