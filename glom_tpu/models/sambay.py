@@ -44,6 +44,8 @@ from glom_tpu.models.hybrid_lm import init_leaf as hybrid_init_leaf
 from glom_tpu.models.hybrid_lm import (
     _cast,
     _mm,
+    _mm_back,
+    _mm_onto,
     blocked_attention,
     causal_conv,
     count_shapes,
@@ -56,7 +58,7 @@ from glom_tpu.models.hybrid_lm import (
 from glom_tpu.utils.config import SambaYConfig
 
 COUNTERS = ("attn_key_blocks_window", "attn_key_blocks_full", "scan_chunks",
-            "attn_forward_kept", "scan_on_kernels")
+            "attn_forward_kept", "scan_on_kernels", "mlp_backward_staged")
 # The selective scan's schedule in its XLA form alone (the kernels' blocks are
 # `kernels/selective_scan.blocks`): positions a carried state (a chunk,
 # recomputed whole in the backward pass), and positions a segment (a chunk's
@@ -150,11 +152,66 @@ def lambda_init(index: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * index)
 
 
+def _gated(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def gated_mlp(u, w_gate_up, w_down, dtype):
+    """`(silu(gate) * up) W_down` in u's type, `[gate, up] = u W_gate_up`
+    rounded to u's type as it leaves the one joined product, h = silu(gate) *
+    up in u's type. The weights are cast to `dtype` at each product.
+
+    Its derivative is its own, of the shape of `laguna.swiglu`'s and
+    `hybrid_lm.relu2_mlp`'s. Kept: u, the weights and gate_up in u's type,
+    which is what autodiff kept (under `run_stack`'s recomputation the joined
+    product runs again and the down product falls out). The backward holds
+    the operands of its products as ARRAYS in u's type, behind
+    `lax.optimization_barrier`s: dy as it arrives; then dh = dy W_down^T,
+    rounded as autodiff rounded it, and ONE elementwise pass over gate_up and
+    dh that writes h, dgate and dup. dW_down = h^T dy, du = [dgate, dup]
+    W_gate_up^T and dW_gate_up = u^T [dgate, dup] read those. Without the
+    barrier the compiler fuses `silu(gate) * up` into dW_down's product as its
+    operand's producer and evaluates it from gate_up once for every output
+    tile that crosses a row (PERF.md section 7, trap 20). The pass is
+    autodiff's own arithmetic in u's type, so no operand is rounded that was
+    not, and the gradients are autodiff's to a product's summation order."""
+    return _gated_mlp_fwd(u, w_gate_up, w_down, dtype)[0]
+
+
+def _gated_mlp_fwd(u, w_gate_up, w_down, dtype):
+    gate_up = _mm(u, _cast(w_gate_up, dtype)).astype(u.dtype)
+    out = _mm(_gated(*jnp.split(gate_up, 2, axis=-1)), _cast(w_down, dtype)).astype(u.dtype)
+    return out, (u, w_gate_up, w_down, gate_up)
+
+
+def _gated_mlp_bwd(dtype, kept, dy):
+    u, w_gate_up, w_down, gate_up = kept
+    dy = jax.lax.optimization_barrier(dy)
+    dh = _mm_back(dy, _cast(w_down, dtype)).astype(u.dtype)
+    h, pull = jax.vjp(_gated, *jnp.split(gate_up, 2, axis=-1))
+    h, dgate, dup = jax.lax.optimization_barrier((h, *pull(dh)))
+    # joined after the barrier: the products fold the join into their operand,
+    # where a joined array behind the barrier is a pass of its own
+    d_gate_up = jnp.concatenate([dgate, dup], axis=-1)
+    return (_mm_back(d_gate_up, _cast(w_gate_up, dtype)).astype(u.dtype),
+            _mm_onto(u, d_gate_up).astype(w_gate_up.dtype), _mm_onto(h, dy).astype(w_down.dtype))
+
+
+gated_mlp.defvjp(_gated_mlp_fwd, _gated_mlp_bwd)
+
+
+def mlp_backward_staged(counted):
+    """The records' `mlp_backward_staged`: the layers of the step, each of
+    whose MLP reads staged operands in its backward (`gated_mlp`). `counted`
+    is one dict a layer, holding `gated_mlp_calls` where the layer called it."""
+    return jnp.float32(sum(c.get("gated_mlp_calls", 0) for c in counted))
+
+
 def mlp(p, x_in, cfg: SambaYConfig, dtype):
     with jax.named_scope("mlp"):
         u = layer_norm(x_in, p["norm2_w"], p["norm2_b"], cfg.layer_norm_eps)
-        gate, up = jnp.split(_mm(u, _cast(p["gate_up"], dtype)).astype(u.dtype), 2, axis=-1)
-        return _mm(jax.nn.silu(gate) * up, _cast(p["down"], dtype)).astype(u.dtype)
+        return gated_mlp(u, p["gate_up"], p["down"], dtype)
 
 
 # -------------------------------------------------------------------- Mamba-1
@@ -337,7 +394,7 @@ def layer(kind: str, index: int, p, x, side, cfg: SambaYConfig, dtype):
     (x, side, the layer's counters)."""
     memory, shared_kv = side
     n = cfg.num_hidden_layers_total
-    counters = {}
+    counters = {"gated_mlp_calls": 1}
     if kind == "M":
         out, y, chunks, on_kernels = mamba_mixer(p, x, cfg, dtype)
         counters.update(scan_chunks=chunks, scan_on_kernels=on_kernels)
@@ -371,8 +428,8 @@ def lm_loss(params, ids, cfg: SambaYConfig, *, compute_dtype=None,
     tied embedding (`hybrid_lm.next_token_loss`). Returns (loss, counters):
     the key blocks the window layers and the full-length layers (`F`, `X`)
     multiplied this step, the chunks of the recurrence a Mamba layer ran, the
-    Mamba layers whose recurrence ran the kernels, and
-    `hybrid_lm.forward_kept`."""
+    Mamba layers whose recurrence ran the kernels, `hybrid_lm.forward_kept`
+    and `mlp_backward_staged`."""
     x, counted = hidden_states(params, ids, cfg, compute_dtype=compute_dtype, remat=remat)
     with jax.named_scope("lm_head_loss"):
         h = layer_norm(x, params["final_norm_w"], params["final_norm_b"],
@@ -384,5 +441,6 @@ def lm_loss(params, ids, cfg: SambaYConfig, *, compute_dtype=None,
                     "attn_key_blocks_full": jnp.sum(of("attn_key_blocks_full")),
                     "scan_chunks": jnp.max(of("scan_chunks")),
                     "scan_on_kernels": jnp.sum(of("scan_on_kernels")),
-                    "attn_forward_kept": forward_kept(counted, remat)}
+                    "attn_forward_kept": forward_kept(counted, remat),
+                    "mlp_backward_staged": mlp_backward_staged(counted)}
     return loss, counters

@@ -86,6 +86,7 @@ def test_the_records_carry_the_step_counters(fitted, tiny):
         assert r["attn_key_blocks_window"] == n_window and r["attn_key_blocks_full"] == n_full
         assert r["scan_chunks"] == 1                                # 80 positions: one chunk
         assert r["attn_forward_kept"] == 0     # the XLA loop names nothing for the recomputation
+        assert r["mlp_backward_staged"] == cfg.num_hidden_layers   # an MLP a layer, each on the rule
     assert set(sambay.COUNTERS) <= set(history[-1])
     from glom_tpu.telemetry import schema
 
